@@ -106,6 +106,11 @@ def main(argv=None):
                         "failed dispatch crashes a sacrificial "
                         "engine into its postmortem")
     args = p.parse_args(argv)
+    # one compile-cache policy for every entry point (configuration
+    # only: opens no device)
+    from bigdl_tpu.utils.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
     if args.chaos:
         return _chaos_demo(args)
     if args.fleet and args.fleet > 1:
@@ -117,8 +122,8 @@ def main(argv=None):
     if (args.tp and args.tp > 1 and argv is None
             and "xla_force_host_platform_device_count"
             not in os.environ.get("XLA_FLAGS", "")):
-        # XLA reads this flag at backend creation, which importing the
-        # package has ALREADY triggered — too late to set in-process.
+        # XLA reads this flag at backend creation, which this process
+        # may already be past — too late to set in-process.
         # Command-line runs re-exec themselves with the flag so a CPU
         # host gets its N virtual devices; programmatic callers set
         # XLA_FLAGS (or bring a real multi-device backend) themselves.

@@ -6,9 +6,11 @@ absorbed by XLA; what remains native here is (a) the CRC32C/TFRecord codec
 utils/tf/TFRecordIterator.scala) and (b) the multithreaded IO staging
 reader (≙ the Engine "io" thread pool feeding input pipelines).
 
-The shared library is built on demand from ``native/`` with g++; every
-entry point has a pure-Python fallback so the framework degrades gracefully
-where no toolchain exists.
+The shared library is built on demand from ``native/`` with g++. In a
+checkout ``make`` decides: it rebuilds a library older than its sources, and
+where it fails the library counts as absent — a stale one is never loaded.
+Every entry point has a pure-Python implementation of the same semantics for
+hosts with no toolchain; ``native_available()`` says which one is in use.
 """
 
 from __future__ import annotations
@@ -27,14 +29,18 @@ _tried = False
 
 
 def _build() -> bool:
+    """Bring the library up to date with its sources. In a checkout
+    (``native/Makefile`` present) that is ``make``'s verdict; an
+    installed package has no sources and ships the library its wheel
+    built."""
     makefile = os.path.join(_REPO, "native", "Makefile")
     if not os.path.exists(makefile):
-        return False
+        return os.path.exists(os.path.join(_HERE, _LIB_NAME))
     try:
         subprocess.run(["make", "-C", os.path.join(_REPO, "native")],
                        check=True, capture_output=True, timeout=120)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError):
         return False
 
 
@@ -46,11 +52,10 @@ def get_lib():
             return _lib
         _tried = True
         path = os.path.join(_HERE, _LIB_NAME)
-        # always offer make a chance: it is a no-op when the .so is newer
-        # than the sources, and it rebuilds a stale .so that predates a
-        # newly added entry point (the load below would otherwise bind a
-        # library missing symbols)
-        if not _build() and not os.path.exists(path):
+        # make is a no-op when the .so is newer than the sources and
+        # rebuilds one that is not; where it cannot, the library is
+        # absent — never a stale one bound under new Python code
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(path)

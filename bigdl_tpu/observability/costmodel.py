@@ -6,18 +6,23 @@ it how much *work* each dispatch performed, so the two together answer
 the ROADMAP's "as fast as the hardware allows" question with numbers:
 
 * :func:`program_cost` extracts FLOPs and bytes-accessed for one
-  compiled program from XLA itself, via
+  program from XLA itself, via
   ``jitted.lower(*args).cost_analysis()``.  Lowering only traces — it
   never compiles, executes, or donates, so the extraction adds **zero**
   device programs and leaves the jit-compile gauge flat.
-* When XLA reports nothing (some backends return empty/None), callers
-  fall back to the analytic transformer formulas on
+* A lowering that targets a TPU reports nothing (``None``): there only
+  the compiled executable knows its FLOPs. :func:`executable_cost`
+  reads them from an executable the caller compiled anyway
+  (``models.perf`` runs its step ahead-of-time for this); callers with
+  no executable in hand use the analytic transformer formulas on
   :class:`bigdl_tpu.models.transformer.TransformerLM`
   (``analytic_flops`` / ``analytic_bytes``, params x tokens with an
-  attention term, spec-aware through the verify path).
+  attention term, spec-aware through the verify path) and say so
+  (``flops_source: "analytic"``).
 * :func:`device_peaks` maps the local device kind to peak FLOP/s and
   peak HBM bytes/s (env-overridable: ``BIGDL_PEAK_FLOPS``,
-  ``BIGDL_PEAK_HBM_GBPS``).
+  ``BIGDL_PEAK_HBM_GBPS``). A device kind that is not in the table is
+  an error, not a default.
 * :class:`DispatchCostModel` folds per-kind program costs together with
   the warm dispatch walls the engine feeds it into achieved FLOP/s,
   achieved bytes/s, arithmetic intensity, a compute-vs-memory-bound
@@ -43,17 +48,18 @@ import time
 from typing import Dict, Optional
 
 __all__ = [
-    "PEAK_TABLE", "DEFAULT_PEAKS", "ENV_PEAK_FLOPS", "ENV_PEAK_HBM_GBPS",
-    "device_peaks", "peak_flops", "program_cost",
+    "PEAK_TABLE", "ENV_PEAK_FLOPS", "ENV_PEAK_HBM_GBPS",
+    "device_peaks", "peak_flops", "program_cost", "executable_cost",
     "DispatchCostModel", "LoopPhaseAccumulator",
 ]
 
 #: Per-device-kind peaks: substring of ``device_kind`` (lowercased) ->
 #: (peak FLOP/s at bf16, peak HBM bytes/s).  Matched longest-substring
 #: first so "TPU v5 lite" wins over "TPU v5".  TPU figures are the
-#: published bf16 peak and HBM bandwidth per chip; the cpu entry is the
-#: same deliberately conservative figure bench.py has always used for
-#: its CPU-fallback MFU denominator.
+#: published bf16 peak and HBM bandwidth per chip; the cpu entry is a
+#: deliberately conservative figure that keeps the engine's cost block
+#: well-defined under the CPU test suite (ROADMAP Design 7 decides its
+#: fate with the benchmark).
 PEAK_TABLE: Dict[str, tuple] = {
     "tpu v6 lite": (918e12, 1.64e12),
     "tpu v6e": (918e12, 1.64e12),
@@ -63,9 +69,6 @@ PEAK_TABLE: Dict[str, tuple] = {
     "tpu v4": (275e12, 1.23e12),
     "cpu": (5e11, 5e10),
 }
-
-#: Fallback when the device kind matches nothing in the table.
-DEFAULT_PEAKS = (5e11, 5e10)
 
 #: Env override for peak FLOP/s (a plain float, e.g. ``197e12``).
 ENV_PEAK_FLOPS = "BIGDL_PEAK_FLOPS"
@@ -84,19 +87,20 @@ def device_peaks(device=None) -> dict:
     device 0), with env overrides applied.
 
     Returns ``{"device_kind", "flops_per_s", "hbm_bytes_per_s",
-    "source"}`` where ``source`` is ``"table"``, ``"default"``, or
-    ``"env"`` (when either override is set).
+    "source"}`` where ``source`` is ``"table"`` or ``"env"`` (when
+    either override is set). Raises ``LookupError`` for a device kind
+    the table does not know unless BOTH overrides are given: a
+    utilization against an invented peak is worse than none.
     """
     dev = device if device is not None else _local_device()
     kind = str(getattr(dev, "device_kind", None)
                or getattr(dev, "platform", "unknown"))
     low = kind.lower()
-    flops, bw = DEFAULT_PEAKS
-    source = "default"
+    flops = bw = None
+    source = "table"
     for sub in sorted(PEAK_TABLE, key=len, reverse=True):
         if sub in low:
             flops, bw = PEAK_TABLE[sub]
-            source = "table"
             break
     env_f = os.environ.get(ENV_PEAK_FLOPS)
     env_b = os.environ.get(ENV_PEAK_HBM_GBPS)
@@ -109,14 +113,29 @@ def device_peaks(device=None) -> dict:
             source = "env"
     except ValueError:
         pass
+    if flops is None or bw is None:
+        raise LookupError(
+            f"no peak FLOP/s / HBM bandwidth known for device kind "
+            f"{kind!r}: add it to costmodel.PEAK_TABLE with its source, "
+            f"or set {ENV_PEAK_FLOPS} and {ENV_PEAK_HBM_GBPS}")
     return {"device_kind": kind, "flops_per_s": float(flops),
             "hbm_bytes_per_s": float(bw), "source": source}
 
 
 def peak_flops(device=None) -> float:
-    """Peak FLOP/s only (bench.py's historical helper, now table+env
-    backed)."""
+    """Peak FLOP/s only (see :func:`device_peaks`)."""
     return device_peaks(device)["flops_per_s"]
+
+
+def _cost_dict(ca) -> Optional[dict]:
+    if not isinstance(ca, dict):
+        return None
+    flops = float(ca.get("flops", 0.0) or 0.0)
+    if flops <= 0.0:
+        return None
+    return {"flops": flops,
+            "bytes": float(ca.get("bytes accessed", 0.0) or 0.0),
+            "source": "xla"}
 
 
 def program_cost(jitted, *args, **kwargs) -> Optional[dict]:
@@ -127,22 +146,20 @@ def program_cost(jitted, *args, **kwargs) -> Optional[dict]:
     never compiles or runs it — no device program is created, donated
     buffers stay live, and the jit cache is untouched (the jit-compile
     gauge stays flat).  Returns ``{"flops", "bytes", "source": "xla"}``
-    or ``None`` when the backend reports nothing useful (callers then
-    use the analytic transformer fallback).
+    or ``None`` when the lowering reports nothing — which is what a
+    TPU-targeted lowering does (only the compiled executable is priced
+    there, see :func:`executable_cost`). A program that fails to lower
+    raises: that is a bug in the caller's arguments, not a missing
+    price.
     """
-    try:
-        ca = jitted.lower(*args, **kwargs).cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax: one dict per device
-            ca = ca[0] if ca else None
-        if not isinstance(ca, dict):
-            return None
-        flops = float(ca.get("flops", 0.0) or 0.0)
-        byts = float(ca.get("bytes accessed", 0.0) or 0.0)
-        if flops <= 0.0:
-            return None
-        return {"flops": flops, "bytes": byts, "source": "xla"}
-    except Exception:
-        return None
+    return _cost_dict(jitted.lower(*args, **kwargs).cost_analysis())
+
+
+def executable_cost(compiled) -> Optional[dict]:
+    """Same block as :func:`program_cost`, read from an executable the
+    caller already compiled (``jitted.lower(...).compile()``). This is
+    the count a TPU reports; it costs no compile of its own."""
+    return _cost_dict(compiled.cost_analysis())
 
 
 def _roofline(intensity: Optional[float], ridge: float) -> Optional[str]:

@@ -39,7 +39,7 @@ MAX_SECONDS = 60.0
 
 
 class ProfilerUnavailable(RuntimeError):
-    """This jax build/backend cannot capture a profile."""
+    """This backend cannot capture a profile."""
 
 
 class ProfilerBusy(RuntimeError):
@@ -49,18 +49,6 @@ class ProfilerBusy(RuntimeError):
 _LOCK = threading.Lock()       # held for the whole capture
 _STATE = threading.Lock()      # guards the _active_dir transition only
 _active_dir: Optional[str] = None
-
-
-def available() -> bool:
-    """Whether this jax build exposes the trace API at all (a True here
-    does not guarantee the backend can capture — ``start_capture``
-    still fails cleanly if it cannot)."""
-    try:
-        import jax.profiler as jp
-        return callable(getattr(jp, "start_trace", None)) and \
-            callable(getattr(jp, "stop_trace", None))
-    except Exception:
-        return False
 
 
 def start_capture(out_dir: Optional[str] = None) -> str:
@@ -74,14 +62,8 @@ def start_capture(out_dir: Optional[str] = None) -> str:
             "a profiler capture is already in flight (the jax profiler "
             "is process-global); retry after it finishes")
     try:
-        try:
-            import jax.profiler as jp
-        except Exception as e:
-            raise ProfilerUnavailable(
-                f"jax.profiler is not importable here: {e!r}") from e
-        if not callable(getattr(jp, "start_trace", None)):
-            raise ProfilerUnavailable(
-                "this jax build has no jax.profiler.start_trace")
+        import jax.profiler as jp
+
         path = out_dir or tempfile.mkdtemp(prefix="bigdl_profile_")
         os.makedirs(path, exist_ok=True)
         try:

@@ -572,9 +572,7 @@ class LocalOptimizer(Optimizer):
                 ins.learning_rate.set(lr)
                 ins.grad_norm.set(float(gnorm))
                 ins.epoch.set(state["epoch"])
-                cache_size = getattr(train_step, "_cache_size", None)
-                if cache_size is not None:
-                    ins.jit_compiles.set(cache_size())
+                ins.jit_compiles.set(train_step._cache_size())
             logger.info(
                 "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
                 "Trained %d records in %.4f seconds. Throughput is %.1f records/second. "

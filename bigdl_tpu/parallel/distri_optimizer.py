@@ -555,9 +555,7 @@ class DistriOptimizer(LocalOptimizer):
                         ins.loss.set(loss_v)
                         ins.learning_rate.set(lr)
                         ins.epoch.set(state["epoch"])
-                        cache_size = getattr(step, "_cache_size", None)
-                        if cache_size is not None:
-                            ins.jit_compiles.set(cache_size())
+                        ins.jit_compiles.set(step._cache_size())
                         # per-host SPMD timings: the whole pipelined window,
                         # and its per-iteration average (the step-time proxy
                         # when dispatch overlaps host work)

@@ -68,6 +68,7 @@ changes HOW MANY target dispatches an output costs, never the output
 from __future__ import annotations
 
 import collections
+import logging
 import sys
 import threading
 import time
@@ -154,15 +155,6 @@ class _SlotState:
         self.last_token = last_token
         self.last_token_at = now
         self.delivered = 1
-
-
-def _compile_count(fn):
-    """Compiled-signature count of one jitted wrapper, or None when
-    this jax build lacks the private ``_cache_size`` probe."""
-    try:
-        return int(fn._cache_size())
-    except Exception:
-        return None
 
 
 class ContinuousBatchingEngine:
@@ -685,8 +677,8 @@ class ContinuousBatchingEngine:
         #: denominator — the registry counters are shared per label)
         self._spec_proposed = 0
         self._spec_accepted = 0
-        #: programs that have run at least once — the jit_compiles
-        #: fallback when jax's _cache_size probe is unavailable
+        #: programs that have run at least once (a first dispatch's
+        #: wall is mostly compile time and is charged as cold)
         self._warm = set()
         #: paged bookkeeping: last KV byte-second accrual stamp, the
         #: page-flow counter baselines behind the delta-published
@@ -1500,14 +1492,7 @@ class ContinuousBatchingEngine:
         if self.draft is not None:
             fns += [self._propose_jit, self._spec_verify_jit,
                     self._d_chunk_jit, self._d_sync_jit]
-        counts = [_compile_count(f) for f in fns]
-        if all(c is None for c in counts):
-            # _cache_size absent in this jax build: approximate with
-            # the warmed-program count (each program compiles exactly
-            # once — shapes are load-independent, which is exactly the
-            # flatness contract the gauge exists to expose)
-            return len(self._warm)
-        return sum(c or 0 for c in counts)
+        return sum(int(f._cache_size()) for f in fns)
 
     # --------------------------------------------------- dispatch costs
     def _cost_device(self):
@@ -1523,9 +1508,10 @@ class ContinuousBatchingEngine:
         over the kind's programs (prefill = target chunk [+ draft
         chunk]; decode = fused step, or propose + verify under
         speculation), lowered against the live buffers — tracing only,
-        zero compiles, zero executions.  Any program the backend will
-        not price drops the whole kind to the analytic transformer
-        formulas at a representative context of half the cache."""
+        zero compiles, zero executions.  Any program the lowering does
+        not price (every program, when the target is a TPU) drops the
+        whole kind to the analytic transformer formulas at a
+        representative context of half the cache, with a warning."""
         S, rows = self.max_slots, self._policy.prefill_rows
         c = self._policy.chunk
         zt = self._h2d(jnp.zeros((S,), jnp.int32))
@@ -1609,6 +1595,15 @@ class ContinuousBatchingEngine:
                     kind, sum(cst["flops"] for cst in costs),
                     sum(cst["bytes"] for cst in costs), "xla")
                 continue
+            # said out loud, once per engine: a TPU-targeted lowering
+            # prices nothing (only the executable does), so on the chip
+            # this is the source — never a silent change from "xla"
+            logging.getLogger(__name__).warning(
+                "engine %s: XLA priced no FLOPs for the lowered %s "
+                "program(s) on %s; flops_source is \"analytic\" "
+                "(TransformerLM.analytic_flops / analytic_bytes)",
+                self.service_name, kind,
+                self._cost.peaks["device_kind"])
             tokens, c_ctx = analytic[kind]
             flops = self.model.analytic_flops(tokens, c_ctx)
             byts = self.model.analytic_bytes(tokens, c_ctx,

@@ -171,6 +171,7 @@ def _leg(workload, n_replicas, engine_cfg, seed, policy, chunk, log,
                             fleet_name=f"bench-{label}")
     log(f"[fleet-bench] {label}: spawning {n_replicas} workers...")
     with sup:
+        res_device = replicas[0].device
         warm = np.arange(1, 9, dtype=np.int32)
         for rep in replicas:
             rep.submit(warm, 4).result(timeout=300)
@@ -210,6 +211,7 @@ def _leg(workload, n_replicas, engine_cfg, seed, policy, chunk, log,
         }
         if drain_at is not None:
             res["fleet"]["drained"] = victim
+    res["device"] = res_device
     return res
 
 
@@ -272,8 +274,21 @@ def run_fleet_comparison(n_replicas: int = 2, n_requests: int = 36,
                           threshold_s=5.0, target=0.9,
                           window_s=60.0, min_count=3)])
 
+    aff = _leg(workload, n_replicas, engine_cfg, model_seed,
+               "affinity", prefill_chunk, log, "affinity")
+    rr = _leg(workload, n_replicas, engine_cfg, model_seed,
+              "round_robin", prefill_chunk, log, "round-robin")
+    d = None
+    if drain_drill:
+        d = _leg(workload, n_replicas, engine_cfg, model_seed,
+                 "affinity", prefill_chunk, log, "drain-drill",
+                 drain_at=max(2, n_requests // 3),
+                 rejoin_at=max(3, (2 * n_requests) // 3))
+
     # single-replica reference on the same seed: the parity oracle for
-    # every fleet leg (and the routing-never-changes-tokens contract)
+    # every fleet leg (and the routing-never-changes-tokens contract).
+    # It runs in THIS process, so it runs last: no device is opened here
+    # while a worker process may still need it.
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.utils import random as rnd
 
@@ -295,18 +310,10 @@ def run_fleet_comparison(n_replicas: int = 2, n_requests: int = 36,
         return all(rows.get(id(req)) == oracle[id(req)]
                    for req in workload)
 
-    aff = _leg(workload, n_replicas, engine_cfg, model_seed,
-               "affinity", prefill_chunk, log, "affinity")
-    rr = _leg(workload, n_replicas, engine_cfg, model_seed,
-              "round_robin", prefill_chunk, log, "round-robin")
     aff_par, rr_par = parity(aff["rows"]), parity(rr["rows"])
 
     drain = None
-    if drain_drill:
-        d = _leg(workload, n_replicas, engine_cfg, model_seed,
-                 "affinity", prefill_chunk, log, "drain-drill",
-                 drain_at=max(2, n_requests // 3),
-                 rejoin_at=max(3, (2 * n_requests) // 3))
+    if d is not None:
         drain = {
             "completed": d["requests"],
             "lost": n_requests - len(d["rows"]),
@@ -316,8 +323,10 @@ def run_fleet_comparison(n_replicas: int = 2, n_requests: int = 36,
             "ttft": d["ttft"],
         }
 
+    worker_device = aff["device"]
     for leg in (aff, rr):
         leg.pop("rows", None)  # ndarray-free JSON row
+        leg.pop("device", None)
     # the affinity leg is the headline: its capacity/what-if block and
     # error-budget floor become the row's detail.capacity /
     # detail.slo_budget (the control leg's copies add nothing)
@@ -343,6 +352,9 @@ def run_fleet_comparison(n_replicas: int = 2, n_requests: int = 36,
         "slo_budget": slo_budget,
         **ratios,
         "token_parity": bool(aff_par and rr_par),
+        # the device the WORKERS report — the row's stamp (the parent
+        # opened none while they ran)
+        "worker_device": worker_device,
         "workload": {
             "kind": "fleet_shared_prefix",
             "replicas": n_replicas,

@@ -186,8 +186,24 @@ class ReplicaSupervisor:
     def start(self) -> "ReplicaSupervisor":
         if self._started:
             return self
-        for r in self._replicas.values():
+        replicas = list(self._replicas.values())
+        n_procs = sum(hasattr(r, "device") for r in replicas)
+        for r in replicas:
             r.start()
+            # worker processes report the device they came up on; a
+            # chip belongs to one process, and nothing here gives each
+            # worker a chip of its own yet
+            dev = getattr(r, "device", None)
+            if dev and dev["platform"] != "cpu" and n_procs > 1:
+                self.stop()
+                raise RuntimeError(
+                    f"worker {r.id} came up on {dev['kind']} "
+                    f"({dev['platform']}) and {n_procs - 1} more worker "
+                    "process(es) would contend for the same chip: "
+                    "per-chip pinning of fleet workers is not built "
+                    "(ROADMAP Reach 7). Run the worker fleet with "
+                    "env={'JAX_PLATFORMS': 'cpu'}, or in-process "
+                    "replicas on one chip.")
         self._started = True
         self.poll_once()
         self._stop_evt.clear()

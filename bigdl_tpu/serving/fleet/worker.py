@@ -78,14 +78,15 @@ _ERRORS = {
 def _worker_main(conn, cfg: dict) -> None:
     """Worker entry point (spawn target — must stay top-level).
 
-    Applies ``cfg["env"]`` BEFORE importing jax (device-slice pinning
-    has to precede backend init), builds the seeded model + engine,
-    acks ``ready``, then serves the op loop until ``stop``/EOF."""
+    Applies ``cfg["env"]`` BEFORE importing jax (whatever places the
+    process on a device has to precede backend init) and otherwise
+    takes the platform it is given — nothing here picks one. Builds
+    the seeded model + engine, acks ``ready`` with the device it came
+    up on, then serves the op loop until ``stop``/EOF."""
     import os
 
     for k, v in (cfg.get("env") or {}).items():
         os.environ[k] = str(v)
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.observability.events import default_recorder
@@ -115,10 +116,14 @@ def _worker_main(conn, cfg: dict) -> None:
             model, service_name=cfg.get("service", "worker"),
             **(cfg.get("engine") or {}))
         eng.start()
+        import jax
+
+        dev = jax.local_devices()[0]
+        device = {"platform": dev.platform, "kind": str(dev.device_kind)}
     except Exception as e:
         send({"ev": "ready", "error": repr(e)})
         return
-    send({"ev": "ready"})
+    send({"ev": "ready", "device": device})
 
     handles: Dict[str, object] = {}
     cancelled: set = set()
@@ -349,6 +354,9 @@ class WorkerReplica:
         #: error bound is rtt/2
         self.clock_rtt_s: Optional[float] = None
         self._clock_synced_at: Optional[float] = None
+        #: ``{"platform", "kind"}`` of the device the child came up
+        #: on, as the child reports it (None until ``ready``)
+        self.device: Optional[dict] = None
         self._proc: Optional[mp.process.BaseProcess] = None
         self._conn = None
         self._reader: Optional[threading.Thread] = None
@@ -438,6 +446,7 @@ class WorkerReplica:
             ev = msg.get("ev")
             if ev == "ready":
                 self._ready_error = msg.get("error")
+                self.device = msg.get("device")
                 self._ready.set()
             elif ev in ("token", "done", "error"):
                 with self._handles_lock:

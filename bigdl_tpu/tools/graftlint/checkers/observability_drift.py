@@ -29,17 +29,13 @@ import os
 import re
 from typing import List
 
-from ..core import Checker, Finding, register
+from ..core import SKIP_DIRS, Checker, Finding, register
 
 #: the one module allowed to register bigdl_* instruments
 ALLOWED = ("bigdl_tpu", "observability", "instruments.py")
 
 #: the guide whose instrument table must cover every registered name
 DOCS_GUIDE = ("docs", "programming-guide", "observability.md")
-
-SKIP_DIRS = {".git", "__pycache__", "build", "dist", "docs", "tests",
-             ".eggs", "bigdl_tpu.egg-info", "native", "docker",
-             ".claude", "related"}
 
 # a registration call with a bigdl_* name literal as its first
 # argument; assembled from pieces so this file never matches itself

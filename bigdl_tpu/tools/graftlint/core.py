@@ -33,10 +33,12 @@ SCHEMA_VERSION = 1
 
 #: directories never scanned (mirrors metrics_lint's historical scope:
 #: tests mint deliberate violations, docs show myapp_* examples,
-#: native/ is C++, the rest are build/VCS droppings)
+#: native/ is C++, the rest are build/VCS droppings — .scratch and
+#: chiprun_out hold the chip tool's scratch copies and outputs)
 SKIP_DIRS = {
     ".git", "__pycache__", "build", "dist", "docs", "tests", ".eggs",
     "bigdl_tpu.egg-info", "native", "docker", ".claude", "related",
+    ".scratch", "chiprun_out",
 }
 
 

@@ -19,13 +19,26 @@ class RandomGenerator:
 
     def __init__(self, seed: int = 1):
         self._seed = seed
-        self._key = jax.random.PRNGKey(seed)
+        # made on first use: creating a key opens the device, and importing
+        # the package must not (a parent that spawns device-owning workers
+        # imports it too)
+        self._root = None
         # Stack of externally pushed keys (used during pure/traced application).
         self._stack = []
 
+    @property
+    def _key(self):
+        if self._root is None:
+            self._root = jax.random.PRNGKey(self._seed)
+        return self._root
+
+    @_key.setter
+    def _key(self, key):
+        self._root = key
+
     def set_seed(self, seed: int) -> "RandomGenerator":
         self._seed = seed
-        self._key = jax.random.PRNGKey(seed)
+        self._root = None
         return self
 
     def get_seed(self) -> int:

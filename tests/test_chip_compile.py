@@ -1,0 +1,238 @@
+"""The main path's kernels and step programs COMPILE for the chip — checked
+here, without one, by handing shapes on a *described* ``v5e:2x2`` topology to
+the TPU compiler that ships with jaxlib (on-chip-measurement guide, section
+2). A pass is not a chip run: nothing executes, so it says nothing about
+results or times. It catches what interpret mode and ``jax.export`` lowering
+cannot: a kernel the Mosaic compiler refuses (tiling, fast memory), a program
+that does not fit 16 GB, a sharded step that cannot be partitioned.
+
+Rules this file keeps (the suite runs under pytest-xdist, each worker imports
+every test file, and only one process at a time may load the TPU library):
+the topology is described ONLY inside the module-scoped ``topo`` fixture —
+never at import, never in ``skipif``/``parametrize`` arguments, never in
+conftest.py — everything compiles in the test's own process, the persistent
+compile cache is off around these tests (a described-device executable
+cannot be read back), and all such tests live in this one file.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+# GPT-2 Large's published widths (the chip_smoke server model); depth is
+# cut to 2 layers here — tiling and partitioning depend on widths only
+LM = dict(vocab_size=50304, embed_dim=1280, num_heads=20, num_layers=2,
+          max_len=1024)
+SERVE = dict(max_slots=8, prefill_chunk=128, prefill_rows=2, page_size=16)
+FLASH = dict(batch=1, heads=8, kv_heads=2, seq=4096)   # GQA 4:1
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """The paged engine over the 2-layer GPT-2-Large-width model, built on
+    the CPU: the source of the engine's own jitted programs."""
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.serving import ContinuousBatchingEngine
+
+    model = TransformerLM(**LM)
+    model.evaluate()
+    model.load_params_dict(jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16), model.params_dict()))
+    eng = ContinuousBatchingEngine(model, service_name="chip_compile",
+                                   **SERVE)
+    yield eng
+    eng.stop()
+
+
+def _abstract(tree, sharding, lead=None):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape if lead is None else (lead,) + a.shape[1:],
+            a.dtype, sharding=sharding), tree)
+
+
+def _engine_args(eng, params, repl, kv, pages=2048):
+    """Abstract arguments of the engine's paged programs at the smoke's
+    pool geometry, replicated inputs on ``repl``, the pool on ``kv``."""
+    S, rows = SERVE["max_slots"], SERVE["prefill_rows"]
+    c, T = SERVE["prefill_chunk"], eng._table_len
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=repl)
+
+    pool = _abstract(eng._kv_pool, kv, lead=pages)
+    bufs = _abstract(eng._buffers, repl)
+    key = _abstract(jax.random.PRNGKey(0), repl)
+    t1 = jax.ShapeDtypeStruct((), jnp.float32, sharding=repl)
+    logits = jax.ShapeDtypeStruct((rows, LM["vocab_size"]), jnp.float32,
+                                  sharding=repl)
+    return {
+        "step": (params, bufs, i32(S), i32(S), pool, i32(S, T), key, t1),
+        "chunk": (params, bufs, i32(rows, c), pool, i32(rows, T),
+                  i32(rows), i32(rows)),
+        "copy_page": (pool, i32(), i32()),
+        "sample0": (logits, key, t1),
+    }
+
+
+def _fits(compiled, limit=16 << 30):
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert used < limit, m
+    return m
+
+
+# ------------------------------------------------------------ flash kernel
+def _flash_case(head_dim, one_chip, grad):
+    from bigdl_tpu.ops.flash_attention import flash_attention, force_interpret
+
+    def sds(heads):
+        return jax.ShapeDtypeStruct(
+            (FLASH["batch"], heads, FLASH["seq"], head_dim), jnp.bfloat16,
+            sharding=one_chip)
+
+    q, kv = sds(FLASH["heads"]), sds(FLASH["kv_heads"])
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(fwd(*a).astype(jnp.float32)),
+                        (0, 1, 2))(q, k, v)
+
+    # jax.devices() is the CPU here, so the kernel's own default would be
+    # the interpreter: steer it to the compiled Mosaic path in the test
+    with force_interpret(False):
+        compiled = jax.jit(bwd if grad else fwd).lower(q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_flash_forward_compiles(topo, one_chip, head_dim):
+    _flash_case(head_dim, one_chip, grad=False)
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_flash_grad_compiles(topo, one_chip, head_dim):
+    _flash_case(head_dim, one_chip, grad=True)
+
+
+# ------------------------------------------------ the paged engine's programs
+@pytest.mark.parametrize("program", ["step", "chunk", "copy_page", "sample0"])
+def test_paged_engine_program_compiles(topo, one_chip, engine, program):
+    """decode step / prefill chunk / page copy / first-token sample at
+    GPT-2 Large widths, 2048 pages x 16, 8 lanes, table length 64."""
+    args = _engine_args(engine, _abstract(engine._params, one_chip),
+                        one_chip, one_chip)[program]
+    jitted = getattr(engine, f"_{program}_jit")
+    m = _fits(jitted.lower(*args).compile())
+    if program in ("step", "chunk", "copy_page"):
+        # the pool is donated and aliased in full: no second copy of it
+        pool_bytes = sum(
+            int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+            for leaf in jax.tree.leaves(args[{"step": 4, "chunk": 3,
+                                              "copy_page": 0}[program]]))
+        assert m.alias_size_in_bytes >= pool_bytes
+
+
+# ------------------------------------------------------- across four chips
+def test_tensor_parallel_decode_step_compiles_on_four_chips(topo, engine):
+    """The engine's decode step on a ("model", 4) mesh: 20 heads -> 5 per
+    chip, params under transformer_tp_rules, the pool heads-sharded."""
+    from bigdl_tpu.parallel.tp import spec_for_params, transformer_tp_rules
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("model",))
+    repl = NamedSharding(mesh, P())
+    kv = engine.model.kv_cache_sharding(mesh)
+    specs = spec_for_params(engine._params, transformer_tp_rules("model"),
+                            P())
+
+    def walk(p, s):
+        if isinstance(p, dict):
+            return {k: walk(v, s[k]) for k, v in p.items()}
+        return jax.ShapeDtypeStruct(p.shape, p.dtype,
+                                    sharding=NamedSharding(mesh, s))
+
+    args = _engine_args(engine, walk(engine._params, specs), repl, kv)
+    compiled = jax.jit(engine._step_jit.__wrapped__, donate_argnums=(4,),
+                       out_shardings=(repl, kv)).lower(
+                           *args["step"]).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text     # the row-parallel reductions
+    # per-device bytes: the sharded pool is a quarter of the whole
+    m = _fits(compiled)
+    whole = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                for leaf in jax.tree.leaves(args["step"][4]))
+    assert m.alias_size_in_bytes < whole // 2
+
+
+def test_data_parallel_sharded_step_compiles_on_four_chips(topo):
+    """DistriOptimizer's sharded (reduce-scatter / all-gather) step on a
+    ("data", 4) mesh, from the optimizer's own builder."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.optim import SGD
+    from bigdl_tpu.parallel import DistriOptimizer
+    from bigdl_tpu.parallel.distri_optimizer import (flatten_params,
+                                                     pad_to_multiple)
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    repl, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    model = nn.Sequential(nn.Linear(784, 128), nn.ReLU(),
+                          nn.Linear(128, 10), nn.LogSoftMax())
+    opt = DistriOptimizer(model=model, dataset=None,
+                          criterion=nn.ClassNLLCriterion(), batch_size=256,
+                          mesh=mesh, parameter_sync="sharded")
+    method = SGD(learning_rate=0.01)
+    params = model.params_dict()
+    flat, _ = pad_to_multiple(flatten_params(params)[0], 4)
+    slots = method.init_slots(flat)
+    step, _, _ = opt._build_sharded_step(
+        model, nn.ClassNLLCriterion(), method, {}, slots)
+    bufs = jax.tree.map(
+        lambda b: jax.ShapeDtypeStruct((4,) + b.shape, b.dtype,
+                                       sharding=data),
+        model.buffers_dict())
+    slots_abs = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype,
+            sharding=data if getattr(s, "ndim", 0) else repl), slots)
+    compiled = step.lower(
+        _abstract(params, repl), bufs, _abstract(flat, data), slots_abs,
+        jax.ShapeDtypeStruct((256, 784), jnp.float32, sharding=data),
+        jax.ShapeDtypeStruct((256, 1), jnp.float32, sharding=data),
+        jax.ShapeDtypeStruct((), jnp.float32, sharding=repl),
+        _abstract(jax.random.PRNGKey(0), repl)).compile()
+    # the gradient exchange survives compilation as collectives (the
+    # compiler may fuse the scatter/gather pair into all-reduces)
+    text = compiled.as_text()
+    assert any(c in text for c in ("all-reduce", "reduce-scatter",
+                                   "all-gather"))
+    _fits(compiled)

@@ -99,10 +99,9 @@ def test_device_peaks_table_match_and_env_override(monkeypatch):
     assert p["flops_per_s"] == 197e12 and p["source"] == "table"
     p5 = device_peaks(types.SimpleNamespace(device_kind="TPU v5"))
     assert p5["flops_per_s"] == 459e12
-    unknown = device_peaks(types.SimpleNamespace(device_kind="FPGA x9"))
-    assert unknown["source"] == "default"
-    assert (unknown["flops_per_s"], unknown["hbm_bytes_per_s"]) \
-        == costmodel.DEFAULT_PEAKS
+    # a device the table does not know is an error, not an invented peak
+    with pytest.raises(LookupError, match="FPGA x9"):
+        device_peaks(types.SimpleNamespace(device_kind="FPGA x9"))
     # env overrides win over the table, bandwidth given in GB/s
     monkeypatch.setenv(costmodel.ENV_PEAK_FLOPS, "123e12")
     monkeypatch.setenv(costmodel.ENV_PEAK_HBM_GBPS, "800")
@@ -261,7 +260,7 @@ def test_engine_cost_block_xla_path_and_flat_jit(lm, reg, rec):
         cost = st["cost"]
         assert cost["devices"] == 1
         assert cost["peak_flops_per_s"] > 0
-        assert cost["peak_source"] in ("table", "default", "env")
+        assert cost["peak_source"] in ("table", "env")
         for kind in ("prefill", "decode"):
             k = cost["kinds"][kind]
             assert k["dispatches"] > 0 and k["wall_s"] > 0

@@ -94,12 +94,16 @@ def test_widedeep_example_feature_columns_learn():
     assert acc > base + 0.08, (acc, base)
 
 
-def test_serving_example():
+def test_serving_example(monkeypatch, tmp_path):
     """The serving walkthrough (one-dispatch generate/beam, ragged,
     int8-draft speculation, concurrent GenerationService) runs end to
     end and returns the concurrently-served rows (exactly prompt + n
     tokens each — the service contract)."""
     from bigdl_tpu.example.serving.serve import main
+
+    # the example enables the persistent compile cache: place it outside
+    # the checkout, so tier-1 writes no CPU entries the chip tool would copy
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
     rows = main(["--tokens", "8", "--vocab", "64"])
     assert len(rows) == 4

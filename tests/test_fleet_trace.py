@@ -436,8 +436,10 @@ def test_two_worker_fleet_merged_trace_end_to_end():
     model = dict(vocab_size=64, embed_dim=16, num_heads=4,
                  num_kv_heads=2, num_layers=2, max_len=96,
                  use_rope=True)
+    # a worker takes the platform it is given: this CPU suite says so
     reps = spawn_worker_fleet(
-        2, model, engine={"max_slots": 2, "prefill_chunk": 4}, seed=7)
+        2, model, engine={"max_slots": 2, "prefill_chunk": 4}, seed=7,
+        env={"JAX_PLATFORMS": "cpu"})
     reg = MetricRegistry()
     with ReplicaSupervisor(reps, poll_interval=0.1,
                            registry=reg) as sup, \
@@ -446,6 +448,8 @@ def test_two_worker_fleet_merged_trace_end_to_end():
         for rep in reps:
             assert rep.clock_offset_s is not None
             assert rep.clock_rtt_s >= 0.0
+            # each worker reports the device it came up on
+            assert rep.device == {"platform": "cpu", "kind": "cpu"}
         outs = [json.loads(_post(
             base + "/v1/generate",
             {"prompt_ids": [1 + i, 2, 3, 4], "max_new_tokens": 6,
@@ -471,3 +475,27 @@ def test_two_worker_fleet_merged_trace_end_to_end():
             base + "/metrics", timeout=60).read().decode()
         assert 'replica="r0"' in text and 'replica="r1"' in text
         assert "bigdl_fleet_clock_offset_seconds" in text
+
+
+def test_worker_fleet_on_an_accelerator_without_a_chip_each_is_refused():
+    """N worker processes on one accelerator would contend for it: the
+    supervisor stops at the first worker that reports a non-CPU device
+    and names the ROADMAP item that builds per-chip pinning."""
+    class Worker:
+        def __init__(self, rid):
+            self.id, self.device = rid, None
+            self.started = self.stopped = False
+
+        def start(self):
+            self.started = True
+            self.device = {"platform": "tpu", "kind": "TPU v5 lite"}
+
+        def stop(self):
+            self.stopped = True
+
+    reps = [Worker("r0"), Worker("r1")]
+    sup = ReplicaSupervisor(reps, registry=MetricRegistry())
+    with pytest.raises(RuntimeError, match="Reach 7"):
+        sup.start()
+    assert reps[0].started and reps[0].stopped
+    assert not reps[1].started      # never brought up to fight for the chip

@@ -117,6 +117,11 @@ class TestPoolRegistry:
 
 # --------------------------------------------------------- memory monitor
 def test_memory_monitor_sample_gauges_and_watermark(reg, rec):
+    import jax.numpy as jnp
+
+    # something must live on the device for a watermark to exist
+    # (importing the package no longer puts an RNG key there)
+    held = jnp.ones((256,), jnp.float32).block_until_ready()
     mon = obs.DeviceMemoryMonitor(registry=reg, history=4)
     obs_memory.register_pool("t/mon", lambda: 1000)
     try:
@@ -146,6 +151,7 @@ def test_memory_monitor_sample_gauges_and_watermark(reg, rec):
     # the watermark left a recorder event
     assert any(e.kind == "memory/high_watermark" for e in rec.tail()) \
         or s["bytes_in_use"] == 0
+    del held
 
 
 # ------------------------------------------------------ recompile watchdog
