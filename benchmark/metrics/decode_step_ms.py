@@ -1,0 +1,6 @@
+"""Model step: median device time of the decode-step program in the trace."""
+from benchmark.reduce_trace import program_median_ms
+
+
+def value(run, trace):
+    return program_median_ms(trace, run["programs"].get("decode_step", []))

@@ -1,0 +1,554 @@
+"""The benchmark's yardstick, checked on the CPU: the manifest resolves to
+files, the traffic generator is deterministic, the window / trace / count
+arithmetic is right on hand-made inputs, each driver runs end to end at a
+tiny size (and writes no device metric), and ``correct`` comes out false for
+a lower precision and for a broken timed path."""
+
+import os
+import re
+import subprocess
+import sys
+import time
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import compare, counts, harness, loadgen, reduce_trace  # noqa: E402
+
+
+
+@pytest.fixture
+def no_chip_needed(monkeypatch):
+    """Skip the harness's look for a chip: the rest of a run on the CPU."""
+    import jax
+
+    monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices()[:n])
+
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+# ---------------------------------------------------------------- manifest
+def test_manifest_names_units_and_files_resolve():
+    man = harness.manifest()
+    assert man["command"] == ["python3", "benchmark/run.py"]
+    assert 1 <= man["run_seconds"] <= 51
+    metrics = man["end_to_end"] + man["per_layer"]
+    names = [m["name"] for m in metrics]
+    assert len(set(names)) == len(names)
+    for m in metrics:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+    e2e = {m["name"]: m for m in man["end_to_end"]}
+    assert "setup_s" in e2e and all(0 < m["bound"] <= 0.1
+                                    for m in e2e.values())
+    cells = [w["name"] for w in man["workloads"]]
+    assert sum(w["chips"] == 4 for w in man["workloads"]) <= max(
+        1, len(cells) // 4)
+    for w in man["workloads"]:
+        assert NAME.match(w["name"]) and len(w["why"]) <= 200
+        cell = harness.load_cell(w["name"], man)        # both files open
+        assert cell["config_json"]["driver"] in ("serve", "train")
+        assert os.path.exists(os.path.join(
+            harness.HERE, "drivers", cell["config_json"]["driver"] + ".py"))
+        assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
+    for c in man["configs"]:
+        assert c["file"].startswith("benchmark/configs/")
+        assert sorted(harness.load_json(ROOT, c["file"])["reduced"]) == \
+            sorted(c["reduced"])
+    for m in man["per_layer"]:
+        assert m["moves"] in e2e and set(m["workloads"]) <= set(cells)
+        reader = harness.load_module("metrics", m["name"])
+        assert callable(reader.value)
+        # a reader with nothing to read returns nothing
+        assert reader.value({"programs": {}}, None) is None
+
+
+# ----------------------------------------------------------------- traffic
+def test_traffic_is_seeded_clipped_and_one_schedule_for_every_seed():
+    # (the mix's own lengths, clips and sharing; any seed up to 2**31 and over)
+    mix = harness.load_json(harness.HERE, "traffic", "chat-steady.json")
+    a = loadgen.open_loop_requests(mix, 2 ** 31 + 5, 40, 50257)
+    b = loadgen.open_loop_requests(mix, 2 ** 31 + 5, 40, 50257)
+    c = loadgen.open_loop_requests(mix, 7, 40, 50257)
+    assert all(np.array_equal(x["prompt"], y["prompt"])
+               and x["due_s"] == y["due_s"] for x, y in zip(a, b))
+    sp = mix["shared_prefix"]
+    for r in a:
+        assert 16 <= len(r["prompt"]) <= 768 and 8 <= r["new_tokens"] <= 256
+        assert len(r["prompt"]) + r["new_tokens"] <= 1024
+        assert 0 <= r["prompt"].min() and r["prompt"].max() < 50257
+        if r["shared"] >= 0:
+            assert len(r["prompt"]) >= sp["tokens"] + sp["min_own_tokens"]
+    heads = {r["shared"]: tuple(r["prompt"][:sp["tokens"]])
+             for r in a if r["shared"] >= 0}
+    assert len(heads) == sp["count"] and all(
+        tuple(r["prompt"][:sp["tokens"]]) == heads[r["shared"]]
+        for r in a if r["shared"] >= 0)
+    # another seed: other tokens, the mix's one schedule
+    assert [(r["due_s"], r["new_tokens"], len(r["prompt"])) for r in a] == \
+        [(r["due_s"], r["new_tokens"], len(r["prompt"])) for r in c]
+    assert not np.array_equal(a[0]["prompt"], c[0]["prompt"])
+    with pytest.raises(ValueError):
+        loadgen.open_loop_requests(
+            dict(mix, arrivals={"process": "uniform", "rate_per_s": 1.0}),
+            7, 40, 50257)
+    bursty = loadgen.open_loop_requests(
+        dict(mix, arrivals={"process": "gamma", "cv": 3.0, "rate_per_s": 1.0}),
+        7, 40, 50257)
+    assert len(bursty) == len(a) and bursty[-1]["due_s"] < 40
+    n = round(mix["arrivals"]["rate_per_s"] * (40 + mix["lead_in_s"]))
+    assert len(a) == len(c) == n
+    due = [r["due_s"] for r in a]
+    assert due == sorted(due) and -mix["lead_in_s"] <= due[0] and due[-1] < 40
+    rec = loadgen.describe_requests(a, 40)
+    assert rec["requests"] + rec["lead_in_requests"] == n
+
+
+# ---------------------------------------------------- window and statistics
+def test_percentile():
+    assert harness.percentile([], 95) is None
+    assert harness.percentile([3.0], 95) == 3.0
+    assert harness.percentile([0, 10], 95) == pytest.approx(9.5)
+    assert harness.median([5, 1, 3]) == 3
+
+
+def test_window_arithmetic_counts_from_the_due_time():
+    serve = harness.load_module("drivers", "serve")
+
+    class Done:
+        def done(self):
+            return True
+
+    def client(due_s, stamps, new=None, lead=False):
+        c = serve.Client({"due_s": -1.0 if lead else due_s,
+                          "prompt": np.zeros(4, np.int32),
+                          "new_tokens": new if new is not None
+                          else len(stamps)}, 100.0 + due_s)
+        c.handle, c.stamps, c.tokens = Done(), stamps, [0] * len(stamps)
+        c.submitted = c.due + 0.002
+        return c
+
+    t0, seconds = 100.0, 10.0
+    clients = [
+        client(1.0, [101.5, 101.6, 101.8]),              # ttft 500 ms
+        client(2.0, [102.1, 109.9, 110.4]),              # last token outside
+        client(9.0, [], new=5),                          # never served
+        client(-1.0, [100.5, 100.6], lead=True),         # lead-in: not measured
+    ]
+    measured, failed, e2e, extra = serve.window_numbers(
+        clients, t0, seconds, cutoff=115.0)
+    assert len(measured) == 3 and len(failed) == 1
+    # the unserved request waited from its due time to the cut-off
+    assert sorted(e2e["ttft_ms"]) == pytest.approx([100.0, 500.0, 6000.0])
+    assert harness.load_module("metrics", "ttft_p95_ms").value(
+        {"ttft_ms": e2e["ttft_ms"]}, None) == pytest.approx(
+        harness.percentile([100.0, 500.0, 6000.0], 95))
+    assert extra["gaps"] == 4
+    assert e2e["itl_p95_ms"] == pytest.approx(harness.percentile(
+        [100.0, 200.0, 7800.0, 500.0], 95), rel=1e-6)
+    # tokens inside [t0, t0 + seconds], lead-in requests' included
+    assert e2e["serve_tok_per_s"] == pytest.approx((3 + 2 + 2) / 10.0)
+    late = [(c.submitted - c.due) * 1e3 for c in measured]
+    assert max(late) == pytest.approx(2.0)
+
+
+# ------------------------------------------------------------------- trace
+def test_reduce_trace_on_a_hand_built_trace():
+    ms = 1e6
+    planes = {
+        "/device:TPU:0": {
+            "XLA Modules": [("jit_step(11)", 0, 4 * ms),
+                            ("jit_step(11)", 10 * ms, 6 * ms),
+                            ("jit_step(11)", 20 * ms, 5 * ms),
+                            ("jit_chunk(7)", 30 * ms, 2 * ms)],
+            "XLA Ops": [("fusion.1", 0, 3 * ms), ("fusion.2", 2 * ms, 2 * ms),
+                        ("all-reduce.3", 10 * ms, 6 * ms),
+                        ("fusion.1", 20 * ms, 5 * ms),
+                        ("copy.9", 30 * ms, 2 * ms)]},
+        "/device:TPU:1": {"XLA Ops": [("fusion.1", 0, 10 * ms)]},
+        "/host:CPU": {"python": [("train/step", 3 * ms, 8 * ms),
+                                 ("outer", 0, 40 * ms),
+                                 ("bench/window", 0, 40 * ms),
+                                 ("$ ignored", 4 * ms, 1 * ms)]},
+    }
+    # the profiler's own start and stop stall the host: only what lies under
+    # the harness's marker span is read, cut at its ends
+    marked = {k: {a: list(b) for a, b in v.items()} for k, v in planes.items()}
+    marked["/host:CPU"]["python"][2] = ("bench/window", 2 * ms, 20 * ms)
+    m = reduce_trace.summarize(marked)
+    assert m["window_s"] == pytest.approx(0.020)
+    # device 0 inside [2, 22] ms: 2..4, 10..16, 20..22
+    assert m["busy_s_per_device"] == pytest.approx([0.010, 0.008])
+    assert m["programs"] == {"jit_step": {
+        "runs": 1, "median_ms": 6.0, "total_ms": 6.0}}
+    # no host spans recorded: first to last start of the commonest program
+    del marked["/host:CPU"]
+    u = reduce_trace.summarize(marked)
+    assert u["window_s"] == pytest.approx(0.020)           # 0 .. 20 ms
+    assert u["busy_s_per_device"][0] == pytest.approx(0.010)
+    assert u["programs"]["jit_step"]["runs"] == 2
+    assert reduce_trace.short_name(
+        "%fusion.9 = (f32[256]{0:T(256)}, f32[2,3]{1,0}) fusion(f32[4]{0} "
+        "%all-reduce.3), kind=kLoop") == "fusion.9 fusion f32[256]"
+    assert not reduce_trace.is_collective(
+        "%fusion.9 = f32[4]{0} fusion(f32[4]{0} %all-reduce.3), kind=kLoop")
+    assert reduce_trace.is_collective(
+        "%all-reduce-start.3 = f32[4]{0} all-reduce-start(f32[4]{0} %x)")
+    assert reduce_trace.merged([(0, 3), (2, 4), (10, 16)]) == \
+        [[0, 4], [10, 16]]
+    s = reduce_trace.summarize(planes)
+    assert s["window_s"] == pytest.approx(0.040)
+    assert s["devices"] == 2
+    # device 0: union 4 + 6 + 5 + 2 = 17 ms; device 1: 10 ms
+    assert s["busy_s_per_device"] == pytest.approx([0.017, 0.010])
+    assert s["busy_s"] == pytest.approx(0.0135)
+    assert reduce_trace.device_idle_pct(s) == pytest.approx(
+        100 * (1 - 0.0135 / 0.040))
+    assert s["programs"]["jit_step"] == {
+        "runs": 3, "median_ms": 5.0, "total_ms": 15.0}
+    assert reduce_trace.program_median_ms(s, ["nope", "jit_chunk"]) == 2.0
+    assert s["top_ops"][0] == ["fusion.1", pytest.approx(0.008)]
+    assert s["collective_ms"] == pytest.approx(6.0)
+    gaps = dict(s["idle_gaps"])
+    # gap 4..10 ms lies under train/step (the innermost span); the others
+    # (16..20, 25..30) only under the outer one
+    assert gaps["train/step"] == pytest.approx(0.006)
+    assert gaps["outer"] == pytest.approx(0.009)
+    assert reduce_trace.device_idle_pct({"devices": 0, "window_s": 1}) is None
+
+
+# ------------------------------------------------------------------ counts
+def test_counts_and_metric_readers_on_hand_worked_numbers():
+    # first bottleneck of ResNet-50 at 56 x 56: 1x1 64->64, 3x3 64->64,
+    # 1x1 64->256 and the 1x1 64->256 projection
+    macs = dict(counts.resnet50_convs())
+    px = 56 * 56
+    assert macs["l0.b0.c1"] == px * 64 * 64
+    assert macs["l0.b0.c2"] == px * 9 * 64 * 64
+    assert macs["l0.b0.c3"] == macs["l0.b0.sc"] == px * 64 * 256
+    assert macs["l1.b0.c1"] == px * 256 * 128            # before its stride
+    assert macs["l1.b0.c2"] == 28 * 28 * 9 * 128 * 128
+    assert macs["conv1"] == 112 * 112 * 49 * 3 * 64 and macs["fc"] == 2048000
+    forward = sum(macs.values())
+    assert 4.0e9 < forward < 4.2e9                       # the known ~4.1 GMACs
+    assert counts.resnet50_train_step_flops(256) == \
+        2 * (3 * forward - macs["conv1"]) * 256
+    # one decode step of a 2-layer, width-8, vocabulary-10 model
+    params = 2 * (8 * 24 + 8 * 8 + 2 * 4 * 8 * 8) + 10 * 8
+    assert counts.gpt2_matmul_params(8, 2, 10) == params
+    flops, data = counts.gpt2_decode_step(8, 2, 10, live_rows=3,
+                                          live_tokens=50)
+    assert flops == 2 * params * 3 + 4 * 8 * 2 * 50
+    assert data == (params + 2 * (24 + 8 + 32 + 8)) * 2 + 2 * 2 * 8 * 2 * 50
+    least, bound = counts.least_seconds(
+        197e12, 819e9 * 2, {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    assert least == pytest.approx(2.0) and bound == "memory"
+    # ... and the readers that use them, on a hand-made run
+    peaks = harness.peaks_for("TPU v5 lite")
+    with pytest.raises(harness.BenchmarkError):
+        harness.peaks_for("some other chip")
+    summary = {"devices": 4, "window_s": 2.0, "busy_s": 1.5,
+               "collective_ms": 30.0,
+               "programs": {"jit_step": {"runs": 10, "median_ms": 20.0,
+                                         "total_ms": 200.0},
+                            "jit__core": {"runs": 10, "median_ms": 200.0,
+                                          "total_ms": 2000.0}}}
+    run = {"programs": {"decode_step": ["jit_step"],
+                        "train_step": ["jit_mapped", "jit__core"]},
+           "sizes": {"n_embd": 1280, "n_layer": 36, "vocab_size": 50304,
+                     "image": 224, "classes": 1000},
+           "peaks": peaks, "batch_per_chip": 256,
+           "live_in_trace": {"rows": 20.0, "tokens": 6000.0},
+           "iteration_ms": [210.0, 212.0, 211.0],
+           "late_ms": [1.0, 2.0], "queue_wait_ms": [5.0], "ttft_ms": [7.0],
+           "prompt_tokens": 1000, "prefix_tokens": 250,
+           "max_pages": 100, "pages_peak": 40,
+           "loop_before": {"phases": {"sweep": 1.0, "decode_dispatch": 1.0}},
+           "loop_after": {"phases": {"sweep": 2.0, "decode_dispatch": 4.0}}}
+    read = lambda name: harness.load_module("metrics", name).value(run, summary)
+    assert read("prefix_hit_pct") == 25.0 and read("kv_pages_peak_pct") == 40.0
+    assert read("loop_host_pct") == 25.0
+    assert read("decode_step_ms") == 20.0 and read("train_step_ms") == 200.0
+    assert read("train_host_gap_ms") == 11.0
+    assert read("device_idle_pct.serve") == read("device_idle_pct.train") == 25.0
+    flops, data = counts.gpt2_decode_step(1280, 36, 50304, 20.0, 6000.0)
+    assert read("decode_step_roofline") == pytest.approx(
+        100 * (data / 819e9) / 0.020)
+    assert 0 < read("decode_step_roofline") < 100
+    assert read("train_step_mfu") == pytest.approx(
+        100 * counts.resnet50_train_step_flops(256) / 197e12 / 0.200)
+    assert 0 < read("train_step_mfu") < 100
+
+
+# ------------------------------------------------------- serving rehearsal
+def tiny_serving_cell():
+    cell = harness.load_cell("gpt2l-chat-steady")
+    cfg, mix = cell["config_json"], cell["traffic_json"]
+    # a wider initialisation than the published 0.02: at two layers of
+    # width 64 the token's own embedding would otherwise decide every logit
+    cfg["sizes"].update(n_layer=2, n_embd=64, n_head=4, n_positions=128,
+                        n_ctx=128, vocab_size=256, initializer_range=0.2)
+    cfg["assumed"].update(vocab_real=250, weights_dtype="float32")
+    cfg["engine"].update(max_slots=4, page_size=4, prefill_chunk=8,
+                         prefill_rows=2)
+    cfg["check"] = {"sample_requests": 12, "limits": {
+        "served_token_gap_max_rel": 1e-3, "served_token_gap_mean_rel": 1e-4,
+        "served_token_gap_under_own_logits_max_rel": 1e-3,
+        "own_logits_error_rel_rms": 1e-4}}
+    mix.update(
+        arrivals={"process": "poisson", "rate_per_s": 6.0},
+        prompt_tokens={"law": "lognormal", "median": 20, "sigma": 0.5,
+                       "min": 8, "max": 60},
+        output_tokens={"law": "lognormal", "median": 8, "sigma": 0.5,
+                       "min": 4, "max": 16},
+        shared_prefix={"share": 0.5, "count": 2, "tokens": 16,
+                       "min_own_tokens": 4},
+        lead_in_s=0.3, drain_limit_s=20.0)
+    return cell
+
+
+@pytest.fixture(scope="module")
+def served():
+    import jax
+
+    serve = harness.load_module("drivers", "serve")
+    cell = tiny_serving_cell()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(harness, "require_chips", lambda n: jax.devices()[:n])
+        out = serve.run(cell, 2 ** 31 + 11, 2.0, False, time.perf_counter())
+    return cell, out
+
+
+def test_serving_rehearsal_is_correct_and_writes_no_device_metric(served):
+    cell, out = served
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["attempted"] == round(6.0 * 2.0) or out["attempted"] > 8
+    assert set(out["values"]) == {"itl_p95_ms", "serve_tok_per_s", "setup_s"}
+    assert all(v > 0 for v in out["values"].values())
+    assert out["device"]["platform"] == "cpu"
+    line = harness.metric_entries(cell["end_to_end"], out["values"])
+    assert set(line) == set(out["values"])
+    # nothing traced on a CPU: every device_trace reader returns nothing
+    for m in cell["per_layer"]:
+        if m["source"] == "device_trace":
+            assert harness.load_module("metrics", m["name"]).value(
+                dict(out["record"], peaks={}), None) is None
+    rec = out["record"]
+    assert rec["prefix_tokens"] > 0 and rec["jit_compiles"] > 0
+    assert harness.load_module("metrics", "loop_host_pct").value(
+        rec, None) > 0
+
+
+def test_serving_check_fails_int8(served):
+    """The lower precision the engine offers: the model's own logits no
+    longer lie as near the reference's, whether the reference's weights are
+    rounded to int8 steps or the program's own int8 path (Quantizer weights,
+    int8 page pool) makes them."""
+    from benchmark import weights as bw
+    from benchmark.models import gpt2
+    from bigdl_tpu.nn.quantized import Quantizer
+
+    serve = harness.load_module("drivers", "serve")
+    cell, out = served
+    cfg, got = cell["config_json"], out["compared"]
+    sound = {r["name"]: r for r in out["checks"]}
+    assert all(r["ok"] for r in out["checks"])
+    assert sound["served_token_gap_under_own_logits_max_rel"]["value"] < 1e-5
+    own = gpt2.paged_logits(Quantizer.quantize(gpt2.build(cfg, 2 ** 31 + 11)),
+                            "int8", cfg, got["rows"])
+    rounded = serve.reference_logits(cfg, 2 ** 31 + 11, got["rows"], bw.rounded)
+    for low in (own, rounded):
+        rows = {r["name"]: r for r in compare.serving_rows(
+            got["reference_logits"], low, got["rows"], got["spans"], True, 0,
+            cfg["check"]["limits"])}
+        assert not rows["own_logits_error_rel_rms"]["ok"]
+        assert rows["own_logits_error_rel_rms"]["value"] > \
+            10 * sound["own_logits_error_rel_rms"]["value"]
+
+
+def test_serving_run_with_a_token_altered_where_it_is_produced(
+        monkeypatch, no_chip_needed):
+    from bigdl_tpu.serving.streams import RequestHandle
+
+    deliver = RequestHandle._deliver
+    monkeypatch.setattr(
+        RequestHandle, "_deliver",
+        lambda self, token, now: deliver(self, (int(token) + 1) % 250, now))
+    serve = harness.load_module("drivers", "serve")
+    out = serve.run(tiny_serving_cell(), 5, 1.5, False, time.perf_counter())
+    assert out["correct"] is False
+    rows = {r["name"]: r for r in out["checks"]}
+    assert not rows["served_token_gap_max_rel"]["ok"]
+    assert not rows["served_token_gap_under_own_logits_max_rel"]["ok"]
+    assert rows["own_logits_error_rel_rms"]["ok"]   # the model is sound
+
+
+# ------------------------------------------------------ training rehearsal
+class TinyNet:
+    """conv3x3 -> BatchNorm -> ReLU -> global average -> linear, on the
+    program's modules (adapter) and in plain jax.numpy (reference): what the
+    training driver needs of a configuration, at a size a test can hold."""
+
+    @staticmethod
+    def weights(config, seed):
+        import jax
+        import jax.numpy as jnp
+
+        k = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 2)
+        return {"c.w": 0.3 * jax.random.normal(k[0], (3, 3, 3, 4)),
+                "c.b": jnp.zeros((4,)), "n.g": jnp.ones((4,)),
+                "n.b": jnp.zeros((4,)),
+                "f.w": 0.3 * jax.random.normal(k[1], (4, 5)),
+                "f.b": jnp.zeros((5,))}
+
+    @staticmethod
+    def build(config, seed):
+        from bigdl_tpu import nn
+
+        model = (nn.Sequential()
+                 .add(nn.SpatialConvolution(3, 4, 3, 3, 1, 1, 1, 1,
+                                            format="NHWC"))
+                 .add(nn.SpatialBatchNormalization(4, 1e-3, format="NHWC"))
+                 .add(nn.ReLU())
+                 .add(nn.SpatialAveragePooling(8, 8, 1, 1, format="NHWC"))
+                 .add(nn.View(4)).add(nn.Linear(4, 5)))
+        w = TinyNet.weights(config, seed)
+        tree = model.params_dict()
+        tree["m0"]["~params"] = {"weight": w["c.w"].transpose(3, 2, 0, 1),
+                                 "bias": w["c.b"]}
+        tree["m1"]["~params"] = {"weight": w["n.g"], "bias": w["n.b"]}
+        tree["m5"]["~params"] = {"weight": w["f.w"].T, "bias": w["f.b"]}
+        model.load_params_dict(tree)
+        return model
+
+    @staticmethod
+    def named(tree, config):
+        p = {k: {a: np.asarray(b) for a, b in tree[k]["~params"].items()}
+             for k in ("m0", "m1", "m5")}
+        return {"c.w": p["m0"]["weight"].transpose(2, 3, 1, 0),
+                "c.b": p["m0"]["bias"], "n.g": p["m1"]["weight"],
+                "n.b": p["m1"]["bias"], "f.w": p["m5"]["weight"].T,
+                "f.b": p["m5"]["bias"]}
+
+    @staticmethod
+    def follow(p0, batches, recipe, shards=1, cast=None):
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from benchmark.reference.resnet50 import sgd_update
+
+        def loss_fn(p, x, y):
+            h = lax.conv_general_dilated(
+                x, p["c.w"], (1, 1), [(1, 1), (1, 1)],
+                dimension_numbers=("NHWC", "HWIO", "NHWC")) + p["c.b"]
+            mean = jnp.mean(h, (0, 1, 2))
+            var = jnp.mean(jnp.square(h - mean), (0, 1, 2))
+            h = (h - mean) / jnp.sqrt(var + 1e-3) * p["n.g"] + p["n.b"]
+            z = (jnp.mean(jax.nn.relu(h), (1, 2)) @ p["f.w"]
+                 + p["f.b"]).astype(jnp.float32)
+            lab = y.reshape(-1).astype(jnp.int32) - 1
+            return -jnp.mean(jnp.take_along_axis(
+                jax.nn.log_softmax(z), lab[:, None], axis=1))
+
+        p, v = p0, jax.tree.map(jnp.zeros_like, p0)
+        losses, after = [], []
+        for x, y in batches:
+            with jax.default_matmul_precision("highest"):
+                loss, g = jax.value_and_grad(loss_fn)(
+                    p, jnp.asarray(x), jnp.asarray(y))
+            p, v, _ = sgd_update(p, v, g, recipe["learning_rate"],
+                                 recipe["momentum"], recipe["dampening"],
+                                 recipe["weight_decay"])
+            losses.append(float(loss))
+            after.append(p)
+        return losses, after
+
+
+def tiny_training_cell():
+    sys.modules["benchmark.models.tinynet"] = TinyNet
+    sys.modules["benchmark.reference.tinynet"] = TinyNet
+    cell = harness.load_cell("resnet50-local-b256")
+    cell["config_json"].update(adapter="tinynet", reference="tinynet")
+    cell["config_json"]["sizes"].update(classes=5, image=8)
+    cell["config_json"]["optimizer"]["recipe"].update(
+        learning_rate=0.1, dampening=0.0, l2=0.0)
+    cell["config_json"]["check"]["limits"] = {
+        "loss_rel_gap": 1e-4, "grad_norm_gap": 1e-2, "delta_norm_gap": 1e-2}
+    cell["traffic_json"].update(samples=32, image=[8, 8, 3], classes=5,
+                                batch_per_chip=4, warmup_iterations=4)
+    return cell
+
+
+def test_training_rehearsal_is_correct_and_bfloat16_fails(no_chip_needed):
+    import jax.numpy as jnp
+
+    train = harness.load_module("drivers", "train")
+    cell = tiny_training_cell()
+    out = train.run(cell, 2 ** 31 + 3, 1.0, False, time.perf_counter())
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    assert set(out["values"]) == {"train_samples_per_s", "setup_s"}
+    assert out["device"]["platform"] == "cpu"
+    assert out["record"]["iteration_ms"]
+    # parameters, inputs and activations in bfloat16: the update is lost
+    control = train.control_rows(cell["config_json"], 2 ** 31 + 3, 1,
+                                 out["compared"], jnp.bfloat16)
+    assert any(not r["ok"] for r in control)
+
+
+def test_training_run_with_a_step_that_leaves_its_state_unchanged(
+        monkeypatch, no_chip_needed):
+    from bigdl_tpu.optim import SGD
+
+    train = harness.load_module("drivers", "train")
+    build = train.build_optimizer
+
+    def broken(*a, **k):
+        opt = build(*a, **k)
+        opt.set_optim_method(SGD(learning_rate=0.0))
+        return opt
+
+    monkeypatch.setattr(train, "build_optimizer", broken)
+    out = train.run(tiny_training_cell(), 4, 0.5, False, time.perf_counter())
+    assert out["correct"] is False
+    assert not {r["name"]: r for r in out["checks"]}[
+        "param_change_norm_worst_leaf_gap"]["ok"]
+
+
+# ------------------------------------------------- references and no chip
+def test_gpt2_reference_agrees_with_the_program_at_a_tiny_size():
+    import jax.numpy as jnp
+
+    from benchmark import weights as bw
+    from benchmark.models import gpt2
+    from benchmark.reference import gpt2 as ref
+
+    cfg = tiny_serving_cell()["config_json"]
+    model = gpt2.build(cfg, 3)
+    ids = np.random.RandomState(0).randint(0, 250, (2, 40))
+    want = np.asarray(ref.forward(
+        bw.gpt2_weights(3, cfg["sizes"], jnp.float32), ids, 4))
+    got = np.asarray(model(jnp.asarray(ids)))
+    assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
+    gaps = compare.served_token_gaps(
+        want[0], np.concatenate([ids[0, :30], want[0, 29:39].argmax(-1)]), 30)
+    assert gaps[0] == 0.0 and (gaps >= 0).all()
+
+
+def test_a_run_without_a_chip_fails_and_prints_no_result():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"),
+         "--workload", "gpt2l-chat-steady", "--seed", "1", "--seconds", "1",
+         "--trace", "0"], env=env, capture_output=True, text=True,
+        timeout=120)
+    assert done.returncode != 0
+    assert '"correct"' not in done.stdout and "TPU" in done.stderr
