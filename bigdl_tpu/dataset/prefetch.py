@@ -28,6 +28,9 @@ def prefetch(iterator: Iterator, buffer_size: int = 2,
 
     ``transfer`` (e.g. a ``jax.device_put`` with a NamedSharding) runs on
     the background thread so H2D DMA overlaps the consumer's step.
+    Each batch is one ``input/batch`` span of the producer thread: pulling
+    it out of ``iterator`` (``input/stack``), then ``transfer``
+    (``input/place``).
     Exceptions in the producer are re-raised at the consumer site.
     """
     q: "queue.Queue" = queue.Queue(maxsize=max(1, buffer_size))
@@ -42,17 +45,28 @@ def prefetch(iterator: Iterator, buffer_size: int = 2,
         while not stop.is_set():
             try:
                 q.put(item, timeout=0.1)
-                return True
+                # a put that got through as the consumer left and drained
+                # the queue must not start one more batch
+                return not stop.is_set()
             except queue.Full:
                 continue
         return False
 
     def produce():
+        from bigdl_tpu.observability import trace
+
+        it = iter(iterator)
         try:
-            for item in iterator:
-                if transfer is not None:
-                    item = transfer(item)
-                if not _put(item):
+            while True:
+                # one root a batch on this thread. The wait on a full
+                # queue lies outside it: ``input/batch`` is busy time
+                with trace.span("input/batch"):
+                    with trace.span("input/stack"):
+                        item = next(it, _STOP)
+                    if item is not _STOP and transfer is not None:
+                        with trace.span("input/place"):
+                            item = transfer(item)
+                if item is _STOP or not _put(item):
                     return
         except BaseException as e:  # propagate to consumer
             err.append(e)

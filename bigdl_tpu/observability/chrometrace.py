@@ -58,23 +58,21 @@ class _Tids:
         return self._map.items()
 
 
-def _span_events(sp: Span, tids: _Tids, pid: int, now_wall: float,
+def _span_events(sp: Span, tids: _Tids, pid: int, now_ns: int,
                  out: List[dict]) -> None:
-    dur = sp.duration
-    args = {}
-    if dur is None:
-        # still open: duration so far (durations are measured on
-        # perf_counter but rendered on the wall axis; the skew over a
-        # span's lifetime is negligible at trace resolution)
-        dur = max(0.0, now_wall - sp.start)
+    args = dict(sp.attrs) if sp.attrs else {}
+    end_ns = sp.end_ns
+    if end_ns is None:
+        # still open: duration so far
+        end_ns = max(now_ns, sp.start_ns)
         args["open"] = True
     out.append({
         "name": sp.name, "cat": "span", "ph": "X",
-        "ts": sp.start * 1e6, "dur": dur * 1e6,
+        "ts": sp.start_ns / 1e3, "dur": (end_ns - sp.start_ns) / 1e3,
         "pid": pid, "tid": tids(sp.thread), "args": args,
     })
     for c in sp.children:
-        _span_events(c, tids, pid, now_wall, out)
+        _span_events(c, tids, pid, now_ns, out)
 
 
 def chrome_trace_events(tracer: Optional[Tracer] = None,
@@ -91,13 +89,13 @@ def chrome_trace_events(tracer: Optional[Tracer] = None,
     recorder = recorder if recorder is not None else default_recorder()
     pid = os.getpid()
     tids = _Tids()
-    now_wall = time.time()
+    now_ns = time.time_ns()
     out: List[dict] = []
 
     for root in tracer.roots():
-        _span_events(root, tids, pid, now_wall, out)
+        _span_events(root, tids, pid, now_ns, out)
     for root in tracer.open_spans():
-        _span_events(root, tids, pid, now_wall, out)
+        _span_events(root, tids, pid, now_ns, out)
 
     off = recorder.wall_offset
     for ev in recorder.tail(last_events):

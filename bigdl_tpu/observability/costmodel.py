@@ -292,12 +292,15 @@ class DispatchCostModel:
 class LoopPhaseAccumulator:
     """Attributes engine-loop wall time to named host-side phases.
 
-    The loop thread brackets each phase with :meth:`add` (measured
-    boundary-to-boundary, so per-iteration phase seconds sum to the
-    iteration wall by construction) and reports device dispatches
-    through :meth:`dispatch`, which also accumulates the *warm* walls
-    into the device-busy pool — the same walls, at the same call sites,
-    that the usage ledger charges, so
+    The loop thread feeds it from the closes of its own spans (the
+    children of ``serving/iteration``, whose boundaries touch, so
+    per-iteration phase seconds sum to the iteration wall by
+    construction): :meth:`add` takes a host phase's span seconds (the
+    self time of ``admission`` and ``deliver``, whose dispatches are
+    their children), :meth:`dispatch` a dispatch span's duration, and
+    also accumulates the *warm* walls into the device-busy pool — the
+    same walls, at the same call sites, that the usage ledger charges,
+    so
     ``device_idle_fraction == 1 - occupancy-ledger busy / devices /
     wall`` reconciles to float precision.
     """

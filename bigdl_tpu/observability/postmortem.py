@@ -116,7 +116,7 @@ def build_postmortem(error: Optional[BaseException] = None,
     try:
         pm["open_spans"] = [
             {"thread": sp.thread, "name": sp.name,
-             "started_wall_s": sp.start, "tree": sp.tree()}
+             "started_wall_s": sp.start_ns / 1e9, "tree": sp.tree()}
             for sp in tracer.open_spans()]
     except Exception as e:
         pm["open_spans"] = []

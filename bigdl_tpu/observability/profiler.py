@@ -67,7 +67,14 @@ def start_capture(out_dir: Optional[str] = None) -> str:
         path = out_dir or tempfile.mkdtemp(prefix="bigdl_profile_")
         os.makedirs(path, exist_ok=True)
         try:
-            jp.start_trace(path)
+            # the Python tracer slows the host it is meant to observe,
+            # and host level 2 costs the serving loop 100 ms a token gap
+            # (PERF.md): level 1 keeps the program's TraceAnnotations,
+            # what a traced benchmark run records
+            opts = jp.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jp.start_trace(path, profiler_options=opts)
         except Exception as e:
             raise ProfilerUnavailable(
                 f"profiler capture unsupported on this backend: "

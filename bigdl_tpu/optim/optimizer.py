@@ -544,76 +544,87 @@ class LocalOptimizer(Optimizer):
 
         obs_on = getattr(self, "_obs_on", False)
         ins = obs.train_instruments() if obs_on else None
+        span = obs.trace.span
+        # one root an iteration; its children tile it (boundaries touch),
+        # so what the loop does while the device idles has a name
         while not self.end_when(state):
-            x, y, n = next(data_iter)
-            lrs = ts.current_lrs()
-            lr = float(lrs[0])
-            rng = bt_random.next_key()
-            t0 = time.time()
-            gnorm = None
-            with obs.trace.span("train/step"):
-                if obs_on:
-                    loss, gnorm, params, buffers, slots = train_step(
-                        params, buffers, slots, x, y, lrs, rng)
-                else:
-                    loss, params, buffers, slots = train_step(
-                        params, buffers, slots, x, y, lrs, rng)
-                loss = float(loss)
-            dt = time.time() - t0
-            state["recordsProcessedThisEpoch"] += n
-            state["Loss"] = loss
-            state["LearningRate"] = float(lr)
-            self.metrics.add("computing time", dt * 1e9)
-            if obs_on:
-                ins.step_seconds.observe(dt)
-                ins.records_total.inc(n)
-                ins.throughput.set(n / max(dt, 1e-9))
-                ins.loss.set(loss)
-                ins.learning_rate.set(lr)
-                ins.grad_norm.set(float(gnorm))
-                ins.epoch.set(state["epoch"])
-                ins.jit_compiles.set(train_step._cache_size())
-            logger.info(
-                "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
-                "Trained %d records in %.4f seconds. Throughput is %.1f records/second. "
-                "Loss is %.4f.",
-                state["epoch"], state["recordsProcessedThisEpoch"], num_samples,
-                state["neval"], time.time() - wall_start, n, dt, n / max(dt, 1e-9), loss)
+            with span("train/iteration", neval=state["neval"]):
+                with span("train/data_wait"):
+                    x, y, n = next(data_iter)
+                # the step's learning rates and key: small device programs
+                # of their own, and a fetch
+                with span("train/arguments"):
+                    lrs = ts.current_lrs()
+                    lr = float(lrs[0])
+                    rng = bt_random.next_key()
+                gnorm = None
+                with span("train/step", histogram=(
+                        ins.step_seconds if obs_on else None)) as step_span:
+                    # the call into the jitted step: the enqueue
+                    with span("train/dispatch"):
+                        if obs_on:
+                            loss, gnorm, params, buffers, slots = train_step(
+                                params, buffers, slots, x, y, lrs, rng)
+                        else:
+                            loss, params, buffers, slots = train_step(
+                                params, buffers, slots, x, y, lrs, rng)
+                    with span("train/fence"):   # the wait for the device
+                        loss = float(loss)
+                dt = step_span.duration
+                with span("train/bookkeeping"):
+                    state["recordsProcessedThisEpoch"] += n
+                    state["Loss"] = loss
+                    state["LearningRate"] = float(lr)
+                    self.metrics.add("computing time", dt * 1e9)
+                    if obs_on:
+                        ins.records_total.inc(n)
+                        ins.throughput.set(n / max(dt, 1e-9))
+                        ins.loss.set(loss)
+                        ins.learning_rate.set(lr)
+                        ins.grad_norm.set(float(gnorm))
+                        ins.epoch.set(state["epoch"])
+                        ins.jit_compiles.set(train_step._cache_size())
+                    logger.info(
+                        "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
+                        "Trained %d records in %.4f seconds. Throughput is %.1f records/second. "
+                        "Loss is %.4f.",
+                        state["epoch"], state["recordsProcessedThisEpoch"], num_samples,
+                        state["neval"], time.time() - wall_start, n, dt, n / max(dt, 1e-9), loss)
 
-            if self.train_summary is not None:
-                self.train_summary.add_scalar("Loss", loss, state["neval"])
-                self.train_summary.add_scalar("LearningRate", float(lr), state["neval"])
-                self.train_summary.add_scalar("Throughput", n / max(dt, 1e-9), state["neval"])
-                # optional parameter histograms, gated on a trigger
-                # (≙ TrainSummary "Parameters" tag, TrainSummary.scala:32)
-                ptrig = getattr(self.train_summary, "get_summary_trigger",
-                                lambda _n: None)("Parameters")
-                if ptrig is not None and ptrig(state):
-                    for pname, leaf in _named_param_leaves(params):
-                        self.train_summary.add_histogram(
-                            pname, np.asarray(leaf), state["neval"])
+                    if self.train_summary is not None:
+                        self.train_summary.add_scalar("Loss", loss, state["neval"])
+                        self.train_summary.add_scalar("LearningRate", float(lr), state["neval"])
+                        self.train_summary.add_scalar("Throughput", n / max(dt, 1e-9), state["neval"])
+                        # optional parameter histograms, gated on a trigger
+                        # (≙ TrainSummary "Parameters" tag, TrainSummary.scala:32)
+                        ptrig = getattr(self.train_summary, "get_summary_trigger",
+                                        lambda _n: None)("Parameters")
+                        if ptrig is not None and ptrig(state):
+                            for pname, leaf in _named_param_leaves(params):
+                                self.train_summary.add_histogram(
+                                    pname, np.asarray(leaf), state["neval"])
 
-            state["neval"] += 1
-            if state["recordsProcessedThisEpoch"] >= num_samples:
-                state["epoch"] += 1
-                state["recordsProcessedThisEpoch"] = 0
-                # reshuffle + restart happen inside _batch_stream (on the
-                # producer side, ordered ahead of the prefetched batches)
-            ts.update_states(neval=state["neval"], epoch=state["epoch"], Loss=loss)
-
-            # write updated weights back before validation/checkpoint
-            if self._should_fire_aux(state):
-                model.load_params_dict(params)
-                model.load_buffers_dict(buffers)
-                with obs.trace.span("train/validation"):
-                    self._run_validation(state)
-                # only a real checkpoint samples the latency histogram —
-                # the no-op branch would flood it with ~µs entries
-                ck_hist = (ins.checkpoint_seconds
-                           if obs_on and self._ckpt_now
-                           and self.checkpoint_path is not None else None)
-                with obs.trace.span("train/checkpoint", histogram=ck_hist):
-                    self._run_checkpoint(state)
+                    state["neval"] += 1
+                    if state["recordsProcessedThisEpoch"] >= num_samples:
+                        state["epoch"] += 1
+                        state["recordsProcessedThisEpoch"] = 0
+                        # reshuffle + restart happen inside _batch_stream (on the
+                        # producer side, ordered ahead of the prefetched batches)
+                    ts.update_states(neval=state["neval"], epoch=state["epoch"], Loss=loss)
+                    aux_now = self._should_fire_aux(state)
+                # write updated weights back before validation/checkpoint
+                if aux_now:
+                    model.load_params_dict(params)
+                    model.load_buffers_dict(buffers)
+                    with span("train/validation"):
+                        self._run_validation(state)
+                    # only a real checkpoint samples the latency histogram
+                    # — the no-op branch would flood it with ~µs entries
+                    ck_hist = (ins.checkpoint_seconds
+                               if obs_on and self._ckpt_now
+                               and self.checkpoint_path is not None else None)
+                    with span("train/checkpoint", histogram=ck_hist):
+                        self._run_checkpoint(state)
 
         model.load_params_dict(params)
         model.load_buffers_dict(buffers)

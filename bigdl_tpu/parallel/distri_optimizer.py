@@ -517,91 +517,105 @@ class DistriOptimizer(LocalOptimizer):
             host = str(jax.process_index())
             pins = obs.parallel_instruments() if obs_on else None
 
+            span = obs.trace.span
+            # one root an iteration, the names LocalOptimizer's loop uses;
+            # the loss is fetched (train/fence) only at the log points
             while not self.end_when(state):
-                x, y, n_local = next(data_iter)
-                if ts is not None:
-                    lrs = ts.current_lrs()
-                    lr = float(lrs[0])
-                else:
-                    lr = method.get_current_rate()
-                    lrs = jnp.asarray(lr, jnp.float32)
-                rng = bt_random.next_key()
-                with obs.trace.span("train/step"):
-                    if self.parameter_sync == "sharded":
-                        loss, params, buffers, flat, slots = step(
-                            params, buffers, flat, slots, x, y, lrs, rng)
-                    else:
-                        loss, params, buffers, slots = step(
-                            params, buffers, slots, x, y, lrs, rng)
-                self._live_slots = slots
-                if self._fault_hook is not None:
-                    self._fault_hook(state)
-                n = n_local * nproc  # global records this iteration
-                state["recordsProcessedThisEpoch"] += n
-                state["LearningRate"] = lr
-                window_records += n
-                window_iters += 1
-                state["neval"] += 1
-                aux_now = self._should_fire_aux(state)
-                log_now = (state["neval"] - 1) % self.log_interval == 0
-                if log_now or aux_now:
-                    loss_v = float(loss)  # the only host sync in the loop
-                    dt = time.time() - window_start
-                    state["Loss"] = loss_v
-                    self.metrics.add("computing time", dt * 1e9)
-                    if obs_on:
-                        ins.records_total.inc(window_records)
-                        ins.throughput.set(window_records / max(dt, 1e-9))
-                        ins.loss.set(loss_v)
-                        ins.learning_rate.set(lr)
-                        ins.epoch.set(state["epoch"])
-                        ins.jit_compiles.set(step._cache_size())
-                        # per-host SPMD timings: the whole pipelined window,
-                        # and its per-iteration average (the step-time proxy
-                        # when dispatch overlaps host work)
-                        pins.sync_window_seconds.labels(host).observe(dt)
-                        pins.step_seconds.labels(host).observe(
-                            dt / max(window_iters, 1))
-                    logger.info(
-                        "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
-                        "Trained %d records in %.4f seconds. "
-                        "Throughput is %.1f records/second. Loss is %.4f.",
-                        state["epoch"], state["recordsProcessedThisEpoch"],
-                        num_samples, state["neval"] - 1, time.time() - wall_start,
-                        window_records, dt, window_records / max(dt, 1e-9), loss_v)
-                    if self.train_summary is not None:
-                        it = state["neval"] - 1
-                        self.train_summary.add_scalar("Loss", loss_v, it)
-                        self.train_summary.add_scalar("LearningRate", lr, it)
-                        self.train_summary.add_scalar(
-                            "Throughput", window_records / max(dt, 1e-9), it)
-                    window_records = 0
-                    window_iters = 0
-                    window_start = time.time()
-                if state["recordsProcessedThisEpoch"] >= num_samples:
-                    state["epoch"] += 1
-                    state["recordsProcessedThisEpoch"] = 0
-                    # reshuffle + restart happen inside _batch_stream (producer
-                    # side, ordered ahead of the prefetched batches)
-                if ts is not None:
-                    kv = dict(neval=state["neval"], epoch=state["epoch"])
-                    if "Loss" in state:
-                        kv["Loss"] = state["Loss"]
-                    ts.update_states(**kv)
-                if aux_now:
-                    # NOTE (Appendix B.5 contract decision): the reference
-                    # validates with start-of-iteration weights; this build
-                    # validates with the just-updated weights — strictly
-                    # fresher, documented as an intentional deviation.
-                    model.load_params_dict(params)
-                    model.load_buffers_dict(buffers_for_model(buffers))
-                    with obs.trace.span("train/validation"):
-                        self._run_validation(state)
-                    ck_hist = (ins.checkpoint_seconds
-                               if obs_on and self._ckpt_now
-                               and self.checkpoint_path is not None else None)
-                    with obs.trace.span("train/checkpoint", histogram=ck_hist):
-                        self._run_checkpoint(state)
+                with span("train/iteration", neval=state["neval"]):
+                    with span("train/data_wait"):
+                        x, y, n_local = next(data_iter)
+                    # the step's learning rates and key: small device
+                    # programs of their own, and a fetch
+                    with span("train/arguments"):
+                        if ts is not None:
+                            lrs = ts.current_lrs()
+                            lr = float(lrs[0])
+                        else:
+                            lr = method.get_current_rate()
+                            lrs = jnp.asarray(lr, jnp.float32)
+                        rng = bt_random.next_key()
+                    with span("train/step"):
+                        # the call into the jitted step: the enqueue
+                        with span("train/dispatch"):
+                            if self.parameter_sync == "sharded":
+                                loss, params, buffers, flat, slots = step(
+                                    params, buffers, flat, slots, x, y, lrs,
+                                    rng)
+                            else:
+                                loss, params, buffers, slots = step(
+                                    params, buffers, slots, x, y, lrs, rng)
+                    with span("train/bookkeeping"):
+                        self._live_slots = slots
+                        if self._fault_hook is not None:
+                            self._fault_hook(state)
+                        n = n_local * nproc  # global records this iteration
+                        state["recordsProcessedThisEpoch"] += n
+                        state["LearningRate"] = lr
+                        window_records += n
+                        window_iters += 1
+                        state["neval"] += 1
+                        aux_now = self._should_fire_aux(state)
+                        log_now = (state["neval"] - 1) % self.log_interval == 0
+                        if log_now or aux_now:
+                            # the only host sync in the loop
+                            with span("train/fence"):
+                                loss_v = float(loss)
+                            dt = time.time() - window_start
+                            state["Loss"] = loss_v
+                            self.metrics.add("computing time", dt * 1e9)
+                            if obs_on:
+                                ins.records_total.inc(window_records)
+                                ins.throughput.set(window_records / max(dt, 1e-9))
+                                ins.loss.set(loss_v)
+                                ins.learning_rate.set(lr)
+                                ins.epoch.set(state["epoch"])
+                                ins.jit_compiles.set(step._cache_size())
+                                # per-host SPMD timings: the whole pipelined window,
+                                # and its per-iteration average (the step-time proxy
+                                # when dispatch overlaps host work)
+                                pins.sync_window_seconds.labels(host).observe(dt)
+                                pins.step_seconds.labels(host).observe(
+                                    dt / max(window_iters, 1))
+                            logger.info(
+                                "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
+                                "Trained %d records in %.4f seconds. "
+                                "Throughput is %.1f records/second. Loss is %.4f.",
+                                state["epoch"], state["recordsProcessedThisEpoch"],
+                                num_samples, state["neval"] - 1, time.time() - wall_start,
+                                window_records, dt, window_records / max(dt, 1e-9), loss_v)
+                            if self.train_summary is not None:
+                                it = state["neval"] - 1
+                                self.train_summary.add_scalar("Loss", loss_v, it)
+                                self.train_summary.add_scalar("LearningRate", lr, it)
+                                self.train_summary.add_scalar(
+                                    "Throughput", window_records / max(dt, 1e-9), it)
+                            window_records = 0
+                            window_iters = 0
+                            window_start = time.time()
+                        if state["recordsProcessedThisEpoch"] >= num_samples:
+                            state["epoch"] += 1
+                            state["recordsProcessedThisEpoch"] = 0
+                            # reshuffle + restart happen inside _batch_stream (producer
+                            # side, ordered ahead of the prefetched batches)
+                        if ts is not None:
+                            kv = dict(neval=state["neval"], epoch=state["epoch"])
+                            if "Loss" in state:
+                                kv["Loss"] = state["Loss"]
+                            ts.update_states(**kv)
+                    if aux_now:
+                        # NOTE (Appendix B.5 contract decision): the reference
+                        # validates with start-of-iteration weights; this build
+                        # validates with the just-updated weights — strictly
+                        # fresher, documented as an intentional deviation.
+                        model.load_params_dict(params)
+                        model.load_buffers_dict(buffers_for_model(buffers))
+                        with span("train/validation"):
+                            self._run_validation(state)
+                        ck_hist = (ins.checkpoint_seconds
+                                   if obs_on and self._ckpt_now
+                                   and self.checkpoint_path is not None else None)
+                        with span("train/checkpoint", histogram=ck_hist):
+                            self._run_checkpoint(state)
 
             if obs_on and window_records:
                 # the partial window between the last log sync and loop exit

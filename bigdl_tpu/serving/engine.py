@@ -81,6 +81,7 @@ import numpy as np
 from bigdl_tpu.observability.costmodel import (
     DispatchCostModel, LoopPhaseAccumulator, device_peaks, program_cost,
 )
+from bigdl_tpu.observability.tracing import trace
 from bigdl_tpu.observability.timeseries import (
     TimeSeriesSampler, render_dashboard,
 )
@@ -799,7 +800,6 @@ class ContinuousBatchingEngine:
         self._cost = DispatchCostModel(
             device_peaks(self._cost_device()), devices=n_dev)
         self._loop_obs = LoopPhaseAccumulator()
-        self._iter_disp = {"prefill": 0.0, "decode": 0.0}
         self._extract_program_costs()
         #: counter children + flushed totals for the per-phase series
         self._loop_phase_counters = {
@@ -2323,8 +2323,6 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------------- loop body
     def _loop(self):
-        from bigdl_tpu.observability import trace
-
         try:
             while not self._stop_evt.is_set():
                 # idle engines BLOCK (submit/stop notify the condition;
@@ -2336,7 +2334,8 @@ class ContinuousBatchingEngine:
                 with self._wake:
                     while (not self._stop_evt.is_set()
                            and not self._has_work()):
-                        self._wake.wait(self.idle_wait_s)
+                        with trace.span("serving/idle_wait"):
+                            self._wake.wait(self.idle_wait_s)
                 if self._stop_evt.is_set():
                     break
                 with trace.span("serving/iteration",
@@ -2349,6 +2348,10 @@ class ContinuousBatchingEngine:
     def _crash(self, e: BaseException) -> None:
         with self._lifecycle:
             self._crashed = e
+            # as stop() does: a crashed engine's sampler thread must
+            # not outlive it (nobody is obliged to call stop() on a dead
+            # engine); under the lock start() takes to start it
+            self._ts.stop()
         self._rec.record("engine/crash", service=self.service_name,
                          error=repr(e))
         # capture the in-flight picture BEFORE failing the handles —
@@ -2491,15 +2494,61 @@ class ContinuousBatchingEngine:
             return None
 
     def _iterate(self) -> bool:
-        now = time.monotonic()
-        worked = False
+        """One turn of the loop, as child spans of ``serving/iteration``
+        whose boundaries touch. The spans are the loop's one timing
+        source: the phase accumulator takes its seconds from them (a
+        dispatch span's duration, reported where it closes; the SELF
+        time of ``admission`` and ``deliver``, whose dispatches are
+        their children), so phase seconds sum to the iteration's wall
+        by construction."""
         lo = self._loop_obs
-        # per-iteration dispatch scratch: _prefill_round /
-        # _decode_all* accumulate their dispatch walls here so the
-        # boundary-measured host segments below can subtract them out
-        # — phase seconds then sum to the iteration wall by
-        # construction
-        self._iter_disp = {"prefill": 0.0, "decode": 0.0}
+        with trace.span("serving/sweep") as sp:
+            now = self._sweep()
+        lo.add("sweep", sp.duration)
+
+        # 3. admission: prefix-aware intake + batched chunked-prefill
+        #    rounds under this iteration's budget — every round
+        #    advances ALL staged admissions together through one
+        #    ragged dispatch
+        worked = False
+        with trace.span("serving/admission") as sp:
+            self._policy.begin_iteration()
+            while True:
+                self._fill_admissions(now)
+                if not self._adms or not self._policy.take_chunk():
+                    break
+                self._prefill_round()
+                worked = True
+        lo.add("admission", sp.self_ns() / 1e9)
+
+        # 4. one fused decode step over every occupied slot; what is
+        #    left of the span beside the dispatch is sampling transfers
+        #    and stream delivery (the "deliver" bubble)
+        with trace.span("serving/deliver") as sp:
+            occupied = [sid for sid, st in enumerate(self._slots)
+                        if st is not None]
+            active = list(occupied)
+            if self._chaos is not None:
+                # frozen slots sit out this round's fused step (their
+                # KV and handle are untouched — they resume when the
+                # freeze expires), simulating a straggler row
+                active = [sid for sid in active
+                          if not self._chaos.slot_frozen(sid)]
+            if active:
+                self._decode_all(active)
+                worked = True
+        lo.add("deliver", sp.self_ns() / 1e9)
+
+        with trace.span("serving/observe") as sp:
+            self._observe(occupied, active)
+        lo.add("observe", sp.duration)
+        return worked
+
+    def _sweep(self) -> float:
+        """Cancellation and deadline eviction over running slots,
+        admissions in progress and the queue. Returns the iteration's
+        ``now`` (monotonic)."""
+        now = time.monotonic()
         # paged: a fresh iteration may admit again — pages freed by
         # the releases/donations above can satisfy what blocked before
         self._adm_blocked = False
@@ -2536,47 +2585,14 @@ class ContinuousBatchingEngine:
         # 2. queued requests: mid-queue deadline/cancel sweep
         for h, err in self._queue.sweep(now):
             self._finish_dropped(h, err)
-        t_sweep = time.monotonic()
-        lo.add("sweep", t_sweep - now)
+        return now
 
-        # 3. admission: prefix-aware intake + batched chunked-prefill
-        #    rounds under this iteration's budget — every round
-        #    advances ALL staged admissions together through one
-        #    ragged dispatch
-        self._policy.begin_iteration()
-        while True:
-            self._fill_admissions(now)
-            if not self._adms or not self._policy.take_chunk():
-                break
-            self._prefill_round()
-            worked = True
-        t_adm = time.monotonic()
-        # the prefill dispatch walls were phase-attributed inside
-        # _prefill_round; the segment's remainder is host admission work
-        lo.add("admission",
-               max(0.0, t_adm - t_sweep - self._iter_disp["prefill"]))
-
-        # 4. one fused decode step over every occupied slot
-        occupied = [sid for sid, st in enumerate(self._slots)
-                    if st is not None]
-        active = list(occupied)
-        if self._chaos is not None:
-            # frozen slots sit out this round's fused step (their KV
-            # and handle are untouched — they resume when the freeze
-            # expires), simulating a straggler row
-            active = [sid for sid in active
-                      if not self._chaos.slot_frozen(sid)]
-        if active:
-            self._decode_all(active)
-            worked = True
-        t_dec = time.monotonic()
-        # decode-segment remainder = sampling transfers + stream
-        # delivery around the dispatch ("deliver" bubble)
-        lo.add("deliver",
-               max(0.0, t_dec - t_adm - self._iter_disp["decode"]))
-
-        # 5. load gauges + watchdog sampling (one probe read and one
-        #    histogram snapshot per objective — iteration-rate cheap)
+    def _observe(self, occupied: List[int], active: List[int]) -> None:
+        """5. load gauges + watchdog sampling (one probe read and one
+        histogram snapshot per objective — iteration-rate cheap), and
+        the phase counters' flush: what the telemetry itself costs on
+        the decode thread."""
+        lo = self._loop_obs
         ins = self._ins
         ins.active_slots.set(sum(s is not None for s in self._slots))
         ins.queue_depth.set(len(self._queue))
@@ -2601,7 +2617,8 @@ class ContinuousBatchingEngine:
         if bw_p is not None:
             ins.membw_util_prefill.set(bw_p)
         lo.iteration()
-        lo.add("observe", time.monotonic() - t_dec)
+        # the counters trail the accumulator by this span's own seconds,
+        # which the next iteration's flush carries
         snap = lo.summary()
         for p, child in self._loop_phase_counters.items():
             delta = snap["phases"][p] - self._loop_flushed[p]
@@ -2609,7 +2626,6 @@ class ContinuousBatchingEngine:
                 child.inc(delta)
                 self._loop_flushed[p] += delta
         ins.loop_idle_fraction.set(snap["device_idle_fraction"])
-        return worked
 
     # ------------------------------------------------ admission stages
     def _free_slot(self) -> Optional[int]:
@@ -3083,56 +3099,6 @@ class ContinuousBatchingEngine:
         was_warm = "chunk" in self._warm and (
             not spec or "d_chunk" in self._warm) and (
             not finals or "sample0" in self._warm)
-        if self._chaos is not None:
-            self._chaos.on_dispatch()
-        t_disp = time.monotonic()
-        if self.paged:
-            # same ragged dispatch, but each row writes through its
-            # admission's reserved block table (idle rows carry the
-            # all-scratch table — their padding writes hit page 0)
-            logits, self._kv_pool = self._chunk_jit(
-                self._params, self._buffers, self._h2d(ids),
-                self._kv_pool, self._adm_tables(), self._h2d(pos0),
-                self._h2d(last))
-        else:
-            logits, self._staging = self._chunk_jit(
-                self._params, self._buffers, self._h2d(ids),
-                self._staging, self._h2d(pos0), self._h2d(last))
-        self._warm.add("chunk")
-        if spec:
-            d_ids = np.zeros((rows, c), np.int32)
-            d_pos0 = np.zeros((rows,), np.int32)
-            for a in self._adms:
-                dk = a.d_next_chunk
-                d_ids[a.row] = a.d_ids[dk * c:(dk + 1) * c]
-                d_pos0[a.row] = dk * c
-            if self.paged:
-                _, self._d_kv_pool = self._d_chunk_jit(
-                    self._d_params, self._d_bufs, self._h2d(d_ids),
-                    self._d_kv_pool, self._adm_tables(draft=True),
-                    self._h2d(d_pos0),
-                    self._h2d(np.zeros((rows,), np.int32)))
-            else:
-                _, self._d_staging = self._d_chunk_jit(
-                    self._d_params, self._d_bufs, self._h2d(d_ids),
-                    self._d_staging, self._h2d(d_pos0),
-                    self._h2d(np.zeros((rows,), np.int32)))
-            self._warm.add("d_chunk")
-        toks = None
-        if finals:
-            # the host-side transfer blocks on the sampled tokens —
-            # which depend on the chunk's logits, so the measured wall
-            # covers the real dispatch on rounds that finish a prompt
-            toks = np.asarray(self._sample0_jit(
-                logits, self._next_key(), self._temp()))
-            self._warm.add("sample0")
-        wall = time.monotonic() - t_disp
-        # the same warm-only wall feeds the usage ledger, the cost
-        # model, and the loop-phase busy pool — one measurement, three
-        # views, so roofline/idle/goodput figures reconcile exactly
-        self._iter_disp["prefill"] += wall
-        self._loop_obs.dispatch("prefill_dispatch", wall, warm=was_warm)
-        self._cost.charge("prefill", wall, warm=was_warm)
         # pro-rata attribution by REAL tokens each row advanced (the
         # padded tail of a final chunk is engine overhead, not billable
         # work; a replayed chunk advances nothing and earns nothing;
@@ -3144,6 +3110,59 @@ class ContinuousBatchingEngine:
                       if a.next_chunk < a.n_chunks else 0)
             d_done = min(c, a.t0 - a.d_next_chunk * c) if spec else 0
             done_by.append((a, t_done, d_done))
+        if self._chaos is not None:
+            self._chaos.on_dispatch()
+        with trace.span(
+                "serving/prefill_dispatch", rows=len(self._adms),
+                tokens=sum(t + d for _, t, d in done_by),
+                request_ids=[a.handle.request_id
+                             for a in self._adms]) as disp:
+            if self.paged:
+                # same ragged dispatch, but each row writes through its
+                # admission's reserved block table (idle rows carry the
+                # all-scratch table — their padding writes hit page 0)
+                logits, self._kv_pool = self._chunk_jit(
+                    self._params, self._buffers, self._h2d(ids),
+                    self._kv_pool, self._adm_tables(), self._h2d(pos0),
+                    self._h2d(last))
+            else:
+                logits, self._staging = self._chunk_jit(
+                    self._params, self._buffers, self._h2d(ids),
+                    self._staging, self._h2d(pos0), self._h2d(last))
+            self._warm.add("chunk")
+            if spec:
+                d_ids = np.zeros((rows, c), np.int32)
+                d_pos0 = np.zeros((rows,), np.int32)
+                for a in self._adms:
+                    dk = a.d_next_chunk
+                    d_ids[a.row] = a.d_ids[dk * c:(dk + 1) * c]
+                    d_pos0[a.row] = dk * c
+                if self.paged:
+                    _, self._d_kv_pool = self._d_chunk_jit(
+                        self._d_params, self._d_bufs, self._h2d(d_ids),
+                        self._d_kv_pool, self._adm_tables(draft=True),
+                        self._h2d(d_pos0),
+                        self._h2d(np.zeros((rows,), np.int32)))
+                else:
+                    _, self._d_staging = self._d_chunk_jit(
+                        self._d_params, self._d_bufs, self._h2d(d_ids),
+                        self._d_staging, self._h2d(d_pos0),
+                        self._h2d(np.zeros((rows,), np.int32)))
+                self._warm.add("d_chunk")
+            toks = None
+            if finals:
+                # the host-side transfer blocks on the sampled tokens —
+                # which depend on the chunk's logits, so the measured wall
+                # covers the real dispatch on rounds that finish a prompt
+                toks = np.asarray(self._sample0_jit(
+                    logits, self._next_key(), self._temp()))
+                self._warm.add("sample0")
+        # the span's warm-only wall feeds the usage ledger, the cost
+        # model, and the loop-phase busy pool — one measurement, three
+        # views, so roofline/idle/goodput figures reconcile exactly
+        wall = disp.duration
+        self._loop_obs.dispatch("prefill_dispatch", wall, warm=was_warm)
+        self._cost.charge("prefill", wall, warm=was_warm)
         if was_warm:
             total_done = sum(t + d for _, t, d in done_by) or 1
             self._usage.charge_dispatch(
@@ -3667,26 +3686,27 @@ class ContinuousBatchingEngine:
         was_warm = "step" in self._warm   # cold = compile in the wall
         if self._chaos is not None:
             self._chaos.on_dispatch()
-        t_disp = time.monotonic()
-        if self.paged:
-            nxt, self._kv_pool = self._step_jit(
-                self._params, self._buffers, self._h2d(tok),
-                self._h2d(pos), self._kv_pool, self._slot_tables(),
-                self._next_key(), self._temp())
-        else:
-            nxt, self._caches = self._step_jit(
-                self._params, self._buffers, self._h2d(tok),
-                self._h2d(pos), self._caches, self._next_key(),
-                self._temp())
-        self._warm.add("step")
-        nxt_np = np.asarray(nxt)   # blocks on the fused step
+        with trace.span("serving/decode_dispatch",
+                        rows=len(active)) as disp:
+            if self.paged:
+                nxt, self._kv_pool = self._step_jit(
+                    self._params, self._buffers, self._h2d(tok),
+                    self._h2d(pos), self._kv_pool, self._slot_tables(),
+                    self._next_key(), self._temp())
+            else:
+                nxt, self._caches = self._step_jit(
+                    self._params, self._buffers, self._h2d(tok),
+                    self._h2d(pos), self._caches, self._next_key(),
+                    self._temp())
+            self._warm.add("step")
+            with trace.span("serving/fetch_tokens"):
+                nxt_np = np.asarray(nxt)   # blocks on the fused step
         now = time.monotonic()
-        # same warm-only wall to ledger, cost model, and loop busy —
-        # one measurement, three reconciling views
-        self._iter_disp["decode"] += now - t_disp
-        self._loop_obs.dispatch("decode_dispatch", now - t_disp,
-                                warm=was_warm)
-        self._cost.charge("decode", now - t_disp, warm=was_warm)
+        # the span's warm-only wall to ledger, cost model, and loop
+        # busy — one measurement, three reconciling views
+        wall = disp.duration
+        self._loop_obs.dispatch("decode_dispatch", wall, warm=was_warm)
+        self._cost.charge("decode", wall, warm=was_warm)
         # every advanced row got exactly one token: the step's wall
         # splits evenly across them — identical to weighting by
         # delivered tokens, the speculative path's rule (idle slots
@@ -3696,7 +3716,7 @@ class ContinuousBatchingEngine:
         if was_warm:
             w = 1.0 / len(active)
             self._usage.charge_dispatch(
-                "decode", now - t_disp,
+                "decode", wall,
                 [(getattr(self._slots[sid].handle, "_usage", None), w)
                  for sid in active],
                 rows_advanced=len(active), capacity_rows=self.max_slots)
@@ -3727,33 +3747,34 @@ class ContinuousBatchingEngine:
             r_draft = r_acc = self._zero_key
         if self._chaos is not None:
             self._chaos.on_dispatch()
-        t_disp = time.monotonic()
-        tok_d, pos_d = self._h2d(tok), self._h2d(pos)
-        if self.paged:
-            props, qlogits, self._d_kv_pool = self._propose_jit(
-                self._d_params, self._d_bufs, tok_d, pos_d,
-                self._d_kv_pool, self._slot_tables(draft=True),
-                r_draft, self._temp())
-            emit, n_acc, self._kv_pool = self._spec_verify_jit(
-                self._params, self._buffers, tok_d, props,
-                qlogits, pos_d, self._kv_pool, self._slot_tables(),
-                r_acc, self._temp())
-        else:
-            props, qlogits, self._d_caches = self._propose_jit(
-                self._d_params, self._d_bufs, tok_d, pos_d,
-                self._d_caches, r_draft, self._temp())
-            emit, n_acc, self._caches = self._spec_verify_jit(
-                self._params, self._buffers, tok_d, props,
-                qlogits, pos_d, self._caches, r_acc,
-                self._temp())
-        emit_np = np.asarray(emit)    # blocks on both dispatches
-        n_np = np.asarray(n_acc)
-        wall = time.monotonic() - t_disp
+        with trace.span("serving/decode_dispatch",
+                        rows=len(active)) as disp:
+            tok_d, pos_d = self._h2d(tok), self._h2d(pos)
+            if self.paged:
+                props, qlogits, self._d_kv_pool = self._propose_jit(
+                    self._d_params, self._d_bufs, tok_d, pos_d,
+                    self._d_kv_pool, self._slot_tables(draft=True),
+                    r_draft, self._temp())
+                emit, n_acc, self._kv_pool = self._spec_verify_jit(
+                    self._params, self._buffers, tok_d, props,
+                    qlogits, pos_d, self._kv_pool, self._slot_tables(),
+                    r_acc, self._temp())
+            else:
+                props, qlogits, self._d_caches = self._propose_jit(
+                    self._d_params, self._d_bufs, tok_d, pos_d,
+                    self._d_caches, r_draft, self._temp())
+                emit, n_acc, self._caches = self._spec_verify_jit(
+                    self._params, self._buffers, tok_d, props,
+                    qlogits, pos_d, self._caches, r_acc,
+                    self._temp())
+            with trace.span("serving/fetch_tokens"):
+                emit_np = np.asarray(emit)    # blocks on both dispatches
+                n_np = np.asarray(n_acc)
         self._warm.update(("spec:propose", "spec:verify"))
         now = time.monotonic()
-        # same warm-only wall to ledger, cost model, and loop busy —
-        # one measurement, three reconciling views
-        self._iter_disp["decode"] += wall
+        # the span's warm-only wall to ledger, cost model, and loop
+        # busy — one measurement, three reconciling views
+        wall = disp.duration
         self._loop_obs.dispatch("decode_dispatch", wall, warm=was_warm)
         self._cost.charge("decode", wall, warm=was_warm)
         # draft sync BEFORE the next round can propose: a

@@ -220,13 +220,15 @@ def test_sampler_disabled_registry_noop_and_lifecycle():
     ts.start()
     ts.start()
     assert ts.running
-    assert any(t.name == "bigdl-timeseries"
-               for t in threading.enumerate())
+    # only the thread THIS sampler started is counted: another test's
+    # engine may have left a sampler of its own in this worker
+    mine = ts._thread
+    assert mine.name == "bigdl-timeseries"
+    assert mine in threading.enumerate()
     ts.stop()
     ts.stop()
     assert not ts.running
-    assert not any(t.name == "bigdl-timeseries"
-                   for t in threading.enumerate())
+    assert mine not in threading.enumerate()
 
 
 def test_render_dashboard_self_contained():
@@ -328,6 +330,7 @@ def test_engine_sampler_lifecycle_and_debug_timeseries(lm, reg, rec):
     assert not eng._ts.running
     with eng:
         assert eng._ts.running
+        mine = eng._ts._thread
         _serve(eng, n_requests=2)
         got = eng.debug_timeseries()
         assert got["service"] == "ts_eng" and got["running"]
@@ -340,8 +343,8 @@ def test_engine_sampler_lifecycle_and_debug_timeseries(lm, reg, rec):
         assert page.startswith("<!doctype html>") and "<svg" in page
     # engine.stop() joins the sampler thread — nothing leaks
     assert not eng._ts.running
-    assert not any(t.name == "bigdl-timeseries"
-                   for t in threading.enumerate())
+    assert mine.name == "bigdl-timeseries"
+    assert mine not in threading.enumerate()
 
 
 # ------------------------------------------------ HTTP route inventory

@@ -380,7 +380,7 @@ def test_postmortem_on_injected_decode_crash(lm, reg, rec, tmp_path):
 
 # ----------------------------------------------- tracer thread reclamation
 def test_tracer_reclaims_short_lived_thread_stacks():
-    tr = obs.Tracer(max_roots=512)
+    tr = obs.Tracer()
 
     def worker(i):
         with tr.span(f"req/{i}"):
@@ -397,7 +397,8 @@ def test_tracer_reclaims_short_lived_thread_stacks():
     # every stack was dropped when its last span closed
     assert tr._live == {}
     assert tr.open_spans() == []
-    assert len(tr.roots()) == 64
+    # (a full collection that ran meanwhile is a ``host/gc`` root of its own)
+    assert len([r for r in tr.roots() if r.name.startswith("req/")]) == 64
 
     # open spans ARE visible while a thread is inside one
     gate = threading.Event()

@@ -461,7 +461,10 @@ def test_optimizer_smoke_populates_training_metrics(reg):
     # the compile-count gauge rides jax's _cache_size
     assert reg.get("bigdl_train_jit_compiles").get() == 1
     assert reg.get("bigdl_train_throughput_records_per_sec").get() > 0
-    assert len(obs.trace.roots(name="train/step")) == 8
+    # one root an iteration; the step is its child
+    its = obs.trace.roots(name="train/iteration")
+    assert len(its) == 8
+    assert all("train/step" in [c.name for c in it.children] for it in its)
     # the same registry renders cleanly for a scraper
     text = obs.render_prometheus(reg)
     assert "# TYPE bigdl_train_step_seconds histogram" in text
