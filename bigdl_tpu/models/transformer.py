@@ -297,6 +297,18 @@ class TransformerLM(Module):
         return kv_pool_sharding(mesh, self.num_kv_heads,
                                 model_axis=model_axis)
 
+    def kv_page_pool_sharding(self, mesh, model_axis: str = "model"):
+        """NamedSharding for this model's ``init_page_pool`` buffers on
+        a tensor-parallel ``mesh``: the paged twin of
+        :meth:`kv_cache_sharding` for leaves ``(max_pages, page_size,
+        H_kv * D)``, whose heads are their LAST dimension — each device
+        holds the pages' rows of its own heads, so the paged KV write
+        needs no collective either."""
+        from bigdl_tpu.parallel.tp import kv_page_pool_sharding
+
+        return kv_page_pool_sharding(mesh, self.num_kv_heads,
+                                     model_axis=model_axis)
+
     # ------------------------------------------------ analytic cost model
     def param_count(self) -> int:
         """Total parameter count (all leaves of ``params_dict``)."""
@@ -444,10 +456,12 @@ class TransformerLM(Module):
                        dtype=jnp.float32, sharding=None, kv_dtype=None):
         """Per-block PAGE-POOL buffers for paged serving
         (bigdl_tpu/serving/paging.py): the ``init_cache`` tree forms
-        with the leading dim indexing pool pages instead of batch rows.
-        One block table indexes EVERY layer — page ``p`` names slice
-        ``p`` of each block's buffers — so a request's pages are one
-        id list, not one per layer."""
+        in the pool's own layout, each leaf ``(max_pages, page_size,
+        H_kv * D)`` — page and offset lead so the KV write lands in
+        place (``MultiHeadAttention.init_page_pool``). One block table
+        indexes EVERY layer — page ``p`` names slice ``p`` of each
+        block's buffers — so a request's pages are one id list, not
+        one per layer."""
         return [getattr(self, f"block{i}").attn.init_page_pool(
                     max_pages, page_size, dtype, sharding=sharding,
                     kv_dtype=kv_dtype)

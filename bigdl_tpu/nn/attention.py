@@ -128,34 +128,66 @@ def _write_kv(cache, k_t, v_t, write):
             dequantize_kv(v_q, v_s, v_t.dtype))
 
 
-def _gather_pages(leaf, tables):
+def _kv_buffers(shape, scale_shape, dtype, sharding, kv_dtype):
+    """Zero KV buffers of ``shape``: ``(k, v)`` in ``dtype``, or for
+    ``kv_dtype="int8"`` the quantized form ``(k_q, v_q, k_scale,
+    v_scale)`` with f32 sidecars of ``scale_shape``. ``sharding``
+    allocates each buffer directly with that layout."""
+    def mk(shp, dt):
+        return jnp.zeros(shp, dt, device=sharding) \
+            if sharding is not None else jnp.zeros(shp, dt)
+
+    if kv_dtype is None:
+        return mk(shape, dtype), mk(shape, dtype)
+    if str(kv_dtype) != "int8":
+        raise ValueError(
+            f"kv_dtype must be None (full precision) or 'int8', "
+            f"got {kv_dtype!r}")
+    return (mk(shape, jnp.int8), mk(shape, jnp.int8),
+            mk(scale_shape, jnp.float32), mk(scale_shape, jnp.float32))
+
+
+def _gather_pages(leaf, tables, heads):
     """Assemble one logical KV row per batch entry from a page pool:
-    ``leaf`` is a pool buffer (max_pages, H, page_size, D) and
-    ``tables`` (B, table_len) the per-row page ids — position ``i`` of
-    row ``b`` lives at ``leaf[tables[b, i // page_size], :,
-    i % page_size]``. Returns the dense view (B, H, table_len *
-    page_size, D) the existing attention math consumes unchanged; XLA
-    lowers the take to one gather, so compiled shape depends only on
-    the POOL geometry, never on any request's length. Table slots past
-    a request's reservation point at the scratch page — garbage the
+    ``leaf`` is a pool buffer (max_pages, page_size, H * D) — one
+    token's ``heads`` heads side by side in the minor dimension (the
+    scale sidecars: (max_pages, page_size, H)) — and ``tables``
+    (B, table_len) the per-row page ids: position ``i`` of row ``b``
+    lives at ``leaf[tables[b, i // page_size], i % page_size]``.
+    Returns the TOKEN-MAJOR dense view (B, table_len * page_size, H, D)
+    — a reshape of the gathered pages, no transpose: the paged
+    attention einsums contract over that order directly. XLA lowers
+    the take to one gather, so compiled shape depends only on the POOL
+    geometry, never on any request's length. Table slots past a
+    request's reservation point at the scratch page — garbage the
     caller's causal mask must (and does) discard."""
     b, tlen = tables.shape
-    g = jnp.take(leaf, tables, axis=0)          # (B, table_len, H, ps, D)
-    _, _, h, ps, d = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(b, h, tlen * ps, d)
+    g = jnp.take(leaf, tables, axis=0)          # (B, table_len, ps, H*D)
+    return g.reshape(b, tlen * g.shape[2], heads, -1)
 
 
 def _write_kv_paged(pool, k_t, v_t, tables, positions):
-    """Paged twin of :func:`_write_kv`: scatter one K/V block into the
-    page-pool buffers through per-row block tables, then gather the
-    dense per-row views attention attends over. ``positions`` is (B,)
-    (one decode token per row) or (B, T) (a ragged chunk); token ``t``
-    of row ``b`` scatters to page ``tables[b, positions[b,t] //
-    page_size]`` at offset ``positions[b, t] % page_size``. The
-    quantized 4-tuple form mirrors the dense path exactly — codes and
-    scale sidecars share the scatter index math, and what is attended
-    is the dequantized STORED view, so a paged cold pass is bitwise the
-    pass a dense engine runs.
+    """Paged twin of :func:`_write_kv`: scatter one K/V block
+    (B, H, T, D) into the page-pool buffers through per-row block
+    tables, then gather the dense per-row views (B, T_total, H, D)
+    attention attends over. ``positions`` is (B,) (one decode token
+    per row) or (B, T) (a ragged chunk); token ``t`` of row ``b``
+    scatters to page ``tables[b, positions[b,t] // page_size]`` at
+    offset ``positions[b, t] % page_size``.
+
+    The write is ``buf.at[page, offset].set(rows)`` on a leaf
+    (max_pages, page_size, H * D): the two indexed dimensions LEAD and
+    the window is one whole row of H * D, so the scatter updates the
+    donated leaf in place. Any layout that breaks either half re-lays
+    the WHOLE leaf around every scatter: heads between page and
+    offset, or a 4-D leaf whose minor dimension is a 64-wide head,
+    which the TPU runtime stores pages-MINOR to dodge lane padding
+    (PERF.md, PR 27; tests/test_chip_compile.py holds it).
+
+    The quantized 4-tuple form mirrors the dense path exactly — codes
+    and scale sidecars share the scatter index math, and what is
+    attended is the dequantized STORED view, so a paged cold pass
+    attends the values a dense engine's pass attends.
 
     Rows whose table slots are the scratch page (idle dispatch lanes)
     scatter junk there — multiple lanes may collide on it, which is
@@ -163,23 +195,25 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions):
     survives the position mask."""
     if jnp.ndim(positions) == 1:
         positions = positions[:, None]          # decode step: T == 1
-    ps = pool[0].shape[2]
+    ps = pool[0].shape[1]
+    heads = k_t.shape[1]
     pg = jnp.take_along_axis(tables, positions // ps, axis=1)  # (B, T)
     off = positions % ps
 
     def write(buf, blk):
-        # blk (B, H, T, D'): advanced indices at dims 0 and 2 put the
-        # scattered axes in front — value layout (B, T, H, D')
-        return buf.at[pg, :, off, :].set(
-            blk.transpose(0, 2, 1, 3).astype(buf.dtype))
+        # blk (B, H, T, D') -> one row of H * D' per token; the
+        # transpose is of the small block, never of the leaf
+        b, h, t, d = blk.shape
+        rows = blk.transpose(0, 2, 1, 3).reshape(b, t, h * d)
+        return buf.at[pg, off].set(rows.astype(buf.dtype))
 
     if len(pool) == 2:
         k_buf, v_buf = pool
         k_buf = write(k_buf, k_t)
         v_buf = write(v_buf, v_t)
         return ((k_buf, v_buf),
-                _gather_pages(k_buf, tables),
-                _gather_pages(v_buf, tables))
+                _gather_pages(k_buf, tables, heads),
+                _gather_pages(v_buf, tables, heads))
     k_q, v_q, k_s, v_s = pool
     kq, ks = quantize_kv(k_t)
     vq, vs = quantize_kv(v_t)
@@ -188,10 +222,10 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions):
     k_s = write(k_s, ks)
     v_s = write(v_s, vs)
     return ((k_q, v_q, k_s, v_s),
-            dequantize_kv(_gather_pages(k_q, tables),
-                          _gather_pages(k_s, tables), k_t.dtype),
-            dequantize_kv(_gather_pages(v_q, tables),
-                          _gather_pages(v_s, tables), v_t.dtype))
+            dequantize_kv(_gather_pages(k_q, tables, heads),
+                          _gather_pages(k_s, tables, heads), k_t.dtype),
+            dequantize_kv(_gather_pages(v_q, tables, heads),
+                          _gather_pages(v_s, tables, heads), v_t.dtype))
 
 
 def rotary_embedding(x, positions, base: float = 10000.0):
@@ -298,22 +332,11 @@ class MultiHeadAttention(Module):
         :func:`dequantize_kv` inside the attention paths. Scale
         sidecars keep rank 4 with heads at dim 1, so a heads-sharded
         pool layout (parallel/tp.py ``kv_pool_spec``) applies to the
-        whole tree unchanged."""
+        whole tree unchanged. (The paged engine's pool has a layout of
+        its own: :meth:`init_page_pool`.)"""
         shape = (batch, self.num_kv_heads, max_len, self.head_dim)
-
-        def mk(shp, dt):
-            return jnp.zeros(shp, dt, device=sharding) \
-                if sharding is not None else jnp.zeros(shp, dt)
-
-        if kv_dtype is None:
-            return mk(shape, dtype), mk(shape, dtype)
-        if str(kv_dtype) != "int8":
-            raise ValueError(
-                f"kv_dtype must be None (full precision) or 'int8', "
-                f"got {kv_dtype!r}")
-        sshape = shape[:-1] + (1,)
-        return (mk(shape, jnp.int8), mk(shape, jnp.int8),
-                mk(sshape, jnp.float32), mk(sshape, jnp.float32))
+        return _kv_buffers(shape, shape[:-1] + (1,), dtype, sharding,
+                           kv_dtype)
 
     def _split_kv_step(self, qkv):
         kv_dim = self.num_kv_heads * self.head_dim
@@ -486,14 +509,24 @@ class MultiHeadAttention(Module):
 
     def init_page_pool(self, max_pages: int, page_size: int,
                        dtype=jnp.float32, sharding=None, kv_dtype=None):
-        """Zero PAGE-POOL buffers for paged serving: the same tree
-        forms as :meth:`init_cache` with the leading dim indexing pages
-        instead of batch rows — (max_pages, H_kv, page_size, D) (+ the
-        int8 scale sidecars). Heads stay at dim 1, so the heads-sharded
-        pool layout (parallel/tp.py ``kv_pool_spec``) applies to a page
-        pool exactly as to a dense pool."""
-        return self.init_cache(max_pages, page_size, dtype,
-                               sharding=sharding, kv_dtype=kv_dtype)
+        """Zero PAGE-POOL buffers for paged serving: the tree forms of
+        :meth:`init_cache` ((k, v), or the int8 4-tuple with f32 scale
+        sidecars) in a layout of their own — each leaf is
+        (max_pages, page_size, H_kv * D), a page's tokens as rows of
+        all heads side by side (sidecars (max_pages, page_size, H_kv)).
+        Page and offset, the two dimensions the KV write indexes, LEAD,
+        so the write is an in-place scatter of whole rows into the
+        donated leaf (see :func:`_write_kv_paged`), and at the widths
+        served the merged minor dimension is whole 128-lane tiles
+        (20 x 64 = 1280): a page takes exactly its logical bytes on a
+        TPU. Heads sit in the LAST dimension, so the heads-sharded
+        layout is parallel/tp.py ``kv_page_pool_spec``, not the dense
+        cache's ``kv_pool_spec``. The page stays dimension 0: whatever
+        treats a leaf as rows of pages (PagePool, copy_page, the host
+        tier) is layout-blind."""
+        shape = (max_pages, page_size, self.num_kv_heads * self.head_dim)
+        return _kv_buffers(shape, shape[:-1] + (self.num_kv_heads,),
+                           dtype, sharding, kv_dtype)
 
     def forward_step_paged(self, x_t, pool, tables, pos):
         """One RAGGED decode step against a page pool: identical math
@@ -517,12 +550,13 @@ class MultiHeadAttention(Module):
         rep = self.num_heads // h_kv
         qg = q.reshape(b, h_kv, rep, self.head_dim)
         scale = 1.0 / math.sqrt(self.head_dim)
-        s = jnp.einsum("bgrd,bgtd->bgrt", qg, k_read,
+        # k_read / v_read are token-major (B, T_total, H_kv, D)
+        s = jnp.einsum("bgrd,btgd->bgrt", qg, k_read,
                        preferred_element_type=jnp.float32) * scale
-        live = jnp.arange(k_read.shape[2])[None, :] <= pos[:, None]
+        live = jnp.arange(k_read.shape[1])[None, :] <= pos[:, None]
         s = jnp.where(live[:, None, None, :], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(v_read.dtype)
-        o = jnp.einsum("bgrt,bgtd->bgrd", p, v_read)
+        o = jnp.einsum("bgrt,btgd->bgrd", p, v_read)
         o = o.reshape(b, self.embed_dim).astype(x_t.dtype)
         o = self.out_proj(o).reshape(b, 1, -1)
         return o, pool
@@ -552,13 +586,14 @@ class MultiHeadAttention(Module):
         rep = self.num_heads // h_kv
         qg = q.reshape(b, h_kv, rep, t, self.head_dim)
         scale = 1.0 / math.sqrt(self.head_dim)
-        s = jnp.einsum("bgrtd,bgTd->bgrtT", qg, k_read,
+        # k_read / v_read are token-major (B, T_total, H_kv, D)
+        s = jnp.einsum("bgrtd,bTgd->bgrtT", qg, k_read,
                        preferred_element_type=jnp.float32) * scale
-        ln = k_read.shape[2]
+        ln = k_read.shape[1]
         live = jnp.arange(ln)[None, None, :] <= positions[:, :, None]
         s = jnp.where(live[:, None, None], s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1).astype(v_read.dtype)
-        o = jnp.einsum("bgrtT,bgTd->bgrtd", p, v_read)
+        o = jnp.einsum("bgrtT,bTgd->bgrtd", p, v_read)
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, self.embed_dim)
         o = self.out_proj(o.reshape(b * t, self.embed_dim).astype(x.dtype))
         return o.reshape(b, t, -1), pool

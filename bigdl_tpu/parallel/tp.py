@@ -20,6 +20,9 @@ the layout the column-parallel QKV projection writes with ZERO
 communication (each device computes exactly its own heads' K/V), used
 by the serving engine's SPMD decode loop
 (``bigdl_tpu.serving.engine.ContinuousBatchingEngine(mesh=...)``).
+``kv_page_pool_spec`` / ``kv_page_pool_sharding`` are the same for the
+PAGED engine's pool, whose leaves ``(max_pages, page_size, H_kv * D)``
+keep the heads in their last dimension.
 """
 
 from __future__ import annotations
@@ -111,6 +114,18 @@ def kv_pool_spec(model_axis: str = "model") -> P:
     return P(None, model_axis, None, None)
 
 
+def kv_page_pool_spec(model_axis: str = "model") -> P:
+    """PartitionSpec for a PAGE-pool buffer ``(max_pages, page_size,
+    H_kv * D)`` (scale sidecars ``(max_pages, page_size, H_kv)``): the
+    last dimension holds a token's heads side by side, so sharding it
+    along the model axis gives each device the contiguous run of its
+    own heads — the column-parallel QKV split again, and a page write
+    needs no collective. Pages and offsets, the dimensions the write
+    indexes, stay replicated (and leading: ``nn/attention.py
+    _write_kv_paged``)."""
+    return P(None, None, model_axis)
+
+
 def fetch_to_host(tree):
     """One bulk device->host move of a buffer tree: a single blocking
     ``device_get`` per leaf, no per-chunk round trips ("RPC Considered
@@ -137,12 +152,10 @@ def put_from_host(tree, sharding=None):
     return jax.tree.map(lambda x: jax.device_put(x, sharding), tree)
 
 
-def kv_pool_sharding(mesh, num_kv_heads: int,
-                     model_axis: str = "model") -> NamedSharding:
-    """NamedSharding for ``TransformerLM.init_cache`` pool buffers,
-    validating that the KV head count divides the model-axis size (an
-    uneven head split would leave ragged shards and break the
-    zero-communication cache-write layout)."""
+def _check_heads_divide(mesh, num_kv_heads: int, model_axis: str):
+    """The KV head count must divide the model-axis size: an uneven
+    head split would leave ragged shards and break the
+    zero-communication cache-write layout."""
     if model_axis not in mesh.axis_names:
         raise ValueError(
             f"mesh axes {tuple(mesh.axis_names)} have no "
@@ -153,4 +166,20 @@ def kv_pool_sharding(mesh, num_kv_heads: int,
             f"num_kv_heads ({num_kv_heads}) must divide evenly over "
             f"the {shards}-way {model_axis!r} mesh axis; choose a "
             f"mesh the head count divides or bring more KV heads")
+
+
+def kv_pool_sharding(mesh, num_kv_heads: int,
+                     model_axis: str = "model") -> NamedSharding:
+    """NamedSharding for ``TransformerLM.init_cache`` pool buffers
+    (heads at dimension 1), validating that the KV head count divides
+    the model-axis size."""
+    _check_heads_divide(mesh, num_kv_heads, model_axis)
     return NamedSharding(mesh, kv_pool_spec(model_axis))
+
+
+def kv_page_pool_sharding(mesh, num_kv_heads: int,
+                          model_axis: str = "model") -> NamedSharding:
+    """NamedSharding for ``TransformerLM.init_page_pool`` buffers
+    (heads in the last dimension), with the same validation."""
+    _check_heads_divide(mesh, num_kv_heads, model_axis)
+    return NamedSharding(mesh, kv_page_pool_spec(model_axis))

@@ -500,12 +500,17 @@ class ContinuousBatchingEngine:
             from jax.sharding import NamedSharding, PartitionSpec
             from bigdl_tpu.parallel.tp import transformer_tp_rules
 
-            self._kv_shard = model.kv_cache_sharding(
-                mesh, model_axis=model_axis)
+            # the page pool keeps heads in its LAST dimension, the
+            # dense caches at dimension 1: each has its own spec
+            def kv_sharding(m):
+                return (m.kv_page_pool_sharding if self.paged
+                        else m.kv_cache_sharding)(
+                            mesh, model_axis=model_axis)
+
+            self._kv_shard = kv_sharding(model)
             if draft is not None:
                 try:
-                    self._d_kv_shard = draft.kv_cache_sharding(
-                        mesh, model_axis=model_axis)
+                    self._d_kv_shard = kv_sharding(draft)
                 except ValueError as e:
                     raise ValueError(
                         f"draft model cannot shard over this mesh: "
@@ -524,8 +529,11 @@ class ContinuousBatchingEngine:
             self._buffers = replicate(self._buffers, mesh)
         dtype = model.tok_embed.dtype
         if self.paged:
-            # THE page pool: one persistent (max_pages, page_size, ...)
-            # buffer set per layer, donated through every dispatch.
+            # THE page pool: one persistent (max_pages, page_size,
+            # heads * head_dim) buffer set per layer, donated through
+            # every dispatch; page and offset lead, so the KV write is
+            # an in-place scatter of whole rows (nn/attention.py
+            # _write_kv_paged).
             # There is no separate staging cache — admissions prefill
             # straight through their reserved tables — and no separate
             # prefix pool: retained prefixes are refcounted shares of

@@ -72,9 +72,10 @@ SCRATCH_PAGE = 0
 class PagePool:
     """Refcounted block allocator over one persistent device KV tree.
 
-    ``buffers`` is ``model.init_cache(max_pages, page_size, ...)`` — a
-    per-layer tuple of ``(k, v)`` (or quantized ``(k_q, v_q, k_scale,
-    v_scale)``) arrays whose leading dim indexes pages; the pool never
+    ``buffers`` is ``model.init_page_pool(max_pages, page_size, ...)``
+    — a per-layer tuple of ``(k, v)`` (or quantized ``(k_q, v_q,
+    k_scale, v_scale)``) arrays whose leading dim indexes pages (what
+    lies behind it is the attention layer's business); the pool never
     touches device memory itself, it only decides which page ids are
     live. The engine rebinds ``buffers`` after every donating dispatch
     (decode/prefill writes, COW copies) exactly as it rebinds its dense

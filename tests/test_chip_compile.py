@@ -153,14 +153,33 @@ def test_paged_engine_program_compiles(topo, one_chip, engine, program):
     args = _engine_args(engine, _abstract(engine._params, one_chip),
                         one_chip, one_chip)[program]
     jitted = getattr(engine, f"_{program}_jit")
-    m = _fits(jitted.lower(*args).compile())
+    compiled = jitted.lower(*args).compile()
+    m = _fits(compiled)
     if program in ("step", "chunk", "copy_page"):
         # the pool is donated and aliased in full: no second copy of it
-        pool_bytes = sum(
-            int(np.prod(leaf.shape)) * leaf.dtype.itemsize
-            for leaf in jax.tree.leaves(args[{"step": 4, "chunk": 3,
-                                              "copy_page": 0}[program]]))
+        leaves = jax.tree.leaves(args[{"step": 4, "chunk": 3,
+                                       "copy_page": 0}[program]])
+        pool_bytes = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                         for leaf in leaves)
         assert m.alias_size_in_bytes >= pool_bytes
+        # ... and a page takes its logical bytes: (16, 1280) bf16 is whole
+        # (8, 128)(2, 1) tiles. (With 64 as the minor dimension the runtime
+        # stores PAGES minor-most and pads them to a multiple of 128.)
+        assert m.alias_size_in_bytes == pool_bytes
+    if program in ("step", "chunk"):
+        # the KV write lands in place: no copy of a whole leaf around the
+        # scatter. The runtime lays a (pages, 16, 1280) leaf out row-major,
+        # pages and offsets leading as the scatter wants them; any 4-D leaf
+        # with the 64-wide head minor gets pages minor-most from it and is
+        # re-laid twice a leaf a dispatch (PERF.md, PR 27).
+        import re
+
+        text = compiled.as_text()
+        dims = ",".join(str(d) for d in leaves[0].shape)
+        assert f"bf16[{dims}]{{2,1,0:" in text
+        assert "scatter(" in text
+        assert not re.findall(rf"= bf16\[{dims}\]\S* (?:copy|transpose)\(",
+                              text)
 
 
 # ------------------------------------------------------- across four chips
@@ -171,7 +190,7 @@ def test_tensor_parallel_decode_step_compiles_on_four_chips(topo, engine):
 
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("model",))
     repl = NamedSharding(mesh, P())
-    kv = engine.model.kv_cache_sharding(mesh)
+    kv = engine.model.kv_page_pool_sharding(mesh)
     specs = spec_for_params(engine._params, transformer_tp_rules("model"),
                             P())
 

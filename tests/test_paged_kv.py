@@ -513,3 +513,238 @@ def test_debug_memory_attributes_pool_and_occupancy(lm):
         assert mid == eng._pages.bytes_in_use
         h.result(timeout=120)
     assert eng._pages.bytes_in_use == 0
+
+
+# ================================== the pool's layout (PR 27)
+# A pool leaf is (max_pages, page_size, H_kv * D): page and offset, the
+# dimensions the KV write indexes, lead, so the write is a scatter of
+# whole rows that updates the donated leaf in place. These tests hold
+# the model-level programs the engine jits (``decode_step_paged`` /
+# ``prefill_chunk_at_paged`` with the pool donated) to that: their
+# compiled form re-lays no leaf, and their logits are the dense cache's
+# on the same tokens (same values attended, same greedy token; not
+# bit-equal: XLA's CPU dot sums a score's head_dim products in another
+# order when K arrives token-major, 5e-7 of a score apart).
+
+LAYOUT_PAGES = 37       # a leaf's element count no other array shares
+
+
+def _bound(lm, fn):
+    """``fn`` of the model's methods as a pure function of its params
+    (what the engine jits)."""
+    from bigdl_tpu.nn.module import bind
+
+    def run(p, bufs, *args):
+        with bind(lm, p, bufs, False, None):
+            return fn(*args)
+
+    return run
+
+
+def _programs(lm, **jit_kw):
+    """(step, chunk, dense_step, dense_chunk), each jitted with its KV
+    tree donated as the engine donates it."""
+    step = jax.jit(_bound(lm, lm.decode_step_paged),
+                   donate_argnums=(4,), **jit_kw)
+    chunk = jax.jit(_bound(lm, lm.prefill_chunk_at_paged),
+                    donate_argnums=(3,), **jit_kw)
+    d_step = jax.jit(_bound(lm, lm.decode_step), donate_argnums=(4,))
+    d_chunk = jax.jit(_bound(lm, lm.prefill_chunk_at), donate_argnums=(3,))
+    return step, chunk, d_step, d_chunk
+
+
+def _state(lm):
+    return (jax.tree.map(jnp.asarray, lm.params_dict()),
+            jax.tree.map(jnp.asarray, lm.buffers_dict()))
+
+
+def _relaid_leaves(hlo, pool):
+    """``copy`` / ``transpose`` instructions of ``hlo`` (fused ones
+    too) whose result holds as many elements as a leaf of ``pool``."""
+    import math
+    import re
+
+    sizes = {int(np.prod(leaf.shape)) for leaf in jax.tree.leaves(pool)}
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?\S+\s*=\s*\w+\[([\d,]+)\]\S*\s+"
+                     r"(copy|transpose)\(", line)
+        if m and math.prod(int(d) for d in m.group(1).split(",")) in sizes:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"],
+                         ids=["float", "int8"])
+def test_paged_programs_write_pool_in_place(lm, kv_dtype):
+    """The optimized step and chunk hold no copy or transpose of a
+    whole pool leaf (the (k, v) and the int8 4-tuple form). A float32
+    pool, so the CPU's bf16-to-f32 widening of scatters is not taken
+    for one. With heads between page and offset (the layout before
+    PR 27) both programs transposed every leaf around its scatter."""
+    params, bufs = _state(lm)
+    pool = lm.init_page_pool(LAYOUT_PAGES, PS, kv_dtype=kv_dtype)
+    tlen = lm.max_len // PS
+    step, chunk, _, _ = _programs(lm)
+    i32 = jnp.int32
+    b, rows = 3, 2
+    compiled = {
+        "step": step.lower(params, bufs, jnp.zeros((b,), i32),
+                           jnp.zeros((b,), i32), pool,
+                           jnp.zeros((b, tlen), i32)).compile(),
+        "chunk": chunk.lower(params, bufs, jnp.zeros((rows, CHUNK), i32),
+                             pool, jnp.zeros((rows, tlen), i32),
+                             jnp.zeros((rows,), i32),
+                             jnp.zeros((rows,), i32)).compile(),
+    }
+    for name, c in compiled.items():
+        hlo = c.as_text()
+        assert "scatter" in hlo, name      # the write is in there
+        assert _relaid_leaves(hlo, pool) == [], name
+
+
+def _tables(rows, tlen):
+    """Block tables from per-row page lists, scratch-padded."""
+    t = np.full((len(rows), tlen), SCRATCH_PAGE, np.int32)
+    for i, pages in enumerate(rows):
+        t[i, :len(pages)] = pages
+    return jnp.asarray(t)
+
+
+def _copy_page(pool, dst, src):
+    # the engine's COW primitive (engine.py copy_page), leaf-blind
+    return jax.tree.map(lambda b: b.at[dst].set(b[src]), pool)
+
+
+def _layout_parity(lm, case, kv_dtype=None, jit_kw=None, state=None,
+                   pool_sharding=None):
+    """Prefill one chunk and decode three tokens through the page pool
+    and through the dense cache; return both logit lists."""
+    params, bufs = state or _state(lm)
+    step, chunk, d_step, d_chunk = _programs(lm, **(jit_kw or {}))
+    tlen = lm.max_len // PS
+    r = np.random.RandomState(7)
+    live = 2
+    lanes = 4 if case == "scratch_collision" else live
+    head = r.randint(0, 32, (CHUNK,))              # one whole page
+    ids0 = r.randint(0, 32, (lanes, CHUNK)).astype(np.int32)
+    if case in ("shared_head", "cow_copy"):
+        ids0[:live] = head
+    pool = lm.init_page_pool(LAYOUT_PAGES, PS, kv_dtype=kv_dtype,
+                             sharding=pool_sharding)
+    dense = lm.init_cache(lanes, lm.max_len, kv_dtype=kv_dtype)
+    pages = [[1, 2, 3], [4, 5, 6]]
+    if case in ("shared_head", "cow_copy"):
+        pages[1][0] = 1            # row 1's head IS row 0's page
+    # idle lanes: all-scratch tables, position 0 — their junk writes
+    # collide on page 0
+    tables = _tables(pages + [[]] * (lanes - live), tlen)
+    zeros = jnp.zeros((lanes,), jnp.int32)
+    last = jnp.full((lanes,), CHUNK - 1, jnp.int32)
+    got, want = [], []
+
+    ids = jnp.asarray(ids0)
+    if case in ("shared_head", "cow_copy"):
+        # row 0 alone writes the shared head page; row 1 rides a
+        # scratch table through that dispatch and reads the page after
+        lg, pool = chunk(params, bufs, ids, pool,
+                         _tables([pages[0], []], tlen), zeros, last)
+        got.append(np.asarray(lg)[:1])
+    else:
+        lg, pool = chunk(params, bufs, ids, pool, tables, zeros, last)
+        got.append(np.asarray(lg)[:live])
+    lg, dense = d_chunk(params, bufs, ids, dense, zeros, last)
+    want.append(np.asarray(lg)[:got[0].shape[0]])
+    if case == "cow_copy":
+        pool = _copy_page(pool, 7, 1)   # privatize row 1's head
+        tables = _tables([pages[0], [7] + pages[1][1:]], tlen)
+
+    tok = jnp.asarray(r.randint(0, 32, (lanes,)).astype(np.int32))
+    for i in range(3):
+        pos = jnp.where(jnp.arange(lanes) < live, CHUNK + i, 0)
+        lg, pool = step(params, bufs, tok, pos.astype(jnp.int32), pool,
+                        tables)
+        got.append(np.asarray(lg)[:live])
+        lg, dense = d_step(params, bufs, tok, pos.astype(jnp.int32),
+                           dense)
+        want.append(np.asarray(lg)[:live])
+        nxt = np.zeros((lanes,), np.int32)     # idle lanes stay idle
+        nxt[:live] = want[-1].argmax(-1)
+        tok = jnp.asarray(nxt)
+    return got, want, pool
+
+
+@pytest.mark.parametrize("case,kv_dtype", [
+    ("plain", None), ("scratch_collision", None), ("shared_head", None),
+    ("cow_copy", None), ("plain", "int8"), ("shared_head", "int8"),
+], ids=["plain", "scratch_collision", "shared_head", "cow_copy", "int8",
+        "int8_shared_head"])
+def test_paged_layout_parity_with_dense(lm, case, kv_dtype):
+    """Chunk then decode through block tables into the token-major
+    pool: the dense cache's logits in float32 and its greedy tokens —
+    with idle lanes colliding on the scratch page, a prefix-shared
+    head page, a COW ``copy_page``, and int8 codes + scale sidecars."""
+    got, want, _ = _layout_parity(lm, case, kv_dtype)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-6)
+        np.testing.assert_array_equal(g.argmax(-1), w.argmax(-1))
+
+
+def test_paged_layout_parity_draft_round(lm):
+    """A draft's proposal round (the gamma-step scan over
+    ``decode_step_paged``) proposes what the dense scan proposes, with
+    the same step logits."""
+    from bigdl_tpu.nn.quantized import Quantizer
+
+    draft = Quantizer.quantize(lm)
+    draft.evaluate()
+    params, bufs = _state(draft)
+    b, gamma, tlen = 2, 3, lm.max_len // PS
+    tables = _tables([[1, 2, 3], [4, 5, 6]], tlen)
+    tok = jnp.asarray([5, 9], jnp.int32)
+    pos = jnp.asarray([0, 0], jnp.int32)
+    key, one = jax.random.PRNGKey(0), jnp.float32(1.0)
+    toks, qlog, pool = draft._propose_fn_paged(b, gamma, tlen)(
+        params, bufs, tok, pos, draft.init_page_pool(LAYOUT_PAGES, PS),
+        tables, key, one)
+    d_toks, d_qlog, _ = draft._propose_fn(b, gamma)(
+        params, bufs, tok, pos, draft.init_cache(b, lm.max_len), key, one)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(d_toks))
+    np.testing.assert_allclose(np.asarray(qlog), np.asarray(d_qlog),
+                               rtol=0, atol=1e-6)
+    # the scan wrote gamma tokens a row, each row into its first page
+    leaf = np.asarray(jax.tree.leaves(pool)[0])
+    assert np.abs(leaf[1, :gamma]).sum() > 0 and not leaf[1, gamma:].any()
+
+
+def test_paged_layout_heads_sharded_mesh(lm_tp):
+    """On a model mesh of 2 the page pool shards its LAST dimension
+    (``kv_page_pool_spec``): each device holds its own heads' slice of
+    every row, the programs keep that sharding on the donated pool,
+    and the logits match the dense cache's."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from bigdl_tpu.parallel import (
+        Engine, kv_page_pool_spec, shard_params, transformer_tp_rules,
+    )
+
+    assert kv_page_pool_spec("model") == P(None, None, "model")
+    mesh2 = Engine.create_mesh([("model", 2)], devices=jax.devices()[:2])
+    kv = lm_tp.kv_page_pool_sharding(mesh2)
+    repl = NamedSharding(mesh2, P())
+    params, bufs = _state(lm_tp)
+    state = (shard_params(params, mesh2, transformer_tp_rules("model")),
+             jax.device_put(bufs, repl))
+    got, want, pool = _layout_parity(
+        lm_tp, "scratch_collision", state=state, pool_sharding=kv,
+        jit_kw={"out_shardings": (repl, kv)})
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=0, atol=1e-5)
+    attn = lm_tp.block0.attn
+    for leaf in jax.tree.leaves(pool):
+        assert leaf.sharding.is_equivalent_to(kv, leaf.ndim)
+        assert leaf.addressable_shards[0].data.shape == (
+            LAYOUT_PAGES, PS, attn.num_kv_heads * attn.head_dim // 2)
+    with pytest.raises(ValueError, match="divide evenly"):
+        lm_tp.kv_page_pool_sharding(
+            Engine.create_mesh([("model", 8)], devices=jax.devices()[:8]))
