@@ -512,19 +512,23 @@ class TransformerLM(Module):
             return logits, new_pools
         return logits[:, 0], new_pools
 
-    def decode_step_paged(self, ids_t, pos, pools, tables):
+    def decode_step_paged(self, ids_t, pos, pools, tables,
+                          decode_attention="rows"):
         """Paged twin of :meth:`decode_step` (ragged (B,) ``pos``
         only): one token per row, KV scattered into and gathered from
         the page pool through ``tables`` inside the same dispatch —
         compiled shape depends on the pool geometry and the table
-        length, never on any request's span."""
+        length, never on any request's span. ``decode_attention`` is
+        ``"rows"`` on one device and ``"heads"`` under a mesh that
+        shards heads (``MultiHeadAttention.forward_step_paged``)."""
         x = jnp.take(self.tok_embed, ids_t, axis=0)[:, None, :]  # (B,1,C)
         if not self.use_rope:
             x = x + jnp.take(self.pos_embed, pos, axis=0)[:, None]
         new_pools = []
         for i in range(self.num_layers):
             x, c = getattr(self, f"block{i}").forward_step_paged(
-                x, pools[i], tables, pos)
+                x, pools[i], tables, pos,
+                decode_attention=decode_attention)
             new_pools.append(c)
         x = self.ln_f(x)
         if self.tie_embeddings:
@@ -962,16 +966,18 @@ class TransformerLM(Module):
 
     def _propose_fn_paged(self, b: int, gamma: int, table_len: int,
                           sampled: bool = False, cache_sharding=None,
-                          repl_sharding=None):
+                          repl_sharding=None, decode_attention="rows"):
         """Paged twin of :meth:`_propose_fn`: the gamma-step proposal
         scan over ``decode_step_paged`` — the draft's page pool cycles
         through the scan carry while the block tables ride as a loop
         constant (a request's pages are fixed for its whole flight, so
         the tables never change inside one proposal). Signature gains
-        ``tables`` after the pool; donation moves with the pool."""
+        ``tables`` after the pool; donation moves with the pool.
+        ``decode_attention`` is ``decode_step_paged``'s: the engine
+        hands every paged program it builds the same one."""
         per_model = _SPEC_JIT.setdefault(self, {})
         key = ("propose_paged", b, gamma, table_len, sampled,
-               cache_sharding)
+               cache_sharding, decode_attention)
         fn = per_model.get(key)
         if fn is not None:
             return fn
@@ -982,7 +988,8 @@ class TransformerLM(Module):
                 def body(carry, _):
                     tok, pos, pools, rng = carry
                     logits, pools = self.decode_step_paged(
-                        tok, pos, pools, tables)
+                        tok, pos, pools, tables,
+                        decode_attention=decode_attention)
                     if sampled:
                         rng, sub = jax.random.split(rng)
                         nxt = jax.random.categorical(

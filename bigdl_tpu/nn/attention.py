@@ -147,33 +147,70 @@ def _kv_buffers(shape, scale_shape, dtype, sharding, kv_dtype):
             mk(scale_shape, jnp.float32), mk(scale_shape, jnp.float32))
 
 
-def _gather_pages(leaf, tables, heads):
+def _gather_pages(leaf, tables, heads=None):
     """Assemble one logical KV row per batch entry from a page pool:
     ``leaf`` is a pool buffer (max_pages, page_size, H * D) — one
-    token's ``heads`` heads side by side in the minor dimension (the
-    scale sidecars: (max_pages, page_size, H)) — and ``tables``
+    token's heads side by side in the minor dimension (the scale
+    sidecars: (max_pages, page_size, H)) — and ``tables``
     (B, table_len) the per-row page ids: position ``i`` of row ``b``
     lives at ``leaf[tables[b, i // page_size], i % page_size]``.
-    Returns the TOKEN-MAJOR dense view (B, table_len * page_size, H, D)
-    — a reshape of the gathered pages, no transpose: the paged
-    attention einsums contract over that order directly. XLA lowers
-    the take to one gather, so compiled shape depends only on the POOL
-    geometry, never on any request's length. Table slots past a
-    request's reservation point at the scratch page — garbage the
-    caller's causal mask must (and does) discard."""
+
+    Hands out one of two views of the same gathered pages. With
+    ``heads=None``, ROWS (B, table_len * page_size, H * D): only
+    (table_len, page_size) merge, the minor dimension stays what the
+    pool stores, whole 128-lane tiles at the widths served — what the
+    decode step contracts over (:func:`_attend_pages_rows`). With
+    ``heads`` given, the TOKEN-MAJOR per-head view (B, table_len *
+    page_size, H, D) the chunk's and the mesh step's per-head einsums
+    read; splitting the minor 1280 into (20, 64) makes a TPU re-lay
+    every gathered byte into padded tiles (PERF.md, PR 30), which a
+    chunk of 128 query tokens earns back and one decode token does not.
+
+    XLA lowers the take to one gather, so compiled shape depends only
+    on the POOL geometry, never on any request's length. Table slots
+    past a request's reservation point at the scratch page — garbage
+    the caller's causal mask must (and does) discard."""
     b, tlen = tables.shape
     g = jnp.take(leaf, tables, axis=0)          # (B, table_len, ps, H*D)
-    return g.reshape(b, tlen * g.shape[2], heads, -1)
+    rows = g.reshape(b, tlen * g.shape[2], g.shape[3])
+    if heads is None:
+        return rows
+    return rows.reshape(b, rows.shape[1], heads, -1)
 
 
-def _write_kv_paged(pool, k_t, v_t, tables, positions):
+def _dequantize_kv_rows(codes, scale, dtype):
+    """:func:`dequantize_kv` on the ROWS view: ``codes`` (B, T, H * D)
+    int8, ``scale`` (B, T, H) — each head's scale spread over that
+    head's D columns, then the same float32 product and the same cast,
+    so what is attended is the stored value to the bit.
+
+    The spread is a product with a one-hot (H, H * D) matrix at the
+    highest precision, which is exact (every output is one scale times
+    1.0) and lands in rows of H * D as the MXU writes them; a
+    ``jnp.repeat`` goes through (H, D) tiles and a re-lay of the whole
+    float32 view (3.4 against 1.7 ms a layer on the chip, PERF.md,
+    PR 30)."""
+    h = scale.shape[-1]
+    d = codes.shape[-1] // h
+    spread = (jnp.arange(h * d)[None, :] // d
+              == jnp.arange(h)[:, None]).astype(scale.dtype)
+    scale_rows = jnp.einsum("bth,hc->btc", scale, spread,
+                            precision=jax.lax.Precision.HIGHEST)
+    return (codes.astype(jnp.float32) * scale_rows).astype(dtype)
+
+
+def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
     """Paged twin of :func:`_write_kv`: scatter one K/V block
     (B, H, T, D) into the page-pool buffers through per-row block
-    tables, then gather the dense per-row views (B, T_total, H, D)
-    attention attends over. ``positions`` is (B,) (one decode token
-    per row) or (B, T) (a ragged chunk); token ``t`` of row ``b``
-    scatters to page ``tables[b, positions[b,t] // page_size]`` at
-    offset ``positions[b, t] % page_size``.
+    tables, then gather the dense per-row views attention attends
+    over: per head, (B, T_total, H, D), or with ``rows=True`` as the
+    pool stores them, (B, T_total, H * D) (the two views of
+    :func:`_gather_pages`; the decode step without a mesh asks for
+    rows, the chunk and the mesh step for heads). ``positions`` is
+    (B,) (one decode token per row) or (B, T) (a ragged chunk); token
+    ``t`` of row ``b`` scatters to page
+    ``tables[b, positions[b,t] // page_size]`` at offset
+    ``positions[b, t] % page_size``.
 
     The write is ``buf.at[page, offset].set(rows)`` on a leaf
     (max_pages, page_size, H * D): the two indexed dimensions LEAD and
@@ -207,13 +244,14 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions):
         rows = blk.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         return buf.at[pg, off].set(rows.astype(buf.dtype))
 
+    view = None if rows else heads
     if len(pool) == 2:
         k_buf, v_buf = pool
         k_buf = write(k_buf, k_t)
         v_buf = write(v_buf, v_t)
         return ((k_buf, v_buf),
-                _gather_pages(k_buf, tables, heads),
-                _gather_pages(v_buf, tables, heads))
+                _gather_pages(k_buf, tables, view),
+                _gather_pages(v_buf, tables, view))
     k_q, v_q, k_s, v_s = pool
     kq, ks = quantize_kv(k_t)
     vq, vs = quantize_kv(v_t)
@@ -221,11 +259,80 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions):
     v_q = write(v_q, vq)
     k_s = write(k_s, ks)
     v_s = write(v_s, vs)
+    # the sidecar's own per-head view is (B, T, H, 1); as rows it stays
+    # (B, T, H) and is spread over each head's columns
+    dequant = _dequantize_kv_rows if rows else dequantize_kv
     return ((k_q, v_q, k_s, v_s),
-            dequantize_kv(_gather_pages(k_q, tables, heads),
-                          _gather_pages(k_s, tables, heads), k_t.dtype),
-            dequantize_kv(_gather_pages(v_q, tables, heads),
-                          _gather_pages(v_s, tables, heads), v_t.dtype))
+            dequant(_gather_pages(k_q, tables, view),
+                    _gather_pages(k_s, tables, view), k_t.dtype),
+            dequant(_gather_pages(v_q, tables, view),
+                    _gather_pages(v_s, tables, view), v_t.dtype))
+
+
+def _attend_pages_heads(q, k_read, v_read, pos):
+    """One query token a row over gathered pages, PER HEAD: ``q``
+    (B, H, D), ``k_read`` / ``v_read`` the token-major per-head view
+    (B, T, H_kv, D), ``pos`` (B,) the last live position of each row.
+    GQA runs grouped against the un-expanded view; scores accumulate
+    in float32. Returns (B, H, D) in ``v_read``'s dtype.
+
+    Heads stay a batch dimension of both einsums, so with heads
+    sharded over a model axis every device attends its own heads and
+    the attention needs no collective: the tensor-parallel engine's
+    form. On one TPU chip the 64-wide minor dimension costs a re-lay
+    of everything gathered (:func:`_attend_pages_rows` is that case's
+    form)."""
+    b, h, d = q.shape
+    h_kv = k_read.shape[2]
+    qg = q.reshape(b, h_kv, h // h_kv, d)
+    s = jnp.einsum("bgrd,btgd->bgrt", qg, k_read,
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(d))
+    live = jnp.arange(k_read.shape[1])[None, :] <= pos[:, None]
+    s = jnp.where(live[:, None, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(v_read.dtype)
+    return jnp.einsum("bgrt,btgd->bgrd", p, v_read).reshape(b, h, d)
+
+
+def _attend_pages_rows(q, k_rows, v_rows, pos):
+    """One query token a row over gathered pages left as ROWS: ``q``
+    (B, H, D), ``k_rows`` / ``v_rows`` (B, T, H_kv * D) as the pool
+    stores them, ``pos`` (B,). Returns (B, H, D) in ``v_rows``' dtype.
+
+    ``q`` is spread onto a block diagonal (B, H, H_kv * D) — head
+    ``h``'s D values in the columns of its own kv head, zeros
+    elsewhere (GQA's ``rep`` query heads share a column block) — so
+    scores and output are two plain matrix products over whole rows
+    and the gathered bytes are read as they lie. The products with
+    the zeros add nothing, both contractions accumulate in float32:
+    the same sums as the per-head form in another order, for H_kv
+    times its multiply-adds, which one query token a row can afford
+    (and a chunk of 128 cannot). Each head then keeps its own D
+    columns of the (B, H, H_kv * D) output.
+
+    The contraction runs over the rows' last dimension, where a mesh
+    shards heads: partitioned, it would be a cross-device sum of
+    partial products known to be zero. The mesh engine keeps
+    :func:`_attend_pages_heads`."""
+    b, h, d = q.shape
+    h_kv = k_rows.shape[2] // d
+    own = jnp.arange(h) // (h // h_kv)          # head -> its kv head
+    on_diag = own[:, None] == jnp.arange(h_kv)[None, :]      # (H, H_kv)
+    q_bd = jnp.where(on_diag[None, :, :, None], q[:, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(b, h, h_kv * d)
+    s = jnp.einsum("bhc,btc->bht", q_bd, k_rows,
+                   preferred_element_type=jnp.float32) * (1.0 / math.sqrt(d))
+    live = jnp.arange(k_rows.shape[1])[None, :] <= pos[:, None]
+    s = jnp.where(live[:, None, :], s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1).astype(v_rows.dtype)
+    o_full = jnp.einsum("bht,btc->bhc", p, v_rows)
+    return o_full.reshape(b, h, h_kv, d)[:, jnp.arange(h), own]
+
+
+#: the two forms of "attend one query token over gathered pages"; the
+#: key is what ``forward_step_paged(..., decode_attention=)`` takes and
+#: what ``engine.stats()["paging"]["decode_attention"]`` reports
+_DECODE_ATTENTION = {"rows": _attend_pages_rows,
+                     "heads": _attend_pages_heads}
 
 
 def rotary_embedding(x, positions, base: float = 10000.0):
@@ -528,7 +635,8 @@ class MultiHeadAttention(Module):
         return _kv_buffers(shape, shape[:-1] + (self.num_kv_heads,),
                            dtype, sharding, kv_dtype)
 
-    def forward_step_paged(self, x_t, pool, tables, pos):
+    def forward_step_paged(self, x_t, pool, tables, pos,
+                           decode_attention="rows"):
         """One RAGGED decode step against a page pool: identical math
         to the ragged form of :meth:`forward_step`, but each row's KV
         row is the concatenation of the pool pages its block table
@@ -537,26 +645,32 @@ class MultiHeadAttention(Module):
         compiled shapes depend only on ``(max_pages, table_len,
         page_size)``. ``pos`` is the (B,) per-row position vector;
         rows parked on the scratch page (all-zero tables) are idle
-        lanes whose output the caller ignores."""
+        lanes whose output the caller ignores.
+
+        ``decode_attention`` names how the one query token of a row
+        meets the pages gathered for it: ``"rows"`` hands the gathered
+        K and V out as rows of H_kv * D and contracts a block-diagonal
+        q with them (:func:`_attend_pages_rows`: no re-lay of what was
+        gathered, the form for one device); ``"heads"`` hands out the
+        per-head view (:func:`_attend_pages_heads`: no collective
+        under a heads-sharded pool, the form for a mesh). Whoever
+        builds the program knows which it is; the engine decides from
+        whether it has a mesh. :meth:`forward_chunk_paged`, many query
+        tokens a row, always reads the per-head view."""
+        if decode_attention not in _DECODE_ATTENTION:
+            raise ValueError(
+                f"decode_attention must be one of "
+                f"{sorted(_DECODE_ATTENTION)}, got {decode_attention!r}")
         b = x_t.shape[0]
         qkv = self.qkv(x_t.reshape(b, self.embed_dim)).reshape(b, 1, -1)
         q, k_t, v_t = self._split_kv_step(qkv)      # q (B,H,1,D)
         if self.rotary:
             q = rotary_embedding_rowwise(q, pos, self.rotary_base)
             k_t = rotary_embedding_rowwise(k_t, pos, self.rotary_base)
-        pool, k_read, v_read = _write_kv_paged(pool, k_t, v_t,
-                                               tables, pos)
-        h_kv = self.num_kv_heads
-        rep = self.num_heads // h_kv
-        qg = q.reshape(b, h_kv, rep, self.head_dim)
-        scale = 1.0 / math.sqrt(self.head_dim)
-        # k_read / v_read are token-major (B, T_total, H_kv, D)
-        s = jnp.einsum("bgrd,btgd->bgrt", qg, k_read,
-                       preferred_element_type=jnp.float32) * scale
-        live = jnp.arange(k_read.shape[1])[None, :] <= pos[:, None]
-        s = jnp.where(live[:, None, None, :], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(v_read.dtype)
-        o = jnp.einsum("bgrt,btgd->bgrd", p, v_read)
+        pool, k_read, v_read = _write_kv_paged(
+            pool, k_t, v_t, tables, pos, rows=decode_attention == "rows")
+        o = _DECODE_ATTENTION[decode_attention](q[:, :, 0], k_read,
+                                                v_read, pos)
         o = o.reshape(b, self.embed_dim).astype(x_t.dtype)
         o = self.out_proj(o).reshape(b, 1, -1)
         return o, pool
@@ -717,11 +831,13 @@ class TransformerBlock(Module):
         h, cache = self.attn.forward_chunk(self.ln1(x), cache, pos0)
         return self._mlp_residual(x + h), cache
 
-    def forward_step_paged(self, x_t, pool, tables, pos):
+    def forward_step_paged(self, x_t, pool, tables, pos,
+                           decode_attention="rows"):
         """Paged decode step (see
         MultiHeadAttention.forward_step_paged)."""
-        h, pool = self.attn.forward_step_paged(self.ln1(x_t), pool,
-                                               tables, pos)
+        h, pool = self.attn.forward_step_paged(
+            self.ln1(x_t), pool, tables, pos,
+            decode_attention=decode_attention)
         return self._mlp_residual(x_t + h), pool
 
     def forward_chunk_paged(self, x, pool, tables, pos0):

@@ -1229,14 +1229,23 @@ class ContinuousBatchingEngine:
         model = self.model
         sampled = self.temperature > 0.0
         top_k, top_p = self.top_k, self.top_p
+        # how one decode token meets its gathered pages, decided here
+        # once for every program below that runs decode_step_paged: on
+        # one device K and V stay rows of H_kv * D under a
+        # block-diagonal q (nothing gathered is re-laid); on a mesh
+        # that contraction would sum over the sharded heads dimension
+        # (two collectives a layer), so heads stay a batch dimension
+        # (nn/attention.py _attend_pages_rows / _attend_pages_heads)
+        attend = self._decode_attention = (
+            "rows" if self.mesh is None else "heads")
 
         def step(p, bufs, tok, pos, pool, tables, rng, temperature):
             # one fused decode over ALL slots; idle lanes carry the
             # all-scratch table (SCRATCH_PAGE padding) so their junk
             # write lands on page 0, never on a live page
             with bind(model, p, bufs, False, None):
-                logits, pool = model.decode_step_paged(tok, pos, pool,
-                                                       tables)
+                logits, pool = model.decode_step_paged(
+                    tok, pos, pool, tables, decode_attention=attend)
             if sampled:
                 nxt = jax.random.categorical(
                     rng, _filter_logits(logits, temperature, top_k,
@@ -1325,7 +1334,7 @@ class ContinuousBatchingEngine:
             self._propose_jit = draft._propose_fn_paged(
                 self.max_slots, g, self._table_len, sampled=sampled,
                 cache_sharding=self._d_kv_shard,
-                repl_sharding=self._repl)
+                repl_sharding=self._repl, decode_attention=attend)
 
             def d_chunk(p, bufs, ids, pool, tables, pos0, last_idx):
                 with bind(draft, p, bufs, False, None):
@@ -1334,8 +1343,8 @@ class ContinuousBatchingEngine:
 
             def d_sync(p, bufs, tok, pos, pool, tables):
                 with bind(draft, p, bufs, False, None):
-                    _, pool = draft.decode_step_paged(tok, pos, pool,
-                                                      tables)
+                    _, pool = draft.decode_step_paged(
+                        tok, pos, pool, tables, decode_attention=attend)
                 return pool
 
             def spec_verify(p, bufs, tok, props, qlogits, pos, pool,
@@ -3673,6 +3682,7 @@ class ContinuousBatchingEngine:
     def _paging_summary(self) -> dict:
         out = {"page_size": self.page_size,
                "table_len": self._table_len,
+               "decode_attention": self._decode_attention,
                "fragmentation": self._fragmentation(),
                "pool": self._pages.stats()}
         if self._d_pages is not None:

@@ -15,6 +15,8 @@ compile cache is off around these tests (a described-device executable
 cannot be read back), and all such tests live in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -53,10 +55,9 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture(scope="module")
-def engine():
-    """The paged engine over the 2-layer GPT-2-Large-width model, built on
-    the CPU: the source of the engine's own jitted programs."""
+def _serving_engine(name, **kw):
+    """The paged engine over the 2-layer GPT-2-Large-width model in
+    bfloat16, built on the CPU."""
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.serving import ContinuousBatchingEngine
 
@@ -64,8 +65,25 @@ def engine():
     model.evaluate()
     model.load_params_dict(jax.tree.map(
         lambda a: a.astype(jnp.bfloat16), model.params_dict()))
-    eng = ContinuousBatchingEngine(model, service_name="chip_compile",
-                                   **SERVE)
+    return ContinuousBatchingEngine(model, service_name=name, **SERVE, **kw)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    """The source of the engine's own jitted programs."""
+    eng = _serving_engine("chip_compile")
+    yield eng
+    eng.stop()
+
+
+@pytest.fixture(scope="module")
+def mesh_engine():
+    """The same under the engine's tensor-parallel mode, on four of the
+    CPU's devices: the source of the step the MESH engine builds (it keeps
+    the per-head decode attention; PERF.md, PR 30)."""
+    eng = _serving_engine(
+        "chip_compile_tp",
+        mesh=Mesh(np.asarray(jax.devices()[:4]), ("model",)))
     yield eng
     eng.stop()
 
@@ -172,20 +190,35 @@ def test_paged_engine_program_compiles(topo, one_chip, engine, program):
         # pages and offsets leading as the scatter wants them; any 4-D leaf
         # with the 64-wide head minor gets pages minor-most from it and is
         # re-laid twice a leaf a dispatch (PERF.md, PR 27).
-        import re
-
         text = compiled.as_text()
         dims = ",".join(str(d) for d in leaves[0].shape)
         assert f"bf16[{dims}]{{2,1,0:" in text
         assert "scatter(" in text
         assert not re.findall(rf"= bf16\[{dims}\]\S* (?:copy|transpose)\(",
                               text)
+    if program == "step":
+        # one decode token a row contracts a block-diagonal q with the
+        # gathered K and V left as rows of 1280: nothing of
+        # (lanes, 1024, 20, 64) is ever made. Splitting the minor 1280
+        # into (20, 64) re-laid both gathered views into tiles padded
+        # 2.4x, 48 of the step's 112 ms on the chip (PERF.md, PR 30).
+        assert engine.stats()["paging"]["decode_attention"] == "rows"
+        split = re.findall(
+            rf"= \w+\[{SERVE['max_slots']},1024,20,64\]\S* \w", text)
+        assert not split, split[:4]
+        parent = 65_462_784    # PR 27's step, same compiler and geometry
+        assert m.temp_size_in_bytes < parent, (
+            f"the step's temporaries: {m.temp_size_in_bytes} bytes, the "
+            f"parent's (per-head einsums over the gathered pages) {parent}")
 
 
 # ------------------------------------------------------- across four chips
-def test_tensor_parallel_decode_step_compiles_on_four_chips(topo, engine):
-    """The engine's decode step on a ("model", 4) mesh: 20 heads -> 5 per
-    chip, params under transformer_tp_rules, the pool heads-sharded."""
+def test_tensor_parallel_decode_step_compiles_on_four_chips(topo,
+                                                            mesh_engine):
+    """The mesh engine's decode step on a ("model", 4) mesh: 20 heads -> 5
+    per chip, params under transformer_tp_rules, the pool heads-sharded."""
+    engine = mesh_engine
+    assert engine.stats()["paging"]["decode_attention"] == "heads"
     from bigdl_tpu.parallel.tp import spec_for_params, transformer_tp_rules
 
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("model",))
@@ -205,7 +238,13 @@ def test_tensor_parallel_decode_step_compiles_on_four_chips(topo, engine):
                        out_shardings=(repl, kv)).lower(
                            *args["step"]).compile()
     text = compiled.as_text()
-    assert "all-reduce" in text     # the row-parallel reductions
+    # the row-parallel reductions and nothing more: the counts PR 27's
+    # step compiles to at 2 layers. The rows form of the decode attention
+    # contracts over the heads-sharded dimension and would add 2
+    # all-reduces and an all-gather or two a layer (9 and 5 here).
+    counts = {c: len(re.findall(rf" {c}(?:-start)?\(", text))
+              for c in ("all-reduce", "all-gather")}
+    assert counts == {"all-reduce": 5, "all-gather": 2}
     # per-device bytes: the sharded pool is a quarter of the whole
     m = _fits(compiled)
     whole = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize

@@ -748,3 +748,109 @@ def test_paged_layout_heads_sharded_mesh(lm_tp):
     with pytest.raises(ValueError, match="divide evenly"):
         lm_tp.kv_page_pool_sharding(
             Engine.create_mesh([("model", 8)], devices=jax.devices()[:8]))
+
+
+# ------------------------------------------------ decode attention forms
+# One query token a row meets its gathered pages in one of two forms
+# (nn/attention.py): "rows" — K and V as the pool stores them, q on a
+# block diagonal, the engine without a mesh — and "heads" — the
+# per-head view, the mesh engine. Same pages in, same numbers out.
+
+def _step_attention_case(case, dtype):
+    """(attn, x_t, pool, tables, pos): 4 lanes over a 4-page table —
+    a row at position 0, one at the table's LAST position, one partly
+    filled (its tail slots on the scratch page) and an idle lane parked
+    on the scratch page — over a pool of random content."""
+    from bigdl_tpu.nn.attention import MultiHeadAttention
+    from bigdl_tpu.utils import random as rnd
+
+    heads, kv_heads, rotary, kv_dtype = {
+        "plain": (4, 4, False, None), "gqa2": (4, 2, False, None),
+        "gqa4": (8, 2, False, None), "rotary": (4, 2, True, None),
+        "int8": (4, 2, False, "int8")}[case]
+    rnd.set_seed(30)
+    attn = MultiHeadAttention(8 * heads, heads, num_kv_heads=kv_heads,
+                              rotary=rotary)
+    attn.evaluate()
+    attn.load_params_dict(jax.tree.map(lambda a: a.astype(dtype),
+                                       attn.params_dict()))
+    tlen, pages = 4, 12
+    r = np.random.RandomState(30)
+    pool = attn.init_page_pool(pages, PS, dtype=dtype, kv_dtype=kv_dtype)
+    if kv_dtype is None:
+        pool = tuple(jnp.asarray(r.standard_normal(b.shape), dtype)
+                     for b in pool)
+    else:
+        pool = tuple(
+            jnp.asarray(r.randint(-127, 128, b.shape), jnp.int8)
+            if b.dtype == jnp.int8
+            else jnp.asarray(r.uniform(0.005, 0.05, b.shape), jnp.float32)
+            for b in pool)
+    tables = _tables([[1, 2, 3, 4], [5, 6, 7, 8], [9, 10], []], tlen)
+    pos = jnp.asarray([0, tlen * PS - 1, 6, 0], jnp.int32)
+    x_t = jnp.asarray(r.standard_normal((4, 1, 8 * heads)), dtype)
+    return attn, x_t, pool, tables, pos
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case",
+                         ["plain", "gqa2", "gqa4", "rotary", "int8"])
+def test_decode_attention_rows_equals_heads(case, dtype):
+    """``forward_step_paged`` under ``decode_attention="rows"`` and
+    ``"heads"``: the same pool written, the same output — float32 to
+    1e-6, bfloat16 to one bfloat16 step of the output (the products
+    with the block diagonal's zeros add nothing; both forms accumulate
+    in float32) — for plain heads, GQA with 2 and 4 query heads a kv
+    head, rotary positions, and the int8 pool with its sidecars."""
+    attn, x_t, pool, tables, pos = _step_attention_case(case, dtype)
+    o_rows, p_rows = attn.forward_step_paged(x_t, pool, tables, pos,
+                                             decode_attention="rows")
+    o_heads, p_heads = attn.forward_step_paged(x_t, pool, tables, pos,
+                                               decode_attention="heads")
+    for a, b in zip(p_rows, p_heads):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # ... and the same values handed to the attention, bit for bit: as
+    # rows the int8 sidecar is spread over each head's columns by an
+    # exact one-hot product, not dequantize_kv's broadcast
+    from bigdl_tpu.nn.attention import _write_kv_paged
+
+    k_t = jnp.zeros((4, attn.num_kv_heads, 1, attn.head_dim), dtype)
+    views = [_write_kv_paged(pool, k_t, k_t, tables, pos, rows=rows)[1:]
+             for rows in (True, False)]
+    for as_rows, per_head in zip(*views):
+        assert as_rows.ndim == 3 and per_head.ndim == 4
+        np.testing.assert_array_equal(
+            np.asarray(as_rows.astype(jnp.float32)),
+            np.asarray(per_head.astype(jnp.float32)).reshape(
+                as_rows.shape))
+    assert o_rows.dtype == o_heads.dtype == dtype
+    got = np.asarray(o_rows.astype(jnp.float32))
+    want = np.asarray(o_heads.astype(jnp.float32))
+    assert np.isfinite(want).all() and np.abs(want).max() > 0.01
+    # bfloat16 keeps 8 significant bits: one step at the largest output
+    atol = 1e-6 if dtype == jnp.float32 else np.abs(want).max() * 2.0 ** -8
+    np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+
+
+def test_decode_attention_form_is_named_and_checked(lm, lm_tp, mesh):
+    """The engine builds its paged step with the rows form when it has
+    no mesh and with the heads form when it has one, and says which in
+    ``stats()["paging"]``; an unknown form is refused."""
+    eng = ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=CHUNK,
+                                   page_size=PS)
+    try:
+        assert eng.stats()["paging"]["decode_attention"] == "rows"
+    finally:
+        eng.stop()
+    eng = ContinuousBatchingEngine(lm_tp, max_slots=2, prefill_chunk=CHUNK,
+                                   page_size=PS, mesh=mesh)
+    try:
+        assert eng.stats()["paging"]["decode_attention"] == "heads"
+    finally:
+        eng.stop()
+    attn, x_t, pool, tables, pos = _step_attention_case("plain",
+                                                        jnp.float32)
+    with pytest.raises(ValueError, match="decode_attention"):
+        attn.forward_step_paged(x_t, pool, tables, pos,
+                                decode_attention="columns")
