@@ -193,14 +193,6 @@ def main(argv=None):
                         "engine build, compile, and warmup included "
                         "(observability.profiler); the artifact dir "
                         "lands in detail.profile_artifact")
-    p.add_argument("--paged", action="store_true",
-                   help="with --serving: paged-KV A/B — one mixed "
-                        "short/long storm through the engine in paged "
-                        "mode (page-granular block pool) vs dense "
-                        "full-window slots at an EQUAL device KV byte "
-                        "budget — emits the peak admitted-concurrency "
-                        "ratio (bar: >= 3x) + TTFT A/B into "
-                        "bench_history.jsonl")
     p.add_argument("--requests", type=int, default=24,
                    help="--serving: workload size")
     p.add_argument("--rate", type=float, default=20.0,
@@ -432,7 +424,7 @@ def _serving_bench(args, dev):
     ratio rides along ungated (max-of-few-samples tail)."""
     from bigdl_tpu.models.transformer import TransformerLM
     from bigdl_tpu.serving.benchmark import (
-        run_paged_comparison, run_poisson_comparison, run_qos_storm,
+        run_poisson_comparison, run_qos_storm,
         run_quantized_comparison, run_shared_prefix_comparison,
         run_speculative_comparison, run_tp_comparison,
         run_working_set_sweep,
@@ -531,28 +523,6 @@ def _serving_bench(args, dev):
             },
         }
         _record_speculative_metrics(res)
-    elif args.paged:
-        res = run_paged_comparison(
-            model, n_requests=max(args.requests, 32),
-            dense_slots=2, paged_slots=8, page_size=4,
-            prefill_chunk=8, prefill_rows=2, log=log)
-        result = {
-            "metric": "serving_paged_admitted_concurrency",
-            "value": res["paged"]["peak_admitted_concurrency"],
-            "unit": "requests",
-            # vs_baseline > 1.0: paged mode admitted more concurrent
-            # requests than dense full-window slots from the SAME
-            # device KV bytes (the acceptance bar is >= 3x on the
-            # short-heavy storm)
-            "vs_baseline": res["admitted_concurrency_ratio"],
-            "detail": {
-                "version": __version__,
-                **_row_stamps(kind),
-                **_cost_fields(res["paged"]),
-                **res,
-            },
-        }
-        _record_paged_metrics(res)
     elif args.shared_prefix and args.working_set:
         res = run_working_set_sweep(
             model, working_sets=(2, max(4, args.working_set)),
@@ -899,30 +869,6 @@ def _record_path_metrics(ins, r, path):
     if r.get("inter_token", {}).get("p99") is not None:
         ins.inter_token_p99.labels(path).set(r["inter_token"]["p99"])
     _record_goodput_metrics(ins, r, path)
-
-
-def _record_paged_metrics(res):
-    """Mirror the paged-KV A/B into the observability registry under
-    ``path`` labels (``paged`` / ``dense``) plus the unlabeled
-    concurrency-ratio / TTFT-speedup / fragmentation scalars. Never
-    lets telemetry break the bench."""
-    try:
-        from bigdl_tpu import observability as obs
-
-        ins = obs.serving_bench_instruments()
-        for path, key in (("paged", "paged"), ("dense", "dense")):
-            _record_path_metrics(ins, res[key], path)
-        if res.get("admitted_concurrency_ratio") is not None:
-            ins.paged_admitted_concurrency_ratio().set(
-                res["admitted_concurrency_ratio"])
-        if res.get("ttft_p99_speedup") is not None:
-            ins.paged_ttft_p99_speedup().set(res["ttft_p99_speedup"])
-        frag = (res["paged"].get("paging") or {}).get("fragmentation")
-        if frag is not None:
-            ins.paged_fragmentation().set(frag)
-    except Exception as e:
-        print(f"[bench] paged metrics registry update failed: {e}",
-              file=sys.stderr)
 
 
 def _record_tp_metrics(res):

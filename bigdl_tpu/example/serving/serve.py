@@ -15,8 +15,10 @@ POINT is the serving machinery, not the prose):
      to engine liveness (503 once the decode loop dies; a watchdog
      alert degrades the body while staying 200), /debug/requests TTFT
      breakdowns, /debug/trace Chrome trace, /debug/memory per-pool
-     HBM attribution (KV slots / staging / tiered prefix pool —
-     device rows AND host-RAM spill — / params),
+     HBM attribution (the KV page pool's capacity and live bytes /
+     the tiered prefix cache — device pages AND host-RAM spill — /
+     params), the page pool's occupancy, fragmentation and
+     alloc/share/COW/free flow from stats()["paging"],
      per-tenant usage accounting (requests submitted under tenant
      names; the /debug/usage table — tokens, device-seconds, KV
      byte-seconds, goodput — round-tripped over HTTP), on-demand
@@ -24,15 +26,11 @@ POINT is the serving machinery, not the prose):
      model (per-kind MFU + roofline class from stats()["cost"], loop-
      phase bubble breakdown from stats()["loop"]), and the live
      /debug/dashboard sparkline page (URL printed on startup)
-  7. --paged: the SAME engine on the paged KV cache — one refcounted
-     block pool per model, per-request block tables, zero-copy
-     prefix sharing — with the pool's occupancy, fragmentation, and
-     alloc/share/COW/free flow printed from stats()["paging"]
-  8. --tp N: the SAME engine tensor-parallel over an N-way model-axis
+  7. --tp N: the SAME engine tensor-parallel over an N-way model-axis
      device mesh (Megatron-sharded params, heads-sharded KV pools,
      SPMD dispatches; N virtual host devices on CPU) — topology and
      per-device pool bytes printed from stats()["mesh"]
-  9. --fleet N: the multi-replica fleet instead — N in-process engine
+  8. --fleet N: the multi-replica fleet instead — N in-process engine
      replicas behind a ReplicaSupervisor and the HTTP front door;
      POST /v1/generate streams tokens as SSE (the meta event says
      which replica the prefix-affinity router picked and why), the
@@ -80,13 +78,6 @@ def main(argv=None):
                         "dequantize fused into the attention read) "
                         "and int8 weights, and print membw_util + "
                         "pool bytes next to the fp engine's figures")
-    p.add_argument("--paged", action="store_true",
-                   help="run the continuous-batching engine on the "
-                        "PAGED KV cache (one refcounted block pool "
-                        "per model, per-request block tables, prefix "
-                        "hits share pages copy-on-write) and print "
-                        "the pool's occupancy, fragmentation, and "
-                        "alloc/share/COW/free flow from stats()")
     p.add_argument("--fleet", type=int, default=0, metavar="N",
                    help="run the MULTI-REPLICA demo instead: N in-"
                         "process engine replicas behind the "
@@ -241,15 +232,9 @@ def main(argv=None):
                 "overridden before startup?)")
         engine_kw["mesh"] = MeshEngine.create_mesh(
             [("model", args.tp)], devices=devs[:args.tp])
-    if args.paged:
-        # paged KV: requests hold page_size-token pages from ONE
-        # refcounted pool instead of a dense full-length slot row, so
-        # a short chat never bills a document's worth of HBM and a
-        # prefix hit is a refcount bump, not a row copy
-        engine_kw["page_size"] = 4
-    # tiered prefix cache: a tiny device pool forces LRU eviction to
-    # DEMOTE rows into pinned host RAM instead of dropping them; a
-    # revisit of a demoted prefix promotes it back asynchronously
+    # tiered prefix cache: a host budget lets page reclaim DEMOTE
+    # retained prefixes into pinned host RAM instead of dropping them;
+    # a revisit of a demoted prefix promotes it back at admission
     engine_kw.setdefault("prefix_cache_rows", 2)
     engine_kw.setdefault("prefix_host_rows", 8)
     fp_before = None
@@ -326,17 +311,18 @@ def main(argv=None):
                   f"({sp['acceptance_rate']:.0%} acceptance rate)")
         if args.tp and args.tp > 1:
             ms = engine.stats()["mesh"]
-            kv = ms["pools"]["kv_slots"]
+            kv = ms["pools"]["kv_page_pool"]
             print(f"[tp]        {ms['model_shards']}-way model mesh "
-                  f"over {ms['devices']} devices; kv_slots "
+                  f"over {ms['devices']} devices; kv_page_pool "
                   f"{kv['physical_bytes'] // 1024} KB global, "
                   f"{kv['bytes_per_device'] // 1024} KB/device "
                   f"(sharded={kv['sharded']}); tokens identical to "
                   "the single-device engine")
 
-        # who owns the HBM: the engine registered its KV slot pool,
-        # prefill staging, prefix pool, and params as named memory
-        # pools — /debug/memory attributes device bytes to each
+        # who owns the HBM: the engine registered its KV page pool
+        # (capacity and live bytes), the bytes its prefix index
+        # retains, and params as named memory pools — /debug/memory
+        # attributes device bytes to each
         mem = json.loads(urllib.request.urlopen(
             f"{base}/debug/memory").read())
         eng_pools = {k.split("/")[-1]: v
@@ -347,33 +333,32 @@ def main(argv=None):
               f"engine pools (KB): "
               + ", ".join(f"{k}={v // 1024}"
                           for k, v in sorted(eng_pools.items())))
-        # the tiered prefix cache shows up as TWO pools: device rows
-        # in prefix_kv_in_use, demoted rows in prefix_host_kv
+        # the tiered prefix cache shows up as TWO pools: device pages
+        # in prefix_kv_in_use, demoted pages in prefix_host_kv
         pc = engine.stats()["prefix_cache"]
         print(f"[prefix]    device tier "
               f"{eng_pools.get('prefix_kv_in_use', 0) // 1024} KB "
-              f"({pc['entries']} rows), host tier "
+              f"({pc['entries']} entries), host tier "
               f"{eng_pools.get('prefix_host_kv', 0) // 1024} KB "
-              f"({pc['host_entries']} rows); hits "
+              f"({pc['host_entries']} entries); hits "
               f"{pc['hits']} ({pc['host_hits']} from host), "
               f"demoted {pc['demotions']}, promoted {pc['promotions']}")
-        if args.paged:
-            # the block pool's health: live occupancy (prefix entries
-            # still hold their pages), internal fragmentation (wasted
-            # tail of each trailing partial page), and the cumulative
-            # alloc/share/COW/free flow — shares and frees are pure
-            # refcount moves, so cow stays 0 on the aligned hit leg
-            pg = engine.stats()["paging"]
-            pool = pg["pool"]
-            print(f"[paged]     page_size {pg['page_size']}: "
-                  f"{pool['pages_in_use']}/{pool['max_pages']} pages "
-                  f"held ({pool['bytes_in_use'] // 1024} KB of "
-                  f"{pool['capacity_bytes'] // 1024} KB), "
-                  f"fragmentation {pg['fragmentation']:.0%}; flow: "
-                  f"{pool['allocated_total']} allocated, "
-                  f"{pool['shared_total']} shared, "
-                  f"{pool['cow_forks_total']} cow, "
-                  f"{pool['freed_total']} freed")
+        # the block pool's health: live occupancy (prefix entries
+        # still hold their pages), internal fragmentation (wasted
+        # tail of each trailing partial page), and the cumulative
+        # alloc/share/COW/free flow — shares and frees are pure
+        # refcount moves, so cow stays 0 on the aligned hit leg
+        pg = engine.stats()["paging"]
+        pool = pg["pool"]
+        print(f"[paged]     page_size {pg['page_size']}: "
+              f"{pool['pages_in_use']}/{pool['max_pages']} pages "
+              f"held ({pool['bytes_in_use'] // 1024} KB of "
+              f"{pool['capacity_bytes'] // 1024} KB), "
+              f"fragmentation {pg['fragmentation']:.0%}; flow: "
+              f"{pool['allocated_total']} allocated, "
+              f"{pool['shared_total']} shared, "
+              f"{pool['cow_forks_total']} cow, "
+              f"{pool['freed_total']} freed")
 
         # who consumed the device: the per-tenant usage table, the
         # goodput block, and the top requests by device-seconds —

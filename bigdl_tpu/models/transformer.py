@@ -282,28 +282,17 @@ class TransformerLM(Module):
         dimension tensor-parallel serving shards the KV pools along."""
         return self.block0.attn.num_kv_heads
 
-    def kv_cache_sharding(self, mesh, model_axis: str = "model"):
-        """NamedSharding for this model's ``init_cache`` buffers on a
-        tensor-parallel ``mesh``: the ``(B, H_kv, T, D)`` caches shard
-        their HEADS dimension along ``model_axis`` — the layout the
-        column-parallel QKV projection (``transformer_tp_rules``)
-        writes with no collective, because each device computes
-        exactly its own heads' K/V. Every compiled prefill / decode /
-        verify entry point then runs SPMD from the input shardings
-        alone (GSPMD places the row-parallel all-reduces); raises when
-        the head count does not divide the axis size."""
-        from bigdl_tpu.parallel.tp import kv_pool_sharding
-
-        return kv_pool_sharding(mesh, self.num_kv_heads,
-                                model_axis=model_axis)
-
     def kv_page_pool_sharding(self, mesh, model_axis: str = "model"):
         """NamedSharding for this model's ``init_page_pool`` buffers on
-        a tensor-parallel ``mesh``: the paged twin of
-        :meth:`kv_cache_sharding` for leaves ``(max_pages, page_size,
-        H_kv * D)``, whose heads are their LAST dimension — each device
-        holds the pages' rows of its own heads, so the paged KV write
-        needs no collective either."""
+        a tensor-parallel ``mesh``: leaves ``(max_pages, page_size,
+        H_kv * D)`` shard their LAST dimension, the heads, along
+        ``model_axis`` — the layout the column-parallel QKV projection
+        (``transformer_tp_rules``) writes with no collective, because
+        each device computes exactly its own heads' K/V. Every compiled
+        prefill / decode / verify entry point then runs SPMD from the
+        input shardings alone (GSPMD places the row-parallel
+        all-reduces); raises when the head count does not divide the
+        axis size."""
         from bigdl_tpu.parallel.tp import kv_page_pool_sharding
 
         return kv_page_pool_sharding(mesh, self.num_kv_heads,

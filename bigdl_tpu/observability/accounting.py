@@ -15,7 +15,7 @@ stay flat with accounting on):
 - ``UsageRecord`` — one request's metered consumption: queue seconds,
   prompt tokens actually prefilled vs served from the prefix cache
   (plus the KV bytes that reuse saved), tokens delivered, **KV
-  byte-seconds held** (staging/slot row bytes x residency — the HBM a
+  byte-seconds held** (held page bytes x residency — the HBM a
   request occupied, over time), and **device-seconds attributed
   pro-rata** from every ragged prefill round and fused decode step
   across the rows each dispatch actually advanced.
@@ -82,7 +82,7 @@ class UsageRecord:
                  "prefix_bytes_saved", "decode_tokens",
                  "device_prefill_s", "device_decode_s",
                  "kv_byte_seconds", "outcome", "preemptions",
-                 "_staging_since", "_slot_since", "_requeued_at")
+                 "_requeued_at")
 
     def __init__(self, request_id: str, tenant: str,
                  prompt_tokens: int, max_new_tokens: int,
@@ -110,7 +110,7 @@ class UsageRecord:
         self.device_prefill_s = 0.0
         #: pro-rata share of fused decode dispatch walls
         self.device_decode_s = 0.0
-        #: staging/slot row bytes x residency seconds (HBM held x time)
+        #: held page bytes x residency seconds (HBM held x time)
         self.kv_byte_seconds = 0.0
         #: terminal outcome once finalized (finished/cancelled/...)
         self.outcome: Optional[str] = None
@@ -118,9 +118,6 @@ class UsageRecord:
         #: the eviction stays billed to this record — preemption never
         #: un-bills the device time the victim already consumed)
         self.preemptions = 0
-        # open residency intervals (row-bytes charged at close)
-        self._staging_since: Optional[float] = None
-        self._slot_since: Optional[float] = None
         # set while preempted-and-requeued: the next ``admitted`` adds
         # the requeue→re-admission span to queue_wait_s instead of
         # restarting the figure from submit
@@ -167,14 +164,13 @@ class UsageLedger:
     serving engine.
 
     Flow (engine loop thread unless noted): ``begin`` at submit (any
-    thread), ``admitted`` when prefill starts (closes the queue wait,
-    opens the staging-row residency), ``add_prefill`` per chunk,
-    ``slot_acquired`` when the staged prompt is inserted (staging
-    residency closes, slot residency opens), ``delivered`` per token,
+    thread), ``admitted`` when prefill starts (closes the queue wait),
+    ``add_prefill`` per chunk, ``accrue_kv`` per loop iteration with the
+    page bytes the request held over it, ``delivered`` per token,
     ``charge_dispatch`` once per device dispatch with the rows it
     advanced, and ``finalize`` exactly once per request (any thread —
     the engine's ``_finish_handle`` arbitration guarantees a single
-    finalizer) — which closes open residencies, folds the record into
+    finalizer) — which folds the record into
     its tenant's aggregate, increments the
     ``bigdl_serving_tenant_*`` counters, and records the
     ``request/usage_final`` flight-recorder event.
@@ -194,7 +190,6 @@ class UsageLedger:
     def __init__(self, service: str = "engine", registry=None,
                  recorder=None, instruments=None,
                  max_tenants: int = 32, recent: int = 256,
-                 slot_row_bytes: int = 0, staging_row_bytes: int = 0,
                  token_bytes: float = 0.0,
                  default_tenant: str = "default",
                  overflow_tenant: str = "other",
@@ -213,8 +208,6 @@ class UsageLedger:
         self.max_tenants = max_tenants
         self.default_tenant = default_tenant
         self.overflow_tenant = overflow_tenant
-        self.slot_row_bytes = int(slot_row_bytes)
-        self.staging_row_bytes = int(staging_row_bytes)
         #: device KV bytes one cached token position occupies
         #: (row_bytes / cache_len) — the prefix-savings exchange rate
         self.token_bytes = float(token_bytes)
@@ -271,9 +264,9 @@ class UsageLedger:
 
     def admitted(self, rec: UsageRecord, now: float,
                  reused_tokens: int = 0) -> None:
-        """Prefill starts: close the queue wait, credit the prefix
-        reuse (tokens and the KV bytes not recomputed), and open the
-        staging-row residency. A RE-admission after preemption adds
+        """Prefill starts: close the queue wait and credit the prefix
+        reuse (tokens and the KV bytes not recomputed). A RE-admission
+        after preemption adds
         the requeue→now span to the accumulated queue wait instead of
         restarting the figure from submit (the first wait was already
         closed — double-billing it would inflate the tenant's queue
@@ -288,21 +281,9 @@ class UsageLedger:
             rec.prefix_reused_tokens += int(reused_tokens)
             rec.prefix_bytes_saved += int(reused_tokens
                                           * self.token_bytes)
-        rec._staging_since = now
 
     def add_prefill(self, rec: UsageRecord, tokens: int) -> None:
         rec.prefill_tokens += int(tokens)
-
-    def slot_acquired(self, rec: UsageRecord, now: float) -> None:
-        """Staged prompt inserted into its pool slot: the staging-row
-        residency closes into ``kv_byte_seconds`` and the slot-row
-        residency opens."""
-        if rec._staging_since is not None:
-            # graftlint: ok[lock-discipline] — staging_row_bytes is immutable after __init__
-            rec.kv_byte_seconds += (self.staging_row_bytes
-                                    * max(0.0, now - rec._staging_since))
-            rec._staging_since = None
-        rec._slot_since = now
 
     def delivered(self, rec: UsageRecord, tokens: int = 1) -> None:
         rec.decode_tokens += int(tokens)
@@ -311,35 +292,21 @@ class UsageLedger:
 
     def preempted(self, rec: UsageRecord, now: float) -> None:
         """The request's slot was preempted (NOT terminal — the
-        request requeues and resumes): close the open slot/staging
-        residency into ``kv_byte_seconds`` — the HBM it held up to the
-        eviction stays billed to this record — and stamp the requeue
-        time so the next ``admitted`` accumulates the second queue
-        wait. Device-seconds already attributed are untouched:
-        preemption never un-bills consumed device time."""
-        if rec._staging_since is not None:
-            # graftlint: ok[lock-discipline] — staging_row_bytes is immutable after __init__
-            rec.kv_byte_seconds += (self.staging_row_bytes
-                                    * max(0.0, now - rec._staging_since))
-            rec._staging_since = None
-        if rec._slot_since is not None:
-            # graftlint: ok[lock-discipline] — slot_row_bytes is immutable after __init__
-            rec.kv_byte_seconds += (self.slot_row_bytes
-                                    * max(0.0, now - rec._slot_since))
-            rec._slot_since = None
+        request requeues and resumes): stamp the requeue time so the
+        next ``admitted`` accumulates the second queue wait. The KV
+        byte-seconds and device-seconds already attributed are
+        untouched: preemption never un-bills what the victim
+        consumed."""
         rec.preemptions += 1
         rec._requeued_at = now
 
     def accrue_kv(self, rec: UsageRecord, byte_seconds: float) -> None:
-        """Paged-KV billing: add ``byte_seconds`` of device KV
-        residency measured externally. A paged engine integrates each
-        holder's pro-rata page footprint (``PagePool.holder_bytes`` —
-        a page shared by r requests bills 1/r to each, so the sum over
+        """KV billing: add ``byte_seconds`` of device KV residency
+        measured externally. The engine integrates each holder's
+        pro-rata page footprint (``PagePool.holder_bytes`` — a page
+        shared by r requests bills 1/r to each, so the sum over
         holders equals the pool's live bytes) over every loop
-        iteration and feeds it here; its ledger is constructed with
-        ``slot_row_bytes=staging_row_bytes=0`` so the dense
-        row-residency bookkeeping above contributes nothing and the
-        two billing models never double-count. Loop thread only."""
+        iteration and feeds it here. Loop thread only."""
         rec.kv_byte_seconds += max(0.0, float(byte_seconds))
 
     # --------------------------------------------------------- dispatch
@@ -395,7 +362,7 @@ class UsageLedger:
     def finalize(self, rec: UsageRecord, outcome: str,
                  now: float) -> None:
         """Terminal accounting for one request (exactly once — later
-        calls are no-ops): close open residencies, aggregate under the
+        calls are no-ops): aggregate under the
         tenant, bump the tenant counters, ring the record, and record
         ``request/usage_final``."""
         with self._lock:
@@ -407,16 +374,6 @@ class UsageLedger:
                 # never admitted (queue-dropped / rejected): its whole
                 # life was queue wait — billed, not vanished
                 rec.queue_wait_s = max(0.0, now - rec.submitted_at)
-            if rec._staging_since is not None:
-                rec.kv_byte_seconds += (
-                    self.staging_row_bytes
-                    * max(0.0, now - rec._staging_since))
-                rec._staging_since = None
-            if rec._slot_since is not None:
-                rec.kv_byte_seconds += (
-                    self.slot_row_bytes
-                    * max(0.0, now - rec._slot_since))
-                rec._slot_since = None
             agg = self._tenants.setdefault(rec.tenant,
                                            _zero_aggregate())
             agg["requests"] += 1

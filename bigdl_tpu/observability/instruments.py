@@ -842,22 +842,6 @@ def serving_bench_instruments(registry: Optional[MetricRegistry] = None
             "bigdl_bench_serving_qos_rate_limited",
             "Submissions refused by per-tenant token buckets during "
             "the QoS storm leg"),
-        paged_admitted_concurrency_ratio=lambda: r.gauge(
-            "bigdl_bench_serving_paged_admitted_concurrency_ratio",
-            "Paged-vs-dense peak admitted concurrency ratio at an "
-            "equal device KV byte budget on the mixed short/long "
-            "storm (the bar is >= 3x: page-granular reservation "
-            "admits more requests from the same bytes)"),
-        paged_ttft_p99_speedup=lambda: r.gauge(
-            "bigdl_bench_serving_paged_ttft_p99_speedup",
-            "Dense-vs-paged engine TTFT p99 speedup on the paged A/B "
-            "storm (>1.0: less queueing behind full-window "
-            "reservations)"),
-        paged_fragmentation=lambda: r.gauge(
-            "bigdl_bench_serving_paged_fragmentation",
-            "Paged leg's end-of-run internal fragmentation (wasted "
-            "fraction of held page capacity; trailing partial pages "
-            "are the only waste paging permits)"),
     )
 
 

@@ -1,16 +1,16 @@
 """bigdl_tpu.serving — continuous-batching LM inference.
 
 The serving-at-scale layer (BigDL 2.0's north-star capability, arxiv
-2204.01715): a persistent device-resident decode loop over a
-slot-pooled KV cache, replacing batch-at-a-time request/response
-dispatch with token-granular continuous batching —
+2204.01715): a persistent device-resident decode loop over one paged
+KV pool, replacing batch-at-a-time request/response dispatch with
+token-granular continuous batching —
 
-- ``ContinuousBatchingEngine`` (``engine``): the loop thread, the
-  pooled ``(max_slots, ...)`` KV cache, mid-flight chunked-prefill
+- ``ContinuousBatchingEngine`` (``engine``): the loop thread, the page
+  pool every request holds its KV in, mid-flight chunked-prefill
   admission (batched ``prefill_rows`` wide through one ragged dispatch
   per round), and per-token slot eviction/reuse. Compiled shapes
-  depend only on ``max_slots``/``prefill_rows``/pool rows — never on
-  load. Pass ``draft=`` (plus ``spec_gamma``) for SPECULATIVE decode:
+  depend only on ``max_slots``/``prefill_rows``/``(max_pages,
+  page_size)`` — never on load. Pass ``draft=`` (plus ``spec_gamma``) for SPECULATIVE decode:
   the draft proposes gamma tokens for every live slot in one scan,
   the target verifies them in one ragged dispatch, and each row
   accepts its own variable-length extension — greedy output stays
@@ -20,19 +20,21 @@ dispatch with token-granular continuous batching —
   pool shards its heads dimension, and each compiled program runs as
   one SPMD dispatch with jit-inserted collectives — token-identical
   to the unsharded engine, jit gauge still flat.
-- ``PrefixCache`` (``prefix_cache``): the host-side radix-trie index
-  over token-id prefixes mapping to retained KV pool rows — a new
-  request whose prompt shares a cached prefix skips prefill for the
-  shared head (O(novel-suffix) TTFT); finished slots donate their KV
-  back under an LRU/ref-count policy within a configurable byte
-  budget.
+- ``PagePool`` / ``BlockTable`` / ``PagedPrefixIndex`` (``paging``):
+  the refcounted page allocator, one request's ordered view of its
+  pages, and the host-side radix-trie index over token-id prefixes
+  mapping to retained pages — a new request whose prompt shares a
+  cached prefix shares those pages and skips prefill for the shared
+  head (O(novel-suffix) TTFT); finished slots share their pages back
+  under an LRU/ref-count policy, reclaimed (or demoted to a host tier)
+  when the pool runs short.
 - ``AdmissionQueue`` / ``PrefillPolicy`` (``scheduler``): bounded
   admission with backpressure, deadline/cancellation sweeps,
   QoS-ordered pop — (priority class, deadline slack, prefix-affinity
   score) under a per-class bounded bypass window — plus the
   prefill-vs-decode token budget and the per-tenant ``TokenBucket``
   rate limiter. Under overload the engine PREEMPTS lower-class slots
-  (KV donated to the prefix pool, automatic token-identical resume),
+  (KV donated to the prefix index, automatic token-identical resume),
   SHEDS lowest-class admissions on SLO burn (``RequestShed``), and
   throttles over-budget tenants (``RequestRateLimited``) — see
   ``stats()["qos"]`` and ``engine(chaos=ChaosInjector())`` for drills.
@@ -72,9 +74,8 @@ each tenant (``handle.usage()``, ``stats()["usage"]``,
 from bigdl_tpu.serving.chaos import ChaosFault, ChaosInjector
 from bigdl_tpu.serving.engine import ContinuousBatchingEngine
 from bigdl_tpu.serving.paging import (
-    SCRATCH_PAGE, BlockTable, PagedPrefixIndex, PagePool,
+    SCRATCH_PAGE, BlockTable, PagedPrefixIndex, PagePool, PrefixEntry,
 )
-from bigdl_tpu.serving.prefix_cache import PrefixCache, PrefixEntry
 from bigdl_tpu.serving.scheduler import (
     AdmissionQueue, PrefillPolicy, SpeculationPolicy, TokenBucket,
     page_fit_score, pages_needed,
@@ -85,9 +86,8 @@ from bigdl_tpu.serving.streams import (
     RequestRateLimited, RequestShed, RequestTimedOut,
 )
 from bigdl_tpu.serving.benchmark import (
-    mixed_length_workload, poisson_workload, quantized_quality_report,
-    repeated_text_workload, run_paged_comparison,
-    run_poisson_comparison, run_qos_storm, run_quantized_comparison,
+    poisson_workload, quantized_quality_report,
+    repeated_text_workload, run_poisson_comparison, run_qos_storm, run_quantized_comparison,
     run_shared_prefix_comparison, run_speculative_comparison,
     run_tp_comparison, run_working_set_sweep, shared_prefix_workload,
 )
@@ -95,8 +95,8 @@ from bigdl_tpu.serving.benchmark import (
 __all__ = [
     "ContinuousBatchingEngine",
     "ChaosInjector", "ChaosFault",
-    "PrefixCache", "PrefixEntry",
-    "PagePool", "BlockTable", "PagedPrefixIndex", "SCRATCH_PAGE",
+    "PagePool", "BlockTable", "PagedPrefixIndex", "PrefixEntry",
+    "SCRATCH_PAGE",
     "AdmissionQueue", "PrefillPolicy", "SpeculationPolicy",
     "TokenBucket", "pages_needed", "page_fit_score",
 ]
@@ -111,5 +111,4 @@ __all__ += [
     "run_tp_comparison", "run_working_set_sweep",
     "quantized_quality_report", "run_quantized_comparison",
     "run_qos_storm",
-    "mixed_length_workload", "run_paged_comparison",
 ]

@@ -64,19 +64,6 @@ outcome-conservation verdict (every submit ends in exactly one
 terminal outcome — no silent drops), and the per-tenant ledger
 breakdown.
 
-``--serving --paged`` runs the PAGED-KV A/B
-(:func:`run_paged_comparison`): one mixed short/long Poisson storm
-replayed through the engine in paged mode (``page_size`` block pool,
-per-request BlockTables) vs dense full-row slots, at an EQUAL device
-KV byte budget — the paged pool holds exactly as many bytes as the
-dense leg's slot rows, it just hands them out page-granular instead
-of window-granular. The headline is the peak admitted-concurrency
-ratio (paged must admit >= 3x the dense leg's concurrent requests
-from the same bytes), alongside TTFT p50/p99 both ways (less
-queueing behind full-window reservations), the paged leg's pool /
-fragmentation block, and greedy token parity (paging moves bytes,
-never tokens).
-
 ``scripts/perf_gate.py`` turns consecutive rows of any variant into a
 CI regression gate.
 """
@@ -1268,193 +1255,4 @@ def run_qos_storm(model, n_requests: int = 24, rate_hz: float = 20.0,
         "workload": {"kind": "qos_storm", "requests": n_requests,
                      "n_greedy": n_greedy, "rate_hz": rate_hz,
                      "seed": seed, "max_slots": max_slots,
-                     "prefill_rows": prefill_rows}}
-
-
-def mixed_length_workload(n_requests: int, rate_hz: float, vocab: int,
-                          short_prompt=(4, 12), short_decode=(4, 12),
-                          long_prompt: int = 32, long_decode: int = 8,
-                          long_every: int = 6, seed: int = 0,
-                          tenants=("tenant-a", "tenant-b", "tenant-c")
-                          ) -> List[dict]:
-    """Sample a MIXED short/long open-loop workload: mostly short
-    interactive requests with every ``long_every``-th a long-prompt
-    batch job — the traffic shape where full-window slot reservation
-    wastes the most KV (a 10-token chat holds a whole context row)
-    and page-granular reservation buys the most concurrency. Long
-    requests use FIXED lengths so their page footprint is
-    deterministic across seeds. Same arrival/replay semantics as
-    :func:`poisson_workload`."""
-    r = np.random.RandomState(seed)
-    at = np.cumsum(r.exponential(1.0 / rate_hz, n_requests))
-    out = []
-    for i in range(n_requests):
-        if long_every and i % long_every == long_every - 1:
-            t0, n = int(long_prompt), int(long_decode)
-        else:
-            t0 = int(r.randint(short_prompt[0], short_prompt[1] + 1))
-            n = int(r.randint(short_decode[0], short_decode[1] + 1))
-        out.append({
-            "arrival_s": float(at[i]),
-            "prompt": r.randint(0, vocab, (t0,)).astype(np.int32),
-            "n": n,
-            "tenant": tenants[i % len(tenants)] if tenants else None,
-        })
-    return out
-
-
-def _peak_concurrency(spans) -> int:
-    """Max number of overlapping ``(start, end)`` intervals — the peak
-    count of requests simultaneously HOLDING a slot (admitted, not yet
-    finished), computed offline from the handles' lifecycle stamps so
-    no sampler races the loop. A release at exactly another's admit
-    counts as a handoff, not an overlap."""
-    events = []
-    for a, b in spans:
-        events.append((a, 1))
-        events.append((b, -1))
-    events.sort()  # (t, -1) orders before (t, +1): handoff, not overlap
-    cur = peak = 0
-    for _, d in events:
-        cur += d
-        peak = max(peak, cur)
-    return peak
-
-
-def run_paged_comparison(model, n_requests: int = 32,
-                         rate_hz: float = 200.0, dense_slots: int = 2,
-                         paged_slots: int = 8, page_size: int = 4,
-                         prefill_chunk: int = 8, prefill_rows: int = 2,
-                         eos_id: Optional[int] = None, seed: int = 0,
-                         registry=None, log=None) -> dict:
-    """Replay ONE mixed short/long Poisson storm through the engine
-    twice at an EQUAL device KV byte budget — paged mode
-    (``page_size``-token block pool sized to exactly the dense leg's
-    slot-row bytes, ``paged_slots`` slots sharing it page-granular) vs
-    dense mode (``dense_slots`` full serving-window rows) — and report
-    the peak admitted concurrency both ways (the capacity claim:
-    page-granular reservation admits >= 3x the requests from the same
-    bytes on short-heavy traffic), TTFT/latency percentiles both ways,
-    the paged leg's pool/fragmentation block, and whether the two
-    paths produced token-identical greedy outputs (they must: paging
-    changes where KV bytes live, never the tokens)."""
-    from bigdl_tpu.serving import ContinuousBatchingEngine
-
-    log = log or (lambda *a, **k: None)
-    vocab = model.vocab_size
-    window = (model.max_len // prefill_chunk) * prefill_chunk
-    table_len = -(-window // page_size)
-    # EQUAL BYTE BUDGET: the paged pool gets exactly the bytes the
-    # dense leg spends on its slot rows (dense_slots full windows),
-    # plus the one reserved scratch page every pool carries
-    max_pages = 1 + dense_slots * table_len
-    # size the long jobs inside the serving window, and the short ones
-    # so a full house of paged_slots worst-case-short requests still
-    # fits the shared budget (each reserves pages for t0 + n tokens
-    # at admission — see engine._start_admission_paged)
-    long_prompt = min(32, window // 2)
-    long_decode = min(16, max(1, window - long_prompt))
-    # the storm must be DENSE enough to queue: arrivals far outpace
-    # service, decodes long enough that early slots are still held
-    # while admission fills the rest — otherwise neither leg ever
-    # reaches its concurrency ceiling and the ratio measures pacing,
-    # not capacity
-    wl = mixed_length_workload(
-        n_requests, rate_hz, vocab,
-        short_prompt=(4, min(12, window // 4)),
-        short_decode=(8, min(16, window // 4)),
-        long_prompt=long_prompt, long_decode=long_decode, seed=seed)
-    warm_prompt = np.asarray(
-        np.random.RandomState(seed + 1).randint(0, vocab, (12,)),
-        np.int32)
-
-    def leg(name: str, stats_keys, **engine_kw) -> dict:
-        engine = ContinuousBatchingEngine(
-            model, prefill_chunk=prefill_chunk,
-            prefill_rows=prefill_rows, eos_id=eos_id,
-            registry=registry, service_name=name,
-            # both legs cache-disabled: the A/B isolates the
-            # reservation granularity, not prefix reuse
-            prefix_cache_rows=0, **engine_kw)
-        ttft: List[float] = []
-        itl: List[float] = []
-        rows: dict = {}
-        spans: List[tuple] = []
-        tlock = threading.Lock()
-
-        def collect(handle, req):
-            row = handle.result()
-            with tlock:
-                rows[id(req)] = row
-                if handle.first_token_at is not None:
-                    ttft.append(handle.first_token_at
-                                - handle.submitted_at)
-                _append_itl(itl, handle)
-                if (handle.admitted_at is not None
-                        and handle.finished_at is not None):
-                    spans.append((handle.admitted_at,
-                                  handle.finished_at))
-            return row.shape[0] - req["prompt"].shape[0]
-
-        log(f"[serving-bench] paged A/B {name} replay...")
-        with engine:
-            engine.submit(warm_prompt, 4).result(timeout=300)
-            res = _replay(
-                wl,
-                lambda req: engine.submit(req["prompt"], req["n"],
-                                          tenant=req.get("tenant")),
-                collect)
-            stats = engine.stats()
-        res["ttft"] = _percentiles(ttft)
-        res["inter_token"] = _percentiles(itl)
-        res["peak_admitted_concurrency"] = _peak_concurrency(spans)
-        for key in stats_keys:
-            res[key] = stats.get(key)
-        res.update(_usage_blocks(stats))
-        res["cost"] = stats.get("cost")
-        res["loop"] = stats.get("loop")
-        res["alerts"] = stats["alerts"]
-        res["rows"] = rows
-        return res
-
-    paged = leg("bench_paged", ("paging", "jit_compiles"),
-                max_slots=paged_slots, page_size=page_size,
-                max_pages=max_pages)
-    dense = leg("bench_dense", ("jit_compiles",),
-                max_slots=dense_slots)
-    parity = all(
-        np.array_equal(paged["rows"][id(req)], dense["rows"][id(req)])
-        for req in wl)
-    for r in (paged, dense):
-        del r["rows"]
-
-    def ttft_ratio(key):
-        a, b = dense["ttft"][key], paged["ttft"][key]
-        return round(a / b, 4) if a and b else None
-
-    pool = (paged.get("paging") or {}).get("pool") or {}
-    page_bytes = pool.get("page_bytes", 0)
-    peak_p = paged["peak_admitted_concurrency"]
-    peak_d = dense["peak_admitted_concurrency"]
-    return {
-        "paged": paged, "dense": dense,
-        "admitted_concurrency_ratio":
-            round(peak_p / peak_d, 4) if peak_d else None,
-        "ttft_p50_speedup": ttft_ratio("p50"),
-        "ttft_p99_speedup": ttft_ratio("p99"),
-        "token_parity": bool(parity),
-        "kv_budget": {
-            # what each leg could spend on request KV: identical by
-            # construction (the scratch page is pool overhead, not
-            # request capacity)
-            "dense_bytes": dense_slots * table_len * page_bytes,
-            "paged_bytes": (max_pages - 1) * page_bytes,
-            "page_bytes": page_bytes,
-            "max_pages": max_pages,
-            "table_len": table_len},
-        "workload": {"kind": "paged", "requests": n_requests,
-                     "rate_hz": rate_hz, "seed": seed,
-                     "dense_slots": dense_slots,
-                     "paged_slots": paged_slots,
-                     "page_size": page_size,
                      "prefill_rows": prefill_rows}}

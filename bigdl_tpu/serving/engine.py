@@ -1,4 +1,4 @@
-"""Continuous-batching decode engine over a slot-pooled KV cache.
+"""Continuous-batching decode engine over one paged KV pool.
 
 The batch-at-a-time services (``GenerationService``'s micro-batcher,
 ≙ the reference's instance-queue in optim/PredictionService.scala) run
@@ -7,57 +7,61 @@ co-batched short request. This engine replaces request/response batch
 dispatch with a persistent device-resident decode loop (the inference
 analog of the RDMA paper's persistent dataflow, arxiv 1805.08430):
 
-- ONE pooled KV cache of shape ``(max_slots, H_kv, cache_len, D)`` per
-  layer lives on device for the engine's whole life. Every compiled
-  program's shape depends only on ``max_slots`` / ``cache_len`` /
-  ``prefill_rows`` / the prefix-pool row count — never on load — so
+- ONE page pool of ``(max_pages, page_size, H_kv * D)`` leaves per
+  layer (``serving.paging.PagePool``) lives on device for the engine's
+  whole life and is the ONLY place KV is stored: running slots,
+  prefills in flight and retained prefixes all hold refcounted pages
+  of it through fixed-width block tables. Every compiled program's
+  shape depends only on ``max_slots`` / ``prefill_rows`` /
+  ``(max_pages, page_size)`` / the table width — never on load — so
   steady state runs a FIXED executable set (decode step, ragged
-  prefill chunk, row copy, first-token sample) no matter what traffic
-  does.
-- a dedicated loop thread runs one fused ``decode_step`` over ALL
-  slots per iteration (rows at their own depths — the ragged per-row
-  position vector path), so requests join and leave the batch at token
-  granularity.
-- admission happens MID-FLIGHT: queued requests prefill in fixed
-  chunks into a ``prefill_rows``-wide staging cache under a
-  per-iteration token budget (``PrefillPolicy``) — each prefill round
-  advances EVERY staged admission by one chunk through one ragged
-  dispatch (each row at its own offset), then finished stagings are
-  scattered into free slots by a donated row copy. Decode never waits
-  for more than one iteration's prefill budget.
+  prefill chunk, page copies, first-token sample) no matter what
+  traffic does.
+- a dedicated loop thread runs one fused ``decode_step_paged`` over
+  ALL slots per iteration (rows at their own depths — the ragged
+  per-row position vector path), so requests join and leave the batch
+  at token granularity.
+- admission happens MID-FLIGHT: a queued request reserves its whole
+  page span up front (no mid-flight OOM; a pool that cannot cover it
+  requeues the request at the head), then prefills in fixed chunks
+  straight through its own table under a per-iteration token budget
+  (``PrefillPolicy``) — each prefill round advances EVERY admission in
+  flight by one chunk through one ragged ``prefill_rows``-wide dispatch
+  (each row at its own offset), and a finished admission hands its
+  table to its slot: nothing is copied. Decode never waits for more
+  than one iteration's prefill budget.
 - prompts are PREFIX-CACHED: a host-side radix trie
-  (``prefix_cache.PrefixCache``) indexes retained KV pool rows by
-  token-id prefix. An admission whose prompt shares a cached prefix
-  copies the pool row into its staging row (one program) and
-  chunk-prefills only the novel tail — O(novel-suffix) TTFT instead
-  of O(prompt). Finished slots donate their KV back to the pool under
-  an LRU/ref-count policy with a configurable byte budget.
-- rows finish at their OWN eos/token budget and their slot frees
-  immediately for the next queued request (eviction ≡ slot reuse; the
-  stale KV is overwritten before it can ever be attended — decode
-  writes position p before masking attention to ``<= p``).
+  (``paging.PagedPrefixIndex``) indexes retained pages by token-id
+  prefix. An admission whose prompt shares a cached prefix SHARES the
+  chunk-aligned pages (a refcount bump) and chunk-prefills only the
+  novel tail — O(novel-suffix) TTFT instead of O(prompt). Finished
+  slots share their pages back into the index under an LRU/ref-count
+  policy; under allocation pressure unpinned entries are reclaimed, or
+  demoted to a host tier when one is configured.
+- rows finish at their OWN eos/token budget and their slot and pages
+  free immediately for the next queued request.
 - the engine optionally runs TENSOR-PARALLEL (``mesh=``): params are
   Megatron-sharded over the mesh's model axis
-  (``parallel.tp.transformer_tp_rules`` / ``shard_params``), all four
-  device pools shard their KV-heads dimension along the same axis,
-  and every compiled program above becomes ONE SPMD dispatch with
-  jit-inserted collectives — models larger than one device's HBM
-  serve at full interconnect bandwidth while the host-side control
-  flow stays mesh-oblivious.
+  (``parallel.tp.transformer_tp_rules`` / ``shard_params``), the page
+  pools shard their KV-heads dimension along the same axis, and every
+  compiled program above becomes ONE SPMD dispatch with jit-inserted
+  collectives — models larger than one device's HBM serve at full
+  interconnect bandwidth while the host-side control flow stays
+  mesh-oblivious.
 - decode is optionally SPECULATIVE (``draft=``): per iteration a
   cheaper draft model proposes ``spec_gamma`` tokens for ALL live
-  slots in one ``lax.scan`` dispatch (its own slot-pooled KV cache,
+  slots in one ``lax.scan`` dispatch (its own page pool and tables,
   allocated/recycled in lockstep with the target's), the target
-  scores every proposal through ONE ragged ``verify_chunk`` dispatch,
-  and each row accepts a VARIABLE-length extension (1..gamma+1
-  tokens) into its slot — per-row position advance, per-row
-  eos/budget truncation mid-extension, streaming handles emitting the
-  burst in order. Compiled shapes depend only on
+  scores every proposal through ONE ragged ``verify_chunk_paged``
+  dispatch, and each row accepts a VARIABLE-length extension
+  (1..gamma+1 tokens) into its slot — per-row position advance,
+  per-row eos/budget truncation mid-extension, streaming handles
+  emitting the burst in order. Compiled shapes depend only on
   ``(max_slots, spec_gamma)``, so the jit gauge stays flat.
 
 Greedy output is token-identical to a lone ``model.generate`` call per
 request — with the prefix cache COLD or WARM, and with speculation ON
-or OFF (tested): cached KV rows are bitwise the values prefill would
+or OFF (tested): shared pages hold bitwise the values prefill would
 recompute (the reuse offset is chunk-aligned, so chunk geometry
 matches; KV at position i depends only on tokens 0..i), same per-row
 ragged decode step, same argmax tie-breaking; a draft only ever
@@ -69,6 +73,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import math
 import sys
 import threading
 import time
@@ -88,7 +93,6 @@ from bigdl_tpu.observability.timeseries import (
 from bigdl_tpu.serving.paging import (
     BlockTable, PagedPrefixIndex, PagePool,
 )
-from bigdl_tpu.serving.prefix_cache import PrefixCache
 from bigdl_tpu.serving.scheduler import (
     AdmissionQueue, PrefillPolicy, SpeculationPolicy, TokenBucket,
     page_fit_score, pages_needed,
@@ -98,43 +102,53 @@ from bigdl_tpu.serving.streams import (
     RequestHandle, RequestRateLimited, RequestShed, RequestTimedOut,
 )
 
+#: finished-request timeline summaries kept for stats() percentiles and
+#: /debug/requests "recent"
+RECENT_TIMELINES = 256
+#: finished usage records kept behind the ledger's top-N queries
+USAGE_RECENT = 256
+#: the idle loop's lost-wakeup safety net (submit/stop notify it)
+IDLE_WAIT_S = 0.5
+#: per-kind floor between two captured incident bundles
+INCIDENT_COOLDOWN_S = 30.0
+
 
 class _Admission:
     """Host-side progress of one chunked prefill. Up to
     ``prefill_rows`` of these are in flight at once, each owning one
-    staging-cache row and one reserved slot; every prefill round
-    advances all of them together through one ragged dispatch."""
+    row of the prefill dispatch and one reserved slot; every prefill
+    round advances all of them together through one ragged dispatch."""
 
     __slots__ = ("handle", "slot", "row", "ids", "t0", "base", "tail",
-                 "n_chunks", "next_chunk", "entry", "d_ids",
+                 "n_chunks", "next_chunk", "d_ids",
                  "d_n_chunks", "d_next_chunk", "table", "d_table")
 
     def __init__(self, handle: RequestHandle, slot: int, row: int,
                  ids: np.ndarray, t0: int, base: int, n_chunks: int,
-                 entry=None, d_ids=None, d_n_chunks: int = 0):
+                 table: BlockTable, d_ids=None, d_n_chunks: int = 0,
+                 d_table: Optional[BlockTable] = None):
         self.handle = handle
-        self.slot = slot          # reserved pool slot (insert target)
-        self.row = row            # staging-cache row this prefill owns
+        self.slot = slot          # reserved slot (handoff target)
+        self.row = row            # prefill-dispatch row this one owns
         self.ids = ids            # (n_chunks * chunk,) right-padded TAIL
         self.t0 = t0              # full prompt length
         self.base = base          # chunk-aligned cached-prefix offset
         self.tail = t0 - base     # tokens actually prefilled
         self.n_chunks = n_chunks
         self.next_chunk = 0
-        self.entry = entry        # pinned PrefixEntry on a hit, else None
         #: speculative decoding: the DRAFT model prefills the FULL
-        #: prompt into its own staging row (a prefix-cache hit skips
+        #: prompt through its own table (a prefix-cache hit skips
         #: target work only — the draft pool holds no reusable prefix),
         #: so its cursor can lag the target's on a hit; the admission
-        #: completes when BOTH caches hold the prompt
+        #: completes when BOTH pools hold the prompt
         self.d_ids = d_ids        # (d_n_chunks * chunk,) full prompt
         self.d_n_chunks = d_n_chunks
         self.d_next_chunk = 0
-        #: paged mode: the BlockTables this admission writes through
-        #: (full span reserved at admission; handed to the slot on
-        #: completion, freed on abort). None on a dense engine.
-        self.table: Optional[BlockTable] = None
-        self.d_table: Optional[BlockTable] = None
+        #: the BlockTables this admission writes through (full span
+        #: reserved at admission; handed to the slot on completion,
+        #: freed on abort)
+        self.table: Optional[BlockTable] = table
+        self.d_table: Optional[BlockTable] = d_table
 
 
 class _SlotState:
@@ -160,8 +174,9 @@ class _SlotState:
 
 class ContinuousBatchingEngine:
     """Token-granular continuous batching over ``TransformerLM``'s
-    incremental-decoding API (``init_cache`` / ``prefill_chunk`` /
-    ``decode_step``), with prefix-cached, batched multi-row prefill.
+    paged incremental-decoding API (``init_page_pool`` /
+    ``prefill_chunk_at_paged`` / ``decode_step_paged``), with
+    prefix-cached, batched multi-row prefill.
 
     ``submit()`` returns a ``RequestHandle`` immediately (bounded FCFS
     queue — ``QueueFull`` is the backpressure signal); the loop thread
@@ -170,36 +185,47 @@ class ContinuousBatchingEngine:
     ``GenerationService``; the default is greedy, whose output is
     token-identical to per-request ``model.generate``.
 
-    PREFIX CACHE: on by default. ``prefix_cache_bytes`` sets the byte
-    budget for the device-resident KV pool the cache retains (None =
-    auto, two pool rows per slot; 0 disables the cache entirely —
-    admission then always prefills the full prompt).
-    ``prefix_cache_rows`` overrides the row count directly;
-    ``prefix_min_tokens`` (default: one prefill chunk) is the floor
-    under which a shared head is not worth a copy dispatch. Reuse is
+    KV PAGES: ``page_size`` tokens each (None derives
+    ``gcd(prefill_chunk, 16)``; it must divide ``prefill_chunk`` so
+    that a reused head ends on a page boundary and shared pages are
+    never written), ``max_pages`` of them (None = every slot at full
+    length plus an equal retained-prefix share). A request reserves
+    ``ceil((prompt + max_new_tokens) / page_size)`` pages at admission
+    and holds nothing longer than it needs.
+
+    PREFIX CACHE: on by default. Retained prefixes are refcounted
+    shares of the same pages, so the pool bounds their bytes;
+    ``prefix_cache_rows`` caps how many entries the index keeps (None =
+    two per slot; 0 disables the cache entirely — admission then always
+    prefills the full prompt), and ``prefix_cache_bytes`` sets that cap
+    in bytes at one full-length request per entry.
+    ``prefix_host_rows`` / ``prefix_host_bytes`` (default 0) give
+    reclaimed entries a host tier to demote to, budgeted at the same
+    rate. ``prefix_min_tokens`` (default: one prefill chunk) is the
+    floor under which a shared head is not worth retaining. Reuse is
     chunk-aligned, so matched lengths round down to a multiple of
     ``prefill_chunk``. ``admission_window > 1`` additionally lets the
     scheduler pop the queued request with the LONGEST cached prefix
     from the first ``admission_window`` candidates (FCFS on ties, with
     a hard starvation bound — see ``AdmissionQueue.pop_ready``).
 
-    BATCHED PREFILL: ``prefill_rows`` widens the staging cache so that
-    many queued admissions chunk-prefill TOGETHER through one ragged
-    dispatch per round instead of one admission at a time.
+    BATCHED PREFILL: ``prefill_rows`` widens the prefill dispatch so
+    that many queued admissions chunk-prefill TOGETHER through one
+    ragged dispatch per round instead of one admission at a time.
 
     SPECULATIVE DECODING: pass ``draft=`` (a smaller ``TransformerLM``
     over the same vocabulary — ``nn.quantized.Quantizer.quantize(model)``
     builds the int8 clone PERF.md benchmarks) and each decode
     iteration becomes draft-propose/target-verify: the draft proposes
     ``spec_gamma`` tokens for ALL live slots in one ``lax.scan``
-    dispatch (``_propose_fn``), the target scores every proposal in
-    one ragged ``verify_chunk`` dispatch, and each row accepts its own
+    dispatch (``_propose_fn_paged``), the target scores every proposal
+    in one ragged ``verify_chunk_paged`` dispatch, and each row accepts its own
     1..gamma+1-token extension (matched proposals plus the target's
     correction/bonus token) — one target forward now yields several
     tokens wherever the draft agrees with the target. The draft owns a
-    parallel slot pool + staging cache, allocated and recycled in
+    page pool and tables of its own, allocated and recycled in
     LOCKSTEP with the target's; admission chunk-prefills the draft's
-    row alongside the target's (the FULL prompt — a prefix-cache hit
+    table alongside the target's (the FULL prompt — a prefix-cache hit
     skips target work only, so on hits the target's final chunk
     replays idempotently while the draft catches up). Greedy output
     stays token-identical to the non-speculative engine (and to lone
@@ -220,9 +246,9 @@ class ContinuousBatchingEngine:
     with a ``model_axis`` axis — ``parallel.Engine.create_mesh([(
     "model", N)])``) and the whole engine runs SPMD: params load
     Megatron-sharded (``tp_rules``, default
-    ``parallel.tp.transformer_tp_rules(model_axis)``), every device
-    pool — KV slots, prefill staging, prefix pool, draft pools —
-    shards its KV-heads dimension along the model axis (the layout
+    ``parallel.tp.transformer_tp_rules(model_axis)``), the page pools
+    (target and draft) shard their KV-heads dimension along the model
+    axis (the layout
     the column-parallel QKV writes with no collective;
     ``num_kv_heads`` must divide the axis size), host inputs enter
     replicated, and jit/GSPMD inserts the row-parallel all-reduces
@@ -253,8 +279,8 @@ class ContinuousBatchingEngine:
     before failing the handles.
 
     RESOURCE OBSERVABILITY: the engine registers its persistent device
-    buffers (KV slot pool, prefill staging, prefix pool + occupied
-    prefix bytes, params) as named memory pools
+    buffers (the page pool's capacity and live bytes, the bytes the
+    prefix index retains, params) as named memory pools
     (``observability.memory.register_pool``) so ``/debug/memory``
     attributes HBM by owner; a ``RecompileWatchdog`` samples the
     compile counter every iteration (post-warmup growth — a shape leak
@@ -269,12 +295,11 @@ class ContinuousBatchingEngine:
     (``observability.accounting``) under the ``tenant=`` it was
     submitted for — queue seconds, prefilled vs prefix-reused prompt
     tokens (and the KV bytes reuse saved), delivered tokens, KV
-    byte-seconds held (staging/slot row bytes x residency), and
+    byte-seconds held (held page bytes x residency), and
     device-seconds attributed pro-rata from every ragged prefill round
     and fused decode step across the rows each dispatch advanced.
     ``usage_tenants`` caps tenant-label cardinality (overflow folds
-    into ``"other"``); ``usage_recent`` bounds the finished-record
-    ring behind top-N queries. Surfaces: ``handle.usage()``,
+    into ``"other"``). Surfaces: ``handle.usage()``,
     ``stats()["usage"]``, ``debug_usage()`` / ``GET /debug/usage``,
     ``request/usage_final`` recorder events, and the
     ``bigdl_serving_tenant_*`` counters. Pure host bookkeeping — the
@@ -287,10 +312,8 @@ class ContinuousBatchingEngine:
                  eos_id: Optional[int] = None, temperature: float = 0.0,
                  top_k=None, top_p=None, queue_capacity: int = 64,
                  seed: int = 0, registry=None,
-                 service_name: str = "engine",
-                 idle_wait_s: float = 0.5, recorder=None,
+                 service_name: str = "engine", recorder=None,
                  postmortem_path: Optional[str] = None,
-                 recent_timelines: int = 256,
                  prefill_rows: int = 1,
                  prefix_cache_bytes: Optional[int] = None,
                  prefix_cache_rows: Optional[int] = None,
@@ -300,7 +323,6 @@ class ContinuousBatchingEngine:
                  admission_window: int = 4,
                  slo_objectives=None,
                  usage_tenants: int = 32,
-                 usage_recent: int = 256,
                  draft=None,
                  spec_gamma: int = 4,
                  mesh=None,
@@ -317,8 +339,7 @@ class ContinuousBatchingEngine:
                  page_size: Optional[int] = None,
                  max_pages: Optional[int] = None,
                  incident_dir: Optional[str] = None,
-                 anomaly_detectors=None,
-                 incident_cooldown_s: float = 30.0):
+                 anomaly_detectors=None):
         from bigdl_tpu.models.transformer import _validate_sampling
         from bigdl_tpu.observability import serving_engine_instruments
         from bigdl_tpu.observability import memory as obs_memory
@@ -383,7 +404,6 @@ class ContinuousBatchingEngine:
                     "— drop them or drop the draft")
             draft.evaluate()
             self._spec = SpeculationPolicy(spec_gamma)
-        self.idle_wait_s = idle_wait_s
         self.service_name = service_name
         self.admission_window = admission_window
         #: flight recorder fed by every lifecycle transition (captured
@@ -401,7 +421,7 @@ class ContinuousBatchingEngine:
         #: another thread appends to raises RuntimeError in CPython,
         #: and /debug readers run on HTTP threads while the loop writes
         self._timelines: collections.deque = collections.deque(
-            maxlen=recent_timelines)
+            maxlen=RECENT_TIMELINES)
         self._timelines_lock = threading.Lock()
         self._policy = PrefillPolicy(prefill_chunk, prefill_budget_tokens,
                                      prefill_rows)
@@ -437,62 +457,54 @@ class ContinuousBatchingEngine:
                 f"engine's serving window ({cap}); shrink max_len or "
                 "bring a longer-context draft")
 
-        # ---- paged KV mode ---------------------------------------------
-        # page_size switches EVERY KV surface (slot rows, prefill
-        # staging, prefix pool, host tier, draft mirrors) from
-        # full-length rows to ONE refcounted block pool per model
-        # (serving.paging): requests hold fixed page_size-token pages
-        # through BlockTables, prefix hits SHARE the aligned pages
-        # copy-on-write instead of copying rows, and eviction /
-        # host-tier demotion / preemption-donation become refcount
-        # moves. Compiled shapes depend only on (max_pages, page_size)
-        # — the jit gauge stays flat exactly as in dense mode.
-        self.paged = page_size is not None
-        if max_pages is not None and not self.paged:
-            raise ValueError("max_pages requires page_size (paged mode)")
-        self.page_size: Optional[int] = None
-        self._pages = self._d_pages = None
-        self._kv_pool = self._d_kv_pool = None
-        self._tables = self._d_tables = None
-        self._table_len = 0
-        if self.paged:
-            page_size = int(page_size)
-            if page_size < 1:
-                raise ValueError(
-                    f"page_size must be >= 1, got {page_size}")
-            if c % page_size != 0:
-                raise ValueError(
-                    f"prefill_chunk ({c}) must be a multiple of "
-                    f"page_size ({page_size}): the chunk-aligned reuse "
-                    "boundary must land on a page boundary, or a hit's "
-                    "shared pages would be written under a live share "
-                    "(the copy-on-write invariant paging.py documents)")
-            self.page_size = page_size
-            #: fixed device block-table width: every request's table is
-            #: padded to the worst-case page count, so compiled shapes
-            #: never depend on any one request's length
-            self._table_len = -(-phys_len // page_size)
-            if max_pages is None:
-                # room for every slot at full length plus an equal
-                # retained-prefix share — roughly the dense engine's
-                # slot-pool + prefix-pool byte budget in page currency
-                max_pages = 1 + 2 * max_slots * self._table_len
-            max_pages = int(max_pages)
-            if max_pages < 1 + self._table_len:
-                raise ValueError(
-                    f"max_pages ({max_pages}) cannot hold one "
-                    f"full-length request ({self._table_len} pages) "
-                    "plus the reserved scratch page")
+        # ---- the page pool's geometry ----------------------------------
+        # EVERY KV surface (slot rows, in-flight prefills, retained
+        # prefixes, host tier, draft mirrors) is ONE refcounted block
+        # pool per model (serving.paging): requests hold fixed
+        # page_size-token pages through BlockTables, prefix hits SHARE
+        # the aligned pages instead of copying rows, and eviction /
+        # host-tier demotion / preemption-donation are refcount moves.
+        # Compiled shapes depend only on (max_pages, page_size).
+        if page_size is None:
+            # derived, not a knob: 16 is what both chip programs pass,
+            # and the gcd keeps prefill_chunk % page_size == 0
+            page_size = math.gcd(c, 16)
+        page_size = int(page_size)
+        if page_size < 1:
+            raise ValueError(
+                f"page_size must be >= 1, got {page_size}")
+        if c % page_size != 0:
+            raise ValueError(
+                f"prefill_chunk ({c}) must be a multiple of "
+                f"page_size ({page_size}): the chunk-aligned reuse "
+                "boundary must land on a page boundary, or a hit's "
+                "shared pages would be written under a live share "
+                "(the copy-on-write invariant paging.py documents)")
+        self.page_size = page_size
+        #: fixed device block-table width: every request's table is
+        #: padded to the worst-case page count, so compiled shapes
+        #: never depend on any one request's length
+        self._table_len = -(-phys_len // page_size)
+        if max_pages is None:
+            # room for every slot at full length plus an equal
+            # retained-prefix share
+            max_pages = 1 + 2 * max_slots * self._table_len
+        max_pages = int(max_pages)
+        if max_pages < 1 + self._table_len:
+            raise ValueError(
+                f"max_pages ({max_pages}) cannot hold one "
+                f"full-length request ({self._table_len} pages) "
+                "plus the reserved scratch page")
 
         # ---- tensor-parallel mesh (SPMD serving) -----------------------
         # With a mesh, EVERY compiled program below runs as one SPMD
         # dispatch: params are Megatron-sharded (transformer_tp_rules /
-        # shard_params), all four device pools (slot KV, staging,
-        # prefix pool, draft pools) shard their KV-HEADS dim along the
-        # model axis (the layout the column-parallel QKV writes with
-        # no collective), host inputs enter replicated, and jit/GSPMD
-        # places the row-parallel all-reduces. Host-side control flow
-        # (scheduler, streams, ledger, recorder) stays mesh-oblivious.
+        # shard_params), the page pools (target and draft) shard their
+        # KV-HEADS dim along the model axis (the layout the
+        # column-parallel QKV writes with no collective), host inputs
+        # enter replicated, and jit/GSPMD places the row-parallel
+        # all-reduces. Host-side control flow (scheduler, streams,
+        # ledger, recorder) stays mesh-oblivious.
         self.mesh = mesh
         self.model_axis = model_axis
         self._kv_shard = self._d_kv_shard = self._repl = None
@@ -500,17 +512,12 @@ class ContinuousBatchingEngine:
             from jax.sharding import NamedSharding, PartitionSpec
             from bigdl_tpu.parallel.tp import transformer_tp_rules
 
-            # the page pool keeps heads in its LAST dimension, the
-            # dense caches at dimension 1: each has its own spec
-            def kv_sharding(m):
-                return (m.kv_page_pool_sharding if self.paged
-                        else m.kv_cache_sharding)(
-                            mesh, model_axis=model_axis)
-
-            self._kv_shard = kv_sharding(model)
+            self._kv_shard = model.kv_page_pool_sharding(
+                mesh, model_axis=model_axis)
             if draft is not None:
                 try:
-                    self._d_kv_shard = kv_sharding(draft)
+                    self._d_kv_shard = draft.kv_page_pool_sharding(
+                        mesh, model_axis=model_axis)
                 except ValueError as e:
                     raise ValueError(
                         f"draft model cannot shard over this mesh: "
@@ -528,39 +535,22 @@ class ContinuousBatchingEngine:
             self._params = shard_params(self._params, mesh, tp_rules)
             self._buffers = replicate(self._buffers, mesh)
         dtype = model.tok_embed.dtype
-        if self.paged:
-            # THE page pool: one persistent (max_pages, page_size,
-            # heads * head_dim) buffer set per layer, donated through
-            # every dispatch; page and offset lead, so the KV write is
-            # an in-place scatter of whole rows (nn/attention.py
-            # _write_kv_paged).
-            # There is no separate staging cache — admissions prefill
-            # straight through their reserved tables — and no separate
-            # prefix pool: retained prefixes are refcounted shares of
-            # these same pages.
-            self._kv_pool = model.init_page_pool(
-                max_pages, page_size, dtype=dtype,
-                sharding=self._kv_shard, kv_dtype=self.kv_dtype)
-            self._pages = PagePool(self._kv_pool, page_size)
-            self._tables = [None] * max_slots
-            self._caches = self._staging = None
-        else:
-            # THE pooled cache: one persistent (max_slots, ...) buffer
-            # set, donated through every step — updates are in-place
-            # for the engine's whole life
-            self._caches = model.init_cache(
-                max_slots, phys_len, dtype=dtype,
-                sharding=self._kv_shard, kv_dtype=self.kv_dtype)
-            # prefill_rows-wide staging cache for chunked prefill; rows
-            # are reused across admissions (stale tail KV is
-            # position-masked, never attended)
-            self._staging = model.init_cache(
-                self._policy.prefill_rows, phys_len, dtype=dtype,
-                sharding=self._kv_shard, kv_dtype=self.kv_dtype)
+        # THE page pool: one persistent (max_pages, page_size,
+        # heads * head_dim) buffer set per layer, donated through
+        # every dispatch; page and offset lead, so the KV write is
+        # an in-place scatter of whole rows (nn/attention.py
+        # _write_kv_paged).
+        # There is no separate staging cache — admissions prefill
+        # straight through their reserved tables — and no separate
+        # prefix pool: retained prefixes are refcounted shares of
+        # these same pages.
+        self._kv_pool = model.init_page_pool(
+            max_pages, page_size, dtype=dtype,
+            sharding=self._kv_shard, kv_dtype=self.kv_dtype)
+        self._pages = PagePool(self._kv_pool, page_size)
+        self._tables: List[Optional[BlockTable]] = [None] * max_slots
+        self._d_kv_pool = self._d_pages = self._d_tables = None
         if draft is not None:
-            # the draft's slot pool + staging mirror the target's
-            # geometry row-for-row (same phys_len so lifecycle stays
-            # lockstep even though draft head counts/dims may differ)
             self._d_params = jax.tree.map(jnp.asarray,
                                           draft.params_dict())
             self._d_bufs = jax.tree.map(jnp.asarray,
@@ -577,107 +567,62 @@ class ContinuousBatchingEngine:
                                               tp_rules)
                 self._d_bufs = replicate(self._d_bufs, mesh)
             d_dtype = draft.tok_embed.dtype
-            if self.paged:
-                # the draft's own page pool: it never shares pages (the
-                # prefix index retains target KV only), so at most
-                # max_slots concurrent tables — sized to always satisfy
-                # a reservation the target pool accepted
-                self._d_kv_pool = draft.init_page_pool(
-                    1 + max_slots * self._table_len, page_size,
-                    dtype=d_dtype, sharding=self._d_kv_shard,
-                    kv_dtype=self.kv_dtype)
-                self._d_pages = PagePool(self._d_kv_pool, page_size)
-                self._d_tables = [None] * max_slots
-                self._d_caches = self._d_staging = None
-            else:
-                self._d_caches = draft.init_cache(
-                    max_slots, phys_len, dtype=d_dtype,
-                    sharding=self._d_kv_shard, kv_dtype=self.kv_dtype)
-                self._d_staging = draft.init_cache(
-                    self._policy.prefill_rows, phys_len, dtype=d_dtype,
-                    sharding=self._d_kv_shard, kv_dtype=self.kv_dtype)
-        else:
-            self._d_caches = self._d_staging = None
-        # prefix-cache KV pool: a third persistent buffer set holding
-        # the retained prefixes, plus its host-side radix-trie index.
-        # The byte budget is enforced as a row budget fixed here, so
-        # every compiled shape stays load-independent.
-        # summed over the LIVE cache leaves, so under kv_dtype="int8"
-        # this is the true quantized physical cost — int8 code buffers
-        # PLUS the f32 scale sidecars — and everything derived from it
-        # (token_bytes, pool/host row budgets, PrefixCache accounting,
-        # the ledger's KV byte-seconds and bytes_saved credits) stays
-        # honest without a special case
-        if self.paged:
-            # the full-length-row EQUIVALENT (what one dense slot of
-            # this geometry would cost): the exchange rate for pool /
-            # host budgets and reuse credits stays comparable across
-            # modes, while actual paged billing is per held page
-            row_bytes = self._table_len * self._pages.page_bytes
-        else:
-            row_bytes = sum(int(leaf.nbytes) // max_slots
-                            for leaf in jax.tree.leaves(self._caches))
+            # the draft's own page pool, tables of the same width (so
+            # lifecycle stays lockstep though head counts/dims may
+            # differ): it never shares pages (the prefix index retains
+            # target KV only), so at most max_slots concurrent tables —
+            # sized to always satisfy a reservation the target pool
+            # accepted
+            self._d_kv_pool = draft.init_page_pool(
+                1 + max_slots * self._table_len, page_size,
+                dtype=d_dtype, sharding=self._d_kv_shard,
+                kv_dtype=self.kv_dtype)
+            self._d_pages = PagePool(self._d_kv_pool, page_size)
+            self._d_tables = [None] * max_slots
+        # the prefix index's budgets keep their row currency: one "row"
+        # is what a full-length request holds (table_len pages). Summed
+        # over the LIVE pool leaves, so under kv_dtype="int8" this is
+        # the true quantized physical cost — int8 code buffers PLUS the
+        # f32 scale sidecars — and everything derived from it
+        # (token_bytes, entry/host budgets, the ledger's bytes_saved
+        # credits) stays honest without a special case
+        row_bytes = self._table_len * self._pages.page_bytes
         self._row_bytes = row_bytes
         #: device KV bytes one cached token position costs — the
         #: exchange rate prefix-reuse savings are credited at
         self._token_bytes = row_bytes / phys_len
         if prefix_cache_rows is not None:
-            pool_rows = max(0, int(prefix_cache_rows))
+            max_entries = max(0, int(prefix_cache_rows))
         elif prefix_cache_bytes is None:
-            pool_rows = 2 * max_slots
+            max_entries = 2 * max_slots
         else:
-            pool_rows = max(0, int(prefix_cache_bytes) // row_bytes)
-        # host tier behind the device pool: evicted rows spill to
-        # pinned host buffers instead of dropping (row budget derived
-        # from its own byte budget; 0 = tier off, eviction drops)
+            max_entries = max(0, int(prefix_cache_bytes) // row_bytes)
+        # host tier behind the device pool: reclaimed entries spill to
+        # pinned host buffers instead of dropping (0 = tier off)
         if prefix_host_rows is not None:
             host_rows = max(0, int(prefix_host_rows))
         elif prefix_host_bytes is None:
             host_rows = 0
         else:
             host_rows = max(0, int(prefix_host_bytes) // row_bytes)
-        if pool_rows > 0 and self.paged:
-            # pages as the retention currency: pool_rows bounds ENTRY
-            # count (cardinality), the shared page pool bounds bytes;
-            # the host budget converts to pages
-            self._pool = None
+        self._prefix: Optional[PagedPrefixIndex] = None
+        if max_entries > 0:
+            # max_entries bounds ENTRY count (cardinality), the shared
+            # page pool bounds bytes; the host budget converts to pages
             self._prefix = PagedPrefixIndex(
-                self._pages, max_entries=pool_rows,
+                self._pages, max_entries=max_entries,
                 min_tokens=(prefix_min_tokens
                             if prefix_min_tokens is not None else c),
                 token_bytes=self._token_bytes,
+                # pages shard over the MODEL axis only: each device's
+                # share is logical / model_shards (a 2-D mesh's data
+                # axis replicates them, so mesh.size would undercount)
                 devices=(int(mesh.shape[model_axis])
                          if mesh is not None else 1),
                 host_pages=host_rows * self._table_len)
-        elif pool_rows > 0:
-            self._pool = model.init_cache(pool_rows, phys_len,
-                                          dtype=dtype,
-                                          sharding=self._kv_shard,
-                                          kv_dtype=self.kv_dtype)
-            self._prefix = PrefixCache(
-                pool_rows, row_bytes,
-                min_tokens=(prefix_min_tokens
-                            if prefix_min_tokens is not None else c),
-                token_bytes=self._token_bytes,
-                # pool rows shard over the MODEL axis only: each
-                # device's share is logical / model_shards (a 2-D
-                # mesh's data axis replicates them, so mesh.size
-                # would undercount)
-                devices=(int(mesh.shape[model_axis])
-                         if mesh is not None else 1),
-                host_rows=host_rows)
-        else:
-            self._pool = None
-            self._prefix = None
         self._prefix_evictions_seen = 0
         self._prefix_demotions_seen = 0
         self._prefix_host_evictions_seen = 0
-        #: host->device promotions in flight, keyed by entry identity:
-        #: {"entry", "tree" (async device_put result), "touched"
-        #: (iteration stamp)} — each record holds a pin on its entry,
-        #: so the host buffer can never be evicted mid-transfer
-        self._promotions: dict = {}
-        self._promotions_max = max(4, 2 * self._policy.prefill_rows)
         #: host-side prompt-token tally actually prefilled by THIS
         #: engine (the reused-fraction denominator — per-instance
         #: exact, unlike the shared-label registry counter)
@@ -689,7 +634,7 @@ class ContinuousBatchingEngine:
         #: programs that have run at least once (a first dispatch's
         #: wall is mostly compile time and is charged as cold)
         self._warm = set()
-        #: paged bookkeeping: last KV byte-second accrual stamp, the
+        #: page bookkeeping: last KV byte-second accrual stamp, the
         #: page-flow counter baselines behind the delta-published
         #: bigdl_serving_page_* instruments, and the blocked-admission
         #: latch (set when the pool cannot satisfy the queue head's
@@ -710,13 +655,7 @@ class ContinuousBatchingEngine:
         self._usage = UsageLedger(
             service=service_name, registry=registry, recorder=self._rec,
             instruments=self._ins, max_tenants=usage_tenants,
-            recent=usage_recent,
-            # paged mode bills KV byte-seconds per actually-held page
-            # (accrue_kv from the loop, holder_bytes pro-rata over
-            # shares) — the dense row-residency terms must be zero or
-            # a request would be double-billed
-            slot_row_bytes=0 if self.paged else row_bytes,
-            staging_row_bytes=0 if self.paged else row_bytes,
+            recent=USAGE_RECENT,
             token_bytes=self._token_bytes,
             devices=(int(mesh.size) if mesh is not None else 1))
         self._queue = AdmissionQueue(
@@ -768,24 +707,24 @@ class ContinuousBatchingEngine:
 
         pools = {f"serving/{service_name}/{key}": pool_reader(key)
                  for key in self._pool_bytes}
-        if self.paged:
-            # the page pool's LIVE footprint next to its capacity:
-            # bytes of pages something still references (slot tables,
-            # in-flight admissions, prefix entries) — /debug/memory
-            # then answers "how full is the pool" not just "how big"
-            pools[f"serving/{service_name}/kv_pages_in_use"] = (
-                lambda e: e._pages.bytes_in_use)
-            if self.draft is not None:
-                pools[f"serving/{service_name}/draft_pages_in_use"] = (
-                    lambda e: e._d_pages.bytes_in_use)
-        self._memory_pools = obs_memory.register_owned_pools(self, pools)
+        # the page pool's LIVE footprint next to its capacity: bytes
+        # of pages something still references (slot tables, in-flight
+        # admissions, prefix entries) — /debug/memory then answers
+        # "how full is the pool" not just "how big"
+        pools[f"serving/{service_name}/kv_pages_in_use"] = (
+            lambda e: e._pages.bytes_in_use)
+        if self.draft is not None:
+            pools[f"serving/{service_name}/draft_pages_in_use"] = (
+                lambda e: e._d_pages.bytes_in_use)
         if self._prefix is not None:
-            self._memory_pools.append(self._prefix.register_memory_pool(
-                f"serving/{service_name}/prefix_kv_in_use"))
-            if self._prefix.host_rows > 0:
-                self._memory_pools.append(
-                    self._prefix.register_host_memory_pool(
-                        f"serving/{service_name}/prefix_host_kv"))
+            # "prefix KV actually retained" (pro-rata over shares) and,
+            # with a host tier, "who owns the spill" in the same table
+            pools[f"serving/{service_name}/prefix_kv_in_use"] = (
+                lambda e: e._prefix.bytes_in_use)
+            if self._prefix.host_pages > 0:
+                pools[f"serving/{service_name}/prefix_host_kv"] = (
+                    lambda e: e._prefix.host_bytes_in_use)
+        self._memory_pools = obs_memory.register_owned_pools(self, pools)
 
         # mesh topology gauges + per-pool per-device footprint
         n_dev = int(mesh.size) if mesh is not None else 1
@@ -836,15 +775,13 @@ class ContinuousBatchingEngine:
                 "acceptance_rate",
                 lambda: (self._spec_accepted / self._spec_proposed
                          if self._spec_proposed else None))
-        if self.paged:
-            # PR 17 pool gauges, charted: occupancy (live references
-            # over usable pages) and reservation fragmentation
-            self._ts.add_source(
-                "page_pool_occupancy",
-                lambda: (self._pages.pages_in_use
-                         / max(1, self._pages.max_pages - 1)))
-            self._ts.add_source("page_fragmentation",
-                                self._fragmentation)
+        # the pool gauges, charted: occupancy (live references over
+        # usable pages) and reservation fragmentation
+        self._ts.add_source(
+            "page_pool_occupancy",
+            lambda: (self._pages.pages_in_use
+                     / max(1, self._pages.max_pages - 1)))
+        self._ts.add_source("page_fragmentation", self._fragmentation)
         self._ts.add_source("alerts", lambda: float(len(self.alerts())))
 
         # ---- anomaly detection + incident capture ----------------------
@@ -863,14 +800,13 @@ class ContinuousBatchingEngine:
         self._ts.set_observer(self._bank.observe)
         self._incidents = IncidentManager(
             service_name, recorder=self._rec, registry=registry,
-            dirpath=incident_dir, cooldown_s=incident_cooldown_s,
+            dirpath=incident_dir, cooldown_s=INCIDENT_COOLDOWN_S,
             config={"service_name": service_name,
                     "max_slots": max_slots, "max_len": self.max_len,
                     "prefill_chunk": self._policy.chunk,
                     "admission_window": admission_window,
                     "kv_dtype": self.kv_dtype,
                     "weights_dtype": self.weights_dtype,
-                    "paged": self.paged,
                     "shed_classes": list(shed_classes or ()),
                     "preempt_slack_s": preempt_slack_s})
         self._inc_ins = incident_instruments(registry)
@@ -918,7 +854,7 @@ class ContinuousBatchingEngine:
         # ---- QoS: preemption, burn-rate shedding, token buckets --------
         # preemption: a HIGH-class request queued past this slack with
         # no free slot evicts the lowest-class longest-remaining slot,
-        # donating its KV to the prefix pool so the automatic resume
+        # donating its KV to the prefix index so the automatic resume
         # re-prefills only the uncached tail (None disables)
         if preempt_slack_s is not None and preempt_slack_s < 0:
             raise ValueError(f"preempt_slack_s must be >= 0 or None, "
@@ -964,259 +900,9 @@ class ContinuousBatchingEngine:
 
     # ------------------------------------------------- compiled programs
     def _build_fns(self):
-        if self.paged:
-            return self._build_fns_paged()
-        from bigdl_tpu.models.transformer import (
-            _filter_logits, _spec_accept,
-        )
-        from bigdl_tpu.nn.module import bind
-
-        self._copy_page_jit = None   # paged-only program
-        model = self.model
-        sampled = self.temperature > 0.0
-        top_k, top_p = self.top_k, self.top_p
-
-        def step(p, bufs, tok, pos, caches, rng, temperature):
-            # one fused decode over ALL slots: (S,) tokens at (S,)
-            # per-row positions (free slots ride along at pos 0 — their
-            # junk write is overwritten by the next admission's insert)
-            with bind(model, p, bufs, False, None):
-                logits, caches = model.decode_step(tok, pos, caches)
-            if sampled:
-                nxt = jax.random.categorical(
-                    rng, _filter_logits(logits, temperature, top_k, top_p),
-                    axis=-1).astype(jnp.int32)
-            else:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, caches
-
-        def chunk(p, bufs, ids, caches, pos0, last_idx):
-            # one RAGGED prefill round over the whole staging cache:
-            # row r writes its chunk at its own traced offset pos0[r]
-            # (rows without an active admission ride along at offset 0
-            # — their junk write lands in their own idle row and is
-            # overwritten by that row's next occupant before it can
-            # ever be attended); last_idx gathers each row's true last
-            # prompt position's logits (the final chunk is
-            # right-padded, so "last position of the chunk" would be a
-            # pad)
-            with bind(model, p, bufs, False, None):
-                return model.prefill_chunk_at(ids, caches, pos0,
-                                              last_idx)
-
-        def copy_row(dst, src, dst_row, src_row):
-            # copy row src_row of cache-tree src into row dst_row of
-            # cache-tree dst (dst donated — in place for the engine's
-            # life). ONE program, three compiled signatures, all
-            # load-independent: staging→pool-slot insert, prefix-pool→
-            # staging on a hit, pool-slot→prefix-pool on donation.
-            return jax.tree.map(
-                lambda d, s: jax.lax.dynamic_update_slice(
-                    d,
-                    jax.lax.dynamic_slice(
-                        s, (src_row,) + (0,) * (s.ndim - 1),
-                        (1,) + s.shape[1:]).astype(d.dtype),
-                    (dst_row,) + (jnp.int32(0),) * (d.ndim - 1)),
-                dst, src)
-
-        def sample0(logits, rng, temperature):
-            if sampled:
-                return jax.random.categorical(
-                    rng, _filter_logits(logits, temperature, top_k, top_p),
-                    axis=-1).astype(jnp.int32)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-
-        # On a mesh, output shardings are PINNED: every program's cache
-        # tree leaves with the same NamedSharding it entered with (and
-        # scalars/logits leave replicated), so the donated buffers
-        # cycle through the loop in ONE stable layout. Left to GSPMD's
-        # own choice, a copy/step output can drift (e.g. to
-        # replicated), and the next dispatch's changed input sharding
-        # compiles a fresh signature — a gauge-visible leak. kv/draft
-        # pools share the spec (heads along the model axis), so one
-        # prefix broadcast covers every cache tree.
-        kv, repl = self._kv_shard, self._repl
-
-        def _jit(fn, donate, out=None):
-            if self.mesh is None:
-                # graftlint: ok[jit-hazard] — meshless (single-device) branch has no shardings to pin
-                return jax.jit(fn, donate_argnums=donate)
-            return jax.jit(fn, donate_argnums=donate, out_shardings=out)
-
-        self._step_jit = _jit(step, (4,), (repl, kv))
-        self._chunk_jit = _jit(chunk, (3,), (repl, kv))
-        self._copy_row_jit = _jit(copy_row, (0,), kv)
-        self._sample0_jit = _jit(sample0, (), repl)
-
-        # ---- host-tier transfer program ------------------------------
-        # demotion source: ONE jitted slice lifting a pool row out as a
-        # (1, ...) tree the engine bulk-copies to host (src NOT donated
-        # — the pool lives on). Raw jnp indexing here would compile an
-        # anonymous executable per call site; a named program keeps the
-        # transfer on a warmed signature like every other copy.
-        self._take_row_jit = None
-        if self._prefix is not None and self._prefix.host_rows > 0:
-            def take_row(src, row):
-                return jax.tree.map(
-                    lambda s: jax.lax.dynamic_slice(
-                        s, (row,) + (0,) * (s.ndim - 1),
-                        (1,) + s.shape[1:]), src)
-
-            self._take_row_jit = _jit(take_row, (), kv)
-
-        # ---- speculative-decoding programs --------------------------
-        self._propose_jit = self._spec_verify_jit = None
-        self._d_chunk_jit = self._d_sync_jit = None
-        if self.draft is not None:
-            draft = self.draft
-            g = self._spec.gamma
-
-            # the draft proposer IS the standalone speculative path's
-            # cached per-(model, batch, gamma) lax.scan
-            # (transformer._propose_fn): (max_slots,) tokens at
-            # (max_slots,) per-row positions, gamma draft steps, ONE
-            # dispatch, draft KV written as it goes
-            self._propose_jit = draft._propose_fn(
-                self.max_slots, g, sampled=sampled,
-                cache_sharding=self._d_kv_shard,
-                repl_sharding=self._repl)
-
-            def d_chunk(p, bufs, ids, caches, pos0, last_idx):
-                # the draft's mirror of the ragged admission prefill:
-                # same chunk geometry, its own staging cache; the
-                # gathered logits are discarded (the first token always
-                # samples from the TARGET's prefill logits)
-                with bind(draft, p, bufs, False, None):
-                    return draft.prefill_chunk_at(ids, caches, pos0,
-                                                  last_idx)
-
-            def d_sync(p, bufs, tok, pos, caches):
-                # one ragged draft step re-writing each row's LAST
-                # accepted token's KV at its own position: for rows
-                # that accepted everything this fills the one position
-                # the propose scan never wrote (the gamma-th proposal's
-                # KV); for every other row it rewrites identical values
-                # in place (same token, same position -> same KV), so
-                # one fixed-shape dispatch serves all rows
-                with bind(draft, p, bufs, False, None):
-                    _, caches = draft.decode_step(tok, pos, caches)
-                return caches
-
-            def spec_verify(p, bufs, tok, props, qlogits, pos, caches,
-                            rng, temperature):
-                # ONE ragged target forward scores every row's
-                # proposals (the verify_chunk path): chunk column 0 is
-                # the row's pending token (its KV is written first),
-                # columns 1..g its proposals; logits column j predicts
-                # the token at position pos+j+1. Acceptance is decided
-                # per ROW in-graph so the host transfer is just the
-                # (S, g+1) emit matrix + (S,) accepted counts.
-                chunk = jnp.concatenate(
-                    [tok[:, None], jnp.swapaxes(props, 0, 1)], axis=1)
-                with bind(model, p, bufs, False, None):
-                    logits, caches = model.verify_chunk(chunk, caches,
-                                                        pos)
-                if sampled:
-                    accept, resid, bonus = _spec_accept(
-                        logits, jnp.swapaxes(qlogits, 0, 1),
-                        chunk[:, 1:], temperature, rng)
-                    n_acc = jnp.sum(jnp.cumprod(
-                        accept.astype(jnp.int32), axis=1), axis=1)
-                    # emit column j: the proposal while accepted; at
-                    # the first rejection the residual draw, on full
-                    # acceptance the bonus draw (columns past n_acc
-                    # are never read by the host)
-                    fix = jnp.take_along_axis(
-                        jnp.concatenate([resid, bonus[:, None]],
-                                        axis=1),
-                        n_acc[:, None], axis=1)
-                    cols = jnp.arange(g + 1)[None, :]
-                    padded = jnp.concatenate(
-                        [chunk[:, 1:], jnp.zeros_like(tok)[:, None]],
-                        axis=1)
-                    emit = jnp.where(cols < n_acc[:, None], padded, fix)
-                else:
-                    v_tok = jnp.argmax(logits, axis=-1).astype(
-                        jnp.int32)
-                    match = (chunk[:, 1:] == v_tok[:, :g]).astype(
-                        jnp.int32)
-                    n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
-                    # matched proposals ARE the target argmax, so the
-                    # emitted burst is v_tok[:, :n_acc+1] verbatim —
-                    # exactly the tokens the non-speculative engine
-                    # would have argmaxed one step at a time
-                    emit = v_tok
-                return emit, n_acc, caches
-
-            self._d_chunk_jit = _jit(d_chunk, (3,), (repl, kv))
-            self._d_sync_jit = _jit(d_sync, (4,), kv)
-            self._spec_verify_jit = _jit(spec_verify, (6,),
-                                         (repl, repl, kv))
-        # warm the copy signatures NOW (zero rows copied onto zero rows
-        # — harmless): the insert/stage/donate copies first fire at a
-        # request's FINISH or at the first cache hit, and a compile
-        # there would show up as a post-warmup jit_compiles bump — the
-        # exact flatness contract the gauge exists to police.
-        z = jnp.int32(0)
-        self._caches = self._copy_row_jit(self._caches, self._staging,
-                                          z, z)
-        self._warm.add("copy:insert")
-        if self._pool is not None:
-            self._staging = self._copy_row_jit(self._staging, self._pool,
-                                               z, z)
-            self._pool = self._copy_row_jit(self._pool, self._caches,
-                                            z, z)
-            self._warm.update(("copy:stage", "copy:donate"))
-        if self._take_row_jit is not None:
-            # warm the demote slice AND the promote scatter (a fourth
-            # copy_row signature: (1, ...) src tree -> pool). The warm
-            # promote input is built EXACTLY the way real promotions
-            # build theirs — host ndarrays through device_put under the
-            # pool's sharding — so the first real promotion lands on
-            # this signature instead of compiling a new one.
-            from bigdl_tpu.parallel.tp import put_from_host
-
-            _ = self._take_row_jit(self._pool, z)
-            host_proto = jax.tree.map(
-                lambda s: np.zeros((1,) + s.shape[1:], s.dtype),
-                self._pool)
-            one_row = put_from_host(host_proto, self._kv_shard)
-            self._pool = self._copy_row_jit(self._pool, one_row, z, z)
-            self._warm.update(("copy:demote", "copy:promote"))
-        if self.draft is not None:
-            # the draft staging->slot insert is a fourth copy
-            # signature (draft tree shapes)
-            self._d_caches = self._copy_row_jit(self._d_caches,
-                                                self._d_staging, z, z)
-            self._warm.add("copy:d_insert")
-            # warm the whole speculative round NOW (zero tokens at
-            # position 0 — junk in empty rows, overwritten by every
-            # admission's full-row insert): the sync dispatch is
-            # CONDITIONAL at runtime (it only fires when some row
-            # fully accepts), so left cold it could first compile many
-            # iterations after warmup and read as a recompile storm
-            # warmed inputs take the SAME layout runtime inputs will
-            # (replicated-committed on a mesh, via _h2d): a layout
-            # mismatch would make the first real dispatch a second
-            # compile — exactly the flatness the gauge polices
-            zt = self._h2d(jnp.zeros((self.max_slots,), jnp.int32))
-            zk = self._h2d(jax.random.PRNGKey(0))
-            t1 = self._h2d(jnp.float32(1.0))
-            props, qlogits, self._d_caches = self._propose_jit(
-                self._d_params, self._d_bufs, zt, zt, self._d_caches,
-                zk, t1)
-            _, _, self._caches = self._spec_verify_jit(
-                self._params, self._buffers, zt, props, qlogits, zt,
-                self._caches, zk, t1)
-            self._d_caches = self._d_sync_jit(
-                self._d_params, self._d_bufs, zt, zt, self._d_caches)
-            self._warm.update(("spec:propose", "spec:verify",
-                               "spec:sync"))
-
-    def _build_fns_paged(self):
-        """Paged twins of the compiled programs: every KV surface is
-        the page pool, gathered/scattered through per-request block
-        tables INSIDE the dispatch. Compiled shapes depend only on
+        """The compiled programs: every KV surface is the page pool,
+        gathered/scattered through per-request block tables INSIDE the
+        dispatch. Compiled shapes depend only on
         ``(max_pages, page_size)`` and the fixed dispatch widths
         (max_slots / prefill_rows / table_len / gamma) — none on load —
         so the jit gauge stays flat while alloc/share/COW-fork/evict/
@@ -1298,6 +984,15 @@ class ContinuousBatchingEngine:
                     axis=-1).astype(jnp.int32)
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
+        # On a mesh, output shardings are PINNED: every program's pool
+        # leaves with the same NamedSharding it entered with (and
+        # scalars/logits leave replicated), so the donated buffers
+        # cycle through the loop in ONE stable layout. Left to GSPMD's
+        # own choice, an output can drift (e.g. to replicated), and the
+        # next dispatch's changed input sharding compiles a fresh
+        # signature — a gauge-visible leak. Target and draft pools
+        # share the spec (heads along the model axis), so one prefix
+        # broadcast covers every pool tree.
         kv, repl = self._kv_shard, self._repl
 
         def _jit(fn, donate, out=None):
@@ -1313,7 +1008,7 @@ class ContinuousBatchingEngine:
         self._sample0_jit = _jit(sample0, (), repl)
 
         self._take_row_jit = None
-        if self._prefix is not None and self._prefix.host_rows > 0:
+        if self._prefix is not None and self._prefix.host_pages > 0:
             def take_row(src, row):
                 # demotion source: one jitted slice lifting page `row`
                 # out as a (1, ...) tree the spill bulk-copies to host
@@ -1324,24 +1019,39 @@ class ContinuousBatchingEngine:
 
             self._take_row_jit = _jit(take_row, (), kv)
 
-        # ---- speculative-decoding programs (paged) -------------------
+        # ---- speculative-decoding programs --------------------------
         self._propose_jit = self._spec_verify_jit = None
         self._d_chunk_jit = self._d_sync_jit = None
         if self.draft is not None:
             draft = self.draft
             g = self._spec.gamma
 
+            # the draft proposer IS the standalone speculative path's
+            # cached lax.scan: (max_slots,) tokens at (max_slots,)
+            # per-row positions, gamma draft steps, ONE dispatch, draft
+            # KV written as it goes
             self._propose_jit = draft._propose_fn_paged(
                 self.max_slots, g, self._table_len, sampled=sampled,
                 cache_sharding=self._d_kv_shard,
                 repl_sharding=self._repl, decode_attention=attend)
 
             def d_chunk(p, bufs, ids, pool, tables, pos0, last_idx):
+                # the draft's mirror of the ragged admission prefill:
+                # same chunk geometry, its own pool; the gathered
+                # logits are discarded (the first token always samples
+                # from the TARGET's prefill logits)
                 with bind(draft, p, bufs, False, None):
                     return draft.prefill_chunk_at_paged(
                         ids, pool, tables, pos0, last_idx)
 
             def d_sync(p, bufs, tok, pos, pool, tables):
+                # one ragged draft step re-writing each row's LAST
+                # accepted token's KV at its own position: for rows
+                # that accepted everything this fills the one position
+                # the propose scan never wrote (the gamma-th proposal's
+                # KV); for every other row it rewrites identical values
+                # in place (same token, same position -> same KV), so
+                # one fixed-shape dispatch serves all rows
                 with bind(draft, p, bufs, False, None):
                     _, pool = draft.decode_step_paged(
                         tok, pos, pool, tables, decode_attention=attend)
@@ -1349,6 +1059,13 @@ class ContinuousBatchingEngine:
 
             def spec_verify(p, bufs, tok, props, qlogits, pos, pool,
                             tables, rng, temperature):
+                # ONE ragged target forward scores every row's
+                # proposals (the verify_chunk path): chunk column 0 is
+                # the row's pending token (its KV is written first),
+                # columns 1..g its proposals; logits column j predicts
+                # the token at position pos+j+1. Acceptance is decided
+                # per ROW in-graph so the host transfer is just the
+                # (S, g+1) emit matrix + (S,) accepted counts.
                 chunk_ids = jnp.concatenate(
                     [tok[:, None], jnp.swapaxes(props, 0, 1)], axis=1)
                 with bind(model, p, bufs, False, None):
@@ -1360,6 +1077,10 @@ class ContinuousBatchingEngine:
                         chunk_ids[:, 1:], temperature, rng)
                     n_acc = jnp.sum(jnp.cumprod(
                         accept.astype(jnp.int32), axis=1), axis=1)
+                    # emit column j: the proposal while accepted; at
+                    # the first rejection the residual draw, on full
+                    # acceptance the bonus draw (columns past n_acc
+                    # are never read by the host)
                     fix = jnp.take_along_axis(
                         jnp.concatenate([resid, bonus[:, None]],
                                         axis=1),
@@ -1375,6 +1096,10 @@ class ContinuousBatchingEngine:
                     match = (chunk_ids[:, 1:] == v_tok[:, :g]).astype(
                         jnp.int32)
                     n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
+                    # matched proposals ARE the target argmax, so the
+                    # emitted burst is v_tok[:, :n_acc+1] verbatim —
+                    # exactly the tokens the non-speculative engine
+                    # would have argmaxed one step at a time
                     emit = v_tok
                 return emit, n_acc, pool
 
@@ -1404,8 +1129,14 @@ class ContinuousBatchingEngine:
             self._warm.update(("copy:demote", "copy:promote"))
         if self.draft is not None:
             # warm the whole speculative round (all-scratch tables:
-            # every junk write lands on page 0) — the sync dispatch is
-            # conditional at runtime, exactly the dense argument
+            # every junk write lands on page 0): the sync dispatch is
+            # CONDITIONAL at runtime (it only fires when some row
+            # fully accepts), so left cold it could first compile many
+            # iterations after warmup and read as a recompile storm.
+            # Warmed inputs take the SAME layout runtime inputs will
+            # (replicated-committed on a mesh, via _h2d): a layout
+            # mismatch would make the first real dispatch a second
+            # compile — exactly the flatness the gauge polices
             zt = self._h2d(jnp.zeros((self.max_slots,), jnp.int32))
             zT = self._h2d(jnp.zeros(
                 (self.max_slots, self._table_len), jnp.int32))
@@ -1440,21 +1171,9 @@ class ContinuousBatchingEngine:
         pool this engine owns (the mesh-summary / per-device gauge
         enumeration; keys match the ``serving/<name>/<pool>`` registry
         suffixes)."""
-        if self.paged:
-            out = {"kv_page_pool": self._kv_pool,
-                   "params": self._params}
-            if self.draft is not None:
-                out["draft_page_pool"] = self._d_kv_pool
-                out["draft_params"] = self._d_params
-            return out
-        out = {"kv_slots": self._caches,
-               "prefill_staging": self._staging,
-               "params": self._params}
-        if self._pool is not None:
-            out["prefix_pool"] = self._pool
+        out = {"kv_page_pool": self._kv_pool, "params": self._params}
         if self.draft is not None:
-            out["draft_kv_slots"] = self._d_caches
-            out["draft_staging"] = self._d_staging
+            out["draft_page_pool"] = self._d_kv_pool
             out["draft_params"] = self._d_params
         return out
 
@@ -1501,9 +1220,7 @@ class ContinuousBatchingEngine:
 
     def _compile_total(self) -> int:
         fns = [self._step_jit, self._chunk_jit, self._copy_row_jit,
-               self._sample0_jit]
-        if self._copy_page_jit is not None:
-            fns.append(self._copy_page_jit)
+               self._sample0_jit, self._copy_page_jit]
         if self._take_row_jit is not None:
             fns.append(self._take_row_jit)
         if self.draft is not None:
@@ -1536,74 +1253,44 @@ class ContinuousBatchingEngine:
         t1 = self._temp_const
         ids = self._h2d(jnp.zeros((rows, c), jnp.int32))
         rpos = self._h2d(jnp.zeros((rows,), jnp.int32))
-        if self.paged:
-            zT = self._h2d(jnp.zeros((S, self._table_len), jnp.int32))
-            zTr = self._h2d(jnp.zeros((rows, self._table_len),
-                                      jnp.int32))
-            progs = {"prefill": [(self._chunk_jit,
-                                  (self._params, self._buffers, ids,
-                                   self._kv_pool, zTr, rpos, rpos))]}
-            if self.draft is None:
-                progs["decode"] = [(self._step_jit,
-                                    (self._params, self._buffers, zt,
-                                     zt, self._kv_pool, zT, zk, t1))]
-            else:
-                progs["prefill"].append(
-                    (self._d_chunk_jit,
-                     (self._d_params, self._d_bufs, ids,
-                      self._d_kv_pool, zTr, rpos, rpos)))
-                try:
-                    props_sd, qlog_sd, _ = jax.eval_shape(
-                        self._propose_jit, self._d_params,
-                        self._d_bufs, zt, zt, self._d_kv_pool, zT,
-                        zk, t1)
-                except Exception:
-                    props_sd = qlog_sd = None
-                progs["decode"] = [
-                    (self._propose_jit,
-                     (self._d_params, self._d_bufs, zt, zt,
-                      self._d_kv_pool, zT, zk, t1))]
-                if props_sd is not None:
-                    progs["decode"].append(
-                        (self._spec_verify_jit,
-                         (self._params, self._buffers, zt, props_sd,
-                          qlog_sd, zt, self._kv_pool, zT, zk, t1)))
+        zT = self._h2d(jnp.zeros((S, self._table_len), jnp.int32))
+        zTr = self._h2d(jnp.zeros((rows, self._table_len),
+                                  jnp.int32))
+        progs = {"prefill": [(self._chunk_jit,
+                              (self._params, self._buffers, ids,
+                               self._kv_pool, zTr, rpos, rpos))]}
+        if self.draft is None:
+            progs["decode"] = [(self._step_jit,
+                                (self._params, self._buffers, zt,
+                                 zt, self._kv_pool, zT, zk, t1))]
         else:
-            progs = {"prefill": [(self._chunk_jit,
-                                  (self._params, self._buffers, ids,
-                                   self._staging, rpos, rpos))]}
-            if self.draft is None:
-                progs["decode"] = [(self._step_jit,
-                                    (self._params, self._buffers, zt,
-                                     zt, self._caches, zk, t1))]
-            else:
-                progs["prefill"].append(
-                    (self._d_chunk_jit,
-                     (self._d_params, self._d_bufs, ids,
-                      self._d_staging, rpos, rpos)))
-                try:
-                    props_sd, qlog_sd, _ = jax.eval_shape(
-                        self._propose_jit, self._d_params,
-                        self._d_bufs, zt, zt, self._d_caches, zk, t1)
-                except Exception:
-                    props_sd = qlog_sd = None
-                progs["decode"] = [
-                    (self._propose_jit,
-                     (self._d_params, self._d_bufs, zt, zt,
-                      self._d_caches, zk, t1))]
-                if props_sd is not None:
-                    progs["decode"].append(
-                        (self._spec_verify_jit,
-                         (self._params, self._buffers, zt, props_sd,
-                          qlog_sd, zt, self._caches, zk, t1)))
+            progs["prefill"].append(
+                (self._d_chunk_jit,
+                 (self._d_params, self._d_bufs, ids,
+                  self._d_kv_pool, zTr, rpos, rpos)))
+            try:
+                props_sd, qlog_sd, _ = jax.eval_shape(
+                    self._propose_jit, self._d_params,
+                    self._d_bufs, zt, zt, self._d_kv_pool, zT,
+                    zk, t1)
+            except Exception:
+                props_sd = qlog_sd = None
+            progs["decode"] = [
+                (self._propose_jit,
+                 (self._d_params, self._d_bufs, zt, zt,
+                  self._d_kv_pool, zT, zk, t1))]
+            if props_sd is not None:
+                progs["decode"].append(
+                    (self._spec_verify_jit,
+                     (self._params, self._buffers, zt, props_sd,
+                      qlog_sd, zt, self._kv_pool, zT, zk, t1)))
         ctx = self._phys_len // 2
         g = self._spec.gamma if self._spec is not None else 0
         analytic = {
             "prefill": (rows * c, ctx),
             "decode": (S * (g + 1) if g else S, ctx),
         }
-        kv_tree = self._kv_pool if self.paged else self._caches
-        cache_itemsize = int(jax.tree.leaves(kv_tree)[0]
+        cache_itemsize = int(jax.tree.leaves(self._kv_pool)[0]
                              .dtype.itemsize)
         for kind, entries in progs.items():
             costs = [program_cost(fn, *args) for fn, args in entries]
@@ -1681,18 +1368,8 @@ class ContinuousBatchingEngine:
         err = EngineStopped("engine stopped before the request finished")
         for h in self._queue.drain():
             self._finish_handle(h, err, "stopped")
-        for key in list(self._promotions):
-            self._drop_promotion(key)
         for a in self._adms:
-            if a.entry is not None:
-                self._prefix.release(a.entry)
-                a.entry = None
-            if a.table is not None:
-                a.table.free()
-                a.table = None
-            if a.d_table is not None:
-                a.d_table.free()
-                a.d_table = None
+            self._free_admission_tables(a)
             self._finish_handle(a.handle, err, "stopped")
         self._adms = []
         for sid, st in enumerate(self._slots):
@@ -1700,13 +1377,12 @@ class ContinuousBatchingEngine:
                 self._finish_handle(st.handle, err, "stopped")
                 self._slots[sid] = None
             self._free_slot_table(sid)
-        if self.paged:
-            # leak invariant: after the tables above and the index's
-            # retained entries release their references, every page
-            # is back on the free list (pages_in_use == 0 — tested)
-            if self._prefix is not None:
-                self._prefix.drop_all()
-            self._sync_page_gauges()
+        # leak invariant: after the tables above and the index's
+        # retained entries release their references, every page is
+        # back on the free list (pages_in_use == 0 — tested)
+        if self._prefix is not None:
+            self._prefix.drop_all()
+        self._sync_page_gauges()
 
     def drain(self) -> None:
         """Stop admitting NEW requests while everything already
@@ -1804,20 +1480,19 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"prompt ({t0}) + max_new_tokens ({n}) exceeds the "
                 f"engine's serving window {self.max_len}")
-        if self.paged:
-            # the request's FULL page reservation (admission reserves
-            # the whole span eagerly — the no-mid-flight-OOM contract)
-            # must fit the pool even with every other page free
-            g = self._spec.gamma if self._spec is not None else 0
-            need = pages_needed(min(t0 + n + g, self._phys_len),
-                                self.page_size)
-            usable = self._pages.max_pages - 1  # page 0 is scratch
-            if need > usable:
-                raise ValueError(
-                    f"request needs {need} KV pages but the pool only "
-                    f"has {usable} allocatable (max_pages="
-                    f"{self._pages.max_pages} minus the scratch page) "
-                    f"— raise max_pages or shorten the request")
+        # the request's FULL page reservation (admission reserves
+        # the whole span eagerly — the no-mid-flight-OOM contract)
+        # must fit the pool even with every other page free
+        g = self._spec.gamma if self._spec is not None else 0
+        need = pages_needed(min(t0 + n + g, self._phys_len),
+                            self.page_size)
+        usable = self._pages.max_pages - 1  # page 0 is scratch
+        if need > usable:
+            raise ValueError(
+                f"request needs {need} KV pages but the pool only "
+                f"has {usable} allocatable (max_pages="
+                f"{self._pages.max_pages} minus the scratch page) "
+                f"— raise max_pages or shorten the request")
         self.start()
         h = RequestHandle(prompt, n, timeout_s, priority=priority)
         if trace_id is not None:
@@ -2056,8 +1731,7 @@ class ContinuousBatchingEngine:
         out["capacity"] = self._capacity_summary(
             loop=out["loop"], cost=out["cost"], usage=out["usage"])
         out["qos"] = self._qos_summary()
-        if self.paged:
-            out["paging"] = self._paging_summary()
+        out["paging"] = self._paging_summary()
         out["alerts"] = self.alerts()
         out["incidents"] = {"count": self._incidents.total,
                             "by_kind": self._incidents.counts_by_kind()}
@@ -2115,11 +1789,11 @@ class ContinuousBatchingEngine:
 
     def _quant_summary(self) -> dict:
         """The ``stats()["quantization"]`` block: which numerics the
-        hot path runs and what one KV slot row physically costs —
-        ``kv_row_bytes`` (scale sidecars included) next to
-        ``fp_row_bytes`` (the same geometry at full precision), whose
-        ratio is the capacity multiplier quantization bought (rows per
-        HBM byte scale by its inverse)."""
+        hot path runs and what one full-length request's pages
+        physically cost — ``kv_row_bytes`` (scale sidecars included)
+        next to ``fp_row_bytes`` (the same geometry at full precision),
+        whose ratio is the capacity multiplier quantization bought
+        (pages per HBM byte scale by its inverse)."""
         return {
             "kv_dtype": self.kv_dtype or "fp",
             "weights_dtype": self.weights_dtype or "fp",
@@ -2343,7 +2017,7 @@ class ContinuousBatchingEngine:
         try:
             while not self._stop_evt.is_set():
                 # idle engines BLOCK (submit/stop notify the condition;
-                # idle_wait_s is only a lost-wakeup safety net) instead
+                # IDLE_WAIT_S is only a lost-wakeup safety net) instead
                 # of spinning no-op iterations that would burn CPU and
                 # flood the tracer/iteration metrics. An empty engine
                 # has no deadlines to sweep — queued deadlines imply
@@ -2352,7 +2026,7 @@ class ContinuousBatchingEngine:
                     while (not self._stop_evt.is_set()
                            and not self._has_work()):
                         with trace.span("serving/idle_wait"):
-                            self._wake.wait(self.idle_wait_s)
+                            self._wake.wait(IDLE_WAIT_S)
                 if self._stop_evt.is_set():
                     break
                 with trace.span("serving/iteration",
@@ -2390,18 +2064,8 @@ class ContinuousBatchingEngine:
             error=e)
         err = EngineStopped(f"engine loop crashed: {e!r}")
         err.__cause__ = e
-        for key in list(self._promotions):
-            self._drop_promotion(key)
         for a in self._adms:
-            if a.entry is not None:
-                self._prefix.release(a.entry)
-                a.entry = None
-            if a.table is not None:
-                a.table.free()
-                a.table = None
-            if a.d_table is not None:
-                a.d_table.free()
-                a.d_table = None
+            self._free_admission_tables(a)
             self._finish_handle(a.handle, err, "crashed")
         self._adms = []
         for sid, st in enumerate(self._slots):
@@ -2409,7 +2073,7 @@ class ContinuousBatchingEngine:
                 self._finish_handle(st.handle, err, "crashed")
                 self._slots[sid] = None
             self._free_slot_table(sid)
-        if self.paged and self._prefix is not None:
+        if self._prefix is not None:
             self._prefix.drop_all()
         for h in self._queue.drain():
             self._finish_handle(h, err, "crashed")
@@ -2501,9 +2165,8 @@ class ContinuousBatchingEngine:
                                     for s in self._slots),
                 "jit_compiles": self._compile_total(),
             }
-            memory = {"pools": self._pool_bytes}
-            if self.paged:
-                memory["paging"] = self._paging_summary()
+            memory = {"pools": self._pool_bytes,
+                      "paging": self._paging_summary()}
             return self._incidents.capture(
                 trigger, timelines=tls, stats=stats, memory=memory,
                 error=error)
@@ -2566,8 +2229,8 @@ class ContinuousBatchingEngine:
         admissions in progress and the queue. Returns the iteration's
         ``now`` (monotonic)."""
         now = time.monotonic()
-        # paged: a fresh iteration may admit again — pages freed by
-        # the releases/donations above can satisfy what blocked before
+        # a fresh iteration may admit again — pages freed by the
+        # releases/donations since can satisfy what blocked before
         self._adm_blocked = False
         if self._chaos is not None:
             self._chaos.begin_iteration()
@@ -2614,9 +2277,8 @@ class ContinuousBatchingEngine:
         ins.active_slots.set(sum(s is not None for s in self._slots))
         ins.queue_depth.set(len(self._queue))
         ins.jit_compiles.set(self._compile_total())
-        if self.paged:
-            self._accrue_paged_kv()
-            self._sync_page_gauges()
+        self._accrue_kv()
+        self._sync_page_gauges()
         self._recompile_wd.sample()
         self._slo_wd.sample()
         self._slo_budget.sample(
@@ -2654,13 +2316,6 @@ class ContinuousBatchingEngine:
                 return sid
         return None
 
-    def _free_staging_row(self) -> Optional[int]:
-        used = {a.row for a in self._adms}
-        for r in range(self._policy.prefill_rows):
-            if r not in used:
-                return r
-        return None
-
     # ------------------------------------------------------ preemption
     def _maybe_preempt(self, now: float) -> bool:
         """With the slot pool exhausted and a high-class request
@@ -2668,7 +2323,7 @@ class ContinuousBatchingEngine:
         lowest class first, longest-remaining-work tie-break (the
         victim with the most decode left ahead of it loses the least
         sunk progress per unit of freed time). The victim's KV is
-        donated to the prefix pool and PINNED, the request requeued
+        donated to the prefix index and PINNED, the request requeued
         at the queue head — its automatic re-admission re-prefills
         only the tail the donated entry doesn't cover and resumes
         token-identical. High-class slots are never preempted; a pool
@@ -2741,26 +2396,34 @@ class ContinuousBatchingEngine:
         self._queue.requeue(h)
 
     def _fill_admissions(self, now: float) -> None:
-        """Start new admissions until the staging cache is full, the
-        slot pool is exhausted, or the queue runs dry. With a prefix
-        cache and ``admission_window > 1``, the pop prefers the queued
-        candidate with the longest cached prefix (bounded bypass —
-        see AdmissionQueue.pop_ready)."""
-        if self.paged and self._adm_blocked:
+        """Start new admissions until every prefill-dispatch row is
+        taken, the slots or the page pool are exhausted, or the queue
+        runs dry. With a prefix cache and ``admission_window > 1``, the
+        pop prefers the queued candidate with the longest cached prefix
+        that the pool can still hold (bounded bypass — see
+        AdmissionQueue.pop_ready)."""
+        if self._adm_blocked:
             # the pool already refused this iteration's queue head —
             # popping more candidates would just thrash requeues
             return
         scorer = None
-        if self.paged and self._prefix is not None \
-                and self.admission_window > 1:
+        if self._prefix is not None and self.admission_window > 1:
             c, ps = self._policy.chunk, self.page_size
 
             def scorer(h):
-                # paged bounded-bypass score: reuse tokens, but a
-                # candidate whose FRESH page need exceeds what the
-                # pool could cover even after a full prefix reclaim
-                # scores negative by the shortfall — electing it
-                # would stall the fill loop for nothing
+                # score by the USABLE (capped, chunk-aligned) reuse —
+                # exactly what _start_admission will skip — so a match
+                # that alignment reduces to zero never bypasses the
+                # FCFS head for nothing; a candidate whose FRESH page
+                # need exceeds what the pool could cover even after a
+                # full prefix reclaim scores negative by the shortfall
+                # — electing it would stall the fill loop for nothing.
+                # The raw lookup is stamped on the handle
+                # (generation-guarded) so the winner's admission
+                # doesn't re-walk the trie. Preempted requests score
+                # by their EFFECTIVE prompt (prompt + already-generated
+                # tokens) — the donated KV makes them near-perfect
+                # hits.
                 p = self._effective_prompt(h)
                 e, m = self._prefix.lookup(p)
                 h._prefix_probe = (e, m, self._prefix.generation)
@@ -2776,31 +2439,6 @@ class ContinuousBatchingEngine:
                 avail = (self._pages.free_pages
                          + self._prefix.device_pages)
                 return page_fit_score(base, fresh, avail)
-        elif self._prefix is not None and self.admission_window > 1:
-            c = self._policy.chunk
-            if self._promotions:
-                self._prune_promotions(now)
-
-            def scorer(h):
-                # score by the USABLE (capped, chunk-aligned) reuse —
-                # exactly what _start_admission will skip — so a match
-                # that alignment reduces to zero never bypasses the
-                # FCFS head for nothing. The raw lookup is stamped on
-                # the handle (generation-guarded) so the winner's
-                # admission doesn't re-walk the trie. Preempted
-                # requests score by their EFFECTIVE prompt (prompt +
-                # already-generated tokens) — the donated KV makes
-                # them near-perfect hits.
-                p = self._effective_prompt(h)
-                e, m = self._prefix.lookup(p)
-                h._prefix_probe = (e, m, self._prefix.generation)
-                if e is not None and e.tier == "host":
-                    # host-tier match: start the async device_put NOW,
-                    # overlapping this candidate's remaining queue wait
-                    # — by its admission the transfer has (usually)
-                    # already landed
-                    self._begin_promotion(e)
-                return (min(m, p.shape[0] - 1) // c) * c
         while len(self._adms) < self._policy.prefill_rows:
             slot = self._free_slot()
             if slot is None:
@@ -2812,9 +2450,9 @@ class ContinuousBatchingEngine:
                 slot = self._free_slot()
                 if slot is None:
                     return
-            row = self._free_staging_row()
-            if row is None:
-                return
+            used = {a.row for a in self._adms}
+            row = next(r for r in range(self._policy.prefill_rows)
+                       if r not in used)
             h, dropped = self._queue.pop_ready(
                 now, scorer=scorer, window=self.admission_window)
             for hd, err in dropped:
@@ -2841,111 +2479,8 @@ class ContinuousBatchingEngine:
 
     def _start_admission(self, h: RequestHandle, slot: int,
                          row: int) -> bool:
-        """Stage one popped request for chunked prefill. Returns True
-        when the admission started; False (paged mode only) when the
-        page pool could not cover the request's reservation — the
-        request is already requeued at the head and the caller stops
-        filling for this iteration."""
-        if self.paged:
-            return self._start_admission_paged(h, slot, row)
-        c = self._policy.chunk
-        prompt = self._effective_prompt(h)
-        t0 = prompt.shape[0]
-        base, entry = 0, None
-        if self._prefix is not None:
-            # reuse the pop_ready scorer's lookup when it is still
-            # valid — the generation guard rejects probes that predate
-            # any donation/eviction (a stale entry's pool row may
-            # already hold different tokens' KV)
-            probe = h.__dict__.pop("_prefix_probe", None)
-            if probe is not None and probe[2] == self._prefix.generation:
-                e, matched = probe[0], probe[1]
-            else:
-                e, matched = self._prefix.lookup(prompt)
-            if e is not None:
-                # cap at t0-1 (the last prompt position must be
-                # COMPUTED — its logits seed the first token), then
-                # chunk-align DOWN so the tail's chunk geometry — and
-                # with it the numerics — matches a cold prefill's, and
-                # the padded tail write can never overflow the cache
-                base = (min(matched, t0 - 1) // c) * c
-            from_host = base > 0 and e.tier == "host"
-            if from_host and not self._promote_entry(e):
-                # the host row could not be made device-resident
-                # (transfer unavailable, every pool row pinned, or the
-                # buffer raced away) — a CLEAN miss, never a copy from
-                # a reused or uninitialized row
-                base, e = 0, None
-            if base > 0:
-                entry = e
-                self._prefix.record_hit(entry, base, host=from_host)
-                self._prefix.acquire(entry)
-                self._staging = self._copy_row_jit(
-                    self._staging, self._pool, jnp.int32(row),
-                    jnp.int32(entry.row))
-                self._warm.add("copy:stage")
-                self._ins.prefix_hits_total.inc()
-                if from_host:
-                    self._ins.prefix_host_hits_total.inc()
-                    self._sync_prefix_gauges()
-                self._ins.prefix_reused_tokens_total.inc(base)
-                self._rec.record("request/prefix_hit", h.request_id,
-                                 service=self.service_name,
-                                 matched_tokens=base,
-                                 raw_matched_tokens=matched,
-                                 tail_tokens=t0 - base,
-                                 tier="host" if from_host else "device")
-            else:
-                self._prefix.record_miss()
-                self._ins.prefix_misses_total.inc()
-            # the preemption-time pin held the donated entry alive
-            # across the queue wait; the admission has now taken its
-            # own reference (or cleanly missed) — the insurance ref
-            # can go
-            pin = h.__dict__.pop("_preempt_pin", None)
-            if pin is not None:
-                self._prefix.release(pin)
-        tail = t0 - base
-        n_chunks = self._policy.n_chunks(tail)
-        ids = np.zeros((n_chunks * c,), np.int32)  # right-pad final chunk
-        ids[:tail] = prompt[base:]
-        d_ids, d_n_chunks = None, 0
-        if self.draft is not None:
-            # the draft prefills the FULL prompt into its own staging
-            # row — the prefix pool holds target KV only, so a hit
-            # skips target chunks but never draft chunks (the draft
-            # cursor then lags and the admission completes when both
-            # caches hold the prompt)
-            d_n_chunks = self._policy.n_chunks(t0)
-            d_ids = np.zeros((d_n_chunks * c,), np.int32)
-            d_ids[:t0] = prompt
-        self._adms.append(_Admission(h, slot, row, ids, t0, base,
-                                     n_chunks, entry, d_ids,
-                                     d_n_chunks))
-        h.prefix_tokens = base
-        t_adm = time.monotonic()
-        if h.admitted_at is None:
-            # set-once: a preempted request keeps its ORIGINAL
-            # admission stamp — first_token_at is set-once too, so a
-            # re-stamp would turn the timeline's prefill_s negative
-            h.admitted_at = t_adm
-        rec = getattr(h, "_usage", None)
-        if rec is not None:
-            # queue wait closes (re-admissions ACCUMULATE from the
-            # requeue stamp), staging-row residency opens, and the
-            # chunk-aligned reuse is credited as tokens + bytes saved
-            self._usage.admitted(rec, t_adm, reused_tokens=base)
-        self._rec.record("request/admitted", h.request_id,
-                         service=self.service_name, slot=slot,
-                         staging_row=row, n_chunks=n_chunks,
-                         prefix_tokens=base)
-        self._ins.admitted_total.inc()
-        return True
-
-    def _start_admission_paged(self, h: RequestHandle, slot: int,
-                               row: int) -> bool:
-        """Paged admission: reserve the request's FULL page span up
-        front — shared prefix head by refcount bump, fresh tail from
+        """Start one popped request's chunked prefill: reserve its
+        FULL page span up front — shared prefix head by refcount bump, fresh tail from
         the free list (with a reclaim sweep of unpinned prefix entries
         under pressure) — and never copy a row. A hit's shared pages
         are READ through the block table while the prefill writes land
@@ -2966,6 +2501,9 @@ class ContinuousBatchingEngine:
         t0 = prompt.shape[0]
         base, entry, from_host = 0, None, False
         if self._prefix is not None:
+            # reuse the pop_ready scorer's lookup when it is still
+            # valid — the generation guard rejects probes that predate
+            # any donation/eviction/tier move
             probe = h.__dict__.pop("_prefix_probe", None)
             if probe is not None \
                     and probe[2] == self._prefix.generation:
@@ -2979,6 +2517,9 @@ class ContinuousBatchingEngine:
                 base = (min(matched, t0 - 1) // c) * c
             from_host = base > 0 and e.tier == "host"
             if from_host and not self._promote_entry(e):
+                # the host pages could not be made device-resident
+                # (pool exhausted, or the buffer raced away) — a CLEAN
+                # miss, never a read of uninitialized pages
                 base, e = 0, None
                 from_host = False
             if base > 0:
@@ -2990,13 +2531,14 @@ class ContinuousBatchingEngine:
         need_tokens = min(t0 + remaining + g, self._phys_len)
         n_fresh = pages_needed(need_tokens, ps) - len(shared)
         table = BlockTable.build(self._pages, shared, n_fresh)
-        if table is None:
-            spill = (self._spill_pages
-                     if self._prefix is not None
-                     and self._prefix.host_rows > 0 else None)
-            if self._prefix is not None:
-                self._prefix.reclaim(n_fresh, spill)
-                table = BlockTable.build(self._pages, shared, n_fresh)
+        if table is None and self._prefix is not None:
+            # hold the hit's head across the sweep: its own entry may
+            # be the victim, and a page survives its entry only while
+            # something else references it
+            self._pages.share(shared)
+            self._prefix.reclaim(n_fresh, self._spill_pages)
+            table = BlockTable.build(self._pages, shared, n_fresh)
+            self._pages.free(shared)
         d_table = None
         if table is not None and self.draft is not None:
             # the draft pool is sized so a draft reservation can never
@@ -3023,7 +2565,7 @@ class ContinuousBatchingEngine:
             return False
         if self._prefix is not None:
             if base > 0:
-                # no staging copy and no entry acquire: the shared
+                # no copy and no entry acquire: the shared
                 # refcounts keep the pages alive even if the entry is
                 # evicted while we prefill (single mutator thread)
                 self._prefix.record_hit(entry, base, host=from_host)
@@ -3042,28 +2584,40 @@ class ContinuousBatchingEngine:
             else:
                 self._prefix.record_miss()
                 self._ins.prefix_misses_total.inc()
+            # the preemption-time pin held the donated entry alive
+            # across the queue wait; the admission has now taken its
+            # own page references (or cleanly missed) — the insurance
+            # ref can go
             pin = h.__dict__.pop("_preempt_pin", None)
             if pin is not None:
                 self._prefix.release(pin)
         tail = t0 - base
         n_chunks = self._policy.n_chunks(tail)
-        ids = np.zeros((n_chunks * c,), np.int32)
+        ids = np.zeros((n_chunks * c,), np.int32)  # right-pad final chunk
         ids[:tail] = prompt[base:]
         d_ids, d_n_chunks = None, 0
         if self.draft is not None:
+            # the draft prefills the FULL prompt — the prefix index
+            # holds target KV only, so a hit skips target chunks but
+            # never draft chunks
             d_n_chunks = self._policy.n_chunks(t0)
             d_ids = np.zeros((d_n_chunks * c,), np.int32)
             d_ids[:t0] = prompt
-        a = _Admission(h, slot, row, ids, t0, base, n_chunks, None,
-                       d_ids, d_n_chunks)
-        a.table, a.d_table = table, d_table
-        self._adms.append(a)
+        self._adms.append(_Admission(h, slot, row, ids, t0, base,
+                                     n_chunks, table, d_ids,
+                                     d_n_chunks, d_table))
         h.prefix_tokens = base
         t_adm = time.monotonic()
         if h.admitted_at is None:
+            # set-once: a preempted request keeps its ORIGINAL
+            # admission stamp — first_token_at is set-once too, so a
+            # re-stamp would turn the timeline's prefill_s negative
             h.admitted_at = t_adm
         rec = getattr(h, "_usage", None)
         if rec is not None:
+            # queue wait closes (re-admissions ACCUMULATE from the
+            # requeue stamp) and the chunk-aligned reuse is credited
+            # as tokens + bytes saved
             self._usage.admitted(rec, t_adm, reused_tokens=base)
         self._rec.record("request/admitted", h.request_id,
                          service=self.service_name, slot=slot,
@@ -3076,9 +2630,9 @@ class ContinuousBatchingEngine:
     def _prefill_round(self) -> None:
         """Advance EVERY in-flight admission by one chunk through one
         ragged dispatch — plus, with a draft, one MIRRORED ragged
-        dispatch over the draft staging cache — then complete the ones
-        whose prompt is fully staged in every cache that needs it
-        (slot insert + first-token sample).
+        dispatch over the draft pool — then complete the ones whose
+        prompt is fully written in every pool that needs it (table
+        handoff + first-token sample).
 
         A prefix-cache hit can leave the target cursor finished while
         the draft still prefills the reused head: those rows REPLAY
@@ -3134,18 +2688,13 @@ class ContinuousBatchingEngine:
                 tokens=sum(t + d for _, t, d in done_by),
                 request_ids=[a.handle.request_id
                              for a in self._adms]) as disp:
-            if self.paged:
-                # same ragged dispatch, but each row writes through its
-                # admission's reserved block table (idle rows carry the
-                # all-scratch table — their padding writes hit page 0)
-                logits, self._kv_pool = self._chunk_jit(
-                    self._params, self._buffers, self._h2d(ids),
-                    self._kv_pool, self._adm_tables(), self._h2d(pos0),
-                    self._h2d(last))
-            else:
-                logits, self._staging = self._chunk_jit(
-                    self._params, self._buffers, self._h2d(ids),
-                    self._staging, self._h2d(pos0), self._h2d(last))
+            # each row writes through its admission's reserved block
+            # table (idle rows carry the all-scratch table — their
+            # padding writes hit page 0)
+            logits, self._kv_pool = self._chunk_jit(
+                self._params, self._buffers, self._h2d(ids),
+                self._kv_pool, self._adm_tables(), self._h2d(pos0),
+                self._h2d(last))
             self._warm.add("chunk")
             if spec:
                 d_ids = np.zeros((rows, c), np.int32)
@@ -3154,17 +2703,11 @@ class ContinuousBatchingEngine:
                     dk = a.d_next_chunk
                     d_ids[a.row] = a.d_ids[dk * c:(dk + 1) * c]
                     d_pos0[a.row] = dk * c
-                if self.paged:
-                    _, self._d_kv_pool = self._d_chunk_jit(
-                        self._d_params, self._d_bufs, self._h2d(d_ids),
-                        self._d_kv_pool, self._adm_tables(draft=True),
-                        self._h2d(d_pos0),
-                        self._h2d(np.zeros((rows,), np.int32)))
-                else:
-                    _, self._d_staging = self._d_chunk_jit(
-                        self._d_params, self._d_bufs, self._h2d(d_ids),
-                        self._d_staging, self._h2d(d_pos0),
-                        self._h2d(np.zeros((rows,), np.int32)))
+                _, self._d_kv_pool = self._d_chunk_jit(
+                    self._d_params, self._d_bufs, self._h2d(d_ids),
+                    self._d_kv_pool, self._adm_tables(draft=True),
+                    self._h2d(d_pos0),
+                    self._h2d(np.zeros((rows,), np.int32)))
                 self._warm.add("d_chunk")
             toks = None
             if finals:
@@ -3211,35 +2754,14 @@ class ContinuousBatchingEngine:
             self._complete_admission(a, int(toks[a.row]))
 
     def _complete_admission(self, a: _Admission, tok: int) -> None:
-        if self.paged:
-            # zero-copy handoff: the admission's reserved tables
-            # BECOME the slot's — the pages already hold the prompt
-            # KV, there is no staging row to scatter
-            self._free_slot_table(a.slot)
-            self._tables[a.slot] = a.table
-            a.table = None
-            if self.draft is not None:
-                self._d_tables[a.slot] = a.d_table
-                a.d_table = None
-        else:
-            # prompt fully staged: scatter the staging row into the
-            # reserved pool slot, release the prefix pin (the staged
-            # copy is now independent of the pool row), deliver the
-            # first token
-            self._caches = self._copy_row_jit(
-                self._caches, self._staging, jnp.int32(a.slot),
-                jnp.int32(a.row))
-            self._warm.add("copy:insert")
-            if self.draft is not None:
-                # draft slot state moves in lockstep: the draft's
-                # staged full-prompt KV lands in the SAME slot index
-                self._d_caches = self._copy_row_jit(
-                    self._d_caches, self._d_staging, jnp.int32(a.slot),
-                    jnp.int32(a.row))
-                self._warm.add("copy:d_insert")
-        if a.entry is not None:
-            self._prefix.release(a.entry)
-            a.entry = None
+        # zero-copy handoff: the admission's reserved tables BECOME
+        # the slot's — the pages already hold the prompt KV
+        self._free_slot_table(a.slot)
+        self._tables[a.slot] = a.table
+        a.table = None
+        if self.draft is not None:
+            self._d_tables[a.slot] = a.d_table
+            a.d_table = None
         self._adms.remove(a)
         now = time.monotonic()
         h = a.handle
@@ -3247,9 +2769,6 @@ class ContinuousBatchingEngine:
         h._deliver(tok, now)
         rec = getattr(h, "_usage", None)
         if rec is not None:
-            # staging residency closes into kv_byte_seconds, the slot
-            # row's opens; the first token counts as delivered
-            self._usage.slot_acquired(rec, now)
             self._usage.delivered(rec, 1)
         if first:
             # re-admissions of a preempted request deliver here too,
@@ -3272,10 +2791,9 @@ class ContinuousBatchingEngine:
                              reprefilled_tokens=a.t0 - a.base)
         if (self.eos_id is not None and tok == self.eos_id) \
                 or len(h._tokens) >= h.max_new_tokens:
-            # instant finisher: the slot row still holds the staged
-            # effective prompt's KV — donate it before the slot
-            # identity is lost (prompt + generated[:-1] is exactly
-            # what the row covers)
+            # instant finisher: the slot's pages hold the effective
+            # prompt's KV — donate them before the slot identity is
+            # lost (prompt + generated[:-1] is exactly what they cover)
             self._maybe_donate(a.slot, np.concatenate(
                 [h.prompt, np.asarray(h._tokens[:-1], np.int32)]),
                 h.request_id)
@@ -3291,17 +2809,18 @@ class ContinuousBatchingEngine:
         st.delivered = len(h._tokens)
         self._slots[a.slot] = st
 
-    def _abort_admission(self, a: _Admission, err: Exception,
-                         kind: str) -> None:
-        if a.entry is not None:
-            self._prefix.release(a.entry)
-            a.entry = None
+    @staticmethod
+    def _free_admission_tables(a: _Admission) -> None:
         if a.table is not None:
             a.table.free()
             a.table = None
         if a.d_table is not None:
             a.d_table.free()
             a.d_table = None
+
+    def _abort_admission(self, a: _Admission, err: Exception,
+                         kind: str) -> None:
+        self._free_admission_tables(a)
         self._adms.remove(a)
         self._count_drop(kind)
         self._finish_handle(a.handle, err, kind)
@@ -3309,45 +2828,27 @@ class ContinuousBatchingEngine:
     # --------------------------------------------------- prefix donation
     def _maybe_donate(self, sid: int, tokens: np.ndarray,
                       request_id: str) -> None:
-        """Offer a finishing slot's KV to the prefix pool. ``tokens``
+        """Offer a finishing slot's KV to the prefix index. ``tokens``
         are exactly the ids whose KV the slot holds (positions
         ``0..len-1``); the index decides (covered / LRU-evict /
-        decline) and the accepted row is filled by one donated copy."""
+        decline). Donation is a refcount move, never a copy: the
+        covering pages are SHARED into the new entry; the slot's own
+        references are freed separately by the caller."""
         if self._prefix is None:
             return
-        if self.paged:
-            # page donation is a refcount move, never a copy: the
-            # covering pages are SHARED into the new entry; the slot's
-            # own references are freed separately by the caller
-            tbl = self._tables[sid]
-            if tbl is not None and tokens.shape[0] > 0:
-                held = tbl.covering(int(tokens.shape[0]))
-                if self._prefix.donate_pages(tokens, held):
-                    self._rec.record(
-                        "request/prefix_donated", request_id,
-                        service=self.service_name,
-                        tokens=int(tokens.shape[0]),
-                        pages=len(held))
-            self._sync_prefix_gauges()
-            return
-        row = self._prefix.donate(tokens)
-        if row is not None:
-            # the claimed row may still hold a DEMOTED victim's KV —
-            # the bulk d2h spill must land before this copy overwrites
-            # it (the engine-side half of the eviction-demotes contract)
-            self._resolve_pending_demotion()
-            self._pool = self._copy_row_jit(
-                self._pool, self._caches, jnp.int32(row),
-                jnp.int32(sid))
-            self._warm.add("copy:donate")
-            self._rec.record("request/prefix_donated", request_id,
-                             service=self.service_name,
-                             tokens=int(tokens.shape[0]), pool_row=row)
+        tbl = self._tables[sid]
+        if tbl is not None and tokens.shape[0] > 0:
+            held = tbl.covering(int(tokens.shape[0]))
+            if self._prefix.donate_pages(tokens, held):
+                self._rec.record(
+                    "request/prefix_donated", request_id,
+                    service=self.service_name,
+                    tokens=int(tokens.shape[0]), pages=len(held))
         self._sync_prefix_gauges()
 
     def _sync_prefix_gauges(self) -> None:
         """Publish the prefix cache's flow deltas and occupancy, both
-        tiers (device pool + host spill)."""
+        tiers (device pages + host spill)."""
         ev = self._prefix.evictions
         if ev > self._prefix_evictions_seen:
             self._ins.prefix_evicted_total.inc(
@@ -3355,7 +2856,7 @@ class ContinuousBatchingEngine:
             self._prefix_evictions_seen = ev
         self._ins.prefix_cache_bytes.set(self._prefix.bytes_in_use)
         self._ins.prefix_cache_entries.set(len(self._prefix))
-        if self._prefix.host_rows > 0:
+        if self._prefix.host_pages > 0:
             dm = self._prefix.demotions
             if dm > self._prefix_demotions_seen:
                 self._ins.prefix_host_demoted_total.inc(
@@ -3372,129 +2873,13 @@ class ContinuousBatchingEngine:
                 self._prefix.stats()["host_entries"])
 
     # ------------------------------------------------ host-tier moves
-    def _resolve_pending_demotion(self) -> None:
-        """Complete the demotion a row claim left open: one jitted
-        slice lifts the victim's pool row out, one bulk ``device_get``
-        parks it on host (each mesh device ships only its own shard),
-        and the cache attaches the buffer. Must run BEFORE the claimed
-        row is overwritten — its KV is the source."""
-        pend = self._prefix.pop_pending_demotion()
-        if pend is None:
-            return
-        from bigdl_tpu.parallel.tp import fetch_to_host
-
-        victim, vrow = pend
-        try:
-            one = self._take_row_jit(self._pool, jnp.int32(vrow))
-            self._warm.add("copy:demote")
-            buf = fetch_to_host(one)
-        except Exception:
-            # a failed spill degrades to the old drop semantics — the
-            # entry is removed, never left pointing at garbage
-            buf = None
-        self._prefix.complete_demotion(victim, buf)
-
-    def _begin_promotion(self, entry) -> None:
-        """Start (or touch) the async host→device transfer for a
-        host-tier entry a queued candidate's lookup landed on. The
-        ``device_put`` returns immediately — the copy overlaps the
-        request's remaining queue wait — and the record PINS the entry
-        so its host buffer cannot be evicted mid-flight."""
-        if self.paged:
-            return  # paged promotion is synchronous at admission
-        key = id(entry)
-        now = time.monotonic()
-        rec = self._promotions.get(key)
-        if rec is not None:
-            rec["touched"] = now
-            return
-        if entry.host_buf is None:
-            return  # spill copy still pending; next score retries
-        if len(self._promotions) >= self._promotions_max:
-            # bound in-flight transfers (device bytes + host pins):
-            # drop the stalest record, releasing its pin
-            stalest = min(self._promotions,
-                          key=lambda k: self._promotions[k]["touched"])
-            self._drop_promotion(stalest)
-        from bigdl_tpu.parallel.tp import put_from_host
-
-        self._prefix.acquire(entry)
-        tree = put_from_host(entry.host_buf, self._kv_shard)
-        self._promotions[key] = {"entry": entry, "tree": tree,
-                                 "touched": now}
-
-    def _drop_promotion(self, key) -> None:
-        rec = self._promotions.pop(key, None)
-        if rec is not None:
-            self._prefix.release(rec["entry"])
-
-    def _prune_promotions(self, now: float) -> None:
-        """Retire promotion records whose entry left the host tier
-        (promoted by another admission, or dropped) and ones no scorer
-        has touched recently (their request was cancelled or timed
-        out) — a record's pin must never outlive its usefulness, or
-        the host LRU cannot evict."""
-        for key in [k for k, r in self._promotions.items()
-                    if r["entry"].tier != "host"
-                    or now - r["touched"] > 30.0]:
-            self._drop_promotion(key)
-
     def _promote_entry(self, entry) -> bool:
         """Make a host-tier entry device-resident for the admission
-        consuming it: claim a pool row (evict-or-demote, exactly the
-        donation discipline), land the transferred ``(1, ...)`` tree
-        with one warmed scatter, and flip the entry's tier. Uses the
-        overlapped transfer when the scorer started one, else starts a
-        blocking one here (window=1 engines never score). False means
-        the promotion fell through — the caller treats the probe as a
-        clean miss."""
-        if self.paged:
-            return self._promote_entry_paged(entry)
-        rec = self._promotions.pop(id(entry), None)
-        if entry.tier != "host":
-            # raced: another admission promoted it first — its pool
-            # row is live, directly consumable
-            if rec is not None:
-                self._prefix.release(entry)
-            return entry.tier == "device"
-        if rec is None:
-            if entry.host_buf is None:
-                return False
-            # pin for the promotion's duration: allocate_row()'s
-            # evict-or-demote sweep must not reclaim this entry's
-            # host buffer out from under its own transfer (the
-            # overlapped path pinned at _begin_promotion)
-            self._prefix.acquire(entry)
-        try:
-            if rec is not None:
-                tree = rec["tree"]
-            else:
-                from bigdl_tpu.parallel.tp import put_from_host
-
-                tree = put_from_host(entry.host_buf, self._kv_shard)
-            row = self._prefix.allocate_row()
-            if row is None:
-                return False  # every device row pinned: clean miss
-            # the claimed row may itself hold a freshly demoted
-            # victim's KV — spill it before the scatter overwrites it
-            self._resolve_pending_demotion()
-            self._pool = self._copy_row_jit(
-                self._pool, tree, jnp.int32(row), jnp.int32(0))
-            self._warm.add("copy:promote")
-            self._prefix.promote(entry, row)
-            self._ins.prefix_host_promoted_total.inc()
-            return True
-        finally:
-            self._prefix.release(entry)
-
-    def _promote_entry_paged(self, entry) -> bool:
-        """Synchronous host→device promotion of a paged host-tier
-        entry: allocate fresh pages (reclaim sweep of unpinned prefix
-        entries under pressure), land each host page buffer with the
-        warmed per-page transfer + scatter, flip the entry's tier.
-        False = clean miss (pool exhausted or the buffer raced away).
-        Per-page copies are small and bounded, so the dense tier's
-        async-overlap machinery buys nothing here."""
+        consuming it, synchronously: allocate fresh pages (reclaim
+        sweep of unpinned prefix entries under pressure), land each
+        host page buffer with the warmed per-page transfer + scatter,
+        flip the entry's tier. False = clean miss (pool exhausted or
+        the buffer raced away)."""
         if entry.tier != "host":
             return entry.tier == "device"
         buf = entry.host_buf
@@ -3503,9 +2888,7 @@ class ContinuousBatchingEngine:
         n = len(buf)
         pages = self._pages.alloc(n)
         if pages is None:
-            spill = (self._spill_pages
-                     if self._prefix.host_rows > 0 else None)
-            self._prefix.reclaim(n, spill)
+            self._prefix.reclaim(n, self._spill_pages)
             pages = self._pages.alloc(n)
         if pages is None:
             return False
@@ -3545,7 +2928,7 @@ class ContinuousBatchingEngine:
         except Exception:
             return None
 
-    # --------------------------------------------------- paged plumbing
+    # ---------------------------------------------------- page plumbing
     def _copy_page(self, dst: int, src: int) -> None:
         """``BlockTable.ensure_writable``'s copy callback: one warmed
         jitted single-page copy inside the target pool. Engine hot
@@ -3581,8 +2964,6 @@ class ContinuousBatchingEngine:
         """Drop slot ``sid``'s page references (target + draft) —
         refcount moves only; pages shared into the prefix index
         survive under the index's references."""
-        if not self.paged:
-            return
         tbl = self._tables[sid]
         if tbl is not None:
             tbl.free()
@@ -3593,8 +2974,8 @@ class ContinuousBatchingEngine:
                 d.free()
                 self._d_tables[sid] = None
 
-    def _accrue_paged_kv(self) -> None:
-        """Per-iteration paged-KV billing: integrate each request's
+    def _accrue_kv(self) -> None:
+        """Per-iteration KV billing: integrate each request's
         ACTUALLY-HELD page bytes over the elapsed interval.
         ``holder_bytes`` prices a shared page pro-rata across its
         refcount, so a page shared by k holders is billed once in
@@ -3687,7 +3068,7 @@ class ContinuousBatchingEngine:
                "pool": self._pages.stats()}
         if self._d_pages is not None:
             out["draft_pool"] = self._d_pages.stats()
-        if isinstance(self._prefix, PagedPrefixIndex):
+        if self._prefix is not None:
             out["prefix_device_pages"] = self._prefix.device_pages
         return out
 
@@ -3706,16 +3087,10 @@ class ContinuousBatchingEngine:
             self._chaos.on_dispatch()
         with trace.span("serving/decode_dispatch",
                         rows=len(active)) as disp:
-            if self.paged:
-                nxt, self._kv_pool = self._step_jit(
-                    self._params, self._buffers, self._h2d(tok),
-                    self._h2d(pos), self._kv_pool, self._slot_tables(),
-                    self._next_key(), self._temp())
-            else:
-                nxt, self._caches = self._step_jit(
-                    self._params, self._buffers, self._h2d(tok),
-                    self._h2d(pos), self._caches, self._next_key(),
-                    self._temp())
+            nxt, self._kv_pool = self._step_jit(
+                self._params, self._buffers, self._h2d(tok),
+                self._h2d(pos), self._kv_pool, self._slot_tables(),
+                self._next_key(), self._temp())
             self._warm.add("step")
             with trace.span("serving/fetch_tokens"):
                 nxt_np = np.asarray(nxt)   # blocks on the fused step
@@ -3768,23 +3143,14 @@ class ContinuousBatchingEngine:
         with trace.span("serving/decode_dispatch",
                         rows=len(active)) as disp:
             tok_d, pos_d = self._h2d(tok), self._h2d(pos)
-            if self.paged:
-                props, qlogits, self._d_kv_pool = self._propose_jit(
-                    self._d_params, self._d_bufs, tok_d, pos_d,
-                    self._d_kv_pool, self._slot_tables(draft=True),
-                    r_draft, self._temp())
-                emit, n_acc, self._kv_pool = self._spec_verify_jit(
-                    self._params, self._buffers, tok_d, props,
-                    qlogits, pos_d, self._kv_pool, self._slot_tables(),
-                    r_acc, self._temp())
-            else:
-                props, qlogits, self._d_caches = self._propose_jit(
-                    self._d_params, self._d_bufs, tok_d, pos_d,
-                    self._d_caches, r_draft, self._temp())
-                emit, n_acc, self._caches = self._spec_verify_jit(
-                    self._params, self._buffers, tok_d, props,
-                    qlogits, pos_d, self._caches, r_acc,
-                    self._temp())
+            props, qlogits, self._d_kv_pool = self._propose_jit(
+                self._d_params, self._d_bufs, tok_d, pos_d,
+                self._d_kv_pool, self._slot_tables(draft=True),
+                r_draft, self._temp())
+            emit, n_acc, self._kv_pool = self._spec_verify_jit(
+                self._params, self._buffers, tok_d, props,
+                qlogits, pos_d, self._kv_pool, self._slot_tables(),
+                r_acc, self._temp())
             with trace.span("serving/fetch_tokens"):
                 emit_np = np.asarray(emit)    # blocks on both dispatches
                 n_np = np.asarray(n_acc)
@@ -3805,7 +3171,7 @@ class ContinuousBatchingEngine:
         # scans already wrote everything); the program is warmed at
         # construction, so the conditional launch can never read as a
         # post-warmup compile. Enqueued async; the data dependency on
-        # _d_caches orders it against the next propose.
+        # the draft pool orders it against the next propose.
         if any(int(n_np[sid]) == g for sid in active):
             sync_tok = np.zeros((self.max_slots,), np.int32)
             sync_pos = np.zeros((self.max_slots,), np.int32)
@@ -3814,15 +3180,10 @@ class ContinuousBatchingEngine:
                 sync_tok[sid] = (tok[sid] if n_r == 0
                                  else int(emit_np[sid, n_r - 1]))
                 sync_pos[sid] = pos[sid] + n_r
-            if self.paged:
-                self._d_kv_pool = self._d_sync_jit(
-                    self._d_params, self._d_bufs, self._h2d(sync_tok),
-                    self._h2d(sync_pos), self._d_kv_pool,
-                    self._slot_tables(draft=True))
-            else:
-                self._d_caches = self._d_sync_jit(
-                    self._d_params, self._d_bufs, self._h2d(sync_tok),
-                    self._h2d(sync_pos), self._d_caches)
+            self._d_kv_pool = self._d_sync_jit(
+                self._d_params, self._d_bufs, self._h2d(sync_tok),
+                self._h2d(sync_pos), self._d_kv_pool,
+                self._slot_tables(draft=True))
         # burst lengths FIRST (pure), so the dispatch wall is
         # attributed before any handle can finalize — a late charge
         # against an already-finalized record would leak out of the

@@ -1,15 +1,14 @@
-"""Paged KV cache: one refcounted block-pool under every KV surface.
+"""Paged KV cache: one refcounted block pool under every KV surface.
 
-The dense engine gives every request a full ``(cache_len, ...)`` KV row
-in each of its pools (slot KV, prefill staging, prefix pool, host tier,
-draft mirrors) — so a 32-token chat bills the same HBM as a
-document that fills ``cache_len``, and a prefix hit *copies* a pool row
-into staging before the first novel token is prefetched. This module is
-the fix, BigDL's block-manager discipline (Dai et al., 2018, arxiv
-1804.05839) applied at page granularity: the unit of KV storage becomes
-a fixed ``page_size``-token **page** of one persistent
-``(max_pages, page_size, ...)`` device buffer per layer, and every KV
-surface becomes host-side bookkeeping over page ids —
+A request that kept a full ``(cache_len, ...)`` KV row would bill a
+32-token chat the same HBM as a document that fills ``cache_len``, and
+a prefix hit would have to *copy* a retained row before the first
+novel token is prefilled. BigDL's block-manager discipline (Dai et
+al., 2018, arxiv 1804.05839) applied at page granularity avoids both:
+the unit of KV storage is a fixed ``page_size``-token **page** of one
+persistent ``(max_pages, page_size, ...)`` device buffer per layer, and
+every KV surface of the serving engine is host-side bookkeeping over
+page ids —
 
 * ``PagePool`` — the allocator: a free list plus per-page reference
   counts over the device tree. Pages are claimed (``alloc``), shared
@@ -23,13 +22,12 @@ surface becomes host-side bookkeeping over page ids —
   every page copy-on-write; ``ensure_writable`` breaks a share with a
   single-page device copy only when a writer actually lands on a page
   someone else still references.
-* ``PagedPrefixIndex`` — the prefix cache re-based on pages: the radix
-  trie, LRU, pin, and generation machinery is inherited unchanged from
-  ``PrefixCache``; what changes is the currency. A donation SHARES the
-  donor slot's pages into the entry (no slot→pool copy), a hit SHARES
-  the entry's aligned pages into the new request's table (no
-  pool→staging copy), and eviction / host-tier demotion are refcount
-  moves plus — for demotion only — one bulk device→host spill per page.
+* ``PagedPrefixIndex`` — the prefix cache: a radix trie over token-id
+  prefixes with LRU, pin and generation bookkeeping, whose entries hold
+  pages. A donation SHARES the donor slot's pages into the entry, a hit
+  SHARES the entry's aligned pages into the new request's table, and
+  eviction / host-tier demotion are refcount moves plus — for demotion
+  only — one bulk device→host spill per page.
 
 Why shared pages are never written (the COW invariant the engine
 maintains): the engine requires ``prefill_chunk % page_size == 0``, so
@@ -42,10 +40,10 @@ on the engine's own paths; it exists (and is tested) as the safety net
 for future writers — n>1 completion forks — that DO write under a
 share.
 
-Thread contract (mirrors ``PrefixCache``): the engine loop thread is
-the only mutator; ``stats()`` readers may race in from HTTP/debug
-threads, so counters and the free list sit behind an internal lock.
-Lock order is strictly index → pool (``PagedPrefixIndex`` calls
+Thread contract: the engine loop thread is the only mutator;
+``stats()`` / ``snapshot()`` readers may race in from HTTP/debug
+threads, so counters, the trie and the free list sit behind internal
+locks. Lock order is strictly index → pool (``PagedPrefixIndex`` calls
 ``PagePool`` while holding its own lock; the pool never calls back), so
 the two locks cannot deadlock.
 """
@@ -53,13 +51,12 @@ the two locks cannot deadlock.
 from __future__ import annotations
 
 import threading
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from bigdl_tpu.serving.prefix_cache import PrefixCache, PrefixEntry
-
-__all__ = ["PagePool", "BlockTable", "PagedPrefixIndex", "SCRATCH_PAGE"]
+__all__ = ["PagePool", "BlockTable", "PagedPrefixIndex", "PrefixEntry",
+           "SCRATCH_PAGE"]
 
 #: page id 0 is never allocated: it is the write sink for idle dispatch
 #: lanes (an all-zero block table routes their junk KV writes here) and
@@ -78,8 +75,7 @@ class PagePool:
     lies behind it is the attention layer's business); the pool never
     touches device memory itself, it only decides which page ids are
     live. The engine rebinds ``buffers`` after every donating dispatch
-    (decode/prefill writes, COW copies) exactly as it rebinds its dense
-    cache trees.
+    (decode/prefill writes, COW copies).
 
     Counters are cumulative and monotonic (the engine publishes them as
     the ``bigdl_serving_page_*_total`` instruments): ``allocated`` =
@@ -298,36 +294,99 @@ class BlockTable:
         self.pages = []
 
 
-class PagedPrefixIndex(PrefixCache):
-    """``PrefixCache`` with pages as the currency instead of pool rows.
+class PrefixEntry:
+    """One retained prefix: ``tokens`` (the exact token ids whose KV
+    ``pages`` hold, positions ``0..length-1``, in position order) and
+    the LRU/ref-count bookkeeping. ``tier`` says where the KV currently
+    lives: ``"device"`` (``pages`` of the pool) or ``"host"``
+    (``host_buf``, one engine-opaque pinned host buffer per page;
+    ``pages`` is empty while demoted so stale use shares nothing)."""
 
-    The trie, lookup, LRU stamps, pin/unpin, ``pin_covering``, hit/miss
-    accounting, and the ``generation`` stale-probe guard are inherited
-    verbatim — prefix REUSE semantics are unchanged. What this subclass
-    replaces is storage motion:
+    __slots__ = ("tokens", "pages", "refs", "last_used", "hits", "tier",
+                 "host_buf")
 
+    def __init__(self, tokens: np.ndarray, pages: Tuple[int, ...],
+                 stamp: int):
+        self.tokens = tokens
+        self.pages = pages
+        self.refs = 0
+        self.last_used = stamp
+        self.hits = 0
+        self.tier = "device"
+        self.host_buf = None
+
+    @property
+    def length(self) -> int:
+        return int(self.tokens.shape[0])
+
+    def __repr__(self):
+        return (f"PrefixEntry(len={self.length}, "
+                f"pages={len(self.pages)}, tier={self.tier}, "
+                f"refs={self.refs}, hits={self.hits})")
+
+
+class _Node:
+    """Radix-trie node: edge-compressed children keyed by first token;
+    ``entry`` marks a retained prefix ending exactly here."""
+
+    __slots__ = ("children", "entry")
+
+    def __init__(self):
+        # first_token -> (edge_tokens np.ndarray, child _Node)
+        self.children: Dict[int, Tuple[np.ndarray, "_Node"]] = {}
+        self.entry: Optional[PrefixEntry] = None
+
+
+def _common_len(a: np.ndarray, b: np.ndarray) -> int:
+    n = min(a.shape[0], b.shape[0])
+    if n == 0:
+        return 0
+    neq = np.flatnonzero(a[:n] != b[:n])
+    return int(neq[0]) if neq.size else n
+
+
+class PagedPrefixIndex:
+    """Radix-trie index over token-id prefixes → retained pool pages.
+
+    Real serving traffic is prefix-heavy: system prompts, few-shot
+    templates and multi-turn conversations share long identical prompt
+    heads, and recomputing those heads through chunked prefill makes
+    TTFT scale with FULL prompt length. A new request whose prompt
+    shares a cached prefix shares the entry's aligned pages and
+    chunk-prefills only the novel tail. Correctness of reuse rests on
+    KV causality — the KV at position ``i`` depends only on tokens
+    ``0..i`` — so any entry sharing the first ``m`` tokens with a
+    prompt yields ``m`` valid positions, even when the entry diverges
+    afterwards (partial match) or extends past the prompt (truncated
+    match).
+
+    Pure HOST bookkeeping; storage motion is refcounts:
+
+    * ``lookup(prompt)`` → best ``(entry, matched)``; the engine
+      consumes a hit as ``entry.pages[: base // page_size]`` via
+      ``PagePool.share`` and commits it with ``record_hit``.
     * ``donate_pages(tokens, pages)`` — a finished/preempted slot's
-      covering pages are SHARED into a new entry (refcount bump; the
-      dense slot→pool row copy does not exist here).
-    * a hit consumes ``entry.pages[: base // page_size]`` via
-      ``PagePool.share`` (the engine does this; the dense pool→staging
-      copy does not exist here).
+      covering pages are SHARED into a new entry.
     * ``reclaim(n_pages, spill)`` — eviction under allocation pressure:
       LRU unpinned entries drop their page references until the pool
       can satisfy the allocation. With a host budget and a ``spill``
       callback the victim DEMOTES instead: its pages are bulk-copied to
       pinned host buffers (one per page, outside the index lock) and
-      the entry stays in the trie as a host-tier resident.
+      the entry stays in the trie as a host-tier resident. The total
+      retained prefix set thus scales with host RAM, not HBM.
     * ``promote_pages(entry, pages)`` — the engine has allocated fresh
       pages and device_put the host buffers back; the entry flips to
-      device tier. Promotion is synchronous at admission in paged mode
-      (page copies are small and the async overlap machinery of the
-      dense tier buys little), so the dense pending-demotion handshake
-      (``pop_pending_demotion``/``complete_demotion``) is unused here.
+      device tier.
+    * ``acquire`` / ``release`` / ``pin_covering`` pin an entry in
+      WHATEVER tier it occupies: a pinned entry is never evicted,
+      demoted or host-evicted.
 
-    The dense row-allocation surface (``donate``, ``allocate_row``,
-    ``promote``, ``release_row``) is disabled and fails loudly — a
-    paged engine must never fall back to row motion.
+    ``max_entries`` caps the entry count (0 disables the cache; the
+    page pool bounds the bytes), ``host_pages`` is the host tier's page
+    budget (0 disables the tier: eviction drops). Every structural
+    change (insert / evict / demote / host-evict / promote) bumps
+    ``generation``, so a caller can validate a cached ``lookup`` result
+    before acting on it.
     """
 
     def __init__(self, pool: PagePool, *, max_entries: int,
@@ -336,30 +395,175 @@ class PagedPrefixIndex(PrefixCache):
         if max_entries < 0:
             raise ValueError(
                 f"max_entries must be >= 0, got {max_entries}")
-        # rows=max_entries keeps the base class's "rows == 0 disables"
-        # convention; row_bytes=0 because bytes are per-page here (the
-        # byte properties and stats() are overridden below).
-        super().__init__(rows=max_entries, row_bytes=0,
-                         min_tokens=min_tokens, token_bytes=token_bytes,
-                         devices=devices, host_rows=0)
+        if host_pages < 0:
+            raise ValueError(
+                f"host_pages must be >= 0, got {host_pages}")
+        if min_tokens < 1:
+            raise ValueError(
+                f"min_tokens must be >= 1, got {min_tokens}")
+        if devices < 1:
+            raise ValueError(f"devices must be >= 1, got {devices}")
         self.pool = pool
+        self.max_entries = int(max_entries)
+        #: devices the pool's pages are sharded across (the serving
+        #: mesh's model-axis size; 1 unsharded) — ``stats()`` derives
+        #: the per-device share one chip's HBM pays
+        self.devices = int(devices)
+        #: prefixes shorter than this are never matched or donated —
+        #: a few shared tokens are not worth an entry
+        self.min_tokens = min_tokens
+        #: device KV bytes one cached token position occupies; the
+        #: exchange rate behind the ``bytes_saved`` savings credit
+        self.token_bytes = float(token_bytes)
         #: host-tier budget in PAGES (0 disables the tier; eviction
         #: then drops instead of demoting)
-        self.host_pages = int(host_pages)
-        # the engine (and _sync_prefix_gauges) gates the host tier on
-        # host_rows > 0; in page currency the page budget IS that gate
-        self.host_rows = self.host_pages
+        self.host_pages = int(host_pages) if max_entries > 0 else 0
+        self._root = _Node()
+        self._entries: List[PrefixEntry] = []
+        self._host_entries: List[PrefixEntry] = []
+        self._stamp = 0
+        self._lock = threading.Lock()
+        #: bumped on every structural change — see the class docstring
+        self.generation = 0
+        # cumulative flow (monotonic, for stats deltas)
+        self.hits = 0
+        self.misses = 0
+        #: subset of ``hits`` served out of the host tier (the entry
+        #: needed a promotion before its pages were consumable)
+        self.host_hits = 0
+        self.reused_tokens = 0
+        #: device KV bytes reuse avoided recomputing + rewriting —
+        #: the cache's cumulative savings credit (reused positions x
+        #: token_bytes), per-request shares ledgered by the engine's
+        #: usage accounting
+        self.bytes_saved = 0
+        self.donations = 0
+        self.evictions = 0
+        # host-tier flow
+        self.demotions = 0
+        self.promotions = 0
+        self.host_evictions = 0
 
-    # ------------------------------------------------- dense API fences
-    def donate(self, tokens: np.ndarray) -> Optional[int]:
-        raise RuntimeError(
-            "PagedPrefixIndex: use donate_pages(), not the dense "
-            "row-copy donate()")
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._entries)
 
-    def allocate_row(self) -> Optional[int]:
-        raise RuntimeError(
-            "PagedPrefixIndex: rows do not exist; allocate pages "
-            "from the PagePool")
+    # ------------------------------------------------------------ match
+    def lookup(self, prompt: np.ndarray
+               ) -> Tuple[Optional[PrefixEntry], int]:
+        """Best cached prefix for ``prompt``: walk the trie as deep as
+        the prompt's tokens agree, then take the better of (a) the
+        deepest entry ENDING on the walked path (a full-entry match —
+        every one of its tokens is a prefix of the prompt) and (b) any
+        entry in the subtree below the divergence point (a PARTIAL
+        match: the entry shares exactly the walked depth, then
+        diverges or extends — its KV is still valid for the shared
+        head, by causality). Returns ``(entry, matched_tokens)`` with
+        ``matched >= min_tokens``, else ``(None, 0)``.
+
+        PURE: no counters move and no LRU stamp is touched — the
+        engine uses ``lookup`` both to probe admissions and to SCORE
+        queued candidates for prefix-aware ordering, and scoring must
+        not pollute the hit-rate. The engine's admission decision
+        lands via ``record_hit`` / ``record_miss``."""
+        prompt = np.asarray(prompt, np.int32)
+        with self._lock:
+            best: Optional[PrefixEntry] = None
+            best_len = 0
+
+            def consider(cand: Optional[PrefixEntry], ln: int):
+                nonlocal best, best_len
+                if cand is not None and ln > best_len:
+                    best, best_len = cand, ln
+
+            node, depth, off = self._root, 0, prompt
+            while True:
+                if node.entry is not None:
+                    consider(node.entry, node.entry.length)
+                if off.shape[0] == 0:
+                    # prompt exhausted AT a node: entries extending
+                    # below all share the full walked depth
+                    consider(self._mru_below(node), depth)
+                    break
+                nxt = node.children.get(int(off[0]))
+                if nxt is None:
+                    # no child continues the prompt, but every entry
+                    # below this node still shares `depth` tokens
+                    consider(self._mru_below(node), depth)
+                    break
+                edge, child = nxt
+                m = _common_len(edge, off)
+                depth += m
+                if m < edge.shape[0]:
+                    # diverged (or prompt exhausted) mid-edge: every
+                    # entry below shares exactly `depth` tokens
+                    consider(self._mru_below(child), depth)
+                    break
+                node, off = child, off[m:]
+            if best is None or best_len < self.min_tokens:
+                return None, 0
+            return best, best_len
+
+    def record_hit(self, entry: PrefixEntry, reused_tokens: int,
+                   host: bool = False) -> None:
+        """Commit an admission's hit: LRU touch, per-entry and global
+        hit counts, and the chunk-aligned reused-token figure the
+        engine actually skipped prefill for. ``host=True`` marks a hit
+        the engine served via a host-tier promotion — the tier split
+        behind the ``bigdl_serving_prefix_host_hits_total`` counter."""
+        with self._lock:
+            self._stamp += 1
+            entry.last_used = self._stamp
+            entry.hits += 1
+            self.hits += 1
+            if host:
+                self.host_hits += 1
+            self.reused_tokens += int(reused_tokens)
+            self.bytes_saved += int(reused_tokens * self.token_bytes)
+
+    def record_miss(self) -> None:
+        with self._lock:
+            self.misses += 1
+
+    def _mru_below(self, node: _Node) -> Optional[PrefixEntry]:
+        """Most-recently-used entry in ``node``'s subtree (entry count
+        is bounded by ``max_entries`` plus the host tier, so the DFS
+        is trivially cheap)."""
+        best = node.entry
+        for edge, child in node.children.values():
+            c = self._mru_below(child)
+            if c is not None and (best is None
+                                  or c.last_used > best.last_used):
+                best = c
+        return best
+
+    # -------------------------------------------------------- pin/unpin
+    def acquire(self, entry: PrefixEntry) -> None:
+        """Pin ``entry``: a pinned entry is never evicted, demoted or
+        host-evicted."""
+        with self._lock:
+            entry.refs += 1
+
+    def release(self, entry: PrefixEntry) -> None:
+        with self._lock:
+            if entry.refs <= 0:
+                raise RuntimeError(
+                    f"release() without matching acquire(): {entry!r}")
+            entry.refs -= 1
+
+    def pin_covering(self, tokens: np.ndarray
+                     ) -> Optional[PrefixEntry]:
+        """Find an entry of which ``tokens`` is a (non-strict) prefix
+        and PIN it (caller must ``release``); None when no such entry
+        exists. The preemption path pins the entry it just donated so
+        LRU pressure cannot evict — and the demote sweep cannot spill
+        — the victim's KV before its automatic resume consumes it."""
+        with self._lock:
+            entry = self._covering_entry(
+                np.asarray(tokens, np.int32))
+            if entry is not None:
+                entry.refs += 1
+            return entry
 
     # --------------------------------------------------------- donation
     def donate_pages(self, tokens: np.ndarray,
@@ -369,12 +573,20 @@ class PagedPrefixIndex(PrefixCache):
         references — the slot's table is freed separately). Declined
         (False) when too short, already covered by an existing entry
         (LRU-touched instead), or the entry budget is exhausted by
-        pinned entries."""
+        pinned entries. May evict the LRU ``refs == 0`` entry — the
+        budget resolves by recency, never by silently dropping pinned
+        entries."""
+        # own the key: np.asarray would ALIAS an int32 caller buffer,
+        # and a client reusing one preallocated prompt array across
+        # requests would then rewrite the trie key under an entry
+        # whose pages still hold the OLD tokens' KV — a silent
+        # wrong-prefix hit later
         tokens = np.array(tokens, np.int32, copy=True)
         # graftlint: ok[lock-discipline] — the pool reference is immutable after __init__; page_size is a pool constant
         n_pages = -(-tokens.shape[0] // self.pool.page_size)
         with self._lock:
-            if (self.rows == 0 or tokens.shape[0] < self.min_tokens
+            if (self.max_entries == 0
+                    or tokens.shape[0] < self.min_tokens
                     or n_pages == 0):
                 return False
             if n_pages > len(pages):
@@ -386,7 +598,7 @@ class PagedPrefixIndex(PrefixCache):
                 self._stamp += 1
                 covered.last_used = self._stamp
                 return False
-            if len(self._entries) >= self.rows:
+            if len(self._entries) >= self.max_entries:
                 victim = self._lru_unpinned()
                 if victim is None:
                     return False
@@ -397,8 +609,7 @@ class PagedPrefixIndex(PrefixCache):
             self.pool.share(held)
             self._stamp += 1
             self.generation += 1
-            entry = PrefixEntry(tokens, -1, self._stamp)
-            entry.pages = held
+            entry = PrefixEntry(tokens, held, self._stamp)
             self._insert(entry)
             self._entries.append(entry)
             self.donations += 1
@@ -441,7 +652,6 @@ class PagedPrefixIndex(PrefixCache):
                 self.generation += 1
                 if demote:
                     victim.tier = "host"
-                    victim.row = -1
                     victim.host_buf = None
                     self._host_entries.append(victim)
                 else:
@@ -493,8 +703,9 @@ class PagedPrefixIndex(PrefixCache):
                       pages: Sequence[int]) -> None:
         """Flip a host-tier entry back to device residency over freshly
         allocated ``pages`` (the caller has already device_put each
-        host buffer into its page). Mirrors the base ``promote``
-        contract: LRU touch, host buffer dropped, generation bump."""
+        host buffer into its page): LRU touch, host buffer dropped,
+        generation bump — probes that captured the entry as host-tier
+        re-validate before acting."""
         with self._lock:
             if entry.tier != "host" or entry not in self._host_entries:
                 raise RuntimeError(
@@ -530,6 +741,81 @@ class PagedPrefixIndex(PrefixCache):
                 e.host_buf = None
                 self.generation += 1
 
+    def _covering_entry(self, tokens: np.ndarray
+                        ) -> Optional[PrefixEntry]:
+        """An existing entry of which ``tokens`` is a (non-strict)
+        prefix — any future prompt matches it at least as deeply as it
+        would match ``tokens``, so the donation adds nothing."""
+        node, off = self._root, tokens
+        while True:
+            if off.shape[0] == 0:
+                return self._mru_below(node)
+            nxt = node.children.get(int(off[0]))
+            if nxt is None:
+                return None
+            edge, child = nxt
+            m = _common_len(edge, off)
+            if m == off.shape[0]:
+                return self._mru_below(child)
+            if m < edge.shape[0]:
+                return None
+            node, off = child, off[m:]
+
+    def _lru_unpinned(self) -> Optional[PrefixEntry]:
+        cand = [e for e in self._entries if e.refs == 0]
+        return min(cand, key=lambda e: e.last_used) if cand else None
+
+    # ---------------------------------------------------- trie plumbing
+    def _insert(self, entry: PrefixEntry) -> None:
+        node, off = self._root, entry.tokens
+        while off.shape[0] > 0:
+            nxt = node.children.get(int(off[0]))
+            if nxt is None:
+                child = _Node()
+                node.children[int(off[0])] = (off, child)
+                child.entry = entry
+                return
+            edge, child = nxt
+            m = _common_len(edge, off)
+            if m < edge.shape[0]:
+                # split the edge at the divergence point
+                mid = _Node()
+                node.children[int(off[0])] = (edge[:m], mid)
+                mid.children[int(edge[m])] = (edge[m:], child)
+                node, off = mid, off[m:]
+            else:
+                node, off = child, off[m:]
+        node.entry = entry
+
+    def _trie_remove(self, entry: PrefixEntry) -> None:
+        # walk to the entry's node, clearing the marker; structural
+        # merge of pass-through nodes is skipped — the trie is bounded
+        # by entries * key-length and rebuilt nodes are reused by the
+        # next insert along the same path
+        node, off = self._root, entry.tokens
+        path: List[Tuple[_Node, int]] = []
+        while off.shape[0] > 0:
+            nxt = node.children.get(int(off[0]))
+            if nxt is None:
+                return
+            edge, child = nxt
+            m = _common_len(edge, off)
+            if m < edge.shape[0]:
+                return
+            path.append((node, int(off[0])))
+            node, off = child, off[m:]
+        if node.entry is entry:
+            node.entry = None
+        # prune now-empty leaf chains so the trie cannot grow without
+        # bound across many donate/evict cycles
+        while path:
+            parent, first = path.pop()
+            edge, child = parent.children[first]
+            if child.entry is None and not child.children:
+                del parent.children[first]
+            else:
+                break
+
     # ------------------------------------------------------------ bytes
     @property
     def bytes_in_use(self) -> int:
@@ -558,6 +844,10 @@ class PagedPrefixIndex(PrefixCache):
 
     # ------------------------------------------------------------ stats
     def stats(self) -> dict:
+        """Operational snapshot: occupancy and cumulative
+        hit/reuse/eviction flow (the engine's ``stats()['prefix_cache']``
+        and ``/debug/requests`` both render this). ``rows`` is the
+        entry cap; ``host_rows`` the host tier's page budget."""
         with self._lock:
             looked = self.hits + self.misses
             dev_pages = sum(len(e.pages) for e in self._entries)
@@ -566,7 +856,7 @@ class PagedPrefixIndex(PrefixCache):
                                for e in self._entries))
             return {
                 "entries": len(self._entries),
-                "rows": self.rows,
+                "rows": self.max_entries,
                 "pages": dev_pages,
                 "bytes": pro_rata,
                 "capacity_bytes": self.pool.capacity_bytes,
@@ -595,6 +885,8 @@ class PagedPrefixIndex(PrefixCache):
             }
 
     def snapshot(self) -> List[dict]:
+        """Per-entry debug view, both tiers (LRU order, oldest
+        first)."""
         with self._lock:
             return [{"length": e.length, "pages": list(e.pages),
                      "tier": e.tier, "refs": e.refs, "hits": e.hits,

@@ -65,16 +65,7 @@ every QoS mechanism having actually fired (shed / preempted /
 rate-limited counts > 0 — a storm that exercised nothing measured
 nothing), and outcome conservation (every submission ended in exactly
 one terminal state — a silent drop is a correctness failure, not a
-perf number)), and ``bench.py --serving --paged`` (``detail.paged.*``
-— the paged-KV engine's latencies band run-to-run; the dense
-full-row leg rides along as ``detail.dense`` outside the path
-precedence. The paged row additionally gates the peak
-admitted-concurrency ratio as an absolute floor
-(``_PAGED_CONCURRENCY_RATIO_FLOOR`` — at an equal device KV byte
-budget, page-granular reservation must keep admitting >= 3x the
-dense leg's concurrent requests; a within-run A/B ratio gates on its
-own scale, like the fleet speedup) and the paged-vs-dense greedy
-token-parity verdict); all nine shapes are understood.
+perf number)); all eight shapes are understood.
 
 Two incident-autopilot gates ride along (skip-if-absent for rows
 predating the fields): the newest plain ``--serving`` row's
@@ -103,13 +94,12 @@ import sys
 #: block, in precedence order (--serving vs --serving --shared-prefix
 #: vs --serving --speculative vs --serving --tp vs --serving
 #: --shared-prefix --working-set vs --serving --fleet vs --serving
-#: --quantized vs --serving --qos vs --serving --paged — each row
-#: shape carries exactly one; the quantized row's fp leg is named
-#: ``fp_baseline``, the qos row's contention-free leg ``uncontended``,
-#: and the paged row's full-row leg ``dense`` so they stay out of
+#: --quantized vs --serving --qos — each row shape carries exactly
+#: one; the quantized row's fp leg is named ``fp_baseline`` and the
+#: qos row's contention-free leg ``uncontended`` so they stay out of
 #: this scan)
 _TTFT_PATHS = ("engine", "cached", "spec", "sharded", "tiered",
-               "affinity", "quantized", "qos", "paged")
+               "affinity", "quantized", "qos")
 
 #: absolute quality ceilings for --serving --quantized rows: int8
 #: numerics must stay this close to fp on the same seeds. Ceilings,
@@ -124,14 +114,6 @@ _QUANT_ACCEPT_DELTA_CEILING = 0.05
 #: the gated statistic — the p99 over a handful of high-class samples
 #: is a max, and host jitter swings it ±50% run to run.
 _QOS_TTFT_P50_RATIO_CEILING = 1.25
-
-#: absolute floor for --serving --paged rows: at an equal device KV
-#: byte budget, page-granular reservation must admit at least this
-#: multiple of the dense leg's peak concurrent requests on the mixed
-#: short/long storm (the issue's acceptance bar). A floor, not a
-#: run-to-run band — the value is a within-run A/B ratio with a
-#: meaningful scale of its own, like the fleet speedup.
-_PAGED_CONCURRENCY_RATIO_FLOOR = 3.0
 
 
 def _p99(row: dict, measure: str):
@@ -326,31 +308,6 @@ def qos_conservation_ok(row: dict):
     if not detail.get("qos"):
         return None
     return detail.get("conservation_ok")
-
-
-def paged_concurrency_ratio(row: dict):
-    """The paged A/B row's peak admitted-concurrency ratio
-    (paged / dense at an equal device KV byte budget), or None for
-    every other row shape and for rows predating the field. Keyed off
-    the ``paged`` leg block — gated as an absolute floor
-    (``_PAGED_CONCURRENCY_RATIO_FLOOR``), not run-to-run: the value is
-    already a within-run A/B ratio."""
-    detail = row.get("detail") or {}
-    if not detail.get("paged"):
-        return None
-    ratio = detail.get("admitted_concurrency_ratio")
-    return float(ratio) if ratio is not None else None
-
-
-def paged_token_parity(row: dict):
-    """The paged A/B row's greedy token-parity verdict (paging must
-    move KV bytes, never tokens), or None for every other row shape /
-    rows predating the field. A deterministic pass/fail fact about the
-    run, gated like the qos conservation verdict."""
-    detail = row.get("detail") or {}
-    if not detail.get("paged"):
-        return None
-    return detail.get("token_parity")
 
 
 #: fault classes the ``serve.py --chaos`` drill must each convert
@@ -616,34 +573,6 @@ def main(argv=None) -> int:
         else:
             print("[perf-gate] ok: qos outcomes conserve (every "
                   "submission reached exactly one terminal state)")
-    # paged A/B rows: the concurrency ratio is a within-run A/B at an
-    # equal byte budget, so it gates as an absolute floor (the
-    # capacity claim must keep holding), and token parity is a
-    # deterministic correctness fact about the run
-    pr = paged_concurrency_ratio(newest)
-    if pr is not None:
-        verdict = (f"paged admitted-concurrency ratio {pr:.3f}x for "
-                   f"{newest.get('metric')} {span}")
-        if pr < _PAGED_CONCURRENCY_RATIO_FLOOR:
-            print(f"[perf-gate] FAIL: {verdict} — page-granular "
-                  "reservation no longer admits "
-                  f"{_PAGED_CONCURRENCY_RATIO_FLOOR}x the dense leg's "
-                  "concurrency from the same KV bytes")
-            failed = True
-        else:
-            print(f"[perf-gate] ok: {verdict} clears the "
-                  f"{_PAGED_CONCURRENCY_RATIO_FLOOR}x floor")
-    pp = paged_token_parity(newest)
-    if pp is not None:
-        if pp is not True:
-            print(f"[perf-gate] FAIL: paged-vs-dense greedy token "
-                  f"parity broke for {newest.get('metric')} {span} — "
-                  "paging changed the tokens, not just where KV "
-                  "bytes live")
-            failed = True
-        else:
-            print("[perf-gate] ok: paged-vs-dense greedy outputs are "
-                  "token-identical")
     # quantized A/B rows: numerics quality gates as absolute ceilings
     # (a quality number has a meaningful scale of its own; gating it
     # against the previous row would let a slow drift walk the

@@ -1,5 +1,4 @@
-"""Paged KV cache (bigdl_tpu/serving/paging.py + the engine's paged
-mode).
+"""Paged KV cache (bigdl_tpu/serving/paging.py + the engine over it).
 
 The subsystem contract under test, unit first and then end-to-end:
 
@@ -15,8 +14,8 @@ The subsystem contract under test, unit first and then end-to-end:
   pure refcount, ``ensure_writable`` breaks a share with one
   single-page device copy and the ORIGINAL holder's bytes are
   untouched (copy-on-write isolation).
-* Engine paged mode — greedy decode stays token-identical to the
-  dense ``model.generate`` oracle across plain / tiered / speculative
+* The engine — greedy decode stays token-identical to the dense
+  ``model.generate`` oracle across plain / tiered / speculative
   / quantized / tensor-parallel variants; a prefix hit SHARES pages
   (``shared_total`` moves, ``cow_forks_total`` does not: the
   zero-copy hit leg); the jit-compile gauge is FLAT through page
@@ -455,8 +454,6 @@ def test_usage_ledger_bills_held_pages(lm):
 
 # ===================================== engine: validation + /debug
 def test_paged_ctor_and_submit_validation(lm):
-    with pytest.raises(ValueError, match="max_pages requires"):
-        ContinuousBatchingEngine(lm, max_slots=1, max_pages=8)
     with pytest.raises(ValueError, match="multiple of"):
         ContinuousBatchingEngine(lm, max_slots=1, prefill_chunk=6,
                                  page_size=4)
@@ -489,6 +486,36 @@ def test_pool_pressure_blocks_admission_not_correctness(lm, rec):
     waits = [e for e in rec.tail() if e.kind == "request/page_wait"]
     assert waits, "pressure never surfaced as a page_wait event"
     assert waits[0].attrs["free_pages"] < waits[0].attrs["needed_pages"]
+
+
+@pytest.mark.parametrize("chunk, page_size, derived",
+                         [(8, None, 8), (16, None, 16), (128, None, 16),
+                          (8, 3, None)])
+def test_page_size_derived_from_prefill_chunk(chunk, page_size, derived):
+    """An engine built with no ``page_size`` serves through a page pool
+    of ``gcd(prefill_chunk, 16)``-token pages, token for token what
+    ``model.generate`` gives; a ``page_size`` that does not divide the
+    chunk is still refused."""
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.utils import random as rnd
+
+    rnd.set_seed(29)
+    m = TransformerLM(32, embed_dim=16, num_heads=4, num_kv_heads=2,
+                      num_layers=2, max_len=256, use_rope=True)
+    m.evaluate()
+    kw = dict(max_slots=1, prefill_chunk=chunk, page_size=page_size,
+              service_name=f"paged_derived_{chunk}_{page_size}")
+    if derived is None:
+        with pytest.raises(ValueError, match="multiple of"):
+            ContinuousBatchingEngine(m, **kw)
+        return
+    p = np.random.RandomState(chunk).randint(0, 32, (11,))
+    with ContinuousBatchingEngine(m, **kw) as eng:
+        row = eng.submit(p, 6).result(timeout=120)
+        pg = eng.stats()["paging"]
+    assert pg["page_size"] == derived
+    assert pg["pool"]["allocated_total"] == pages_needed(17, derived)
+    np.testing.assert_array_equal(row, _direct(m, p, 6))
 
 
 def test_debug_memory_attributes_pool_and_occupancy(lm):

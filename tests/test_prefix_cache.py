@@ -24,8 +24,8 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.serving import (
-    AdmissionQueue, ContinuousBatchingEngine, PrefillPolicy, PrefixCache,
-    RequestTimedOut,
+    AdmissionQueue, ContinuousBatchingEngine, PagedPrefixIndex, PagePool,
+    PrefillPolicy, RequestTimedOut,
 )
 from bigdl_tpu.serving.streams import RequestHandle
 
@@ -59,12 +59,39 @@ def _direct(lm, prompt, n, eos=None):
 
 
 # --------------------------------------------------------- trie units
+PS = 4          # page_size of the index units: an 8-token key is 2 pages
+
+
+def _index(max_pages, **kw):
+    """A prefix index over a small pool of a one-layer (k, v) tree."""
+    leaf = np.zeros((max_pages, PS, 8), np.float32)
+    pool = PagePool(((leaf, leaf.copy()),), PS)
+    return pool, PagedPrefixIndex(pool, **kw)
+
+
+def _serve(pool, pc, tokens):
+    """One request's life on the page surface: reserve its pages
+    (reclaiming retained prefixes under pressure), finish, offer them
+    to the index, free its own references. None = the pool could not
+    hold it; else whether the donation was accepted."""
+    n = -(-len(tokens) // PS)
+    pages = pool.alloc(n)
+    if pages is None:
+        pc.reclaim(n)
+        pages = pool.alloc(n)
+    if pages is None:
+        return None
+    accepted = pc.donate_pages(tokens, pages)
+    pool.free(pages)
+    return accepted
+
+
 def test_radix_trie_match_semantics():
-    pc = PrefixCache(rows=4, row_bytes=1000, min_tokens=4)
+    pool, pc = _index(12, max_entries=4, min_tokens=4)
     t1 = np.arange(1, 9, dtype=np.int32)               # [1..8]
     t2 = np.asarray([1, 2, 3, 4, 9, 9, 9, 9], np.int32)  # splits at 4
-    assert pc.donate(t1) is not None
-    assert pc.donate(t2) is not None
+    assert _serve(pool, pc, t1)
+    assert _serve(pool, pc, t2)
     assert len(pc) == 2
 
     # exact: the full entry is a prefix of the prompt
@@ -87,31 +114,35 @@ def test_radix_trie_match_semantics():
     assert pc.stats()["hits"] == 0 and pc.stats()["misses"] == 0
 
     # covered donation: a prefix of an existing entry adds nothing
-    assert pc.donate(t1[:6]) is None
+    assert _serve(pool, pc, t1[:6]) is False
     assert len(pc) == 2 and pc.stats()["donations"] == 2
 
     # donate COPIES the key: a caller mutating its buffer afterwards
     # (e.g. a client reusing one preallocated prompt array) must not
     # rewrite the trie under the entry's retained KV
     buf = np.asarray([5, 5, 5, 5, 5, 5], np.int32)
-    assert pc.donate(buf) is not None
+    assert _serve(pool, pc, buf)
     buf[:] = 9
     e, m = pc.lookup(np.asarray([5, 5, 5, 5, 5, 5, 1], np.int32))
     assert m == 6 and np.array_equal(e.tokens, [5] * 6)
+    # an entry holds exactly the pages that cover its key
+    assert len(e.pages) == 2 and pc.stats()["pages"] == 6
 
 
 def test_lru_and_refcount_eviction_under_byte_pressure():
-    pc = PrefixCache(rows=2, row_bytes=512, min_tokens=4)
+    # 6 allocatable pages, 2 entries of 2 pages each: the entry cap
+    # binds first, then (below) the pool's bytes do
+    pool, pc = _index(7, max_entries=2, min_tokens=4)
     t1 = np.asarray([1] * 8, np.int32)
     t2 = np.asarray([2] * 8, np.int32)
     t3 = np.asarray([3] * 8, np.int32)
-    assert pc.donate(t1) is not None and pc.donate(t2) is not None
-    assert pc.bytes_in_use == 2 * 512 == pc.capacity_bytes
+    assert _serve(pool, pc, t1) and _serve(pool, pc, t2)
+    assert pc.bytes_in_use == 4 * pool.page_bytes == pool.bytes_in_use
+    assert pc.capacity_bytes == pool.capacity_bytes
     # touch t1 so t2 is the LRU victim
     e1, _ = pc.lookup(t1)
     pc.record_hit(e1, 8)
-    row3 = pc.donate(t3)
-    assert row3 is not None and pc.stats()["evictions"] == 1
+    assert _serve(pool, pc, t3) and pc.stats()["evictions"] == 1
     assert pc.lookup(t2)[0] is None          # t2 evicted
     assert pc.lookup(t1)[0] is not None      # t1 survived (recently used)
 
@@ -120,10 +151,18 @@ def test_lru_and_refcount_eviction_under_byte_pressure():
     t4 = np.asarray([4] * 8, np.int32)
     e3, _ = pc.lookup(t3)
     pc.acquire(e3)
-    assert pc.donate(t4) is None             # both rows pinned: declined
+    assert _serve(pool, pc, t4) is False     # both pinned: declined
     pc.release(e3)
-    assert pc.donate(t4) is not None         # t3 evictable now
+    assert _serve(pool, pc, t4)              # t3 evictable now
     assert pc.lookup(t1)[0] is e1            # the pinned entry survived
+
+    # byte pressure: with the pool full, a request's pages come out of
+    # the LRU UNPINNED entry — never the pinned one
+    live = pool.alloc(2)
+    assert pool.free_pages == 0 and not pc.reclaim(4)
+    assert pool.free_pages == 2 and pc.lookup(t4)[0] is None
+    assert pc.lookup(t1)[0] is e1 and e1.pages
+    pool.free(live)
     pc.release(e1)
     with pytest.raises(RuntimeError, match="acquire"):
         pc.release(e1)
@@ -132,14 +171,18 @@ def test_lru_and_refcount_eviction_under_byte_pressure():
 def test_policy_and_cache_validation():
     with pytest.raises(ValueError, match="prefill_rows"):
         PrefillPolicy(chunk=4, prefill_rows=0)
-    with pytest.raises(ValueError, match="rows"):
-        PrefixCache(rows=-1, row_bytes=8)
+    with pytest.raises(ValueError, match="max_entries"):
+        _index(4, max_entries=-1)
     with pytest.raises(ValueError, match="min_tokens"):
-        PrefixCache(rows=1, row_bytes=8, min_tokens=0)
-    # rows=0 is the disabled cache: donations are declined, lookups miss
-    pc = PrefixCache(rows=0, row_bytes=8)
-    assert pc.donate(np.arange(8, dtype=np.int32)) is None
+        _index(4, max_entries=1, min_tokens=0)
+    with pytest.raises(ValueError, match="host_pages"):
+        _index(4, max_entries=1, host_pages=-1)
+    # max_entries=0 is the disabled cache: donations are declined,
+    # lookups miss
+    pool, pc = _index(4, max_entries=0)
+    assert _serve(pool, pc, np.arange(8, dtype=np.int32)) is False
     assert pc.lookup(np.arange(8, dtype=np.int32)) == (None, 0)
+    assert pool.pages_in_use == 0
 
 
 # ------------------------------------------------- scheduler satellites
@@ -326,21 +369,24 @@ def test_concurrent_submits_sharing_one_prefix(lm):
 
 
 def test_engine_eviction_under_byte_pressure(lm):
-    """prefix_cache_rows=1: the second donated template evicts the
-    first (LRU, refs==0), visible in stats — and serving stays
-    correct throughout."""
+    """Capacity is the page pool's: with 5 allocatable pages the second
+    template's admission reclaims the first's retained pages (LRU,
+    refs==0) while the entry cap is nowhere near — visible in stats,
+    and serving stays correct throughout."""
     r = np.random.RandomState(12)
     t1, t2 = r.randint(0, 32, (8,)), r.randint(0, 32, (8,))
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
-                                  prefix_cache_rows=1) as eng:
+                                  max_len=16, max_pages=6) as eng:
         for tpl in (t1, t2):
             p = np.concatenate([tpl, r.randint(0, 32, (2,))])
             np.testing.assert_array_equal(eng.submit(p, 3).result(60),
                                           _direct(lm, p, 3))
         s = eng.stats()["prefix_cache"]
-        assert s["rows"] == 1 and s["entries"] == 1
+        pool = eng.stats()["paging"]["pool"]
+        assert s["entries"] == 1 < s["rows"]
         assert s["evictions"] >= 1
-        assert s["bytes"] == s["capacity_bytes"]
+        assert s["bytes"] == s["pages"] * pool["page_bytes"]
+        assert s["bytes"] <= s["capacity_bytes"] == pool["capacity_bytes"]
 
 
 def test_prefix_cache_disabled(lm):
@@ -354,7 +400,7 @@ def test_prefix_cache_disabled(lm):
         np.testing.assert_array_equal(h.result(60), _direct(lm, p, 4))
         assert h.prefix_tokens == 0
         assert eng.stats()["prefix_cache"] == {"enabled": False}
-    assert eng._pool is None
+    assert eng._prefix is None
 
 
 # --------------------------------------- engine: batched multi-row path

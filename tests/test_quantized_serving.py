@@ -173,8 +173,11 @@ def test_int8_regime_parity_and_flat_jit(lm):
 
     rows_plain, st_plain = run(prefix_cache_bytes=0,
                                service_name="q_plain")
-    rows_tier, st_tier = run(prefix_cache_rows=1, prefix_host_rows=8,
-                             service_name="q_tier")
+    # 7 allocatable pages: a request holds 4-5 and its retained
+    # template 3-4, so every admission demotes and every revisit
+    # promotes
+    rows_tier, st_tier = run(max_len=24, max_pages=8,
+                             prefix_host_rows=8, service_name="q_tier")
     rows_spec, st_spec = run(prefix_cache_bytes=0, draft=draft,
                              spec_gamma=3, service_name="q_spec")
     for a, b, c in zip(rows_plain, rows_tier, rows_spec):
@@ -194,15 +197,16 @@ def test_demote_promote_bit_identical(lm):
     bytes stay halved), and fetch→put returns bit-identical leaves, so
     a demoted+promoted row equals one that never left the device."""
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
-                                  kv_dtype="int8", prefix_cache_rows=1,
-                                  prefix_host_rows=4,
+                                  kv_dtype="int8", max_len=16,
+                                  max_pages=6, prefix_host_rows=4,
                                   service_name="q_bits") as eng:
         r = np.random.RandomState(42)
         tpls = [r.randint(0, 32, (8,)) for _ in range(2)]
         for tpl in tpls:
             eng.submit(np.concatenate([tpl, r.randint(0, 32, (2,))]),
                        3).result(timeout=60)
-        # the second donation demoted the first template's row
+        # the second admission (4 of 5 allocatable pages) demoted the
+        # first template's 3 retained pages
         pc = eng._prefix
         assert pc.stats()["demotions"] >= 1
         entry = next(e for e in pc._host_entries if e.host_buf
@@ -212,7 +216,8 @@ def test_demote_promote_bit_identical(lm):
         assert np.dtype(np.int8) in dtypes          # codes spilled raw
         assert np.dtype(np.float32) in dtypes       # scales ride along
         host_bytes = sum(leaf.nbytes for leaf in leaves)
-        assert host_bytes == eng._row_bytes < eng._fp_row_bytes
+        assert host_bytes == 3 * eng._pages.page_bytes
+        assert eng._row_bytes < eng._fp_row_bytes
 
         # the promotion transfer itself is bit-exact: host → device →
         # host round-trips every code and scale unchanged
@@ -296,15 +301,17 @@ def test_spec_acceptance_delta_bounded(lm):
 
 # ----------------------------------------------- capacity and honesty
 def test_capacity_doubles_at_equal_byte_budget(lm):
-    """The capacity claim: at the SAME ``prefix_cache_bytes`` budget
-    the int8 engine fits 2x the pool rows (head_dim=4: ratio exactly
-    0.5), and the memory-pool registry + stats report the honest
-    quantized bytes, scale sidecars included."""
+    """The capacity claim: at the SAME ``max_pages * page_bytes`` budget
+    the int8 pool holds 2x the pages, and at the same
+    ``prefix_cache_bytes`` the index keeps 2x the entries (head_dim=4:
+    ratio exactly 0.5); the memory-pool registry + stats report the
+    honest quantized bytes, scale sidecars included."""
     from bigdl_tpu.observability import memory as obs_memory
 
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
                                   service_name="q_cap_fp") as fp_eng:
         fp_bytes = fp_eng.stats()["quantization"]["kv_row_bytes"]
+        fp_pool = fp_eng.stats()["paging"]["pool"]
         budget = 4 * fp_bytes
         fp_rows = None
         with ContinuousBatchingEngine(
@@ -314,15 +321,19 @@ def test_capacity_doubles_at_equal_byte_budget(lm):
             fp_rows = e2.stats()["prefix_cache"]["rows"]
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
                                   kv_dtype="int8",
+                                  max_pages=2 * fp_pool["max_pages"],
                                   prefix_cache_bytes=budget,
                                   service_name="q_cap_q8") as q_eng:
         qz = q_eng.stats()["quantization"]
+        q_pool = q_eng.stats()["paging"]["pool"]
         q_rows = q_eng.stats()["prefix_cache"]["rows"]
         sizes = obs_memory.pool_sizes()
-        assert sizes["serving/q_cap_q8/kv_slots"] == \
-            obs_memory.tree_device_bytes(q_eng._caches)
-        assert sizes["serving/q_cap_q8/kv_slots"] == \
-            2 * qz["kv_row_bytes"]
+        assert sizes["serving/q_cap_q8/kv_page_pool"] == \
+            obs_memory.tree_device_bytes(q_eng._kv_pool)
+        assert sizes["serving/q_cap_q8/kv_page_pool"] == \
+            q_pool["capacity_bytes"] == fp_pool["capacity_bytes"]
+    assert q_pool["page_bytes"] * 2 == fp_pool["page_bytes"]
+    assert q_pool["max_pages"] == 2 * fp_pool["max_pages"]
     assert qz["row_bytes_ratio"] == 0.5
     assert qz["kv_row_bytes"] * 2 == qz["fp_row_bytes"] == fp_bytes
     assert fp_rows == 4 and q_rows == 8

@@ -309,16 +309,14 @@ def engine_run(lm):
 def test_engine_pool_attribution_moves_on_donation(engine_run):
     eng, mreg, mrec, handles = engine_run
     sizes = obs_memory.pool_sizes()
-    kv = sizes["serving/resobs/kv_slots"]
-    assert kv == obs_memory.tree_bytes(eng._caches) > 0
-    assert sizes["serving/resobs/prefill_staging"] \
-        == obs_memory.tree_bytes(eng._staging) > 0
+    kv = sizes["serving/resobs/kv_page_pool"]
+    assert kv == obs_memory.tree_bytes(eng._kv_pool) > 0
     assert sizes["serving/resobs/params"] > 0
-    assert sizes["serving/resobs/prefix_pool"] == 2 * kv  # 2x slot rows
-    # finished slots DONATED their KV: occupied prefix bytes moved off 0
+    # the slots are idle again, so every live page is one a finished
+    # slot DONATED to the prefix index: occupied bytes moved off 0
     in_use = sizes["serving/resobs/prefix_kv_in_use"]
     assert in_use == eng._prefix.bytes_in_use > 0
-    assert in_use <= sizes["serving/resobs/prefix_pool"]
+    assert in_use == sizes["serving/resobs/kv_pages_in_use"] <= kv
     # and the monitor publishes the attribution as gauges
     mon = obs.DeviceMemoryMonitor(registry=mreg)
     mon.sample()
@@ -366,8 +364,8 @@ def test_debug_memory_endpoint_roundtrip(engine_run):
         doc = json.loads(urllib.request.urlopen(
             f"http://127.0.0.1:{srv.port}/debug/memory").read())
     assert doc["now"]["devices"]
-    assert doc["now"]["pools"]["serving/resobs/kv_slots"] \
-        == obs_memory.tree_bytes(eng._caches)
+    assert doc["now"]["pools"]["serving/resobs/kv_page_pool"] \
+        == obs_memory.tree_bytes(eng._kv_pool)
     assert doc["peak_bytes"] >= 0 and doc["history"]
     # the default-monitor route answers too (no explicit monitor wired)
     with obs.start_http_server(host="127.0.0.1") as srv:

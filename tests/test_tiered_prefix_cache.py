@@ -1,11 +1,12 @@
-"""Tiered prefix-KV cache: device pool + host-RAM spill
-(``bigdl_tpu/serving/prefix_cache.py`` host tier, wired through the
-engine's admission and donation paths).
+"""Tiered prefix-KV cache: device pages + host-RAM spill
+(``bigdl_tpu/serving/paging.py`` ``PagedPrefixIndex`` host tier, wired
+through the engine's admission and donation paths).
 
-The acceptance contract under test: device-pool LRU eviction DEMOTES
-unpinned rows into host buffers (one bulk d2h copy, separate host byte
-budget with its own LRU) instead of dropping them; a trie hit on a
-host-tier entry promotes the row back before admission; and none of
+The acceptance contract under test: reclaiming pages under allocation
+pressure DEMOTES unpinned entries into host buffers (one bulk d2h copy
+per page, separate host byte budget with its own LRU) instead of
+dropping them; a trie hit on a host-tier entry promotes its pages back
+before admission; and none of
 that bends the engine's invariants — warm output stays token-identical
 to the cache-disabled engine (and the lone-generate oracle) across
 demote→promote→reuse cycles, including under tensor parallelism and
@@ -13,7 +14,7 @@ with speculative decoding on; the jit-compile gauge stays flat through
 promotions; usage-ledger device-seconds still conserve; both tiers
 attribute in the memory-pool registry; and the generation guard turns
 every tier-transition race (lookup vs demote, promote vs host-evict)
-into a clean miss, never a wrong-row copy. Plus the
+into a clean miss, never a share of reused pages. Plus the
 ``scripts/perf_gate.py`` tiered-row gates (headline hit rate
 higher-is-better, tiered p50 TTFT lower-is-better)."""
 
@@ -28,7 +29,9 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.parallel import Engine, fetch_to_host, put_from_host
-from bigdl_tpu.serving import ContinuousBatchingEngine, PrefixCache
+from bigdl_tpu.serving import (
+    ContinuousBatchingEngine, PagedPrefixIndex, PagePool,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -66,45 +69,74 @@ def _direct(lm, prompt, n):
     return np.asarray(lm.generate(jnp.asarray(prompt)[None], n))[0]
 
 
-def _demote(pc, entry, buf="host-kv"):
-    """Drain the pending-demotion contract the way the engine does:
-    claim acknowledged, bulk copy done, buffer attached."""
-    pend = pc.pop_pending_demotion()
-    assert pend is not None and pend[0] is entry
-    pc.complete_demotion(entry, buf)
-    return pend[1]
+PS = 4          # page_size of the index units: an 8-token key is 2 pages
+
+
+def _index(max_pages, **kw):
+    """A prefix index over a small pool of a one-layer (k, v) tree."""
+    leaf = np.zeros((max_pages, PS, 8), np.float32)
+    pool = PagePool(((leaf, leaf.copy()),), PS)
+    return pool, PagedPrefixIndex(pool, min_tokens=4, **kw)
+
+
+def _spill(pages):
+    """The engine's demotion callback: one host buffer per page."""
+    return ["host-kv"] * len(pages)
+
+
+def _serve(pool, pc, tokens, spill=_spill):
+    """One request's life on the page surface: reserve its pages
+    (reclaiming — demoting — retained prefixes under pressure), finish,
+    offer them to the index, free its own references."""
+    n = -(-len(tokens) // PS)
+    pages = pool.alloc(n)
+    if pages is None:
+        pc.reclaim(n, spill)
+        pages = pool.alloc(n)
+    assert pages is not None
+    accepted = pc.donate_pages(tokens, pages)
+    pool.free(pages)
+    return accepted
 
 
 # ---------------------------------------------------- host-tier units
 def test_host_lru_and_byte_budget():
-    """Device eviction demotes into the host tier; the host tier has
+    """Device reclaim demotes into the host tier; the host tier has
     its OWN byte budget and LRU; only attached buffers count toward
     host bytes."""
-    pc = PrefixCache(rows=2, row_bytes=512, min_tokens=4, host_rows=2)
+    # 4 allocatable pages = two retained keys; host budget the same
+    pool, pc = _index(5, max_entries=8, host_pages=4)
     ts = [np.asarray([k] * 8, np.int32) for k in range(1, 6)]
-    assert pc.donate(ts[0]) is not None and pc.donate(ts[1]) is not None
-    assert pc.host_capacity_bytes == 2 * 512
+    assert _serve(pool, pc, ts[0]) and _serve(pool, pc, ts[1])
+    assert pc.host_capacity_bytes == 4 * pool.page_bytes
     assert pc.host_bytes_in_use == 0
 
-    # third donation: device LRU (ts[0]) demotes instead of dropping
-    assert pc.donate(ts[2]) is not None
+    # third request: device LRU (ts[0]) demotes instead of dropping;
+    # while its copy is in flight it holds no host bytes yet
+    seen = []
+
+    def spill(pages):
+        e, m = pc.lookup(ts[0])
+        seen.append((m, e.tier, pc.host_bytes_in_use))
+        return _spill(pages)
+
+    assert _serve(pool, pc, ts[2], spill)
+    assert seen == [(8, "host", 0)]
     e0, m = pc.lookup(ts[0])
-    assert m == 8 and e0.tier == "host"
-    assert pc.host_bytes_in_use == 0          # copy still pending
-    _demote(pc, e0)
-    assert pc.host_bytes_in_use == 512
+    assert m == 8 and e0.tier == "host" and e0.pages == ()
+    assert pc.host_bytes_in_use == 2 * pool.page_bytes
     assert pc.stats()["demotions"] == 1
 
     # fourth: ts[1] demotes too — host tier now at its budget
-    assert pc.donate(ts[3]) is not None
-    e1, _ = pc.lookup(ts[1])
-    _demote(pc, e1)
-    assert pc.host_bytes_in_use == 2 * 512 == pc.host_capacity_bytes
+    assert _serve(pool, pc, ts[3])
+    assert pc.lookup(ts[1])[0].tier == "host"
+    assert pc.host_bytes_in_use == 4 * pool.page_bytes \
+        == pc.host_capacity_bytes
     assert pc.stats()["host_entries"] == 2
 
     # fifth: the HOST tier is full, so its LRU (ts[0], the oldest
     # stamp) truly leaves the cache to make room for the new demotion
-    assert pc.donate(ts[4]) is not None
+    assert _serve(pool, pc, ts[4])
     assert pc.stats()["host_evictions"] == 1
     assert pc.lookup(ts[0])[0] is None
     e, _ = pc.lookup(ts[1])
@@ -123,30 +155,29 @@ def test_host_lru_and_byte_budget():
 def test_pin_spans_demote_and_blocks_host_eviction():
     """refs pin an entry in WHATEVER tier it occupies: a pinned device
     entry is never demoted, a pinned host entry is never host-evicted
-    — when every host row is pinned the demotion degrades to a plain
+    — when every host page is pinned the demotion degrades to a plain
     drop, never an over-budget spill."""
-    pc = PrefixCache(rows=2, row_bytes=256, min_tokens=4, host_rows=1)
+    pool, pc = _index(5, max_entries=8, host_pages=2)
     t1, t2, t3, t4 = (np.asarray([k] * 8, np.int32) for k in range(1, 5))
-    assert pc.donate(t1) is not None and pc.donate(t2) is not None
+    assert _serve(pool, pc, t1) and _serve(pool, pc, t2)
     e1, _ = pc.lookup(t1)
     pc.acquire(e1)
 
     # pinned device entry survives: the victim is t2
-    assert pc.donate(t3) is not None
+    assert _serve(pool, pc, t3)
     assert e1.tier == "device"
     e2, _ = pc.lookup(t2)
     assert e2.tier == "host"
-    _demote(pc, e2)
     pc.acquire(e2)                     # pin SPANS the demoted tier
 
-    # host tier full of pinned entries: the next device eviction (t3)
-    # cannot spill — it drops, and e2's buffer survives untouched
-    assert pc.donate(t4) is not None
-    assert pc.pop_pending_demotion() is None
+    # host tier full of pinned entries: the next device victim (t3)
+    # cannot spill — it drops, and e2's buffers survive untouched
+    assert _serve(pool, pc, t4)
+    assert pc.stats()["demotions"] == 1
     assert pc.stats()["host_evictions"] == 0
     assert pc.lookup(t3)[0] is None
     e2b, m = pc.lookup(t2)
-    assert e2b is e2 and m == 8 and e2.host_buf == "host-kv"
+    assert e2b is e2 and m == 8 and e2.host_buf == ["host-kv"] * 2
 
     pc.release(e1), pc.release(e2)
 
@@ -155,52 +186,66 @@ def test_generation_guard_covers_host_tier():
     """The stale-probe regression the satellite pins: EVERY tier
     transition (demote, host-evict, promote, failed demotion) bumps
     ``generation``, so a probe captured before the transition
-    re-validates into a clean miss instead of copying a reused row."""
-    pc = PrefixCache(rows=1, row_bytes=128, min_tokens=4, host_rows=1)
+    re-validates into a clean miss instead of sharing reused pages."""
+    # 2 allocatable pages = one retained key; host budget the same
+    pool, pc = _index(3, max_entries=8, host_pages=2)
     t1, t2 = np.asarray([1] * 8, np.int32), np.asarray([2] * 8, np.int32)
-    assert pc.donate(t1) is not None
+    assert _serve(pool, pc, t1)
     e1, m = pc.lookup(t1)
     probe_gen = pc.generation
 
-    # lookup racing a demotion: the donation that demotes e1 bumps
+    # lookup racing a demotion: the admission that demotes e1 bumps
     # generation, so the engine's (entry, match, gen) probe goes stale
-    assert pc.donate(t2) is not None
+    assert _serve(pool, pc, t2)
     assert pc.generation != probe_gen
-    assert e1.tier == "host"
-    _demote(pc, e1)
+    assert e1.tier == "host" and e1.host_buf is not None
 
     # promote racing a host eviction: capture e1 as a host-tier probe,
     # then evict its buffer — generation moves again, host_buf clears,
-    # and promote() of the evicted entry refuses outright
+    # and promote_pages() of the evicted entry refuses outright
     e1b, _ = pc.lookup(t1)
     assert e1b is e1
     probe_gen = pc.generation
     t3 = np.asarray([3] * 8, np.int32)
-    assert pc.donate(t3) is not None          # t2 demotes, e1 host-evicts
+    assert _serve(pool, pc, t3)               # t2 demotes, e1 host-evicts
     assert pc.generation != probe_gen
     assert e1.host_buf is None
     with pytest.raises(RuntimeError, match="non-host"):
-        pc.promote(e1, 0)
-    # a demotion completing after its entry was host-evicted is a
-    # no-op — the stale buffer is dropped, not re-attached
-    pc.complete_demotion(e1, "stale-buffer")
-    assert e1.host_buf is None
+        pc.promote_pages(e1, (1, 2))
     assert pc.lookup(t1)[0] is None
+
+    # a spill completing after its entry left the host tier (the copy
+    # runs outside the index lock) is a no-op — the stale buffer is
+    # dropped, not re-attached
+    e3, _ = pc.lookup(t3)
+
+    def racing_spill(pages):
+        pc.drop_all()
+        return _spill(pages)
+
+    demoted = pc.stats()["demotions"]
+    assert _serve(pool, pc, np.asarray([4] * 8, np.int32), racing_spill)
+    assert e3.host_buf is None and pc.lookup(t3)[0] is None
+    assert pc.stats()["demotions"] == demoted
 
     # a demotion whose d2h copy FAILED (buf None) drops the entry and
     # bumps generation — a later promotion can never read garbage
-    e2, _ = pc.lookup(t2)
-    assert e2 is not None and e2.tier == "host"
+    e4, _ = pc.lookup(np.asarray([4] * 8, np.int32))
+    assert e4 is not None and e4.tier == "device"
     gen = pc.generation
-    pc.complete_demotion(e2, None)
-    assert pc.generation != gen and pc.lookup(t2)[0] is None
+    assert _serve(pool, pc, np.asarray([5] * 8, np.int32),
+                  lambda pages: None)
+    assert pc.generation != gen
+    assert pc.lookup(np.asarray([4] * 8, np.int32))[0] is None
+    assert pc.stats()["host_entries"] == 0
 
-    # allocate_row/release_row round-trip: a fallen-through promotion
-    # returns its claimed row to the free list
-    row = pc.allocate_row()
-    assert row is not None
-    pc.release_row(row)
-    assert pc.allocate_row() == row
+    # a fallen-through promotion returns its claimed pages to the free
+    # list: the pool hands the same pages out again
+    pc.drop_all()
+    pages = pool.alloc(2)
+    assert pages is not None
+    pool.free(pages)
+    assert sorted(pool.alloc(2)) == sorted(pages)
 
 
 def test_fetch_put_host_round_trip_sharded(mesh):
@@ -223,9 +268,16 @@ def test_fetch_put_host_round_trip_sharded(mesh):
 
 
 # ------------------------------------------------- engine: tiered flow
+#: 7 allocatable pages of 4 tokens: a request of these tests holds 4-5
+#: and its retained prefix 3-4, so each admission reclaims (demotes)
+#: the previous template's pages and each revisit promotes them back
+TIGHT = dict(max_len=24, max_pages=8)
+
+
 def _cycle_requests(rstate, templates, rounds, tail=2, decode=4):
-    """Round-robin template traffic: with a 1-row device pool every
-    revisit forces a demote→promote cycle."""
+    """Round-robin template traffic: over a pool with room for one
+    request and one retained template (``TIGHT``) every revisit forces
+    a demote→promote cycle."""
     reqs = []
     for i in range(rounds * len(templates)):
         tpl = templates[i % len(templates)]
@@ -236,9 +288,9 @@ def _cycle_requests(rstate, templates, rounds, tail=2, decode=4):
 
 
 def test_demote_promote_reuse_parity_and_flat_jit(lm):
-    """The tentpole end-to-end: a 1-row device pool under 3-template
-    round-robin traffic demotes on every donation and promotes on
-    every revisit — output stays token-identical to the cache-DISABLED
+    """The tentpole end-to-end: a one-template device pool under
+    3-template round-robin traffic demotes on every admission and
+    promotes on every revisit — output stays token-identical to the cache-DISABLED
     engine and the lone oracle, reuse still lands (prefix_tokens), the
     per-tier counters move, and the compile gauge is flat from the
     first finished request on ('copy:demote'/'copy:promote' are
@@ -261,8 +313,7 @@ def test_demote_promote_reuse_parity_and_flat_jit(lm):
             st = eng.stats()
         return rows, handles, st, jit0
 
-    rows_t, handles, st, jit0 = run(prefix_cache_rows=1,
-                                    prefix_host_rows=8)
+    rows_t, handles, st, jit0 = run(prefix_host_rows=8, **TIGHT)
     rows_d, _, _, _ = run(prefix_cache_bytes=0)
     for (p, n), rt, rd in zip(reqs, rows_t, rows_d):
         want = _direct(lm, p, n)
@@ -309,9 +360,9 @@ def test_memory_pool_attributes_both_tiers(lm):
     r = np.random.RandomState(34)
     tpls = [r.randint(0, 32, (8,)) for _ in range(3)]
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
-                                  prefix_cache_rows=1,
                                   prefix_host_rows=4,
-                                  service_name="tier_mem") as eng:
+                                  service_name="tier_mem",
+                                  **TIGHT) as eng:
         for tpl in tpls:
             eng.submit(np.concatenate([tpl, r.randint(0, 32, (2,))]),
                        3).result(timeout=60)
@@ -331,9 +382,9 @@ def test_ledger_conservation_with_promotions_in_flight(lm):
     tpls = [r.randint(0, 32, (8,)) for _ in range(3)]
     reqs = _cycle_requests(r, tpls, rounds=2)
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
-                                  prefix_cache_rows=1,
                                   prefix_host_rows=8,
-                                  service_name="tier_usage") as eng:
+                                  service_name="tier_usage",
+                                  **TIGHT) as eng:
         for i, (p, n) in enumerate(reqs):
             eng.submit(p, n, tenant=f"t{i % 2}").result(timeout=120)
         usage = eng.stats()["usage"]
@@ -355,9 +406,9 @@ def test_tp_demote_promote_parity_on_mesh(lm_tp, mesh):
     tpls = [r.randint(0, 32, (8,)) for _ in range(3)]
     reqs = _cycle_requests(r, tpls, rounds=2)
     with ContinuousBatchingEngine(lm_tp, max_slots=2, prefill_chunk=4,
-                                  prefix_cache_rows=1,
                                   prefix_host_rows=8, mesh=mesh,
-                                  service_name="tp_tiered") as eng:
+                                  service_name="tp_tiered",
+                                  **TIGHT) as eng:
         first = eng.submit(*reqs[0][:2])
         rows = [first.result(timeout=180)]
         jit0 = eng.stats()["jit_compiles"]
@@ -383,10 +434,10 @@ def test_speculative_decode_with_host_tier_parity(lm):
     tpls = [r.randint(0, 32, (8,)) for _ in range(3)]
     reqs = _cycle_requests(r, tpls, rounds=2, decode=6)
     with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
-                                  prefix_cache_rows=1,
                                   prefix_host_rows=8, draft=draft,
                                   spec_gamma=3,
-                                  service_name="spec_tiered") as eng:
+                                  service_name="spec_tiered",
+                                  **TIGHT) as eng:
         rows = [eng.submit(p, n).result(timeout=180) for p, n in reqs]
         st = eng.stats()
     for (p, n), row in zip(reqs, rows):

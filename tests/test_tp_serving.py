@@ -155,7 +155,7 @@ def test_mesh_stats_and_pool_attribution(lm, mesh):
         assert ms["enabled"] and ms["devices"] == 4
         assert ms["axes"] == {"model": 4}
         assert ms["model_shards"] == 4
-        kv = ms["pools"]["kv_slots"]
+        kv = ms["pools"]["kv_page_pool"]
         # evenly sharded: physical == logical, per-device == 1/4
         assert kv["sharded"]
         assert kv["physical_bytes"] == kv["logical_bytes"]
@@ -164,8 +164,8 @@ def test_mesh_stats_and_pool_attribution(lm, mesh):
         # replicated leaves (layernorms, biases) count once per device
         assert par["physical_bytes"] > par["logical_bytes"]
         sizes = obs_memory.pool_sizes()
-        assert sizes["serving/tp_stats/kv_slots"] == \
-            obs_memory.tree_device_bytes(eng._caches)
+        assert sizes["serving/tp_stats/kv_page_pool"] == \
+            obs_memory.tree_device_bytes(eng._kv_pool)
         assert sizes["serving/tp_stats/params"] == par["physical_bytes"]
     finally:
         eng.stop(drain=False)

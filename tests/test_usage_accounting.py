@@ -79,7 +79,6 @@ def _conserves(summary, rel=1e-3):
 # ------------------------------------------------------- ledger units
 def test_ledger_unit_conservation_and_residency(reg, rec):
     led = UsageLedger(service="unit", registry=reg, recorder=rec,
-                      slot_row_bytes=1000, staging_row_bytes=500,
                       token_bytes=10.0)
     a = led.begin("req-a", "alice", prompt_tokens=8, max_new_tokens=4,
                   submitted_at=0.0)
@@ -102,12 +101,14 @@ def test_ledger_unit_conservation_and_residency(reg, rec):
     assert a.device_prefill_s == pytest.approx(1.5)
     assert b.device_prefill_s == pytest.approx(0.5)
 
-    # staging held 10->12 (500 B x 2 s), slot 12->22 (1000 B x 10 s)
-    led.slot_acquired(a, 12.0)
+    # residency as the engine feeds it: 500 B of pages held 10->12
+    # while prefilling, 1000 B 12->22 while decoding
+    led.accrue_kv(a, 500 * 2.0)
     assert a.kv_byte_seconds == pytest.approx(1000.0)
     led.delivered(a, 1)
     led.charge_dispatch("decode", 1.0, [(a, 1.0)],
                         rows_advanced=1, capacity_rows=2)
+    led.accrue_kv(a, 1000 * 10.0)
     led.finalize(a, "finished", 22.0)
     assert a.kv_byte_seconds == pytest.approx(1000.0 + 10000.0)
     # double-finalize is a no-op (the _finish_handle race contract)
