@@ -14,10 +14,132 @@ when run under shard_map — exposed via ``global_stats_axis``.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from bigdl_tpu.nn.module import Module
+
+
+def _per_channel(v, x, ch_ax):
+    shape = [1] * x.ndim
+    shape[ch_ax] = x.shape[ch_ax]
+    return v.reshape(shape)
+
+
+def _scale_shift(x, mean, var, weight, bias, ch_ax, eps):
+    """``(x - mean) * rsqrt(var + eps) * weight + bias`` folded into one
+    per-channel scale and shift. The statistics are float32 (bf16
+    accumulations drift), but the output stays in the INPUT dtype: a bf16
+    activation must not be promoted to f32 by the f32 running buffers, or
+    every downstream matmul/conv silently runs at f32 and the MXU loses
+    half its rate."""
+    inv = jax.lax.rsqrt(var.astype(jnp.float32) + eps)
+    if weight is not None:
+        scale = weight.astype(jnp.float32) * inv
+        shift = bias.astype(jnp.float32) - mean * scale
+    else:
+        scale = inv
+        shift = -mean * inv
+    return (x * _per_channel(scale, x, ch_ax).astype(x.dtype)
+            + _per_channel(shift, x, ch_ax).astype(x.dtype))
+
+
+def _channel_sums(a, b, ch_ax):
+    """``sum(a)`` and ``sum(a * b)`` per channel from ONE read of the
+    operands: sibling reductions over the same operands compile to one
+    multi-output fusion."""
+    axes = tuple(i for i in range(a.ndim) if i != ch_ax)
+    return jnp.sum(a, axis=axes), jnp.sum(a * b, axis=axes)
+
+
+def _count(x, ch_ax, axis_name):
+    """Elements per channel, over ``axis_name``'s shards too."""
+    n = x.size / x.shape[ch_ax]
+    return n if axis_name is None else n * jax.lax.psum(1, axis_name)
+
+
+def _cotangent_of(primal, ct):
+    """``ct`` typed as ``primal``'s cotangent. Under ``shard_map``'s
+    varying-axes typing a parameter replicated over a mesh axis takes the
+    SUM over that axis of what the shards' rows give it (autodiff does this
+    in the transpose of the implicit broadcast; a custom rule has to).
+    Without the typing (``check_vma=False``) nothing varies by type, a
+    shard hands back its own part and the caller sums, as with autodiff."""
+    extra = tuple(jax.typeof(ct).vma - jax.typeof(primal).vma)
+    return (jax.lax.psum(ct, extra) if extra else ct).astype(primal.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _batch_norm_train(x, weight, bias, pivot, ch_ax, eps, axis_name):
+    """Training-mode batch normalisation as ONE operation: returns
+    ``(y, mean, var)`` with the biased batch variance, the statistics over
+    every axis but ``ch_ax`` (and over ``axis_name``'s shards: sync-BN).
+    ``weight`` / ``bias`` are None without the affine.
+
+    A memory-bound layer costs the passes it makes over the activation, so
+    both directions are written for the fewest: the statistics come from
+    one read of ``x``, and the backward below makes two passes and keeps
+    ``x`` alone, where autodiff of mean / var / rsqrt kept the centred and
+    the normalised copy and read them again.
+
+    One read means ``var = E[(x - c)^2] - E[x - c]^2``, the same variance
+    for ANY per-channel constant ``c`` = ``pivot`` (no gradient flows to
+    it), but in float32 the difference loses the digits that ``E[x - c]^2``
+    has over the variance: with ``c = 0`` a channel whose mean is 100x its
+    spread keeps 2-3 of 7. The caller passes its running mean, known before
+    the read (a pivot taken from ``x`` itself would keep the sums out of
+    the fusion that produces ``x``), so the loss is that of a batch mean's
+    distance from the running mean, not from zero."""
+    return _batch_norm_train_fwd(x, weight, bias, pivot, ch_ax, eps,
+                                 axis_name)[0]
+
+
+def _batch_norm_train_fwd(x, weight, bias, pivot, ch_ax, eps, axis_name):
+    c = pivot.astype(jnp.float32)
+    xs = x.astype(jnp.float32) - _per_channel(c, x, ch_ax)
+    s, ss = _channel_sums(xs, xs, ch_ax)
+    if axis_name is not None:
+        s, ss = jax.lax.psum((s, ss), axis_name)
+    n = _count(x, ch_ax, axis_name)
+    shifted_mean = s / n
+    mean = c + shifted_mean
+    var = jnp.maximum(ss / n - shifted_mean * shifted_mean, 0.0)
+    y = _scale_shift(x, mean, var, weight, bias, ch_ax, eps)
+    return (y, mean, var), (x, weight, bias, mean, jax.lax.rsqrt(var + eps))
+
+
+def _batch_norm_train_bwd(ch_ax, eps, axis_name, res, cts):
+    x, weight, bias, mean, inv = res
+    dy, dmean, dvar = cts
+    dy32 = dy.astype(jnp.float32)
+    xc = x.astype(jnp.float32) - _per_channel(mean, x, ch_ax)
+    # pass 1, one read of dy and x: sum(dy) and sum(dy * (x - mean))
+    db_own, dxc_own = _channel_sums(dy32, xc, ch_ax)
+    db, dxc = db_own, dxc_own
+    if axis_name is not None:
+        db, dxc = jax.lax.psum((db_own, dxc_own), axis_name)
+        # the statistics are sums over the shards: typed, their cotangents
+        # arrive whole; untyped, each shard holds a part (psum's transpose)
+        if axis_name not in jax.typeof(jax.lax.axis_index(axis_name)).vma:
+            dmean, dvar = jax.lax.psum((dmean, dvar), axis_name)
+    n = _count(x, ch_ax, axis_name)
+    scale = inv if weight is None else weight.astype(jnp.float32) * inv
+    # pass 2: dx = scale * (dy - db/n - xc * inv^2 * dxc/n), and what the
+    # mean and var outputs hand back (d mean/dx = 1/n, d var/dx = 2 xc/n)
+    k_xc = (2.0 * dvar - scale * inv * inv * dxc) / n
+    k_1 = (dmean - scale * db) / n
+    dx = _cotangent_of(x, dy32 * _per_channel(scale, x, ch_ax)
+                       + xc * _per_channel(k_xc, x, ch_ax)
+                       + _per_channel(k_1, x, ch_ax))
+    if weight is None:
+        return dx, None, None, None
+    return (dx, _cotangent_of(weight, dxc_own * inv),
+            _cotangent_of(bias, db_own), None)
+
+
+_batch_norm_train.defvjp(_batch_norm_train_fwd, _batch_norm_train_bwd)
 
 
 class BatchNormalization(Module):
@@ -53,52 +175,28 @@ class BatchNormalization(Module):
             ch_ax = x.ndim - 1
         else:
             ch_ax = 1 if x.ndim >= self.n_dim else 0
-        axes = tuple(i for i in range(x.ndim) if i != ch_ax)
-        # statistics in f32 (bf16 accumulations drift), but the normalized
-        # output stays in the INPUT dtype: a bf16 activation must not be
-        # promoted to f32 by the f32 running buffers, or every downstream
-        # matmul/conv silently runs at f32 and the MXU loses half its rate
-        x32 = x if x.dtype == jnp.float32 else x.astype(jnp.float32)
-        if self.training:
-            mean = jnp.mean(x32, axis=axes)
-            var = jnp.var(x32, axis=axes)
-            n = x.size / x.shape[ch_ax]
-            if self.global_stats_axis is not None:
-                # global var needs the variance OF the per-shard means too:
-                # var = E[x^2] - E[x]^2 across the whole global batch
-                mean_g = jax.lax.pmean(mean, self.global_stats_axis)
-                var = jax.lax.pmean(var + mean ** 2, self.global_stats_axis) - mean_g ** 2
-                mean = mean_g
-                n = n * jax.lax.psum(1, self.global_stats_axis)
-                unbiased = var * n / jnp.maximum(1.0, n - 1.0)
-            else:
-                unbiased = var * n / max(1.0, n - 1)
-            # keep the buffer dtype stable (f32 stats must not flip a bf16
-            # buffer to f32 mid-training — that would retrace the jitted step)
-            self._set_buffer(
-                "running_mean",
-                ((1 - self.momentum) * self.running_mean
-                 + self.momentum * mean).astype(self.running_mean.dtype),
-            )
-            self._set_buffer(
-                "running_var",
-                ((1 - self.momentum) * self.running_var
-                 + self.momentum * unbiased).astype(self.running_var.dtype),
-            )
-        else:
-            mean, var = self.running_mean, self.running_var
-        # fold everything into one per-channel scale/shift applied in x.dtype
-        inv = jax.lax.rsqrt(var.astype(jnp.float32) + self.eps)
-        if self.affine:
-            scale = self.weight.astype(jnp.float32) * inv
-            shift = self.bias.astype(jnp.float32) - mean * scale
-        else:
-            scale = inv
-            shift = -mean * inv
-        shape = [1] * x.ndim
-        shape[ch_ax] = x.shape[ch_ax]
-        return (x * scale.reshape(shape).astype(x.dtype)
-                + shift.reshape(shape).astype(x.dtype))
+        weight, bias = (self.weight, self.bias) if self.affine else (None, None)
+        if not self.training:
+            return _scale_shift(x, self.running_mean, self.running_var,
+                                weight, bias, ch_ax, self.eps)
+        axis = self.global_stats_axis
+        y, mean, var = _batch_norm_train(x, weight, bias, self.running_mean,
+                                         ch_ax, self.eps, axis)
+        n = _count(x, ch_ax, axis)
+        unbiased = var * n / max(1.0, n - 1)
+        # keep the buffer dtype stable (f32 stats must not flip a bf16
+        # buffer to f32 mid-training — that would retrace the jitted step)
+        self._set_buffer(
+            "running_mean",
+            ((1 - self.momentum) * self.running_mean
+             + self.momentum * mean).astype(self.running_mean.dtype),
+        )
+        self._set_buffer(
+            "running_var",
+            ((1 - self.momentum) * self.running_var
+             + self.momentum * unbiased).astype(self.running_var.dtype),
+        )
+        return y
 
     def _extra_repr(self):
         return f"({self.n_output}, eps={self.eps}, momentum={self.momentum})"

@@ -294,3 +294,61 @@ def test_data_parallel_sharded_step_compiles_on_four_chips(topo):
     assert any(c in text for c in ("all-reduce", "reduce-scatter",
                                    "all-gather"))
     _fits(compiled)
+
+
+# ------------------------------------------------------ BatchNorm's passes
+def _stage1_bottleneck(bn):
+    """One identity bottleneck of ResNet-50's first stage (64/64/256
+    channels over 56 x 56, NHWC) as ``models/resnet`` builds it, its
+    BatchNorm from ``bn``."""
+    from bigdl_tpu import nn
+
+    def conv(n_in, n_out, k):
+        return nn.SpatialConvolution(n_in, n_out, k, k, 1, 1, k // 2, k // 2,
+                                     format="NHWC")
+
+    main = nn.Sequential()
+    for n_in, n_out, k in ((256, 64, 1), (64, 64, 3), (64, 256, 1)):
+        main.add(conv(n_in, n_out, k)).add(bn(n_out, 1e-3, format="NHWC"))
+        if n_out == 64:
+            main.add(nn.ReLU())
+    return (nn.Sequential()
+            .add(nn.ConcatTable().add(main).add(nn.Identity()))
+            .add(nn.CAddTable()).add(nn.ReLU())).training_mode()
+
+
+def test_batchnorm_training_pass_moves_fewer_bytes(topo, one_chip):
+    """Forward and backward of a first-stage bottleneck at the training
+    cell's size (256 x 56 x 56, float32): with the one-read statistics and
+    the two-pass backward of ``nn/normalization.py`` XLA counts at least a
+    tenth fewer bytes than with BatchNorm as autodiff of ``jnp.mean`` and
+    ``jnp.var`` (tests/two_read_batchnorm.py). The ResNet-50 step is bound
+    by the bytes it moves (PERF.md, PR 32): an edit to the pass that reads
+    the activation once more shows here, in seconds, without the network."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.nn.module import pure_apply
+    from two_read_batchnorm import TwoReadSpatialBatchNormalization
+
+    x = jax.ShapeDtypeStruct((256, 56, 56, 256), jnp.float32,
+                             sharding=one_chip)
+
+    def bytes_accessed(bn):
+        block = _stage1_bottleneck(bn)
+        apply_fn = pure_apply(block)
+
+        def loss(params, buffers, x):
+            y, new_buffers = apply_fn(params, buffers, x, training=True)
+            return jnp.mean(y * y), new_buffers
+
+        compiled = jax.jit(jax.value_and_grad(
+            loss, (0, 2), has_aux=True)).lower(
+                _abstract(block.params_dict(), one_chip),
+                _abstract(block.buffers_dict(), one_chip), x).compile()
+        _fits(compiled)
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        return cost["bytes accessed"]
+
+    one_read = bytes_accessed(nn.SpatialBatchNormalization)
+    two_read = bytes_accessed(TwoReadSpatialBatchNormalization)
+    assert one_read <= 0.9 * two_read, (one_read, two_read)
