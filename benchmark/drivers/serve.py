@@ -13,23 +13,22 @@ import numpy as np
 
 from benchmark import compare, harness, loadgen
 
-# memory_analysis of the compile rehearsal on a v5e (PERF.md, PR 23): the
-# pool's (pages, heads, 16, 64) bf16 leaves take 9/8 of their logical bytes
-POOL_DEVICE_FACTOR = 1.125
 
-
-def pool_pages(config, limit_bytes, weight_bytes):
+def pool_pages(config, geometry, limit_bytes, weight_bytes):
     """Pages for the KV pool, sized at run time as chip_smoke.py does: 90 %
-    of the device's memory, less the weights and a reserve for the programs'
-    scratch, over a page's device bytes."""
-    e, z = config["engine"], config["sizes"]
-    table_len = -(-int(z["n_positions"]) // int(e["page_size"]))
-    floor = 1 + int(e["max_slots"]) * table_len   # every lane at full context
+    of the device's memory, less the weights, a reserve for the programs'
+    scratch and the state a lane holds whatever its length, over a page's
+    device bytes. ``geometry`` is the adapter's ``cache_geometry(config)``:
+    the architecture's own arithmetic (which layers hold pages, how wide)."""
+    e = config["engine"]
+    slots = int(e["max_slots"])
+    table_len = -(-int(geometry["max_positions"]) // int(e["page_size"]))
+    floor = 1 + slots * table_len             # every lane at full context
     if not limit_bytes:      # a device that states no limit (the CPU of a test)
         return floor
-    page = int(z["n_layer"]) * 2 * int(e["page_size"]) * int(z["n_embd"]) * 2
-    budget = int(limit_bytes * 0.9) - weight_bytes - int(e["reserve_bytes"])
-    return max(floor, int(budget // (page * POOL_DEVICE_FACTOR)))
+    budget = (int(limit_bytes * 0.9) - weight_bytes - int(e["reserve_bytes"])
+              - slots * int(geometry["fixed_device_bytes_per_lane"]))
+    return max(floor, int(budget // geometry["page_device_bytes"]))
 
 
 class Client:
@@ -144,11 +143,11 @@ def check_sample(measured, seed, k):
     return [longest] + [rest[i] for i in pick]
 
 
-def sample_rows(config, sample):
-    """The sampled requests as one (n, n_positions) array of prompt then
-    served tokens (zeros behind), with each row's (prompt, total) lengths."""
-    rows = np.zeros((len(sample), int(config["sizes"]["n_positions"])),
-                    np.int32)
+def sample_rows(sample, width):
+    """The sampled requests as one (n, width) array of prompt then served
+    tokens (zeros behind), with each row's (prompt, total) lengths.
+    ``width`` is the geometry's ``max_positions``: one fixed shape a cell."""
+    rows = np.zeros((len(sample), int(width)), np.int32)
     spans = []
     for i, c in enumerate(sample):
         row = np.concatenate([c.req["prompt"], np.asarray(c.tokens, np.int32)])
@@ -159,19 +158,16 @@ def sample_rows(config, sample):
 
 def reference_logits(config, seed, rows, weights_map=None):
     """The plain reference, once over ``rows`` (one fixed shape): float32
-    logits on the host. ``weights_map`` (the precision control, by hand or in
-    a test) rounds the seed's weights first."""
-    import jax.numpy as jnp
-
-    from benchmark import weights as bw
-
+    logits on the host, from the seed's weights as the adapter makes them
+    (the tree its ``build`` loads into the program). ``weights_map`` (the
+    precision control, by hand or in a test) rounds them first."""
+    adapter = importlib.import_module("benchmark.models." + config["adapter"])
     ref = importlib.import_module(
         "benchmark.reference." + config["reference"])
-    z = config["sizes"]
-    w = bw.gpt2_weights(seed, z, jnp.dtype(config["assumed"]["weights_dtype"]))
+    w = adapter.weights(config, seed)
     if weights_map is not None:
         w = weights_map(w)
-    return np.asarray(ref.forward(w, rows, int(z["n_head"])))
+    return np.asarray(ref.forward(w, rows, config))
 
 
 def start_engine(cell, seed):
@@ -187,7 +183,9 @@ def start_engine(cell, seed):
     model = adapter.build(config, seed)
     limit = (devs[0].memory_stats() or {}).get("bytes_limit")
     harness.log(f"[serve] model built: {devs[0].memory_stats()}")
-    max_pages = pool_pages(config, limit, adapter.weight_bytes(model))
+    geometry = adapter.cache_geometry(config)
+    max_pages = pool_pages(config, geometry, limit,
+                           adapter.weight_bytes(model))
     e = config["engine"]
     kwargs = dict(max_slots=int(e["max_slots"]),
                   prefill_chunk=int(e["prefill_chunk"]),
@@ -204,7 +202,8 @@ def start_engine(cell, seed):
         raise
     harness.log(f"[serve] warm: {compiles.summary()}, max_pages {max_pages}")
     return {"engine": engine, "devs": devs, "adapter": adapter,
-            "compiles": compiles, "max_pages": max_pages}
+            "geometry": geometry, "compiles": compiles,
+            "max_pages": max_pages}
 
 
 def drive_window(ctx, mix, reqs, seconds, trace=False, trace_dir=None):
@@ -266,6 +265,7 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
     harness.say(loadgen.describe_requests(reqs, seconds))
     ctx = start_engine(cell, seed)
     engine, adapter = ctx["engine"], ctx["adapter"]
+    width = ctx["geometry"]["max_positions"]
     try:
         w = drive_window(ctx, mix, reqs, seconds, trace, trace_dir)
         setup_s = w["opened"] - t_start
@@ -301,7 +301,7 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
         engine.stop()
     sample = check_sample(measured, seed,
                           int(config["check"]["sample_requests"]))
-    rows, spans = sample_rows(config, sample)
+    rows, spans = sample_rows(sample, width)
     # the engine's pool goes; the model it served stays for one more pass:
     # its own paged-prefill logits over the sampled rows
     served_model, kv_dtype = engine.model, engine.kv_dtype

@@ -17,19 +17,40 @@ from benchmark import compare, harness, loadgen
 CHECK_STEPS = 3
 
 
-class RowLog:
-    """Pass-through stage of the input pipeline that notes which records the
-    loop was fed, in order, for the first steps (nothing after that)."""
+class FeedLog:
+    """Pass-through stage of the input pipeline that notes which of ``items``
+    (records, or a pre-built mix's whole batches) the loop was fed, in order,
+    for the first steps (nothing after that)."""
 
-    def __init__(self, records, keep):
-        self._index = {id(r): i for i, r in enumerate(records)}
+    def __init__(self, items, keep):
+        self._index = {id(r): i for i, r in enumerate(items)}
         self.keep, self.rows = keep, []
 
     def __call__(self, it):
-        for rec in it:
+        for item in it:
             if len(self.rows) < self.keep:
-                self.rows.append(self._index[id(rec)])
-            yield rec
+                self.rows.append(self._index[id(item)])
+            yield item
+
+
+def feed(mix, x, y, batch):
+    """What the optimizer's dataset holds, and how the first steps' batches
+    are read back from the log of what it was fed. ``"prebuilt": true``: the
+    mix's whole batches as ``MiniBatch``es of host arrays, made once here
+    (the reference perf harness's way: no stacking on the timed path);
+    otherwise one ``Sample`` a record, batched by the optimizer's own
+    ``SampleToMiniBatch`` on its producer thread."""
+    if mix.get("prebuilt"):
+        from bigdl_tpu.dataset.minibatch import MiniBatch
+
+        pairs = loadgen.whole_batches(x, y, batch)
+        items = [MiniBatch(xb, yb.reshape(-1, 1)) for xb, yb in pairs]
+        return items, CHECK_STEPS, lambda fed: [pairs[i] for i in fed]
+    from bigdl_tpu.dataset.sample import Sample
+
+    items = [Sample(x[i], y[i:i + 1]) for i in range(x.shape[0])]
+    return items, CHECK_STEPS * batch, lambda fed: [
+        (x[idx], y[idx]) for idx in np.asarray(fed).reshape(CHECK_STEPS, batch)]
 
 
 class Stamps:
@@ -148,7 +169,6 @@ def reference_steps(config, seed, batches, shards, adapter, reference,
 
 def run(cell, seed, seconds, trace, t_start, trace_dir=None):
     from bigdl_tpu.dataset.dataset import DataSet
-    from bigdl_tpu.dataset.sample import Sample
     config, mix = cell["config_json"], cell["traffic_json"]
     chips = cell["chips"]
     devs = harness.require_chips(chips)
@@ -160,8 +180,8 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
     harness.say(loadgen.describe_dataset(mix, x, chips))
     batch = int(mix["batch_per_chip"]) * chips
     warmup = int(mix["warmup_iterations"])
-    records = [Sample(x[i], y[i:i + 1]) for i in range(x.shape[0])]
-    rows = RowLog(records, CHECK_STEPS * batch)
+    items, keep, first_batches = feed(mix, x, y, batch)
+    fed = FeedLog(items, keep)
 
     model = adapter.build(config, seed)
     stamps = Stamps()
@@ -181,7 +201,7 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
 
     window = Window(stamps, warmup, seconds, model, open_window)
     opt = build_optimizer(config, mix, model,
-                          DataSet.array(records).transform(rows), chips,
+                          DataSet.array(items).transform(fed), chips,
                           as_trigger(window))
     opt.set_train_summary(stamps)
     opt.set_validation(as_trigger(window.wants_params), None, [])
@@ -214,8 +234,7 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
     # the reference needs the device's memory: the program's state goes
     del opt, model, window.model
     gc.collect()
-    order = np.asarray(rows.rows).reshape(CHECK_STEPS, batch)
-    batches = [(x[idx], y[idx]) for idx in order]
+    batches = first_batches(fed.rows)
     ref = reference_steps(config, seed, batches, chips, adapter, reference)
     check_rows = compare.training_rows(prog, ref, compiles_in_window,
                                        config["check"]["limits"])

@@ -114,10 +114,19 @@ def synthetic_dataset(mix, seed):
     return x, y
 
 
+def whole_batches(x, y, batch):
+    """The dataset's records in their own order as whole batches
+    ``[(images, labels), ...]``: views of ``x`` and ``y``, nothing copied
+    (what a mix with ``"prebuilt": true`` feeds; a remainder is left out)."""
+    return [(x[i:i + batch], y[i:i + batch])
+            for i in range(0, x.shape[0] - batch + 1, batch)]
+
+
 def describe_dataset(mix, x, chips):
     return {"traffic": "synthetic_dataset", "samples": int(x.shape[0]),
             "image": list(x.shape[1:]), "classes": int(mix["classes"]),
             "batch": int(mix["batch_per_chip"]) * chips, "chips": chips,
+            "prebuilt": bool(mix.get("prebuilt", False)),
             "batches_per_epoch": int(x.shape[0])
             // (int(mix["batch_per_chip"]) * chips),
             "host_bytes": int(x.nbytes)}

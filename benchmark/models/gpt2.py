@@ -10,6 +10,28 @@ from benchmark import weights as bw
 
 REFERENCE = "gpt2"
 
+# memory_analysis of the compile rehearsal on a v5e (PERF.md, PR 23): the
+# pool's (pages, heads, 16, 64) bf16 leaves take 9/8 of their logical bytes
+POOL_DEVICE_FACTOR = 1.125
+
+
+def weights(config, seed):
+    """The benchmark's seeded weights in its own layout: the tree ``build``
+    loads into the program and the plain reference reads."""
+    return bw.gpt2_weights(seed, config["sizes"],
+                           jnp.dtype(config["assumed"]["weights_dtype"]))
+
+
+def cache_geometry(config):
+    """What the driver sizes the page pool from: every layer holds K and V
+    pages of the model's width in bfloat16, and a lane holds nothing but
+    pages."""
+    z, e = config["sizes"], config["engine"]
+    page = int(z["n_layer"]) * 2 * int(e["page_size"]) * int(z["n_embd"]) * 2
+    return {"max_positions": int(z["n_positions"]),
+            "page_device_bytes": page * POOL_DEVICE_FACTOR,
+            "fixed_device_bytes_per_lane": 0}
+
 
 def program_tree(w):
     """The benchmark's layout -> ``TransformerLM.params_dict()``'s."""
@@ -36,8 +58,7 @@ def build(config, seed):
                           num_layers=int(z["n_layer"]),
                           max_len=int(z["n_positions"]))
     model.evaluate()
-    dtype = jnp.dtype(config["assumed"]["weights_dtype"])
-    tree = program_tree(bw.gpt2_weights(seed, z, dtype))
+    tree = program_tree(weights(config, seed))
     have = jax.tree.structure(model.params_dict())
     if jax.tree.structure(tree) != have:
         raise ValueError("TransformerLM's parameter tree is not the one "

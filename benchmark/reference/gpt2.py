@@ -51,13 +51,15 @@ def as_float32(weights):
     return jax.tree.map(lambda a: a.astype(jnp.float32), weights)
 
 
-def forward(weights, ids, heads, block_fn=_block):
+def forward(weights, ids, config):
     """(batch, time) int ids -> (batch, time, vocab) float32 logits.
     ``weights`` in the layout of ``benchmark.weights.gpt2_weights``, any
-    floating type: they are read as float32 values."""
+    floating type: they are read as float32 values. Of ``config`` (the
+    configuration file) only the number of heads is read."""
+    heads = int(config["sizes"]["n_head"])
     ids = jnp.asarray(ids, jnp.int32)
     w = as_float32({k: v for k, v in weights.items() if k != "blocks"})
     x = jnp.take(w["wte"], ids, axis=0) + w["wpe"][None, :ids.shape[1]]
     for blk in weights["blocks"]:
-        x = block_fn(x, as_float32(blk), heads)
+        x = _block(x, as_float32(blk), heads)
     return _head(x, w["lnf_g"], w["lnf_b"], w["wte"])

@@ -18,6 +18,7 @@ the sound runs' largest and each control's smallest.
 """
 import argparse
 import gc
+import importlib
 import json
 import os
 import sys
@@ -39,8 +40,6 @@ def rows_of(result, key="checks"):
 def control_only(cell, driver, seeds):
     """The training control without the program: the plain reference over
     the first steps of the cell's own batches, in float32 and in bfloat16."""
-    import importlib
-
     import jax.numpy as jnp
 
     from benchmark import loadgen
@@ -71,11 +70,11 @@ def serving_controls(cell, seed, compared):
     import jax
 
     from benchmark import compare, weights
-    from benchmark.models import gpt2
     from bigdl_tpu.nn.quantized import Quantizer
 
     serve = harness.load_module("drivers", "serve")
     config = cell["config_json"]
+    adapter = importlib.import_module("benchmark.models." + config["adapter"])
     rows, spans = compared["rows"], compared["spans"]
     ref = compared["reference_logits"]
 
@@ -91,14 +90,14 @@ def serving_controls(cell, seed, compared):
     for name, fn in (("reference_int8", weights.rounded),
                      ("reference_fp8", weights.rounded_fp8)):
         out[name] = numbers(serve.reference_logits(config, seed, rows, fn))
-    model = gpt2.build(config, seed)
+    model = adapter.build(config, seed)
     out["program_kv_int8"] = numbers(
-        gpt2.paged_logits(model, "int8", config, rows))
+        adapter.paged_logits(model, "int8", config, rows))
     quantized = Quantizer.quantize(model)
     del model
     gc.collect()
     out["program_int8"] = numbers(
-        gpt2.paged_logits(quantized, "int8", config, rows))
+        adapter.paged_logits(quantized, "int8", config, rows))
     del quantized
     gc.collect()
     jax.clear_caches()
@@ -112,11 +111,11 @@ def own_logits_on_random_rows(cell, seeds):
     the context), against the reference. Served rows read the same number
     (PERF.md sets the two side by side)."""
     from benchmark import compare
-    from benchmark.models import gpt2
 
     serve = harness.load_module("drivers", "serve")
     config = cell["config_json"]
-    t = int(config["sizes"]["n_positions"])
+    adapter = importlib.import_module("benchmark.models." + config["adapter"])
+    t = int(adapter.cache_geometry(config)["max_positions"])
     for seed in seeds:
         rng = np.random.RandomState(seed % (2 ** 32))
         rows = np.zeros((int(config["check"]["sample_requests"]), t), np.int32)
@@ -125,8 +124,8 @@ def own_logits_on_random_rows(cell, seeds):
             n = int(rng.randint(min(256, t // 2), t + 1))
             row[:n] = rng.randint(0, int(config["assumed"]["vocab_real"]), n)
             spans.append((n // 2, n))
-        model = gpt2.build(config, seed)
-        own = gpt2.paged_logits(model, None, config, rows)
+        model = adapter.build(config, seed)
+        own = adapter.paged_logits(model, None, config, rows)
         del model
         gc.collect()
         ref = serve.reference_logits(config, seed, rows)

@@ -472,10 +472,15 @@ class TinyNet:
         return losses, after
 
 
-def tiny_training_cell():
+def tiny_training_cell(prebuilt=True):
     sys.modules["benchmark.models.tinynet"] = TinyNet
     sys.modules["benchmark.reference.tinynet"] = TinyNet
     cell = harness.load_cell("resnet50-local-b256")
+    # the cell's mix feeds pre-built batches; the record path (one Sample a
+    # record, stacked by the optimizer's producer thread) stays in the
+    # generator for the input-pipeline cell of PERF.md section 7
+    assert cell["traffic_json"]["prebuilt"] is True
+    cell["traffic_json"]["prebuilt"] = prebuilt
     cell["config_json"].update(adapter="tinynet", reference="tinynet")
     cell["config_json"]["sizes"].update(classes=5, image=8)
     cell["config_json"]["optimizer"]["recipe"].update(
@@ -487,13 +492,89 @@ def tiny_training_cell():
     return cell
 
 
-def test_training_rehearsal_is_correct_and_bfloat16_fails(no_chip_needed):
+@pytest.mark.parametrize("seed", [0, 7, 2 ** 31 + 5])
+def test_prebuilt_mix_holds_the_seeded_records_in_their_own_order(seed):
+    """What a ``"prebuilt": true`` mix feeds: the arrays ``synthetic_dataset``
+    draws from the seed, cut into whole batches in their own order, each
+    bit-equal to what the program's stacker makes of the same records."""
+    from bigdl_tpu.dataset.minibatch import MiniBatch
+    from bigdl_tpu.dataset.sample import Sample
+
+    train = harness.load_module("drivers", "train")
+    mix = dict(tiny_training_cell()["traffic_json"], samples=18)
+    x, y = loadgen.synthetic_dataset(mix, seed)
+    pairs = loadgen.whole_batches(x, y, 4)
+    assert len(pairs) == 4                       # the remainder is left out
+    assert np.array_equal(np.concatenate([p[0] for p in pairs]), x[:16])
+    assert np.array_equal(np.concatenate([p[1] for p in pairs]), y[:16])
+    assert all(np.shares_memory(p[0], x) for p in pairs)   # nothing copied
+    items, keep, first_batches = train.feed(mix, x, y, 4)
+    assert keep == train.CHECK_STEPS and len(items) == 4
+    for k, item in enumerate(items):
+        stacked = MiniBatch.from_samples(
+            [Sample(x[i], y[i:i + 1]) for i in range(4 * k, 4 * k + 4)])
+        assert item.size() == 4
+        assert np.array_equal(item.get_input(), stacked.get_input())
+        assert np.array_equal(item.get_target(), stacked.get_target())
+        assert item.get_target().shape == stacked.get_target().shape
+    got = first_batches([2, 0, 3])
+    assert [np.array_equal(got[j][0], pairs[k][0])
+            for j, k in enumerate((2, 0, 3))] == [True] * 3
+    # the record path: one Sample a record, the first steps' rows by index
+    items, keep, first_batches = train.feed(dict(mix, prebuilt=False), x, y, 4)
+    assert keep == 3 * 4 and len(items) == 18
+    idx = [5, 1, 17, 2, 0, 3, 4, 6, 9, 8, 7, 10]
+    got = first_batches(idx)
+    assert np.array_equal(got[1][0], x[[0, 3, 4, 6]])
+    assert np.array_equal(got[2][1], y[[9, 8, 7, 10]])
+
+
+def test_the_loop_is_fed_three_different_batches_of_the_cells_four():
+    """The check's steps want rows that all differ. The program's dataset
+    walks the four pre-built batches in an order of its own (a drawn offset,
+    a reshuffle after every pass over its four items): pinned here, so that
+    a change of that order which repeats a batch inside the first steps
+    fails on the CPU and not as a weaker check on the chip."""
+    from bigdl_tpu import nn
+    from bigdl_tpu.dataset.dataset import DataSet
+    from bigdl_tpu.optim import LocalOptimizer, Trigger
+
+    train = harness.load_module("drivers", "train")
+    mix = dict(tiny_training_cell()["traffic_json"], samples=16)
+    x, y = loadgen.synthetic_dataset(mix, 3)
+    items, keep, _ = train.feed(mix, x, y, 4)
+    fed = train.FeedLog(items, keep)
+    opt = LocalOptimizer(model=nn.Sequential(nn.Linear(2, 2)),
+                         dataset=DataSet.array(items).transform(fed),
+                         criterion=nn.MSECriterion(), batch_size=4,
+                         end_when=Trigger.max_iteration(1))
+    stream = opt._batch_stream()
+    seen = [next(stream) for _ in range(8)]
+    assert len(fed.rows) == train.CHECK_STEPS == 3
+    assert len(set(fed.rows)) == 3, fed.rows
+    assert [s is items[i] for s, i in zip(seen, fed.rows)] == [True] * 3
+    assert all(s.size() == 4 for s in seen)
+
+
+@pytest.mark.parametrize("prebuilt", [True, False])
+def test_training_rehearsal_is_correct_and_bfloat16_fails(no_chip_needed,
+                                                          prebuilt):
     import jax.numpy as jnp
 
     train = harness.load_module("drivers", "train")
-    cell = tiny_training_cell()
+    cell = tiny_training_cell(prebuilt)
     out = train.run(cell, 2 ** 31 + 3, 1.0, False, time.perf_counter())
     assert out["correct"] is True, out["checks"]
+    # the reference followed the batches the loop was fed: whole batches of
+    # the seeded arrays in their own order, or rows of a shuffled draw
+    x, y = loadgen.synthetic_dataset(cell["traffic_json"], 2 ** 31 + 3)
+    whole = loadgen.whole_batches(x, y, 4)
+    fed = out["compared"]["batches"]
+    assert len(fed) == 3 and all(b[0].shape == (4, 8, 8, 3) for b in fed)
+    assert all(any(np.array_equal(b[0], w[0]) and np.array_equal(b[1], w[1])
+                   for w in whole) for b in fed) is prebuilt
+    assert not any(np.array_equal(a[0], b[0])
+                   for i, a in enumerate(fed) for b in fed[i + 1:])
     assert out["attempted"] > 0 and out["failed"] == 0
     assert set(out["values"]) == {"train_samples_per_s", "setup_s"}
     assert out["device"]["platform"] == "cpu"
@@ -527,15 +608,13 @@ def test_training_run_with_a_step_that_leaves_its_state_unchanged(
 def test_gpt2_reference_agrees_with_the_program_at_a_tiny_size():
     import jax.numpy as jnp
 
-    from benchmark import weights as bw
     from benchmark.models import gpt2
     from benchmark.reference import gpt2 as ref
 
     cfg = tiny_serving_cell()["config_json"]
     model = gpt2.build(cfg, 3)
     ids = np.random.RandomState(0).randint(0, 250, (2, 40))
-    want = np.asarray(ref.forward(
-        bw.gpt2_weights(3, cfg["sizes"], jnp.float32), ids, 4))
+    want = np.asarray(ref.forward(gpt2.weights(cfg, 3), ids, cfg))
     got = np.asarray(model(jnp.asarray(ids)))
     assert np.abs(got - want).max() < 1e-5 * np.abs(want).max()
     gaps = compare.served_token_gaps(

@@ -234,7 +234,6 @@ def test_training_metrics_on_hand_built_spans(hand_built):
     assert read("train_dispatch_ms", run, summary) == pytest.approx(1.0)
     # bookkeeping 4.0 plus the 0.5 ms of the iteration no child names
     assert read("train_bookkeeping_ms", run, summary) == pytest.approx(4.5)
-    assert read("input_batch_build_ms", run, summary) == pytest.approx(170.0)
     assert read("train_gc_pause_max_ms", run, summary) == pytest.approx(2.5)
     assert read("idle_named_pct.train", run, summary) == pytest.approx(100.0)
     t = program_spans.training(run, summary)
@@ -319,7 +318,7 @@ def test_serving_metrics_and_closure_on_hand_built_spans(hand_built, capfd):
 
 @pytest.mark.parametrize("name", [
     "train_data_wait_ms", "train_arguments_ms", "train_dispatch_ms",
-    "train_bookkeeping_ms", "input_batch_build_ms", "train_gc_pause_max_ms", "idle_named_pct.train",
+    "train_bookkeeping_ms", "train_gc_pause_max_ms", "idle_named_pct.train",
     "loop_deliver_ms", "loop_observe_ms"])
 def test_new_metric_is_in_the_manifest_and_reads_nothing_from_nothing(
         name, hand_built):
