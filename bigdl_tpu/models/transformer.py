@@ -282,6 +282,11 @@ class TransformerLM(Module):
         dimension tensor-parallel serving shards the KV pools along."""
         return self.block0.attn.num_kv_heads
 
+    def kv_token_elems(self) -> int:
+        """K and V elements one cached token holds over every layer."""
+        return (2 * self.num_layers * self.num_kv_heads
+                * self.block0.attn.head_dim)
+
     def kv_page_pool_sharding(self, mesh, model_axis: str = "model"):
         """NamedSharding for this model's ``init_page_pool`` buffers on
         a tensor-parallel ``mesh``: leaves ``(max_pages, page_size,

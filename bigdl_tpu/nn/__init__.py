@@ -5,7 +5,7 @@ TPU-native re-design of the reference's ``com.intel.analytics.bigdl.nn``
 traces into pure XLA programs via ``bigdl_tpu.nn.module.pure_apply``.
 """
 
-from bigdl_tpu.nn.module import Module, pure_apply, bind
+from bigdl_tpu.nn.module import Module, pure_apply, bind, abstract_init
 from bigdl_tpu.nn import init
 from bigdl_tpu.nn.container import (
     Container, Sequential, Concat, ConcatTable, ParallelTable, MapTable, Bottle,
@@ -60,8 +60,10 @@ from bigdl_tpu.nn.recurrent import (
     Recurrent, BiRecurrent, RecurrentDecoder, TimeDistributed,
 )
 from bigdl_tpu.nn.attention import (
-    LayerNorm, MultiHeadAttention, TransformerBlock, dot_product_attention,
+    LayerNorm, MultiHeadAttention, RMSNorm, TransformerBlock,
+    dot_product_attention,
 )
+from bigdl_tpu.nn.gated_delta import GatedDeltaNet, GatedMLP
 from bigdl_tpu.nn.criterion import (
     Criterion, ClassNLLCriterion, CrossEntropyCriterion, CategoricalCrossEntropy,
     MSECriterion, AbsCriterion, BCECriterion, SmoothL1Criterion,

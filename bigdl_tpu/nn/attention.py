@@ -362,6 +362,24 @@ def rotary_embedding_rowwise(x, positions, base: float = 10000.0):
         lambda xi, pi: rotary_embedding(xi, pi, base))(x, positions)
 
 
+class RMSNorm(Module):
+    """Root-mean-square normalization over the last dim with a learned
+    gain and no bias or mean subtraction (Zhang & Sennrich 2019): the
+    statistic in float32, the result in the input's dtype."""
+
+    def __init__(self, n_output: int, eps: float = 1e-6):
+        super().__init__()
+        self.n_output = n_output
+        self.eps = eps
+        self.register_parameter("weight", jnp.ones((n_output,)))
+
+    def forward(self, input):
+        x = input.astype(jnp.float32)
+        y = x * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
+        return (y * self.weight.astype(jnp.float32)).astype(input.dtype)
+
+
 class MultiHeadAttention(Module):
     """Fused-QKV multi-head self/cross attention.
 
@@ -371,14 +389,20 @@ class MultiHeadAttention(Module):
 
     ``rotary=True`` applies RoPE to q/k after the projection (no learned
     positional table needed upstream); composes with GQA, flash, ring
-    attention, and the KV cache (the cache stores rotated keys)."""
+    attention, and the KV cache (the cache stores rotated keys).
+
+    ``qk_norm=True`` normalizes the WHOLE q projection and the whole k
+    projection with an ``RMSNorm`` each before the heads are split (and
+    before any rotation): every path, cached and paged, splits through
+    ``_split_kv_step``, so the cache holds normalized keys."""
 
     def __init__(self, embed_dim: int, num_heads: int, dropout: float = 0.0,
                  with_bias: bool = True, causal: bool = False,
                  sequence_parallel: Optional[str] = None,
                  use_flash: bool = False,
                  num_kv_heads: Optional[int] = None,
-                 rotary: bool = False, rotary_base: float = 10000.0):
+                 rotary: bool = False, rotary_base: float = 10000.0,
+                 qk_norm: bool = False, norm_eps: float = 1e-6):
         super().__init__()
         assert embed_dim % num_heads == 0
         if rotary and (embed_dim // num_heads) % 2:
@@ -408,6 +432,10 @@ class MultiHeadAttention(Module):
         self.qkv = Linear(embed_dim, embed_dim + 2 * kv_dim,
                           with_bias=with_bias)
         self.out_proj = Linear(embed_dim, embed_dim, with_bias=with_bias)
+        self.qk_norm = qk_norm
+        if qk_norm:
+            self.q_norm = RMSNorm(embed_dim, norm_eps)
+            self.k_norm = RMSNorm(kv_dim, norm_eps)
         if dropout > 0:
             self.drop = Dropout(dropout)
 
@@ -447,9 +475,12 @@ class MultiHeadAttention(Module):
 
     def _split_kv_step(self, qkv):
         kv_dim = self.num_kv_heads * self.head_dim
-        q = self._split_heads(qkv[..., :self.embed_dim])
-        k = self._split_heads(qkv[..., self.embed_dim:self.embed_dim + kv_dim],
-                              self.num_kv_heads)
+        q = qkv[..., :self.embed_dim]
+        k = qkv[..., self.embed_dim:self.embed_dim + kv_dim]
+        if self.qk_norm:
+            q, k = self.q_norm(q), self.k_norm(k)
+        q = self._split_heads(q)
+        k = self._split_heads(k, self.num_kv_heads)
         v = self._split_heads(qkv[..., self.embed_dim + kv_dim:],
                               self.num_kv_heads)
         return q, k, v

@@ -22,7 +22,9 @@ JAX-native:
 
 State model: each Module owns
   _parameters  — trainable jnp arrays (leaves of the grad pytree)
-  _gradients   — accumulated gradients, same keys (eager API parity)
+  _gradients   — accumulated gradients, same keys (eager API parity);
+                 None until the training path first touches one, so a
+                 model built to serve holds its weights and nothing more
   _buffers     — non-trainable state (BN running stats, …)
   _modules     — child modules (ordered; auto-registered on attribute set)
 """
@@ -54,6 +56,47 @@ _PURE_BIND_DEPTH = 0
 import weakref  # noqa: E402
 
 _VJP_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+#: >0 while constructors run under :func:`abstract_init`
+_ABSTRACT_INIT_DEPTH = 0
+
+
+def abstract_init(build: Callable[[], "Module"]) -> "Module":
+    """``build()`` with every parameter and buffer a SHAPE: the
+    constructors run under ``jax.eval_shape`` (their initialisers are
+    traced, never executed, and draw from a scoped key, so the global
+    stream does not move) and ``register_parameter`` /
+    ``register_buffer`` keep a ``jax.ShapeDtypeStruct`` where they
+    would keep an array. The model that comes back allocates nothing;
+    ``load_params_dict`` (and ``load_buffers_dict`` where it has
+    buffers) then gives it its values in whatever dtype they are
+    served in — a model too large to initialise in float32 on the
+    device is built this way. A leaf left unloaded fails at first use.
+    Constructors that keep ``jnp`` values outside ``register_*``
+    cannot be built abstractly (the value would be a leaked tracer)."""
+    global _ABSTRACT_INIT_DEPTH
+    made = []
+
+    def run():
+        global _ABSTRACT_INIT_DEPTH
+        _ABSTRACT_INIT_DEPTH += 1
+        bt_random.RNG.push_key(jax.random.PRNGKey(0))
+        try:
+            made.append(build())
+        finally:
+            bt_random.RNG.pop_key()
+            _ABSTRACT_INIT_DEPTH -= 1
+        return 0
+
+    jax.eval_shape(run)
+    return made[0]
+
+
+def _registered(value):
+    value = jnp.asarray(value)
+    if _ABSTRACT_INIT_DEPTH > 0:
+        return jax.ShapeDtypeStruct(value.shape, value.dtype)
+    return value
 
 
 def in_pure_bind() -> bool:
@@ -113,16 +156,25 @@ class Module:
         object.__setattr__(self, name, value)
 
     def register_parameter(self, name: str, value, regularizer=None):
-        value = jnp.asarray(value)
+        value = _registered(value)
         self._parameters[name] = value
-        self._gradients[name] = jnp.zeros_like(value)
+        # allocated by the training path on first use (_grad): a model
+        # that only ever serves never pays for a gradient buffer
+        self._gradients[name] = None
         object.__setattr__(self, name, value)
         if regularizer is not None:
             self._regularizers[name] = regularizer
 
     def register_buffer(self, name: str, value):
-        self._buffers[name] = jnp.asarray(value)
+        self._buffers[name] = _registered(value)
         object.__setattr__(self, name, self._buffers[name])
+
+    def _grad(self, name: str):
+        """Parameter ``name``'s accumulated gradient, zeros on first use."""
+        g = self._gradients[name]
+        if g is None:
+            g = self._gradients[name] = jnp.zeros_like(self._parameters[name])
+        return g
 
     def _set_param(self, name: str, value):
         """Rebind a registered parameter (used by bind/load)."""
@@ -262,7 +314,7 @@ class Module:
         for _, m in self.named_modules():
             for k in m._parameters:
                 ws.append(m._parameters[k])
-                gs.append(m._gradients[k])
+                gs.append(m._grad(k))
         return ws, gs
 
     def get_parameters(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -318,7 +370,7 @@ class Module:
     def grads_dict(self) -> Dict:
         d = {}
         if self._gradients:
-            d[_PARAMS_KEY] = dict(self._gradients)
+            d[_PARAMS_KEY] = {k: self._grad(k) for k in self._gradients}
         for name, child in self._modules.items():
             sub = child.grads_dict()
             if sub:
@@ -328,7 +380,8 @@ class Module:
     def _acc_grad_dict(self, d: Dict) -> None:
         if _PARAMS_KEY in d:
             for k, g in d[_PARAMS_KEY].items():
-                self._gradients[k] = self._gradients[k] + g
+                cur = self._gradients[k]
+                self._gradients[k] = g if cur is None else cur + g
         for name, child in self._modules.items():
             if name in d:
                 child._acc_grad_dict(d[name])
@@ -377,13 +430,15 @@ class Module:
     def zero_grad_parameters(self) -> None:
         for _, m in self.named_modules():
             for k in m._gradients:
-                m._gradients[k] = jnp.zeros_like(m._gradients[k])
+                # a gradient nothing has touched is already zero
+                if m._gradients[k] is not None:
+                    m._gradients[k] = jnp.zeros_like(m._gradients[k])
 
     def update_parameters(self, learning_rate: float) -> None:
         """Eager in-place-style SGD step (API parity; real training uses optim/)."""
         for _, m in self.named_modules():
             for k in m._parameters:
-                m._set_param(k, m._parameters[k] - learning_rate * m._gradients[k])
+                m._set_param(k, m._parameters[k] - learning_rate * m._grad(k))
 
     # ------------------------------------------------------------ modes/state
     def training_mode(self) -> "Module":  # ≙ training()

@@ -258,6 +258,31 @@ def serving_engine_instruments(service: str = "engine",
             "bigdl_serving_prefix_cache_entries",
             "Prefix-cache entries currently retained", labelnames=lbl
         ).labels(service),
+        state_snapshots_in_use=r.gauge(
+            "bigdl_serving_state_snapshots_in_use",
+            "Lane-state snapshots the store currently holds (a model "
+            "with lane state: requests in flight and prefix entries "
+            "reference them)", labelnames=lbl).labels(service),
+        state_snapshots_taken_total=r.counter(
+            "bigdl_serving_state_snapshots_taken_total",
+            "Lane-state snapshots taken while prompts prefilled (one "
+            "device copy each, at a multiple of the snapshot stride)",
+            labelnames=lbl).labels(service),
+        state_snapshots_skipped_total=r.counter(
+            "bigdl_serving_state_snapshots_skipped_total",
+            "Stride boundaries a prefill passed without a snapshot: "
+            "the store was full of snapshots requests in flight hold",
+            labelnames=lbl).labels(service),
+        state_restored_total=r.counter(
+            "bigdl_serving_state_restored_total",
+            "Admissions that resumed from a lane-state snapshot (a "
+            "prefix hit, or a preempted request's return)",
+            labelnames=lbl).labels(service),
+        state_hits_shortened_total=r.counter(
+            "bigdl_serving_state_hits_shortened_total",
+            "Prefix hits cut short of the matched pages because no "
+            "lane-state snapshot stood at the match (the difference is "
+            "prefilled again)", labelnames=lbl).labels(service),
         prefix_host_hits_total=r.counter(
             "bigdl_serving_prefix_host_hits_total",
             "Prefix-cache hits served from the host tier (row demoted "

@@ -91,7 +91,8 @@ from bigdl_tpu.observability.timeseries import (
     TimeSeriesSampler, render_dashboard,
 )
 from bigdl_tpu.serving.paging import (
-    BlockTable, PagedPrefixIndex, PagePool,
+    BlockTable, PagedPrefixIndex, PagePool, SnapshotStore, lane_leaves,
+    page_leaves, with_lanes, with_pages,
 )
 from bigdl_tpu.serving.scheduler import (
     AdmissionQueue, PrefillPolicy, SpeculationPolicy, TokenBucket,
@@ -111,6 +112,10 @@ USAGE_RECENT = 256
 IDLE_WAIT_S = 0.5
 #: per-kind floor between two captured incident bundles
 INCIDENT_COOLDOWN_S = 30.0
+#: lane-state snapshots the store holds for each serving lane (a model
+#: with lane state; derived, not a knob: the snapshot a lane resumed
+#: from plus one its prefill takes, so a full engine still caches)
+SNAPSHOTS_PER_LANE = 2
 
 
 class _Admission:
@@ -121,7 +126,8 @@ class _Admission:
 
     __slots__ = ("handle", "slot", "row", "ids", "t0", "base", "tail",
                  "n_chunks", "next_chunk", "d_ids",
-                 "d_n_chunks", "d_next_chunk", "table", "d_table")
+                 "d_n_chunks", "d_next_chunk", "table", "d_table", "snaps",
+                 "keep_at")
 
     def __init__(self, handle: RequestHandle, slot: int, row: int,
                  ids: np.ndarray, t0: int, base: int, n_chunks: int,
@@ -149,13 +155,22 @@ class _Admission:
         #: freed on abort)
         self.table: Optional[BlockTable] = table
         self.d_table: Optional[BlockTable] = d_table
+        #: a model with lane state: the (position, snapshot id) pairs
+        #: this request holds a reference on — the one it resumed from
+        #: and those its prefill took; they follow it into its slot and
+        #: its donated prefix entry. Of those it takes it keeps the
+        #: newest and the one at ``keep_at``, the deepest stride
+        #: boundary inside the pages its prompt MATCHED (where other
+        #: prompts are seen to branch off); older ones go back
+        self.snaps: List[tuple] = []
+        self.keep_at = 0
 
 
 class _SlotState:
     """Host-side view of one occupied KV slot."""
 
     __slots__ = ("handle", "pos", "last_token", "last_token_at",
-                 "delivered")
+                 "delivered", "snaps")
 
     def __init__(self, handle: RequestHandle, pos: int, last_token: int,
                  now: float):
@@ -170,6 +185,7 @@ class _SlotState:
         self.last_token = last_token
         self.last_token_at = now
         self.delivered = 1
+        self.snaps: List[tuple] = []
 
 
 class ContinuousBatchingEngine:
@@ -260,6 +276,35 @@ class ContinuousBatchingEngine:
     every device). ``stats()["mesh"]`` reports topology plus per-pool
     logical/physical/per-device bytes; ``bigdl_serving_mesh_*``
     gauges carry the same figures.
+
+    LANE STATE: a model some of whose layers keep a fixed recurrent
+    state a sequence (``model.has_lane_state``, ``models/hybrid.py``)
+    is served with TWO kinds of state in the one cache manager. Its
+    pool is ``{"pages": ..., "lanes": ...}``: pages as above for the
+    layers that hold K and V, and one state a serving lane (slot
+    ``i`` is lane ``i``, plus a scratch lane that idle prefill rows
+    write) for the rest. The prefill dispatch is told which lane each
+    row fills (a row at position 0 starts from a zero state), the
+    fused decode step which lanes are decoding (the others keep their
+    state bit for bit). A cached prefix is then its pages AND the state
+    at some position: while a prompt prefills, the lane's state is
+    copied into a fixed device store (``paging.SnapshotStore``,
+    ``SNAPSHOTS_PER_LANE`` x ``max_slots`` of them) whenever its
+    position reaches a multiple of ``S_snap`` — the lane state's bytes
+    over one token's KV bytes, rounded up to whole prefill chunks, so a
+    cached prefix costs at most about twice its pages — and the donated
+    ``PrefixEntry`` carries the ones the request kept (the newest, the
+    one it resumed from, and the one at the boundary where its prompt
+    left the pages it matched). A hit is as long as the
+    deepest snapshot at or under the match: admission shares the pages
+    up to it, copies it into the lane and prefills from there
+    (``serving/state_restore`` / ``serving/state_snapshot`` spans;
+    ``stats()["paging"]["state"]``). A preempted request resumes the
+    same way, from its deepest snapshot. Not served with lane state
+    yet, each refused at construction: a ``draft`` (speculation needs
+    the state rolled back on rejection), a host tier for the prefix
+    index (demoted pages would come back without their state), a
+    ``mesh`` (the lane state has no sharded layout), int8 KV.
 
     When to prefer this over ``GenerationService``: mixed or long
     decode lengths under concurrent load (no head-of-line blocking on
@@ -383,6 +428,29 @@ class ContinuousBatchingEngine:
             model = Quantizer.quantize(model)
         model.evaluate()
         self.model = model
+        #: two kinds of state: some layers keep one recurrent state a
+        #: lane beside the pages of the others
+        self._lane_state = bool(getattr(model, "has_lane_state", False))
+        if self._lane_state:
+            refused = {
+                "draft": (draft is not None, "speculation needs the "
+                          "recurrent state rolled back when a proposal "
+                          "is rejected"),
+                "prefix_host_rows/prefix_host_bytes": (
+                    bool(prefix_host_rows or prefix_host_bytes),
+                    "a demoted entry's pages would come back without "
+                    "the state snapshots that belong to them"),
+                "mesh": (mesh is not None, "the lane state has no "
+                         "sharded layout"),
+                "kv_dtype": (kv_dtype is not None, "its pages are "
+                             "served in the weights' dtype"),
+            }
+            for name, (given, why) in refused.items():
+                if given:
+                    raise ValueError(
+                        f"{type(model).__name__} keeps a recurrent state "
+                        f"a lane; {name} is not served with lane state "
+                        f"yet: {why}")
         self.max_slots = max_slots
         self.eos_id = eos_id
         self.temperature = temperature
@@ -544,9 +612,16 @@ class ContinuousBatchingEngine:
         # straight through their reserved tables — and no separate
         # prefix pool: retained prefixes are refcounted shares of
         # these same pages.
-        self._kv_pool = model.init_page_pool(
-            max_pages, page_size, dtype=dtype,
-            sharding=self._kv_shard, kv_dtype=self.kv_dtype)
+        if self._lane_state:
+            # lane i is slot i; the last lane is scratch: idle prefill
+            # rows write it (the lanes' page 0)
+            self._kv_pool = model.init_page_pool(
+                max_pages, page_size, dtype=dtype,
+                lanes=max_slots + 1)
+        else:
+            self._kv_pool = model.init_page_pool(
+                max_pages, page_size, dtype=dtype,
+                sharding=self._kv_shard, kv_dtype=self.kv_dtype)
         self._pages = PagePool(self._kv_pool, page_size)
         self._tables: List[Optional[BlockTable]] = [None] * max_slots
         self._d_kv_pool = self._d_pages = self._d_tables = None
@@ -605,6 +680,26 @@ class ContinuousBatchingEngine:
             host_rows = 0
         else:
             host_rows = max(0, int(prefix_host_bytes) // row_bytes)
+        # ---- lane state: the snapshot store and its stride ------------
+        self._snaps: Optional[SnapshotStore] = None
+        self._snap_store = None
+        self._snap_stride = 0
+        if self._lane_state:
+            lanes = lane_leaves(self._kv_pool)
+            lane_bytes = sum(int(l.nbytes) for l in jax.tree.leaves(lanes)
+                             ) // (max_slots + 1)
+            #: tokens between two snapshots of a prefilling lane: what a
+            #: snapshot costs in tokens of KV, in whole chunks
+            self._snap_stride = c * max(1, math.ceil(
+                lane_bytes / self._token_bytes / c))
+            n_snaps = SNAPSHOTS_PER_LANE * max_slots
+            # a snapshot is a lane's leaves FLAT: the store pays a
+            # state's logical bytes, whatever tiles the lanes' own
+            # layout pads to (the copies re-lay one lane's worth)
+            self._snap_store = jax.tree.map(
+                lambda l: jnp.zeros((n_snaps, math.prod(l.shape[1:])),
+                                    l.dtype), lanes)
+            self._snaps = SnapshotStore(n_snaps, lane_bytes)
         self._prefix: Optional[PagedPrefixIndex] = None
         if max_entries > 0:
             # max_entries bounds ENTRY count (cardinality), the shared
@@ -619,7 +714,8 @@ class ContinuousBatchingEngine:
                 # axis replicates them, so mesh.size would undercount)
                 devices=(int(mesh.shape[model_axis])
                          if mesh is not None else 1),
-                host_pages=host_rows * self._table_len)
+                host_pages=host_rows * self._table_len,
+                snapshots=self._snaps)
         self._prefix_evictions_seen = 0
         self._prefix_demotions_seen = 0
         self._prefix_host_evictions_seen = 0
@@ -678,8 +774,7 @@ class ContinuousBatchingEngine:
         # to the full-precision row the same geometry would cost — the
         # before/after pair behind the quantized-capacity claim
         self._fp_row_bytes = int(
-            2 * model.num_layers * model.num_kv_heads * phys_len
-            * model.block0.attn.head_dim * jnp.dtype(dtype).itemsize)
+            model.kv_token_elems() * phys_len * jnp.dtype(dtype).itemsize)
         self._ins.quantized_kv.set(
             1 if self.kv_dtype else 0, force=True)
         self._ins.quantized_weights.set(
@@ -925,13 +1020,20 @@ class ContinuousBatchingEngine:
         attend = self._decode_attention = (
             "rows" if self.mesh is None else "heads")
 
-        def step(p, bufs, tok, pos, pool, tables, rng, temperature):
+        lane_state = self._lane_state
+
+        def step(p, bufs, tok, pos, pool, tables, rng, temperature,
+                 *active):
             # one fused decode over ALL slots; idle lanes carry the
             # all-scratch table (SCRATCH_PAGE padding) so their junk
-            # write lands on page 0, never on a live page
+            # write lands on page 0, never on a live page. A lane's
+            # recurrent state has no scratch to park on: ``active``
+            # (lane-state models only) masks it inside the program
+            kw = {"active": active[0]} if lane_state else {}
             with bind(model, p, bufs, False, None):
                 logits, pool = model.decode_step_paged(
-                    tok, pos, pool, tables, decode_attention=attend)
+                    tok, pos, pool, tables, decode_attention=attend,
+                    **kw)
             if sampled:
                 nxt = jax.random.categorical(
                     rng, _filter_logits(logits, temperature, top_k,
@@ -941,40 +1043,56 @@ class ContinuousBatchingEngine:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return nxt, pool
 
-        def chunk(p, bufs, ids, pool, tables, pos0, last_idx):
+        def chunk(p, bufs, ids, pool, tables, pos0, last_idx, *lanes):
             # the ragged admission prefill, writing through each row's
             # reserved table: a prefix hit's row starts at pos0 = base
             # (page-aligned — see the ctor's chunk/page check), so its
             # writes land only in its FRESH pages while the shared head
-            # is read via the gather — zero row copies on the hit leg
+            # is read via the gather — zero row copies on the hit leg.
+            # ``lanes`` (lane-state models only): the lane each row's
+            # recurrent state lives in, the scratch lane for idle rows
+            kw = {"lanes": lanes[0]} if lane_state else {}
             with bind(model, p, bufs, False, None):
                 return model.prefill_chunk_at_paged(ids, pool, tables,
-                                                    pos0, last_idx)
+                                                    pos0, last_idx, **kw)
+
+        def one_row(a, row):
+            return jax.lax.dynamic_slice(
+                a, (row,) + (0,) * (a.ndim - 1), (1,) + a.shape[1:])
+
+        def row_copy(d, s, dst_row, src_row):
+            return jax.lax.dynamic_update_slice(
+                d, one_row(s, src_row).astype(d.dtype),
+                (dst_row,) + (jnp.int32(0),) * (d.ndim - 1))
 
         def copy_page(pool, dst, src):
             # single-page pool-internal copy — the COW privatization
             # primitive (BlockTable.ensure_writable's copy_page
-            # callback) — one compiled signature, load-independent
-            return jax.tree.map(
-                lambda b: jax.lax.dynamic_update_slice(
-                    b,
-                    jax.lax.dynamic_slice(
-                        b, (src,) + (0,) * (b.ndim - 1),
-                        (1,) + b.shape[1:]),
-                    (dst,) + (jnp.int32(0),) * (b.ndim - 1)),
-                pool)
+            # callback) — one compiled signature, load-independent.
+            # Pages only: a pool's lane state is not rows of pages
+            return with_pages(pool, jax.tree.map(
+                lambda b: row_copy(b, b, dst, src), page_leaves(pool)))
 
         def copy_row(dst, src, dst_row, src_row):
             # generic tree row copy, kept for the promote landing:
             # (1, ...) host-transferred page tree -> pool page dst_row
+            return with_pages(dst, jax.tree.map(
+                lambda d, s: row_copy(d, s, dst_row, src_row),
+                page_leaves(dst), src))
+
+        def restore_state(pool, store, lane, sid):
+            # admission on a hit: snapshot sid (flat) becomes lane's state
+            return with_lanes(pool, jax.tree.map(
+                lambda l, s: row_copy(
+                    l, one_row(s, sid).reshape((1,) + l.shape[1:]), lane, 0),
+                lane_leaves(pool), store))
+
+        def snapshot_state(store, pool, sid, lane):
+            # a prefilling lane's state kept at a stride boundary
             return jax.tree.map(
-                lambda d, s: jax.lax.dynamic_update_slice(
-                    d,
-                    jax.lax.dynamic_slice(
-                        s, (src_row,) + (0,) * (s.ndim - 1),
-                        (1,) + s.shape[1:]).astype(d.dtype),
-                    (dst_row,) + (jnp.int32(0),) * (d.ndim - 1)),
-                dst, src)
+                lambda s, l: row_copy(
+                    s, one_row(l, lane).reshape(1, -1), sid, 0),
+                store, lane_leaves(pool))
 
         def sample0(logits, rng, temperature):
             if sampled:
@@ -1006,6 +1124,10 @@ class ContinuousBatchingEngine:
         self._copy_page_jit = _jit(copy_page, (0,), kv)
         self._copy_row_jit = _jit(copy_row, (0,), kv)
         self._sample0_jit = _jit(sample0, (), repl)
+        self._restore_jit = self._snapshot_jit = None
+        if lane_state:
+            self._restore_jit = _jit(restore_state, (0,))
+            self._snapshot_jit = _jit(snapshot_state, (0,))
 
         self._take_row_jit = None
         if self._prefix is not None and self._prefix.host_pages > 0:
@@ -1116,6 +1238,15 @@ class ContinuousBatchingEngine:
         z = jnp.int32(0)
         self._kv_pool = self._copy_page_jit(self._kv_pool, z, z)
         self._warm.add("copy:page")
+        if lane_state:
+            # the scratch lane into free snapshot 0 and back: both
+            # copies first fire on traffic (a stride boundary, a hit)
+            scratch = jnp.int32(self.max_slots)
+            self._snap_store = self._snapshot_jit(
+                self._snap_store, self._kv_pool, z, scratch)
+            self._kv_pool = self._restore_jit(
+                self._kv_pool, self._snap_store, scratch, z)
+            self._warm.update(("state:snapshot", "state:restore"))
         if self._take_row_jit is not None:
             from bigdl_tpu.parallel.tp import put_from_host
 
@@ -1171,7 +1302,11 @@ class ContinuousBatchingEngine:
         pool this engine owns (the mesh-summary / per-device gauge
         enumeration; keys match the ``serving/<name>/<pool>`` registry
         suffixes)."""
-        out = {"kv_page_pool": self._kv_pool, "params": self._params}
+        out = {"kv_page_pool": page_leaves(self._kv_pool),
+               "params": self._params}
+        if self._lane_state:
+            out["lane_state"] = lane_leaves(self._kv_pool)
+            out["state_snapshots"] = self._snap_store
         if self.draft is not None:
             out["draft_page_pool"] = self._d_kv_pool
             out["draft_params"] = self._d_params
@@ -1223,6 +1358,8 @@ class ContinuousBatchingEngine:
                self._sample0_jit, self._copy_page_jit]
         if self._take_row_jit is not None:
             fns.append(self._take_row_jit)
+        if self._lane_state:
+            fns += [self._restore_jit, self._snapshot_jit]
         if self.draft is not None:
             fns += [self._propose_jit, self._spec_verify_jit,
                     self._d_chunk_jit, self._d_sync_jit]
@@ -1256,13 +1393,19 @@ class ContinuousBatchingEngine:
         zT = self._h2d(jnp.zeros((S, self._table_len), jnp.int32))
         zTr = self._h2d(jnp.zeros((rows, self._table_len),
                                   jnp.int32))
+        lanes_arg, active_arg = (), ()
+        if self._lane_state:
+            lanes_arg = (self._h2d(jnp.zeros((rows,), jnp.int32)),)
+            active_arg = (self._h2d(jnp.zeros((S,), bool)),)
         progs = {"prefill": [(self._chunk_jit,
                               (self._params, self._buffers, ids,
-                               self._kv_pool, zTr, rpos, rpos))]}
+                               self._kv_pool, zTr, rpos, rpos)
+                              + lanes_arg)]}
         if self.draft is None:
             progs["decode"] = [(self._step_jit,
                                 (self._params, self._buffers, zt,
-                                 zt, self._kv_pool, zT, zk, t1))]
+                                 zt, self._kv_pool, zT, zk, t1)
+                                + active_arg)]
         else:
             progs["prefill"].append(
                 (self._d_chunk_jit,
@@ -1290,7 +1433,7 @@ class ContinuousBatchingEngine:
             "prefill": (rows * c, ctx),
             "decode": (S * (g + 1) if g else S, ctx),
         }
-        cache_itemsize = int(jax.tree.leaves(self._kv_pool)[0]
+        cache_itemsize = int(jax.tree.leaves(page_leaves(self._kv_pool))[0]
                              .dtype.itemsize)
         for kind, entries in progs.items():
             costs = [program_cost(fn, *args) for fn, args in entries]
@@ -1374,6 +1517,7 @@ class ContinuousBatchingEngine:
         self._adms = []
         for sid, st in enumerate(self._slots):
             if st is not None:
+                self._release_snaps(st.snaps)
                 self._finish_handle(st.handle, err, "stopped")
                 self._slots[sid] = None
             self._free_slot_table(sid)
@@ -2070,6 +2214,7 @@ class ContinuousBatchingEngine:
         self._adms = []
         for sid, st in enumerate(self._slots):
             if st is not None:
+                self._release_snaps(st.snaps)
                 self._finish_handle(st.handle, err, "crashed")
                 self._slots[sid] = None
             self._free_slot_table(sid)
@@ -2357,7 +2502,7 @@ class ContinuousBatchingEngine:
         # exactly the donation key a finishing slot would use
         tokens = np.concatenate(
             [h.prompt, np.asarray(h._tokens[:-1], np.int32)])
-        self._maybe_donate(sid, tokens, h.request_id)
+        self._maybe_donate(sid, tokens, h.request_id, st.snaps)
         if self._prefix is not None:
             # pin the covering entry so the LRU cannot evict the
             # donated KV while the victim waits in the queue — the
@@ -2373,6 +2518,7 @@ class ContinuousBatchingEngine:
                 if stale is not None:
                     self._prefix.release(stale)
                 h._preempt_pin = pin
+        self._release_snaps(st.snaps)
         self._free_slot_table(sid)
         self._slots[sid] = None
         self._ins.evicted_total.inc()
@@ -2425,8 +2571,9 @@ class ContinuousBatchingEngine:
                 # tokens) — the donated KV makes them near-perfect
                 # hits.
                 p = self._effective_prompt(h)
-                e, m = self._prefix.lookup(p)
-                h._prefix_probe = (e, m, self._prefix.generation)
+                found = self._prefix.match(p)
+                e, m = found.entry, found.length
+                h._prefix_probe = (found, self._prefix.generation)
                 base = (min(m, p.shape[0] - 1) // c) * c
                 if e is not None and e.tier != "device":
                     base = 0  # promote may still land it, score cold
@@ -2500,21 +2647,31 @@ class ContinuousBatchingEngine:
         prompt = self._effective_prompt(h)
         t0 = prompt.shape[0]
         base, entry, from_host = 0, None, False
+        shortfall, resume_sid = 0, None
         if self._prefix is not None:
             # reuse the pop_ready scorer's lookup when it is still
             # valid — the generation guard rejects probes that predate
             # any donation/eviction/tier move
             probe = h.__dict__.pop("_prefix_probe", None)
             if probe is not None \
-                    and probe[2] == self._prefix.generation:
-                e, matched = probe[0], probe[1]
+                    and probe[1] == self._prefix.generation:
+                found = probe[0]
             else:
-                e, matched = self._prefix.lookup(prompt)
+                found = self._prefix.match(prompt)
+            e, matched = found.entry, found.length
             if e is not None:
                 # cap at t0-1 (last position must be COMPUTED), then
                 # chunk-align DOWN — and c % page_size == 0 makes the
                 # reuse base page-aligned, the COW-free invariant
                 base = (min(matched, t0 - 1) // c) * c
+                if self._lane_state:
+                    # the match is already cut to the deepest snapshot
+                    # (a multiple of the stride, so of the chunk); what
+                    # the pages alone would have covered beyond it is
+                    # prefilled again
+                    resume_sid = e.resume_at(base)[1]
+                    shortfall = (min(found.matched, t0 - 1) // c) * c \
+                        - base
             from_host = base > 0 and e.tier == "host"
             if from_host and not self._promote_entry(e):
                 # the host pages could not be made device-resident
@@ -2530,6 +2687,13 @@ class ContinuousBatchingEngine:
         remaining = h.max_new_tokens - len(h._tokens)
         need_tokens = min(t0 + remaining + g, self._phys_len)
         n_fresh = pages_needed(need_tokens, ps) - len(shared)
+        if resume_sid is not None:
+            # the admission's own reference, taken BEFORE any sweep
+            # (whose victim may be the hit's own entry, which would
+            # free the snapshot with it): the snapshot outlives its
+            # entry's eviction while this request prefills, and follows
+            # the request into the entry it donates
+            self._snaps.share([resume_sid])
         table = BlockTable.build(self._pages, shared, n_fresh)
         if table is None and self._prefix is not None:
             # hold the hit's head across the sweep: its own entry may
@@ -2552,6 +2716,8 @@ class ContinuousBatchingEngine:
                 table.free()
                 table = None
         if table is None:
+            if resume_sid is not None:
+                self._snaps.free([resume_sid])
             self._queue.requeue(h)
             self._adm_blocked = True
             # sticky per-request latch: the finished timeline reports
@@ -2564,6 +2730,9 @@ class ContinuousBatchingEngine:
                              free_pages=self._pages.free_pages)
             return False
         if self._prefix is not None:
+            if shortfall:
+                self._prefix.record_shortfall(shortfall)
+                self._ins.state_hits_shortened_total.inc()
             if base > 0:
                 # no copy and no entry acquire: the shared
                 # refcounts keep the pages alive even if the entry is
@@ -2603,9 +2772,14 @@ class ContinuousBatchingEngine:
             d_n_chunks = self._policy.n_chunks(t0)
             d_ids = np.zeros((d_n_chunks * c,), np.int32)
             d_ids[:t0] = prompt
-        self._adms.append(_Admission(h, slot, row, ids, t0, base,
-                                     n_chunks, table, d_ids,
-                                     d_n_chunks, d_table))
+        adm = _Admission(h, slot, row, ids, t0, base, n_chunks, table,
+                         d_ids, d_n_chunks, d_table)
+        self._adms.append(adm)
+        if self._lane_state:
+            adm.keep_at = base + shortfall \
+                - (base + shortfall) % self._snap_stride
+        if resume_sid is not None or shortfall:
+            self._restore_state(adm, resume_sid, shortfall)
         h.prefix_tokens = base
         t_adm = time.monotonic()
         if h.admitted_at is None:
@@ -2694,8 +2868,10 @@ class ContinuousBatchingEngine:
             logits, self._kv_pool = self._chunk_jit(
                 self._params, self._buffers, self._h2d(ids),
                 self._kv_pool, self._adm_tables(), self._h2d(pos0),
-                self._h2d(last))
+                self._h2d(last), *self._adm_lanes())
             self._warm.add("chunk")
+            if self._lane_state:
+                self._take_snapshots(c)
             if spec:
                 d_ids = np.zeros((rows, c), np.int32)
                 d_pos0 = np.zeros((rows,), np.int32)
@@ -2759,6 +2935,7 @@ class ContinuousBatchingEngine:
         self._free_slot_table(a.slot)
         self._tables[a.slot] = a.table
         a.table = None
+        held_snaps, a.snaps = a.snaps, []
         if self.draft is not None:
             self._d_tables[a.slot] = a.d_table
             a.d_table = None
@@ -2796,12 +2973,14 @@ class ContinuousBatchingEngine:
             # lost (prompt + generated[:-1] is exactly what they cover)
             self._maybe_donate(a.slot, np.concatenate(
                 [h.prompt, np.asarray(h._tokens[:-1], np.int32)]),
-                h.request_id)
+                h.request_id, held_snaps)
+            self._release_snaps(held_snaps)
             self._free_slot_table(a.slot)
             self._finish_handle(h, None, "finished")
             self._ins.finished_total.inc()
             return
         st = _SlotState(h, a.t0, tok, now)
+        st.snaps = held_snaps
         # a resumed request's slot picks up where the preempted one
         # left off: pos == effective-prompt length keeps the
         # variable-advance invariant (KV covers [0, pos), the just-
@@ -2809,8 +2988,9 @@ class ContinuousBatchingEngine:
         st.delivered = len(h._tokens)
         self._slots[a.slot] = st
 
-    @staticmethod
-    def _free_admission_tables(a: _Admission) -> None:
+    def _free_admission_tables(self, a: _Admission) -> None:
+        self._release_snaps(a.snaps)
+        a.snaps = []
         if a.table is not None:
             a.table.free()
             a.table = None
@@ -2827,19 +3007,21 @@ class ContinuousBatchingEngine:
 
     # --------------------------------------------------- prefix donation
     def _maybe_donate(self, sid: int, tokens: np.ndarray,
-                      request_id: str) -> None:
+                      request_id: str, snaps=()) -> None:
         """Offer a finishing slot's KV to the prefix index. ``tokens``
         are exactly the ids whose KV the slot holds (positions
         ``0..len-1``); the index decides (covered / LRU-evict /
         decline). Donation is a refcount move, never a copy: the
         covering pages are SHARED into the new entry; the slot's own
-        references are freed separately by the caller."""
+        references are freed separately by the caller. ``snaps`` (a
+        model with lane state): the request's state snapshots, shared
+        into the entry the same way."""
         if self._prefix is None:
             return
         tbl = self._tables[sid]
         if tbl is not None and tokens.shape[0] > 0:
             held = tbl.covering(int(tokens.shape[0]))
-            if self._prefix.donate_pages(tokens, held):
+            if self._prefix.donate_pages(tokens, held, snaps):
                 self._rec.record(
                     "request/prefix_donated", request_id,
                     service=self.service_name,
@@ -2949,6 +3131,84 @@ class ContinuousBatchingEngine:
             if tbl is not None:
                 t[a.row] = tbl.as_array(self._table_len)
         return self._h2d(t)
+
+    def _adm_lanes(self) -> tuple:
+        """The prefill dispatch's extra argument for a model with lane
+        state: the lane (= reserved slot) each row's recurrent state
+        lives in, the scratch lane for idle rows. Empty otherwise."""
+        if not self._lane_state:
+            return ()
+        lanes = np.full((self._policy.prefill_rows,), self.max_slots,
+                        np.int32)
+        for a in self._adms:
+            lanes[a.row] = a.slot
+        return (self._h2d(lanes),)
+
+    # ------------------------------------------------ lane-state copies
+    def _restore_state(self, a: _Admission, snap: Optional[int],
+                       shortfall: int) -> None:
+        """Admission on a hit: snapshot ``snap`` becomes the state of
+        the admission's lane (one warmed copy, dispatched and not
+        waited for), and the admission keeps its reference. The span
+        also records a match NO snapshot stood under (``snap`` None,
+        ``bytes`` 0: nothing is copied, all of the match is prefilled
+        again), so that its attributes sum to every matched token."""
+        copied = 0 if snap is None else self._snaps.snapshot_bytes
+        with trace.span("serving/state_restore",
+                        matched_tokens=a.base + shortfall,
+                        resumed_tokens=a.base, bytes=copied):
+            if snap is None:
+                return
+            self._kv_pool = self._restore_jit(
+                self._kv_pool, self._snap_store, jnp.int32(a.slot),
+                jnp.int32(snap))
+        a.snaps.append((a.base, snap))
+        self._snaps.touch(snap)
+        self._ins.state_restored_total.inc()
+
+    def _take_snapshots(self, c: int) -> None:
+        """After a prefill dispatch: every row whose chunk was whole
+        and ended on a multiple of the stride has its lane's state
+        copied into a free snapshot (the dispatch that wrote the state
+        is ahead of the copy on the device's queue). A full store first
+        gives up the snapshot nothing has resumed from for longest
+        (``PagedPrefixIndex.reclaim_snapshot``); failing that the
+        boundary goes without one. A request keeps at most three: the
+        one it resumed from, the one at the boundary its match named
+        (``keep_at``) and the newest; a long cold prompt would
+        otherwise fill the store with states nothing will resume from
+        before it reaches the boundary others share."""
+        for a in self._adms:
+            if a.next_chunk >= a.n_chunks:
+                continue
+            end = a.base + (a.next_chunk + 1) * c
+            if end > a.t0 or end % self._snap_stride:
+                continue
+            snap = self._snaps.take()
+            if snap is None and self._prefix is not None \
+                    and self._prefix.reclaim_snapshot():
+                snap = self._snaps.take()
+            if snap is None:
+                self._snaps.note_skipped()
+                self._ins.state_snapshots_skipped_total.inc()
+                continue
+            with trace.span("serving/state_snapshot", position=end,
+                            bytes=self._snaps.snapshot_bytes):
+                self._snap_store = self._snapshot_jit(
+                    self._snap_store, self._kv_pool, jnp.int32(snap),
+                    jnp.int32(a.slot))
+            a.snaps.append((end, snap))
+            self._ins.state_snapshots_taken_total.inc()
+            keep = {a.base, a.keep_at, end}
+            self._release_snaps([p for p in a.snaps if p[0] not in keep])
+            a.snaps = [p for p in a.snaps if p[0] in keep]
+        self._ins.state_snapshots_in_use.set(self._snaps.in_use)
+
+    def _release_snaps(self, snaps) -> None:
+        """Drop a request's own snapshot references (what it donated
+        lives on under its entry's)."""
+        if snaps:
+            self._snaps.free([snap for _, snap in snaps])
 
     def _slot_tables(self, draft: bool = False):
         """The decode dispatch's ``(max_slots, table_len)`` block
@@ -3070,6 +3330,16 @@ class ContinuousBatchingEngine:
             out["draft_pool"] = self._d_pages.stats()
         if self._prefix is not None:
             out["prefix_device_pages"] = self._prefix.device_pages
+        if self._lane_state:
+            out["state"] = {
+                "lanes": self.max_slots,
+                "snapshot_stride_tokens": self._snap_stride,
+                **self._snaps.stats()}
+            if self._prefix is not None:
+                out["state"]["hits_shortened_total"] = \
+                    self._prefix.hits_shortened
+                out["state"]["shortfall_tokens_total"] = \
+                    self._prefix.shortfall_tokens
         return out
 
     # --------------------------------------------------------- decode
@@ -3087,10 +3357,15 @@ class ContinuousBatchingEngine:
             self._chaos.on_dispatch()
         with trace.span("serving/decode_dispatch",
                         rows=len(active)) as disp:
+            active_arg = ()
+            if self._lane_state:
+                live = np.zeros((self.max_slots,), bool)
+                live[active] = True
+                active_arg = (self._h2d(live),)
             nxt, self._kv_pool = self._step_jit(
                 self._params, self._buffers, self._h2d(tok),
                 self._h2d(pos), self._kv_pool, self._slot_tables(),
-                self._next_key(), self._temp())
+                self._next_key(), self._temp(), *active_arg)
             self._warm.add("step")
             with trace.span("serving/fetch_tokens"):
                 nxt_np = np.asarray(nxt)   # blocks on the fused step
@@ -3282,7 +3557,8 @@ class ContinuousBatchingEngine:
         tokens = np.concatenate(
             [st.handle.prompt,
              np.asarray(st.handle._tokens[:-1], np.int32)])
-        self._maybe_donate(sid, tokens, st.handle.request_id)
+        self._maybe_donate(sid, tokens, st.handle.request_id, st.snaps)
+        self._release_snaps(st.snaps)
         self._free_slot_table(sid)
         self._slots[sid] = None
         self._ins.evicted_total.inc()

@@ -40,6 +40,17 @@ on the engine's own paths; it exists (and is tested) as the safety net
 for future writers — n>1 completion forks — that DO write under a
 share.
 
+Two kinds of state (a model some of whose layers keep a fixed recurrent
+state a sequence, ``models/hybrid.py``): the model's pool is then
+``{"pages": ..., "lanes": ...}``, and everything here that treats a tree
+as rows of pages reads ``page_leaves(pool)`` — the ``lanes`` sub-tree
+(leaves led by the serving lane) is the engine's, one lane a slot. A
+cached prefix of such a model is its pages AND the state at some
+position: ``SnapshotStore`` is the refcounted allocator over a fixed
+device store of lane-state copies, a ``PrefixEntry`` carries
+``snaps`` ((position, snapshot id), each one reference), and a match is
+only as long as the deepest snapshot at or under it (``match``).
+
 Thread contract: the engine loop thread is the only mutator;
 ``stats()`` / ``snapshot()`` readers may race in from HTTP/debug
 threads, so counters, the trie and the free list sit behind internal
@@ -51,12 +62,38 @@ the two locks cannot deadlock.
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
 __all__ = ["PagePool", "BlockTable", "PagedPrefixIndex", "PrefixEntry",
-           "SCRATCH_PAGE"]
+           "PrefixMatch", "SnapshotStore", "SCRATCH_PAGE", "page_leaves",
+           "lane_leaves", "with_pages", "with_lanes"]
+
+
+def _two_kinds(pool) -> bool:
+    return isinstance(pool, dict) and "lanes" in pool
+
+
+def page_leaves(pool):
+    """The sub-tree of ``pool`` whose leaves lead with pages: all of it,
+    or the ``"pages"`` half of a pool that also holds lane state."""
+    return pool["pages"] if _two_kinds(pool) else pool
+
+
+def lane_leaves(pool):
+    """The sub-tree whose leaves lead with the serving lane; None for a
+    pool that is pages alone."""
+    return pool["lanes"] if _two_kinds(pool) else None
+
+
+def with_pages(pool, pages):
+    return {**pool, "pages": pages} if _two_kinds(pool) else pages
+
+
+def with_lanes(pool, lanes):
+    return {**pool, "lanes": lanes}
 
 #: page id 0 is never allocated: it is the write sink for idle dispatch
 #: lanes (an all-zero block table routes their junk KV writes here) and
@@ -66,7 +103,72 @@ __all__ = ["PagePool", "BlockTable", "PagedPrefixIndex", "PrefixEntry",
 SCRATCH_PAGE = 0
 
 
-class PagePool:
+class _RefCounts:
+    """The refcounted free list under ``PagePool`` and ``SnapshotStore``:
+    ids ``first..n-1`` are handed out LIFO with one reference, gain
+    references by ``share`` and return to the list when ``free`` drops the
+    last one. Sharing or freeing a free id is a bookkeeping bug and fails
+    loudly; ``noun`` names the id in those errors. ``allocated``,
+    ``shared`` and ``freed`` are cumulative, so ``allocated - freed`` ids
+    are out at all times."""
+
+    noun = "id"
+
+    def __init__(self, n: int, first: int):
+        # LIFO: recently freed ids are re-issued first, so a churning
+        # workload keeps touching the same HBM region
+        self._free: List[int] = list(range(n - 1, first - 1, -1))
+        self._refs = np.zeros(n, np.int32)
+        self._lock = threading.Lock()
+        self.allocated = 0
+        self.shared = 0
+        self.freed = 0
+
+    def alloc(self, n: int) -> Optional[List[int]]:
+        """Claim ``n`` free ids (refcount 1 each), all-or-nothing:
+        ``None`` when fewer than ``n`` are free, so a caller never
+        holds a partial reservation it must unwind."""
+        if n < 0:
+            raise ValueError(f"alloc(n={n})")
+        with self._lock:
+            if len(self._free) < n:
+                return None
+            ids = [self._free.pop() for _ in range(n)]
+            for i in ids:
+                self._refs[i] = 1
+            self.allocated += n
+            return ids
+
+    def share(self, ids: Sequence[int]) -> None:
+        """Add one reference to each id — the whole of what a prefix
+        hit or a table fork costs."""
+        with self._lock:
+            for i in ids:
+                if self._refs[i] <= 0:
+                    raise RuntimeError(
+                        f"share() of free {self.noun} {i}")
+                self._refs[i] += 1
+            self.shared += len(ids)
+
+    def free(self, ids: Sequence[int]) -> None:
+        """Drop one reference from each id; it returns to the free
+        list only when its last reference drops."""
+        with self._lock:
+            for i in ids:
+                if self._refs[i] <= 0:
+                    raise RuntimeError(
+                        f"free() of free {self.noun} {i}")
+                self._refs[i] -= 1
+                if self._refs[i] == 0:
+                    self._free.append(i)
+                    self.freed += 1
+
+    def refcount(self, i: int) -> int:
+        with self._lock:
+            return int(self._refs[i])
+
+
+class PagePool(_RefCounts):
     """Refcounted block allocator over one persistent device KV tree.
 
     ``buffers`` is ``model.init_page_pool(max_pages, page_size, ...)``
@@ -85,10 +187,13 @@ class PagePool:
     ``allocated - freed == pages_in_use`` at all times).
     """
 
+    noun = "page"
+
     def __init__(self, buffers, page_size: int):
         import jax
 
-        leaves = jax.tree_util.tree_leaves(buffers)
+        # a pool that also holds lane state: its pages half alone
+        leaves = jax.tree_util.tree_leaves(page_leaves(buffers))
         if not leaves:
             raise ValueError("PagePool needs a non-empty buffer tree")
         max_pages = int(leaves[0].shape[0])
@@ -105,67 +210,14 @@ class PagePool:
         #: device bytes one page owns across every layer's buffers
         #: (scale sidecars included) — the billing unit
         self.page_bytes = sum(int(l.nbytes) for l in leaves) // max_pages
-        # LIFO free list: recently freed pages are re-issued first so a
-        # churning workload keeps touching the same HBM region
-        self._free: List[int] = list(range(max_pages - 1, 0, -1))
-        self._refs = np.zeros(max_pages, np.int32)
-        self._lock = threading.Lock()
-        # cumulative flow
-        self.allocated = 0
-        self.shared = 0
+        super().__init__(max_pages, first=1)   # page 0 is scratch
         self.cow_forks = 0
-        self.freed = 0
-
-    # ------------------------------------------------------------ alloc
-    def alloc(self, n: int) -> Optional[List[int]]:
-        """Claim ``n`` fresh pages (refcount 1 each), all-or-nothing:
-        ``None`` when fewer than ``n`` pages are free, so a caller
-        never holds a partial reservation it must unwind."""
-        if n < 0:
-            raise ValueError(f"alloc(n={n})")
-        with self._lock:
-            if len(self._free) < n:
-                return None
-            pages = [self._free.pop() for _ in range(n)]
-            for p in pages:
-                self._refs[p] = 1
-            self.allocated += n
-            return pages
-
-    def share(self, pages: Sequence[int]) -> None:
-        """Add one reference to each page — the whole of what a prefix
-        hit or a table fork costs. Sharing a free page is a
-        bookkeeping bug and fails loudly."""
-        with self._lock:
-            for p in pages:
-                if self._refs[p] <= 0:
-                    raise RuntimeError(
-                        f"share() of free page {p}")
-                self._refs[p] += 1
-            self.shared += len(pages)
-
-    def free(self, pages: Sequence[int]) -> None:
-        """Drop one reference from each page; a page returns to the
-        free list only when its last reference drops."""
-        with self._lock:
-            for p in pages:
-                if self._refs[p] <= 0:
-                    raise RuntimeError(
-                        f"free() of free page {p}")
-                self._refs[p] -= 1
-                if self._refs[p] == 0:
-                    self._free.append(p)
-                    self.freed += 1
 
     def note_cow_fork(self) -> None:
         with self._lock:
             self.cow_forks += 1
 
     # ------------------------------------------------------------ views
-    def refcount(self, page: int) -> int:
-        with self._lock:
-            return int(self._refs[page])
-
     @property
     def free_pages(self) -> int:
         with self._lock:
@@ -294,21 +346,105 @@ class BlockTable:
         self.pages = []
 
 
+class SnapshotStore(_RefCounts):
+    """Refcounted allocator over a fixed device store of lane-state
+    copies (the engine owns the arrays; this decides which ids are
+    live). Holders are requests in flight (the snapshot they resumed
+    from and those their prefill took) and prefix entries; a snapshot
+    returns to the free list when its last reference drops.
+    ``last_used`` is stamped when a snapshot is taken and when one is
+    restored: what nothing resumes from ages out first
+    (``PagedPrefixIndex.reclaim_snapshot``)."""
+
+    noun = "snapshot"
+
+    def __init__(self, capacity: int, snapshot_bytes: int):
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        self.capacity = int(capacity)
+        self.snapshot_bytes = int(snapshot_bytes)
+        super().__init__(self.capacity, first=0)
+        self._used = np.zeros(capacity, np.int64)
+        self._stamp = 0
+        # cumulative flow (snapshots taken are ``allocated``)
+        self.restored = 0
+        self.skipped = 0
+
+    def take(self) -> Optional[int]:
+        """A free snapshot id with one reference, stamped as just used;
+        None when the store is full."""
+        got = self.alloc(1)
+        if got is None:
+            return None
+        with self._lock:
+            self._stamp += 1
+            self._used[got[0]] = self._stamp
+        return got[0]
+
+    def touch(self, sid: int) -> None:
+        """A restore read ``sid``."""
+        with self._lock:
+            self._stamp += 1
+            self._used[sid] = self._stamp
+            self.restored += 1
+
+    def note_skipped(self) -> None:
+        with self._lock:
+            self.skipped += 1
+
+    def last_used(self, sid: int) -> int:
+        with self._lock:
+            return int(self._used[sid])
+
+    @property
+    def in_use(self) -> int:
+        with self._lock:
+            return self.capacity - len(self._free)
+
+    def stats(self) -> dict:
+        with self._lock:
+            used = self.capacity - len(self._free)
+            return {"snapshot_capacity": self.capacity,
+                    "snapshots_in_use": used,
+                    "snapshot_bytes": self.snapshot_bytes,
+                    "bytes_in_use": used * self.snapshot_bytes,
+                    "taken_total": self.allocated,
+                    "restored_total": self.restored,
+                    "skipped_total": self.skipped,
+                    "freed_total": self.freed}
+
+
+class PrefixMatch(NamedTuple):
+    """The best cached prefix, how many of its tokens an admission may
+    skip (``length``), and how many it shares (``matched``). The two
+    differ only for a model with lane state, where ``length`` is the
+    deepest snapshot at or under ``matched`` (and under the prompt's
+    last position, which is always computed)."""
+
+    entry: Optional["PrefixEntry"]
+    length: int
+    matched: int
+
+
 class PrefixEntry:
     """One retained prefix: ``tokens`` (the exact token ids whose KV
     ``pages`` hold, positions ``0..length-1``, in position order) and
-    the LRU/ref-count bookkeeping. ``tier`` says where the KV currently
+    the LRU/ref-count bookkeeping. ``snaps`` (a model with lane state
+    only) are the ``(position, snapshot id)`` pairs, ascending, at which
+    the recurrent state of this prefix is kept; the entry holds one
+    reference on each. ``tier`` says where the KV currently
     lives: ``"device"`` (``pages`` of the pool) or ``"host"``
     (``host_buf``, one engine-opaque pinned host buffer per page;
     ``pages`` is empty while demoted so stale use shares nothing)."""
 
     __slots__ = ("tokens", "pages", "refs", "last_used", "hits", "tier",
-                 "host_buf")
+                 "host_buf", "snaps")
 
     def __init__(self, tokens: np.ndarray, pages: Tuple[int, ...],
-                 stamp: int):
+                 stamp: int, snaps: Tuple[Tuple[int, int], ...] = ()):
         self.tokens = tokens
         self.pages = pages
+        self.snaps = snaps
         self.refs = 0
         self.last_used = stamp
         self.hits = 0
@@ -318,6 +454,15 @@ class PrefixEntry:
     @property
     def length(self) -> int:
         return int(self.tokens.shape[0])
+
+    def resume_at(self, limit: int) -> Tuple[int, Optional[int]]:
+        """The deepest ``(position, snapshot id)`` at or under ``limit``;
+        ``(0, None)`` where none stands."""
+        best = (0, None)
+        for pos, sid in self.snaps:
+            if best[0] < pos <= limit:
+                best = (pos, sid)
+        return best
 
     def __repr__(self):
         return (f"PrefixEntry(len={self.length}, "
@@ -362,7 +507,7 @@ class PagedPrefixIndex:
 
     Pure HOST bookkeeping; storage motion is refcounts:
 
-    * ``lookup(prompt)`` → best ``(entry, matched)``; the engine
+    * ``match(prompt)`` → best ``(entry, length, matched)``; the engine
       consumes a hit as ``entry.pages[: base // page_size]`` via
       ``PagePool.share`` and commits it with ``record_hit``.
     * ``donate_pages(tokens, pages)`` — a finished/preempted slot's
@@ -391,7 +536,13 @@ class PagedPrefixIndex:
 
     def __init__(self, pool: PagePool, *, max_entries: int,
                  min_tokens: int = 1, token_bytes: float = 0.0,
-                 devices: int = 1, host_pages: int = 0):
+                 devices: int = 1, host_pages: int = 0,
+                 snapshots: Optional[SnapshotStore] = None):
+        if snapshots is not None and host_pages > 0:
+            raise ValueError(
+                "a prefix index whose entries hold state snapshots has "
+                "no host tier: a demoted entry's pages would come back "
+                "without the state that belongs to them")
         if max_entries < 0:
             raise ValueError(
                 f"max_entries must be >= 0, got {max_entries}")
@@ -418,6 +569,13 @@ class PagedPrefixIndex:
         #: host-tier budget in PAGES (0 disables the tier; eviction
         #: then drops instead of demoting)
         self.host_pages = int(host_pages) if max_entries > 0 else 0
+        #: the lane-state snapshot allocator (a model with lane state);
+        #: None for a model that is pages alone
+        self.snapshots = snapshots
+        #: matched tokens admissions prefilled again because no snapshot
+        #: stood at the match (cumulative, ``record_hit``/``record_miss``)
+        self.shortfall_tokens = 0
+        self.hits_shortened = 0
         self._root = _Node()
         self._entries: List[PrefixEntry] = []
         self._host_entries: List[PrefixEntry] = []
@@ -449,8 +607,7 @@ class PagedPrefixIndex:
             return len(self._entries)
 
     # ------------------------------------------------------------ match
-    def lookup(self, prompt: np.ndarray
-               ) -> Tuple[Optional[PrefixEntry], int]:
+    def match(self, prompt: np.ndarray) -> PrefixMatch:
         """Best cached prefix for ``prompt``: walk the trie as deep as
         the prompt's tokens agree, then take the better of (a) the
         deepest entry ENDING on the walked path (a full-entry match —
@@ -458,8 +615,13 @@ class PagedPrefixIndex:
         entry in the subtree below the divergence point (a PARTIAL
         match: the entry shares exactly the walked depth, then
         diverges or extends — its KV is still valid for the shared
-        head, by causality). Returns ``(entry, matched_tokens)`` with
-        ``matched >= min_tokens``, else ``(None, 0)``.
+        head, by causality). Returns ``PrefixMatch(entry, length,
+        matched)`` with ``matched >= min_tokens``, else ``(None, 0,
+        0)``. With a snapshot store a candidate is worth the deepest
+        snapshot it holds at or under what it shares (and under the
+        prompt's last position), so ``length <= matched``, and among
+        the entries below a divergence the one that resumes deepest
+        wins, the most recently used on ties.
 
         PURE: no counters move and no LRU stamp is touched — the
         engine uses ``lookup`` both to probe admissions and to SCORE
@@ -469,12 +631,19 @@ class PagedPrefixIndex:
         prompt = np.asarray(prompt, np.int32)
         with self._lock:
             best: Optional[PrefixEntry] = None
-            best_len = 0
+            best_len = best_raw = 0
+            stateful = self.snapshots is not None
+            cap = int(prompt.shape[0]) - 1
 
             def consider(cand: Optional[PrefixEntry], ln: int):
-                nonlocal best, best_len
-                if cand is not None and ln > best_len:
-                    best, best_len = cand, ln
+                nonlocal best, best_len, best_raw
+                if cand is None:
+                    return
+                raw = ln
+                if stateful:
+                    ln = cand.resume_at(min(ln, cap))[0]
+                if (ln, raw) > (best_len, best_raw):
+                    best, best_len, best_raw = cand, ln, raw
 
             node, depth, off = self._root, 0, prompt
             while True:
@@ -483,13 +652,13 @@ class PagedPrefixIndex:
                 if off.shape[0] == 0:
                     # prompt exhausted AT a node: entries extending
                     # below all share the full walked depth
-                    consider(self._mru_below(node), depth)
+                    consider(self._mru_below(node, min(depth, cap)), depth)
                     break
                 nxt = node.children.get(int(off[0]))
                 if nxt is None:
                     # no child continues the prompt, but every entry
                     # below this node still shares `depth` tokens
-                    consider(self._mru_below(node), depth)
+                    consider(self._mru_below(node, min(depth, cap)), depth)
                     break
                 edge, child = nxt
                 m = _common_len(edge, off)
@@ -497,12 +666,20 @@ class PagedPrefixIndex:
                 if m < edge.shape[0]:
                     # diverged (or prompt exhausted) mid-edge: every
                     # entry below shares exactly `depth` tokens
-                    consider(self._mru_below(child), depth)
+                    consider(self._mru_below(child, min(depth, cap)), depth)
                     break
                 node, off = child, off[m:]
-            if best is None or best_len < self.min_tokens:
-                return None, 0
-            return best, best_len
+            if best is None or best_raw < self.min_tokens:
+                return PrefixMatch(None, 0, 0)
+            return PrefixMatch(best, best_len, best_raw)
+
+    def record_shortfall(self, tokens: int) -> None:
+        """``tokens`` matched positions are prefilled again: no snapshot
+        stood at the match (the engine's admission decision)."""
+        with self._lock:
+            if tokens > 0:
+                self.shortfall_tokens += int(tokens)
+                self.hits_shortened += 1
 
     def record_hit(self, entry: PrefixEntry, reused_tokens: int,
                    host: bool = False) -> None:
@@ -525,15 +702,21 @@ class PagedPrefixIndex:
         with self._lock:
             self.misses += 1
 
-    def _mru_below(self, node: _Node) -> Optional[PrefixEntry]:
+    def _mru_below(self, node: _Node, limit: Optional[int] = None
+                   ) -> Optional[PrefixEntry]:
         """Most-recently-used entry in ``node``'s subtree (entry count
         is bounded by ``max_entries`` plus the host tier, so the DFS
-        is trivially cheap)."""
+        is trivially cheap). With a snapshot store and a ``limit``,
+        the entry that resumes deepest at or under ``limit`` first."""
+        # graftlint: ok[lock-discipline] — the store reference is immutable after __init__ (callers hold the index lock)
+        if self.snapshots is None or limit is None:
+            key = lambda e: e.last_used
+        else:
+            key = lambda e: (e.resume_at(limit)[0], e.last_used)
         best = node.entry
         for edge, child in node.children.values():
-            c = self._mru_below(child)
-            if c is not None and (best is None
-                                  or c.last_used > best.last_used):
+            c = self._mru_below(child, limit)
+            if c is not None and (best is None or key(c) > key(best)):
                 best = c
         return best
 
@@ -567,7 +750,8 @@ class PagedPrefixIndex:
 
     # --------------------------------------------------------- donation
     def donate_pages(self, tokens: np.ndarray,
-                     pages: Sequence[int]) -> bool:
+                     pages: Sequence[int],
+                     snaps: Sequence[Tuple[int, int]] = ()) -> bool:
         """Retain a finished request's prefix by sharing the ``pages``
         that hold its KV (position order; the caller keeps its own
         references — the slot's table is freed separately). Declined
@@ -575,7 +759,10 @@ class PagedPrefixIndex:
         (LRU-touched instead), or the entry budget is exhausted by
         pinned entries. May evict the LRU ``refs == 0`` entry — the
         budget resolves by recency, never by silently dropping pinned
-        entries."""
+        entries. ``snaps`` (a model with lane state) are the donor's
+        ``(position, snapshot id)`` pairs at or under the donated
+        length; the entry SHARES each, the donor keeps its own
+        references."""
         # own the key: np.asarray would ALIAS an int32 caller buffer,
         # and a client reusing one preallocated prompt array across
         # requests would then rewrite the trie key under an entry
@@ -607,9 +794,13 @@ class PagedPrefixIndex:
             # index -> pool lock order (see module docstring): the pool
             # never calls back into the index, so this nesting is safe
             self.pool.share(held)
+            kept = tuple(sorted((int(p), int(sid)) for p, sid in snaps
+                                if p <= tokens.shape[0]))
+            if self.snapshots is not None:
+                self.snapshots.share([sid for _, sid in kept])
             self._stamp += 1
             self.generation += 1
-            entry = PrefixEntry(tokens, held, self._stamp)
+            entry = PrefixEntry(tokens, held, self._stamp, kept)
             self._insert(entry)
             self._entries.append(entry)
             self.donations += 1
@@ -622,8 +813,40 @@ class PagedPrefixIndex:
         self._trie_remove(entry)
         self.pool.free(entry.pages)
         entry.pages = ()
+        self._free_snaps(entry)
         self.evictions += 1
         self.generation += 1
+
+    def _free_snaps(self, entry: PrefixEntry) -> None:
+        """An entry that goes takes its snapshot references with it."""
+        if self.snapshots is not None and entry.snaps:
+            self.snapshots.free([sid for _, sid in entry.snaps])
+        entry.snaps = ()
+
+    def reclaim_snapshot(self) -> bool:
+        """Free one snapshot for a prefill that wants to take one: the
+        least recently used snapshot that only unpinned entries hold
+        leaves every one of them (the entries stay, their matches
+        resume shallower). False when every snapshot is held by a
+        request in flight or a pinned entry."""
+        # graftlint: ok[lock-discipline] — the store reference is immutable after __init__ and has its own lock
+        store = self.snapshots
+        with self._lock:
+            holders: Dict[int, List[PrefixEntry]] = {}
+            for e in self._entries:
+                for _, sid in e.snaps:
+                    holders.setdefault(sid, []).append(e)
+            free_able = [sid for sid, es in holders.items()
+                         if store.refcount(sid) == len(es)
+                         and all(e.refs == 0 for e in es)]
+            if not free_able:
+                return False
+            victim = min(free_able, key=store.last_used)
+            for e in holders[victim]:
+                e.snaps = tuple(p for p in e.snaps if p[1] != victim)
+            store.free([victim] * len(holders[victim]))
+            self.generation += 1
+            return True
 
     # --------------------------------------------------------- pressure
     def reclaim(self, n_pages: int,
@@ -656,6 +879,7 @@ class PagedPrefixIndex:
                     self._host_entries.append(victim)
                 else:
                     self._trie_remove(victim)
+                    self._free_snaps(victim)
             held = victim.pages
             if demote:
                 buf = spill(held)
@@ -882,6 +1106,8 @@ class PagedPrefixIndex:
                 "demotions": self.demotions,
                 "promotions": self.promotions,
                 "host_evictions": self.host_evictions,
+                "shortfall_tokens": self.shortfall_tokens,
+                "hits_shortened": self.hits_shortened,
             }
 
     def snapshot(self) -> List[dict]:
@@ -889,6 +1115,7 @@ class PagedPrefixIndex:
         first)."""
         with self._lock:
             return [{"length": e.length, "pages": list(e.pages),
+                     "snapshots": [list(p) for p in e.snaps],
                      "tier": e.tier, "refs": e.refs, "hits": e.hits,
                      "last_used": e.last_used}
                     for e in sorted(self._entries + self._host_entries,

@@ -68,7 +68,8 @@ def _to_host(module):
             m._parameters[k] = np.asarray(m._parameters[k])
             object.__setattr__(m, k, m._parameters[k])
         for k in list(m._gradients):
-            m._gradients[k] = np.asarray(m._gradients[k])
+            if m._gradients[k] is not None:
+                m._gradients[k] = np.asarray(m._gradients[k])
         for k in list(m._buffers):
             m._buffers[k] = np.asarray(m._buffers[k])
             object.__setattr__(m, k, m._buffers[k])
@@ -79,7 +80,8 @@ def _to_device(module):
         for k in list(m._parameters):
             m._set_param(k, jnp.asarray(m._parameters[k]))
         for k in list(m._gradients):
-            m._gradients[k] = jnp.asarray(m._gradients[k])
+            if m._gradients[k] is not None:
+                m._gradients[k] = jnp.asarray(m._gradients[k])
         for k in list(m._buffers):
             m._set_buffer(k, jnp.asarray(m._buffers[k]))
 

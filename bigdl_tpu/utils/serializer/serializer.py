@@ -202,7 +202,7 @@ def _materialize(mid: str, records, tensors, memo) -> Module:
 
     for k, key in rec["params"].items():
         inst._set_param(k, jnp.asarray(tensors[key]))
-        inst._gradients[k] = jnp.zeros_like(inst._parameters[k])
+        inst._gradients[k] = None
     for k, key in rec["buffers"].items():
         inst._set_buffer(k, jnp.asarray(tensors[key]))
     for k, enc in rec.get("extra", {}).items():

@@ -212,6 +212,63 @@ def test_paged_engine_program_compiles(topo, one_chip, engine, program):
             f"parent's (per-head einsums over the gathered pages) {parent}")
 
 
+# ------------------------------------------- the hybrid decoder's programs
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_hybrid_decoder_program_compiles_and_holds_its_pool_in_place(
+        topo, one_chip, program):
+    """One period of the hybrid decoder (3 gated delta-rule layers and a
+    full-attention layer) at its published widths, built as shapes: the
+    decode step over 16 lanes and the 2 x 256 prefill chunk compile for the
+    chip, the pool (pages AND lane state) is donated and aliased in full,
+    pages take their logical bytes, and the lane state takes 4/3 of its
+    (the minor 192 of S pads to 256 lanes of the tile): the factor the
+    benchmark's adapter budgets with."""
+    from benchmark.models import olmo_hybrid as adapter
+    from bigdl_tpu.models.hybrid import HybridDecoderLM
+    from bigdl_tpu.nn.module import abstract_init, bind
+
+    kinds = ("linear_attention",) * 3 + ("full_attention",)
+    slots, rows, chunk, page, ctx = 16, 2, 256, 16, 4096
+    model = abstract_init(lambda: HybridDecoderLM(
+        100352, 3840, 30, kinds, 11008, ctx, num_kv_heads=30,
+        linear_heads=30, linear_key_dim=96, linear_value_dim=192))
+    model.evaluate()
+    sd = lambda a, dt=None: jax.ShapeDtypeStruct(
+        a.shape, dt or a.dtype, sharding=one_chip)
+    params = jax.tree.map(lambda a: sd(a, jnp.bfloat16), model.params_dict())
+    pool = jax.tree.map(sd, jax.eval_shape(lambda: model.init_page_pool(
+        1 + slots * ctx // page, page, dtype=jnp.bfloat16, lanes=slots + 1)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def step(p, tok, pos, pool, tables, active):
+        with bind(model, p, {}, False, None):
+            logits, pool = model.decode_step_paged(
+                tok, pos, pool, tables, active=active)
+        return jnp.argmax(logits, -1), pool
+
+    def prefill(p, ids, pool, tables, pos0, last, lanes):
+        with bind(model, p, {}, False, None):
+            return model.prefill_chunk_at_paged(ids, pool, tables, pos0,
+                                                last, lanes=lanes)
+
+    if program == "step":
+        compiled = jax.jit(step, donate_argnums=(3,)).lower(
+            params, i32(slots), i32(slots), pool, i32(slots, ctx // page),
+            jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
+    else:
+        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(rows, chunk), pool, i32(rows, ctx // page), i32(rows),
+            i32(rows), i32(rows)).compile()
+    m = _fits(compiled)
+    size = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    pages, lanes = size(pool["pages"]), size(pool["lanes"])
+    assert m.alias_size_in_bytes >= pages + lanes
+    padded = m.alias_size_in_bytes - pages
+    assert abs(padded / lanes - adapter.LANE_DEVICE_FACTOR) < 0.01, (
+        padded, lanes)
+
+
 # ------------------------------------------------------- across four chips
 def test_tensor_parallel_decode_step_compiles_on_four_chips(topo,
                                                             mesh_engine):

@@ -266,6 +266,13 @@ FIXTURES = {
         3, cell=nn.RnnCell(4, 4, nn.Tanh())), lambda: _f(2, 4)),
     # attention
     "mha": (lambda: nn.MultiHeadAttention(8, 2), lambda: _f(2, 5, 8)),
+    "mha_qknorm": (lambda: nn.MultiHeadAttention(8, 2, with_bias=False,
+                                                 causal=True, qk_norm=True),
+                   lambda: _f(2, 5, 8)),
+    "rmsnorm": (lambda: nn.RMSNorm(6), lambda: _f(2, 6)),
+    "gatedmlp": (lambda: nn.GatedMLP(6, 10), lambda: _f(2, 5, 6)),
+    "gateddeltanet": (lambda: nn.GatedDeltaNet(8, 2, 4, 6),
+                      lambda: _f(2, 9, 8)),
     "transformer_block": (lambda: nn.TransformerBlock(8, 2),
                           lambda: _f(2, 5, 8)),
 }

@@ -95,20 +95,20 @@ def test_radix_trie_match_semantics():
     assert len(pc) == 2
 
     # exact: the full entry is a prefix of the prompt
-    e, m = pc.lookup(np.asarray([1, 2, 3, 4, 5, 6, 7, 8, 30], np.int32))
+    e, m = pc.match(np.asarray([1, 2, 3, 4, 5, 6, 7, 8, 30], np.int32))[:2]
     assert m == 8 and np.array_equal(e.tokens, t1)
     # partial: prompt diverges mid-entry — the shared head still counts
-    e, m = pc.lookup(np.asarray([1, 2, 3, 4, 5, 6, 30, 30], np.int32))
+    e, m = pc.match(np.asarray([1, 2, 3, 4, 5, 6, 30, 30], np.int32))[:2]
     assert m == 6 and np.array_equal(e.tokens, t1)
     # truncated: the prompt is SHORTER than every entry — KV causality
     # still makes the shared head valid
-    e, m = pc.lookup(np.asarray([1, 2, 3, 4, 9], np.int32))
+    e, m = pc.match(np.asarray([1, 2, 3, 4, 9], np.int32))[:2]
     assert m == 5 and np.array_equal(e.tokens, t2)
     # below the min_tokens floor: no match
-    e, m = pc.lookup(np.asarray([1, 2, 3, 30], np.int32))
+    e, m = pc.match(np.asarray([1, 2, 3, 30], np.int32))[:2]
     assert e is None and m == 0
     # total miss
-    e, m = pc.lookup(np.asarray([7, 7, 7, 7, 7], np.int32))
+    e, m = pc.match(np.asarray([7, 7, 7, 7, 7], np.int32))[:2]
     assert e is None and m == 0
     # lookup is PURE: nothing above moved the counters
     assert pc.stats()["hits"] == 0 and pc.stats()["misses"] == 0
@@ -123,7 +123,7 @@ def test_radix_trie_match_semantics():
     buf = np.asarray([5, 5, 5, 5, 5, 5], np.int32)
     assert _serve(pool, pc, buf)
     buf[:] = 9
-    e, m = pc.lookup(np.asarray([5, 5, 5, 5, 5, 5, 1], np.int32))
+    e, m = pc.match(np.asarray([5, 5, 5, 5, 5, 5, 1], np.int32))[:2]
     assert m == 6 and np.array_equal(e.tokens, [5] * 6)
     # an entry holds exactly the pages that cover its key
     assert len(e.pages) == 2 and pc.stats()["pages"] == 6
@@ -140,28 +140,28 @@ def test_lru_and_refcount_eviction_under_byte_pressure():
     assert pc.bytes_in_use == 4 * pool.page_bytes == pool.bytes_in_use
     assert pc.capacity_bytes == pool.capacity_bytes
     # touch t1 so t2 is the LRU victim
-    e1, _ = pc.lookup(t1)
+    e1, _ = pc.match(t1)[:2]
     pc.record_hit(e1, 8)
     assert _serve(pool, pc, t3) and pc.stats()["evictions"] == 1
-    assert pc.lookup(t2)[0] is None          # t2 evicted
-    assert pc.lookup(t1)[0] is not None      # t1 survived (recently used)
+    assert pc.match(t2).entry is None          # t2 evicted
+    assert pc.match(t1).entry is not None      # t1 survived (recently used)
 
     # ref-count: a PINNED entry is never evicted, even at full budget
     pc.acquire(e1)
     t4 = np.asarray([4] * 8, np.int32)
-    e3, _ = pc.lookup(t3)
+    e3, _ = pc.match(t3)[:2]
     pc.acquire(e3)
     assert _serve(pool, pc, t4) is False     # both pinned: declined
     pc.release(e3)
     assert _serve(pool, pc, t4)              # t3 evictable now
-    assert pc.lookup(t1)[0] is e1            # the pinned entry survived
+    assert pc.match(t1).entry is e1            # the pinned entry survived
 
     # byte pressure: with the pool full, a request's pages come out of
     # the LRU UNPINNED entry — never the pinned one
     live = pool.alloc(2)
     assert pool.free_pages == 0 and not pc.reclaim(4)
-    assert pool.free_pages == 2 and pc.lookup(t4)[0] is None
-    assert pc.lookup(t1)[0] is e1 and e1.pages
+    assert pool.free_pages == 2 and pc.match(t4).entry is None
+    assert pc.match(t1).entry is e1 and e1.pages
     pool.free(live)
     pc.release(e1)
     with pytest.raises(RuntimeError, match="acquire"):
@@ -181,7 +181,7 @@ def test_policy_and_cache_validation():
     # lookups miss
     pool, pc = _index(4, max_entries=0)
     assert _serve(pool, pc, np.arange(8, dtype=np.int32)) is False
-    assert pc.lookup(np.arange(8, dtype=np.int32)) == (None, 0)
+    assert pc.match(np.arange(8, dtype=np.int32))[:2] == (None, 0)
     assert pool.pages_in_use == 0
 
 

@@ -116,20 +116,20 @@ def test_host_lru_and_byte_budget():
     seen = []
 
     def spill(pages):
-        e, m = pc.lookup(ts[0])
+        e, m = pc.match(ts[0])[:2]
         seen.append((m, e.tier, pc.host_bytes_in_use))
         return _spill(pages)
 
     assert _serve(pool, pc, ts[2], spill)
     assert seen == [(8, "host", 0)]
-    e0, m = pc.lookup(ts[0])
+    e0, m = pc.match(ts[0])[:2]
     assert m == 8 and e0.tier == "host" and e0.pages == ()
     assert pc.host_bytes_in_use == 2 * pool.page_bytes
     assert pc.stats()["demotions"] == 1
 
     # fourth: ts[1] demotes too — host tier now at its budget
     assert _serve(pool, pc, ts[3])
-    assert pc.lookup(ts[1])[0].tier == "host"
+    assert pc.match(ts[1]).entry.tier == "host"
     assert pc.host_bytes_in_use == 4 * pool.page_bytes \
         == pc.host_capacity_bytes
     assert pc.stats()["host_entries"] == 2
@@ -138,8 +138,8 @@ def test_host_lru_and_byte_budget():
     # stamp) truly leaves the cache to make room for the new demotion
     assert _serve(pool, pc, ts[4])
     assert pc.stats()["host_evictions"] == 1
-    assert pc.lookup(ts[0])[0] is None
-    e, _ = pc.lookup(ts[1])
+    assert pc.match(ts[0]).entry is None
+    e, _ = pc.match(ts[1])[:2]
     assert e is not None and e.tier == "host"
 
     # host hits split from device hits in the counters
@@ -160,13 +160,13 @@ def test_pin_spans_demote_and_blocks_host_eviction():
     pool, pc = _index(5, max_entries=8, host_pages=2)
     t1, t2, t3, t4 = (np.asarray([k] * 8, np.int32) for k in range(1, 5))
     assert _serve(pool, pc, t1) and _serve(pool, pc, t2)
-    e1, _ = pc.lookup(t1)
+    e1, _ = pc.match(t1)[:2]
     pc.acquire(e1)
 
     # pinned device entry survives: the victim is t2
     assert _serve(pool, pc, t3)
     assert e1.tier == "device"
-    e2, _ = pc.lookup(t2)
+    e2, _ = pc.match(t2)[:2]
     assert e2.tier == "host"
     pc.acquire(e2)                     # pin SPANS the demoted tier
 
@@ -175,8 +175,8 @@ def test_pin_spans_demote_and_blocks_host_eviction():
     assert _serve(pool, pc, t4)
     assert pc.stats()["demotions"] == 1
     assert pc.stats()["host_evictions"] == 0
-    assert pc.lookup(t3)[0] is None
-    e2b, m = pc.lookup(t2)
+    assert pc.match(t3).entry is None
+    e2b, m = pc.match(t2)[:2]
     assert e2b is e2 and m == 8 and e2.host_buf == ["host-kv"] * 2
 
     pc.release(e1), pc.release(e2)
@@ -191,7 +191,7 @@ def test_generation_guard_covers_host_tier():
     pool, pc = _index(3, max_entries=8, host_pages=2)
     t1, t2 = np.asarray([1] * 8, np.int32), np.asarray([2] * 8, np.int32)
     assert _serve(pool, pc, t1)
-    e1, m = pc.lookup(t1)
+    e1, m = pc.match(t1)[:2]
     probe_gen = pc.generation
 
     # lookup racing a demotion: the admission that demotes e1 bumps
@@ -203,7 +203,7 @@ def test_generation_guard_covers_host_tier():
     # promote racing a host eviction: capture e1 as a host-tier probe,
     # then evict its buffer — generation moves again, host_buf clears,
     # and promote_pages() of the evicted entry refuses outright
-    e1b, _ = pc.lookup(t1)
+    e1b, _ = pc.match(t1)[:2]
     assert e1b is e1
     probe_gen = pc.generation
     t3 = np.asarray([3] * 8, np.int32)
@@ -212,12 +212,12 @@ def test_generation_guard_covers_host_tier():
     assert e1.host_buf is None
     with pytest.raises(RuntimeError, match="non-host"):
         pc.promote_pages(e1, (1, 2))
-    assert pc.lookup(t1)[0] is None
+    assert pc.match(t1).entry is None
 
     # a spill completing after its entry left the host tier (the copy
     # runs outside the index lock) is a no-op — the stale buffer is
     # dropped, not re-attached
-    e3, _ = pc.lookup(t3)
+    e3, _ = pc.match(t3)[:2]
 
     def racing_spill(pages):
         pc.drop_all()
@@ -225,18 +225,18 @@ def test_generation_guard_covers_host_tier():
 
     demoted = pc.stats()["demotions"]
     assert _serve(pool, pc, np.asarray([4] * 8, np.int32), racing_spill)
-    assert e3.host_buf is None and pc.lookup(t3)[0] is None
+    assert e3.host_buf is None and pc.match(t3).entry is None
     assert pc.stats()["demotions"] == demoted
 
     # a demotion whose d2h copy FAILED (buf None) drops the entry and
     # bumps generation — a later promotion can never read garbage
-    e4, _ = pc.lookup(np.asarray([4] * 8, np.int32))
+    e4, _ = pc.match(np.asarray([4] * 8, np.int32))[:2]
     assert e4 is not None and e4.tier == "device"
     gen = pc.generation
     assert _serve(pool, pc, np.asarray([5] * 8, np.int32),
                   lambda pages: None)
     assert pc.generation != gen
-    assert pc.lookup(np.asarray([4] * 8, np.int32))[0] is None
+    assert pc.match(np.asarray([4] * 8, np.int32)).entry is None
     assert pc.stats()["host_entries"] == 0
 
     # a fallen-through promotion returns its claimed pages to the free
