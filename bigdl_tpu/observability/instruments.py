@@ -42,8 +42,17 @@ def train_instruments(registry: Optional[MetricRegistry] = None
     return SimpleNamespace(
         step_seconds=r.histogram(
             "bigdl_train_step_seconds",
-            "Wall time of one training step (dispatch + host sync)",
+            "Wall time of one training step: from its dispatch, or from "
+            "the loss of the step before it where that one still ran, to "
+            "its own loss fetched",
             buckets=TIME_BUCKETS),
+        fences=r.counter(
+            "bigdl_train_fences_total",
+            "Steps whose loss the loop has fetched, by ``behind``: 1 when "
+            "the next step was already dispatched (the device did not wait "
+            "for the host), 0 when the loop was synchronous (a reader of "
+            "the loss, an aux point, the last step)",
+            labelnames=("behind",)),
         records_total=r.counter(
             "bigdl_train_records_total",
             "Training records consumed"),

@@ -2,7 +2,7 @@
 
 from bigdl_tpu.optim.optim_method import (
     OptimMethod, SGD, Adam, AdamW, Adagrad, Adadelta, Adamax, RMSprop, Ftrl,
-    LBFGS, ParallelAdam,
+    LBFGS, ParallelAdam, TrainState,
     LearningRateSchedule, Default, Poly, Step, MultiStep, EpochStep, EpochDecay,
     Exponential, Plateau, Warmup, SequentialSchedule, EpochSchedule, NaturalExp,
     CosineDecay,

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import math
 import pickle
+from collections import ChainMap
 from typing import Any, Dict, Optional
 
 import jax
@@ -149,9 +150,11 @@ class Plateau(LearningRateSchedule):
     def rate(self, method, state):
         if self._lr is None:
             self._lr = method.learning_rate
-        cur = state.get(self.monitor)
         epoch = state.get("epoch", 1)
-        if cur is not None and epoch != self._last_epoch:
+        # the monitored value is read once an epoch, where it is used: a
+        # read of ``Loss`` waits for the step in flight (TrainState)
+        cur = state.get(self.monitor) if epoch != self._last_epoch else None
+        if cur is not None:
             self._last_epoch = epoch
             if self._cooldown_left > 0:
                 self._cooldown_left -= 1
@@ -196,9 +199,12 @@ class SequentialSchedule(LearningRateSchedule):
         offset = 0
         for sched, cnt in self.schedules:
             if n < offset + cnt or (sched, cnt) == self.schedules[-1]:
-                sub = dict(state)
-                sub["neval"] = n - offset + 1
-                sub["epoch"] = (n - offset) // max(self.iteration_per_epoch, 1) + 1
+                # its own counters over the table's other keys, which are
+                # read through and not copied (TrainState: a copy settles)
+                sub = ChainMap({
+                    "neval": n - offset + 1,
+                    "epoch": (n - offset) // max(self.iteration_per_epoch, 1) + 1,
+                }, state)
                 return sched.rate(method, sub)
             offset += cnt
         return method.learning_rate
@@ -260,6 +266,75 @@ class NaturalExp(LearningRateSchedule):
 
 
 # ---------------------------------------------------------------------------
+# The state table
+# ---------------------------------------------------------------------------
+class TrainState(dict):
+    """The state table of an ``OptimMethod`` (epoch / neval / Loss / score /
+    recordsProcessedThisEpoch, Appendix B.7): a dict in which a key can be
+    *deferred*. ``defer(key, fetch)`` makes the next read of ``key`` call
+    ``fetch()`` and keep what it returns, so a value that is still on the
+    device costs its wait only where somebody reads it: the train loop defers
+    ``Loss`` of the step it has just dispatched, a trigger or schedule that
+    reads it gets that step's loss as it always did, and a loop whose
+    triggers read only counters runs a step ahead of the device.
+
+    Reads by key (``[]``, ``get``) settle that key; reads of the whole table
+    (``items``, ``values``, ``copy``, iteration into another dict, pickling)
+    settle every key; a write through ``[]`` or ``update`` replaces a
+    deferred value. It pickles as its settled contents."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self._deferred = {}
+
+    def defer(self, key, fetch) -> None:
+        self._deferred[key] = fetch
+        super().setdefault(key, None)     # the key is there for `in`
+
+    def settle(self, key=None) -> None:
+        """Fetch ``key`` if it is deferred; every deferred key for None."""
+        keys = list(self._deferred) if key is None else \
+            [key] if key in self._deferred else ()
+        for k in keys:
+            super().__setitem__(k, self._deferred.pop(k)())
+
+    def __getitem__(self, key):
+        self.settle(key)
+        return super().__getitem__(key)
+
+    def get(self, key, default=None):
+        self.settle(key)
+        return super().get(key, default)
+
+    def __setitem__(self, key, value):
+        self._deferred.pop(key, None)
+        super().__setitem__(key, value)
+
+    def update(self, *args, **kw):
+        for k, v in dict(*args, **kw).items():
+            self[k] = v
+
+    def __iter__(self):
+        # overridden so that dict(state) and {**state} copy through
+        # __getitem__ and not from the raw storage under it
+        return super().__iter__()
+
+    def items(self):
+        self.settle()
+        return super().items()
+
+    def values(self):
+        self.settle()
+        return super().values()
+
+    def copy(self):
+        return TrainState(self.items())
+
+    def __reduce__(self):
+        return TrainState, (dict(self.items()),)
+
+
+# ---------------------------------------------------------------------------
 # OptimMethod base
 # ---------------------------------------------------------------------------
 class OptimMethod(ConfigCaptured):
@@ -268,7 +343,7 @@ class OptimMethod(ConfigCaptured):
 
     def __init__(self, learning_rate: float = 1e-3):
         self.learning_rate = float(learning_rate)
-        self.state: Dict[str, Any] = {"epoch": 1, "neval": 1}
+        self.state: Dict[str, Any] = TrainState(epoch=1, neval=1)
         self.schedule: Optional[LearningRateSchedule] = None
 
     # ---------------------------------------------------------- pure pytree
@@ -323,7 +398,7 @@ class OptimMethod(ConfigCaptured):
             return pickle.load(f)
 
     def clear_history(self) -> None:
-        self.state = {"epoch": 1, "neval": 1}
+        self.state = TrainState(epoch=1, neval=1)
         if hasattr(self, "_flat_slots"):
             del self._flat_slots
 

@@ -15,11 +15,14 @@ compiled once; the loop around it reproduces the reference's semantics:
 infinite shuffled iterator, approximate epoch boundary
 (recordsProcessedThisEpoch >= numSamples, Appendix B.6), state-table keys
 (Appendix B.7), trigger-driven validation/checkpoint/summary, per-iteration
-throughput log (optim/DistriOptimizer.scala:390-393 parity).
+throughput log (optim/DistriOptimizer.scala:390-393 parity). The local
+loop keeps one step in flight: a step's loss is fetched and logged behind
+the next step's dispatch, unless somebody reads ``state["Loss"]`` sooner.
 """
 
 from __future__ import annotations
 
+import collections
 import logging
 import os
 import time
@@ -35,7 +38,7 @@ from bigdl_tpu.dataset.sample import Sample
 from bigdl_tpu.dataset.transformer import SampleToMiniBatch
 from bigdl_tpu.nn.module import Module, pure_apply
 from bigdl_tpu.optim.metrics import Metrics
-from bigdl_tpu.optim.optim_method import OptimMethod, SGD
+from bigdl_tpu.optim.optim_method import OptimMethod, SGD, TrainState
 from bigdl_tpu.optim.trigger import Trigger
 from bigdl_tpu.optim.validation import ValidationMethod
 from bigdl_tpu.utils import random as bt_random
@@ -232,6 +235,7 @@ class TrainStep:
         #: global pre-clip gradient L2 norm —
         #: (loss, grad_norm, params, buffers, slots)
         self.step_with_stats = _core
+        self._rates = self._lrs = None      # lrs_for_step's last answer
 
     def init_slots(self, params):
         leaves = jax.tree.leaves(params)
@@ -239,12 +243,72 @@ class TrainStep:
             m.init_slots([leaves[i] for i in idxs])
             for m, idxs in zip(self.methods, self._idxs_per_group))
 
+    def current_rates(self):
+        """One host float a group, from each method's schedule."""
+        return [m.get_current_rate() for m in self.methods]
+
     def current_lrs(self):
-        return jnp.asarray([m.get_current_rate() for m in self.methods], jnp.float32)
+        return jnp.asarray(self.current_rates(), jnp.float32)
+
+    def lrs_for_step(self):
+        """``(lrs, lr)`` for the next step: ``current_lrs()``, made anew
+        only when a schedule has changed a rate, and group 0's rate as the
+        step will see it (rounded to float32), both without a device read."""
+        rates = self.current_rates()
+        if rates != self._rates:
+            self._rates, self._lrs = rates, jnp.asarray(rates, jnp.float32)
+        return self._lrs, float(np.float32(rates[0]))
 
     def update_states(self, **kv):
         for m in self.methods:
             m.state.update(kv)
+
+    def defer_states(self, key, fetch):
+        """``key`` of every method's state table is ``fetch()``, called by
+        whoever reads it first (``TrainState.defer``)."""
+        for m in self.methods:
+            m.state.defer(key, fetch)
+
+
+class _StepInFlight:
+    """A dispatched train step as the host knows it, and its loss, which
+    stays on the device until somebody needs it: the loop's report of the
+    step, or whoever reads ``state["Loss"]`` first. That wait is the
+    ``train/fence`` span, with the step's ``neval`` and ``behind``: 1 when
+    a later step was already dispatched (the device has work queued behind
+    the one waited for), 0 when the loop was synchronous here."""
+
+    def __init__(self, after, loss, gnorm, start_ns, *, neval, epoch, n, lr,
+                 records):
+        self.after = after          # the step dispatched before this one
+        if after is not None:
+            after.overtaken = True
+        self._device = (loss, gnorm)
+        self.start_ns = start_ns    # its dispatch began
+        self.overtaken = False      # a later step has been dispatched
+        self.neval, self.epoch, self.n, self.lr = neval, epoch, n, lr
+        self.records = records      # recordsProcessedThisEpoch with it
+
+    def loss(self) -> float:
+        if self._device is not None:
+            from bigdl_tpu.observability import trace
+
+            free_ns = 0     # when the device was free for this step
+            if self.after is not None:
+                self.after.loss()   # the device ran that one first
+                free_ns, self.after = self.after.fetched_ns, None
+            loss, gnorm = self._device
+            self.behind = int(self.overtaken)
+            with trace.span("train/fence", neval=self.neval,
+                            behind=self.behind) as fence:
+                self.value = float(loss)
+                self.grad_norm = None if gnorm is None else float(gnorm)
+            self._device = None
+            self.fetched_ns = fence.end_ns
+            # the step's seconds: from its dispatch, or from the loss of
+            # the step before it where the device still ran that one
+            self.seconds = (fence.end_ns - max(self.start_ns, free_ns)) / 1e9
+        return self.value
 
 
 def make_train_step(model: Module, criterion, optim_method: OptimMethod,
@@ -438,10 +502,6 @@ class LocalOptimizer(Optimizer):
     def optimize(self) -> Module:
         model, criterion = self.model, self.criterion
         method = self.optim_method
-        state = method.state
-        state.setdefault("epoch", 1)
-        state.setdefault("neval", 1)
-        state.setdefault("recordsProcessedThisEpoch", 0)
 
         # copy once so step-1 donation can never invalidate the model's
         # own arrays (params_dict returns live references); after each aux
@@ -457,6 +517,14 @@ class LocalOptimizer(Optimizer):
         ts = make_train_step(model, criterion, method, self.grad_clip,
                              self.sub_optim_methods, grad_accum=ga)
         slots = ts.init_slots(params)
+        for m in ts.methods:
+            # a table unpickled by an older checkpoint, or assigned
+            if not isinstance(m.state, TrainState):
+                m.state = TrainState(m.state)
+        state = method.state
+        state.setdefault("epoch", 1)
+        state.setdefault("neval", 1)
+        state.setdefault("recordsProcessedThisEpoch", 0)
         # donate params/buffers/slots: the step's outputs reuse their
         # buffers in place of a full params+slots copy every iteration
         # (~2x peak parameter memory otherwise); every consumer of the
@@ -464,7 +532,7 @@ class LocalOptimizer(Optimizer):
         # freshest POST-step outputs, which are only donated by the NEXT
         # call, and the async checkpoint thread serializes a deepcopy.
         # With observability on, the stats variant also returns the grad
-        # norm (same math; the loop already syncs loss each iteration).
+        # norm (same math; it is fetched with the loss).
         from bigdl_tpu import observability as obs
 
         self._obs_on = obs.enabled()
@@ -473,7 +541,7 @@ class LocalOptimizer(Optimizer):
             donate_argnums=(0, 1, 2))
 
         num_samples = self.dataset.size()
-        data_iter = self._prepared_batches()
+        data_iter = self._prepared_batches(queued_steps=1)
         wall_start = time.time()
 
         # /debug/memory attribution: params and optimizer slots are the
@@ -523,11 +591,16 @@ class LocalOptimizer(Optimizer):
         y = jax.tree.map(jnp.asarray, batch.get_target())
         return x, y, batch.size()
 
-    def _prepared_batches(self, prepare=None):
+    def _prepared_batches(self, prepare=None, queued_steps=0):
         """Host batch prep + H2D transfer moved onto a background thread
         (``bigdl.prefetch.buffer`` batches deep, 0 disables) so the input
         pipeline overlaps the device step — ≙ the reference's "io" thread
-        pool staging batches per executor (utils/Engine.scala:218-355)."""
+        pool staging batches per executor (utils/Engine.scala:218-355).
+        ``queued_steps``: steps the loop keeps dispatched behind the running
+        one. Each holds its batch on the device until it has run, so it
+        counts as one of the batches staged ahead and the queue is that
+        much shorter: as many batches are alive as with a loop that waits
+        for every step."""
         from bigdl_tpu.dataset.prefetch import prefetch
         from bigdl_tpu.utils import config as bt_config
 
@@ -536,82 +609,116 @@ class LocalOptimizer(Optimizer):
         stream = self._batch_stream()
         if depth <= 0:
             return (prepare(b) for b in stream)
-        return prefetch(stream, buffer_size=depth, transfer=prepare)
+        return prefetch(stream, buffer_size=max(1, depth - queued_steps),
+                        transfer=prepare)
 
     def _optimize_loop(self, model, state, params, buffers, ts, slots,
                        train_step, num_samples, data_iter, wall_start):
+        """One step is kept in flight: step k+1 is dispatched while step k
+        runs, and step k's loss is fetched and reported (the log line, the
+        summary, the instruments) behind that dispatch, so the device does
+        not idle while the host turns round. ``state["Loss"]`` is deferred
+        to the step just dispatched (``TrainState``): a trigger, schedule
+        or hook that reads it waits for that step and gets its loss, which
+        makes that turn of the loop as synchronous as the reader needs; so
+        does an aux point (validation, checkpoint, parameter histograms),
+        which reads the step's parameters."""
         from bigdl_tpu import observability as obs
 
         obs_on = getattr(self, "_obs_on", False)
         ins = obs.train_instruments() if obs_on else None
         span = obs.trace.span
-        # one root an iteration; its children tile it (boundaries touch),
-        # so what the loop does while the device idles has a name
-        while not self.end_when(state):
-            with span("train/iteration", neval=state["neval"]):
-                with span("train/data_wait"):
-                    x, y, n = next(data_iter)
-                # the step's learning rates and key: small device programs
-                # of their own, and a fetch
-                with span("train/arguments"):
-                    lrs = ts.current_lrs()
-                    lr = float(lrs[0])
-                    rng = bt_random.next_key()
-                gnorm = None
-                with span("train/step", histogram=(
-                        ins.step_seconds if obs_on else None)) as step_span:
-                    # the call into the jitted step: the enqueue
-                    with span("train/dispatch"):
-                        if obs_on:
-                            loss, gnorm, params, buffers, slots = train_step(
-                                params, buffers, slots, x, y, lrs, rng)
-                        else:
-                            loss, params, buffers, slots = train_step(
-                                params, buffers, slots, x, y, lrs, rng)
-                    with span("train/fence"):   # the wait for the device
-                        loss = float(loss)
-                dt = step_span.duration
+        # dispatched steps not yet reported, oldest first: at most the one
+        # in flight and the one before it
+        flight = collections.deque()
+
+        def report(keep):
+            """Fence and report, in order, all but the newest ``keep``."""
+            while len(flight) > keep:
+                step = flight.popleft()     # off the list first: never twice
+                loss = step.loss()
                 with span("train/bookkeeping"):
-                    state["recordsProcessedThisEpoch"] += n
-                    state["Loss"] = loss
-                    state["LearningRate"] = float(lr)
+                    dt, n = step.seconds, step.n
+                    rate = n / max(dt, 1e-9)
                     self.metrics.add("computing time", dt * 1e9)
                     if obs_on:
+                        ins.step_seconds.observe(dt)
+                        ins.fences.labels(str(step.behind)).inc()
                         ins.records_total.inc(n)
-                        ins.throughput.set(n / max(dt, 1e-9))
+                        ins.throughput.set(rate)
                         ins.loss.set(loss)
-                        ins.learning_rate.set(lr)
-                        ins.grad_norm.set(float(gnorm))
-                        ins.epoch.set(state["epoch"])
+                        ins.learning_rate.set(step.lr)
+                        ins.grad_norm.set(step.grad_norm)
+                        ins.epoch.set(step.epoch)
                         ins.jit_compiles.set(train_step._cache_size())
                     logger.info(
                         "[Epoch %d %d/%d][Iteration %d][Wall Clock %.3fs] "
                         "Trained %d records in %.4f seconds. Throughput is %.1f records/second. "
                         "Loss is %.4f.",
-                        state["epoch"], state["recordsProcessedThisEpoch"], num_samples,
-                        state["neval"], time.time() - wall_start, n, dt, n / max(dt, 1e-9), loss)
-
+                        step.epoch, step.records, num_samples, step.neval,
+                        time.time() - wall_start, n, dt, rate, loss)
                     if self.train_summary is not None:
-                        self.train_summary.add_scalar("Loss", loss, state["neval"])
-                        self.train_summary.add_scalar("LearningRate", float(lr), state["neval"])
-                        self.train_summary.add_scalar("Throughput", n / max(dt, 1e-9), state["neval"])
+                        self.train_summary.add_scalar("Loss", loss, step.neval)
+                        self.train_summary.add_scalar("LearningRate", step.lr, step.neval)
+                        self.train_summary.add_scalar("Throughput", rate, step.neval)
+
+        # one root an iteration; its children tile it (boundaries touch),
+        # so what the loop does between two dispatches has a name
+        try:
+            while not self.end_when(state):
+                with span("train/iteration", neval=state["neval"]):
+                    with span("train/data_wait"):
+                        x, y, n = next(data_iter)
+                    # the step's learning rates and key. Nothing here waits
+                    # for the device: the rates are host floats, their array
+                    # is remade only when one changes, and the key's split
+                    # queues behind the running step
+                    with span("train/arguments"):
+                        lrs, lr = ts.lrs_for_step()
+                        rng = bt_random.next_key()
+                    gnorm = None
+                    with span("train/step"):
+                        # the call into the jitted step: the enqueue
+                        with span("train/dispatch") as dispatch:
+                            if obs_on:
+                                loss, gnorm, params, buffers, slots = train_step(
+                                    params, buffers, slots, x, y, lrs, rng)
+                            else:
+                                loss, params, buffers, slots = train_step(
+                                    params, buffers, slots, x, y, lrs, rng)
+                    with span("train/bookkeeping"):
+                        # what the host knows of this step without its loss
+                        state["recordsProcessedThisEpoch"] += n
+                        state["LearningRate"] = lr
+                        step = _StepInFlight(
+                            flight[-1] if flight else None, loss, gnorm,
+                            dispatch.start_ns, neval=state["neval"],
+                            epoch=state["epoch"], n=n, lr=lr,
+                            records=state["recordsProcessedThisEpoch"])
+                        flight.append(step)
+                        ts.defer_states("Loss", step.loss)
                         # optional parameter histograms, gated on a trigger
                         # (≙ TrainSummary "Parameters" tag, TrainSummary.scala:32)
                         ptrig = getattr(self.train_summary, "get_summary_trigger",
                                         lambda _n: None)("Parameters")
-                        if ptrig is not None and ptrig(state):
+                        hist_now = ptrig is not None and ptrig(state)
+                        state["neval"] += 1
+                        if state["recordsProcessedThisEpoch"] >= num_samples:
+                            state["epoch"] += 1
+                            state["recordsProcessedThisEpoch"] = 0
+                            # reshuffle + restart happen inside _batch_stream (on the
+                            # producer side, ordered ahead of the prefetched batches)
+                        ts.update_states(neval=state["neval"], epoch=state["epoch"])
+                        aux_now = self._should_fire_aux(state)
+                    # the step before: its loss arrives while this one runs.
+                    # An aux point reads this step's parameters, so there
+                    # this step is fenced too, as every step was
+                    report(keep=0 if aux_now or hist_now else 1)
+                    if hist_now:
+                        with span("train/bookkeeping"):
                             for pname, leaf in _named_param_leaves(params):
                                 self.train_summary.add_histogram(
-                                    pname, np.asarray(leaf), state["neval"])
-
-                    state["neval"] += 1
-                    if state["recordsProcessedThisEpoch"] >= num_samples:
-                        state["epoch"] += 1
-                        state["recordsProcessedThisEpoch"] = 0
-                        # reshuffle + restart happen inside _batch_stream (on the
-                        # producer side, ordered ahead of the prefetched batches)
-                    ts.update_states(neval=state["neval"], epoch=state["epoch"], Loss=loss)
-                    aux_now = self._should_fire_aux(state)
+                                    pname, np.asarray(leaf), step.neval)
                 # write updated weights back before validation/checkpoint
                 if aux_now:
                     model.load_params_dict(params)
@@ -625,6 +732,12 @@ class LocalOptimizer(Optimizer):
                                and self.checkpoint_path is not None else None)
                     with span("train/checkpoint", histogram=ck_hist):
                         self._run_checkpoint(state)
+        finally:
+            # the step still in flight, also on the way out of an
+            # exception: every dispatched step is reported exactly once
+            report(keep=0)
+            for m in ts.methods:
+                m.state.settle()
 
         model.load_params_dict(params)
         model.load_buffers_dict(buffers)

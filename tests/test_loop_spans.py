@@ -206,12 +206,13 @@ def test_chrome_trace_reads_the_stamps_and_the_attrs():
 
 # ------------------------------------------------------ the training loops
 class SlowSummary:
-    """A train-summary hook that takes a few milliseconds, so that an
-    iteration of a toy model is long beside the spans' own cost."""
+    """A train-summary hook that takes some milliseconds, so that an
+    iteration of a toy model is long beside the spans' own cost (some
+    15 us a span, seven spans an iteration: 1 % of 10 ms)."""
 
     def add_scalar(self, tag, value, step):
         if tag == "Loss":
-            time.sleep(0.01)
+            time.sleep(0.02)
 
 
 def _samples(n=64, d=8):
@@ -253,10 +254,24 @@ def test_local_optimizer_iterations_are_tiled_by_named_spans():
     opt.optimize()
     recs = _export(obs.trace)
     its, kids = _assert_iterations_tiled(recs, 6)
-    for it in its:
+    for k, it in enumerate(its, 1):
+        # one step is kept in flight: the step holds its enqueue alone, and
+        # the wait is for the step BEFORE, behind this one's counters and
+        # ahead of that step's log line and summary
         step = kids[it["span_id"]][2]
-        assert [c["name"] for c in kids[step["span_id"]]] == \
-            ["train/dispatch", "train/fence"]
+        assert [c["name"] for c in kids[step["span_id"]]] == ["train/dispatch"]
+        rest = kids[it["span_id"]][4:]
+        assert [c["name"] for c in rest] == \
+            ([] if k == 1 else ["train/fence", "train/bookkeeping"])
+        if rest:
+            assert rest[0]["attrs"] == {"neval": k - 1, "behind": 1}
+            assert rest[1]["end_ns"] - rest[1]["start_ns"] >= 20e6  # the hook
+    # the last step is fenced and reported on the way out of the loop
+    tail = [r for r in recs if r["parent_id"] is None
+            and r["start_ns"] >= its[-1]["end_ns"]
+            and r["thread"] == its[-1]["thread"]]
+    assert [r["name"] for r in tail] == ["train/fence", "train/bookkeeping"]
+    assert tail[0]["attrs"] == {"neval": 6, "behind": 0}
     # the producer thread: one root a batch, busy time only
     batches = [r for r in recs if r["name"] == "input/batch"]
     assert len(batches) >= 6
