@@ -1,24 +1,39 @@
-"""A decoder whose layers are of two kinds: gated delta-rule layers
-(``nn.GatedDeltaNet``: a fixed recurrent state a sequence) and
-full-attention layers (``nn.MultiHeadAttention``: keys and values that
-grow with the sequence), each followed by a gated MLP, RMSNorm on each
-branch's OUTPUT (``h = x + norm(mixer(x))``, ``out = h + norm(mlp(h))``),
-a final RMSNorm and an untied head.
+"""A decoder whose layers are of four kinds, named in order by
+``layer_types``:
+
+- ``linear_attention``: gated delta-rule layers (``nn.GatedDeltaNet``: a
+  fixed recurrent state a sequence, and a short convolution's tail);
+- ``lightning_attention``: fixed-decay linear attention with rotary
+  positions (``nn.LightningAttention``: a fixed recurrent state);
+- ``full_attention``: softmax attention over all of a sequence's keys and
+  values (``nn.MultiHeadAttention``), which grow with the sequence;
+- ``sparse_attention``: grouped-query softmax attention over the blocks a
+  query selects through a cache of compressed keys
+  (``nn.BlockSparseAttention``), whose keys, values and compressed keys
+  grow with the sequence;
+
+each followed by a gated MLP, in one of two block styles taken from the
+configuration: ``post_norm`` (RMSNorm on each branch's OUTPUT, ``h = x +
+norm(mixer(x))``, ``out = h + norm(mlp(h))``) or ``pre_norm`` (``h = x +
+s * mixer(norm(x))``, ``out = h + s * mlp(norm(h))`` with the residual
+scale ``s``); the embedding and the logits each take a scale; a final
+RMSNorm and an untied head.
 
 It speaks the paged engine's four entry points (``init_page_pool``,
 ``prefill_chunk_at_paged``, ``verify_chunk_paged``,
 ``decode_step_paged``). Its pool is two named sub-trees::
 
-    {"pages": [(k, v) per full layer],       leaves lead with PAGES
-     "lanes": [(S, tail) per linear layer]}  leaves lead with LANES
+    {"pages": [one entry a full or sparse layer],   leaves lead with PAGES
+     "lanes": [one entry a linear or lightning layer]}       ... with LANES
 
-``pages`` is what ``TransformerLM.init_page_pool`` returns (one block
-table indexes every full layer); ``lanes`` holds one recurrent state a
-serving lane, whatever the sequence's length. The prefill entry points
-are told which lane each row fills (``lanes``), the decode step which
-lanes are decoding (``active``: the others keep their state bit for
-bit). A row whose ``pos0`` is 0 starts from a zero state, so admitting
-a sequence needs no reset program.
+``pages`` holds ``(k, v)`` for a full layer (what
+``TransformerLM.init_page_pool`` returns) and ``{"k", "v", "ck"}`` for a
+sparse one (``ck``: one compressed key a page); one block table indexes
+them all. ``lanes`` holds one recurrent state a serving lane, whatever the
+sequence's length. The prefill entry points are told which lane each row
+fills (``lanes``), the decode step which lanes are decoding (``active``:
+the others keep their state bit for bit). A row whose ``pos0`` is 0 starts
+from a zero state, so admitting a sequence needs no reset program.
 """
 
 from __future__ import annotations
@@ -27,68 +42,102 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bigdl_tpu import nn
-from bigdl_tpu.nn.attention import MultiHeadAttention, RMSNorm
-from bigdl_tpu.nn.gated_delta import GatedDeltaNet, GatedMLP, project
+from bigdl_tpu.nn.attention import RMSNorm
+from bigdl_tpu.nn.gated_delta import GatedMLP, project
 from bigdl_tpu.nn.module import Module
 
 LINEAR, FULL = "linear_attention", "full_attention"
+LIGHTNING, SPARSE = "lightning_attention", "sparse_attention"
+#: kinds whose cache grows with the sequence (pages), and the rest (a
+#: fixed state a lane)
+PAGED = (FULL, SPARSE)
+POST_NORM, PRE_NORM = "post_norm", "pre_norm"
 
 
 class HybridBlock(Module):
-    """One layer: a mixer of either kind, then the gated MLP, each
-    branch normalized on its way back into the residual stream, which
-    is float32 whatever the weights' dtype (every matrix product takes
-    its operand in the weights' dtype; nothing between two products is
-    rounded to it a second time)."""
+    """One layer: a mixer of any kind, then the gated MLP, each branch
+    normalized on its way back into the residual stream (``post_norm``)
+    or on its way out of it (``pre_norm``, the branch scaled by
+    ``residual_scale``). The stream is float32 whatever the weights'
+    dtype (every matrix product takes its operand in the weights' dtype;
+    nothing between two products is rounded to it a second time)."""
 
     def __init__(self, kind: str, embed_dim: int, num_heads: int,
                  mlp_dim: int, num_kv_heads: Optional[int],
                  linear_heads: int, linear_key_dim: int,
                  linear_value_dim: int, conv_kernel: int,
                  allow_neg_eigval: bool, eps: float,
-                 rope_theta: Optional[float]):
+                 rope_theta: Optional[float], style: str = POST_NORM,
+                 residual_scale: float = 1.0,
+                 sparse: Optional[dict] = None):
         super().__init__()
-        if kind not in (LINEAR, FULL):
-            raise ValueError(f"layer type {kind!r}: expected {LINEAR!r} "
-                             f"or {FULL!r}")
-        self.kind = kind
-        if kind == LINEAR:
-            self.mixer = GatedDeltaNet(
+        head_dim = embed_dim // num_heads
+        mixers = {
+            LINEAR: lambda: nn.GatedDeltaNet(
                 embed_dim, linear_heads, linear_key_dim, linear_value_dim,
                 conv_kernel=conv_kernel, allow_neg_eigval=allow_neg_eigval,
-                norm_eps=eps)
-        else:
-            self.mixer = MultiHeadAttention(
+                norm_eps=eps),
+            FULL: lambda: nn.MultiHeadAttention(
                 embed_dim, num_heads, with_bias=False, causal=True,
                 num_kv_heads=num_kv_heads, qk_norm=True, norm_eps=eps,
                 rotary=rope_theta is not None,
-                rotary_base=rope_theta or 10000.0)
+                rotary_base=rope_theta or 10000.0),
+            LIGHTNING: lambda: nn.LightningAttention(
+                embed_dim, num_heads, head_dim, rotary_base=rope_theta,
+                norm_eps=eps),
+            SPARSE: lambda: nn.BlockSparseAttention(
+                embed_dim, num_heads, num_kv_heads or num_heads, head_dim,
+                norm_eps=eps, **(sparse or {})),
+        }
+        if kind not in mixers:
+            raise ValueError(f"layer type {kind!r}: expected one of "
+                             f"{sorted(mixers)}")
+        if style not in (POST_NORM, PRE_NORM):
+            raise ValueError(f"block style {style!r}: expected "
+                             f"{POST_NORM!r} or {PRE_NORM!r}")
+        self.kind, self.style = kind, style
+        self.residual_scale = residual_scale
+        self.mixer = mixers[kind]()
         self.mixer_norm = RMSNorm(embed_dim, eps)
         self.mlp = GatedMLP(embed_dim, mlp_dim)
         self.mlp_norm = RMSNorm(embed_dim, eps)
 
-    def _rest(self, x, mixed):
-        h = x + self.mixer_norm(mixed.astype(jnp.float32))
-        return h + self.mlp_norm(self.mlp(h))
+    def _enter(self, x):
+        """What the mixer takes of the float32 stream ``x``."""
+        if self.style == PRE_NORM:
+            x = self.mixer_norm(x)
+        # the full-attention mixer takes the weights' dtype (its pages are)
+        return (x.astype(self.mlp.down.weight.dtype) if self.kind == FULL
+                else x)
 
-    def _served(self, x):
-        """The residual stream as the full-attention mixer takes it: in
-        the weights' dtype (its pages are)."""
-        return x.astype(self.mlp.down.weight.dtype)
+    def _rest(self, x, mixed):
+        mixed = mixed.astype(jnp.float32)
+        if self.style == PRE_NORM:
+            h = x + self.residual_scale * mixed
+            return h + self.residual_scale * self.mlp(self.mlp_norm(h))
+        h = x + self.mixer_norm(mixed)
+        return h + self.mlp_norm(self.mlp(h))
 
     def forward(self, input):
         x = input.astype(jnp.float32)
-        return self._rest(x, self.mixer(
-            x if self.kind == LINEAR else self._served(x)))
+        return self._rest(x, self.mixer(self._enter(x)))
 
 
 class HybridDecoderLM(Module):
     """Input (batch, time) int32 ids, output (batch, time, vocab) logits.
-    ``layer_types`` names each layer's kind in order. ``rope_theta``
-    None means the full-attention layers do not rotate (the
-    convolutions and decays of the linear layers carry position)."""
+    ``layer_types`` names each layer's kind in order. ``rope_theta`` is
+    the rotation of the full-attention and lightning layers (which take
+    the attention's heads); None means they do not rotate (the
+    convolutions and decays of the linear layers carry position). The
+    sparse layers never rotate.
+    ``sparse`` holds the sparse layers' sizes (``nn.BlockSparseAttention``'s
+    keywords). ``block_style``, ``residual_scale``, ``embed_scale`` and
+    ``logit_scale`` are the block's layout and the three scales of a
+    muP-parametrized model (``x0 = embed_scale * E[id]``, ``logits =
+    head(norm(x) * logit_scale)``)."""
 
     #: the serving engine asks: some layers hold a state per lane
     has_lane_state = True
@@ -99,7 +148,10 @@ class HybridDecoderLM(Module):
                  linear_heads: Optional[int] = None,
                  linear_key_dim: int = 64, linear_value_dim: int = 128,
                  conv_kernel: int = 4, allow_neg_eigval: bool = True,
-                 eps: float = 1e-6, rope_theta: Optional[float] = None):
+                 eps: float = 1e-6, rope_theta: Optional[float] = None,
+                 block_style: str = POST_NORM, residual_scale: float = 1.0,
+                 embed_scale: float = 1.0, logit_scale: float = 1.0,
+                 sparse: Optional[dict] = None):
         super().__init__()
         self.vocab_size, self.embed_dim = vocab_size, embed_dim
         self.max_len = max_len
@@ -107,6 +159,7 @@ class HybridDecoderLM(Module):
         self.num_layers = len(self.layer_types)
         self.num_kv_heads = num_kv_heads or num_heads
         self.head_dim = embed_dim // num_heads
+        self.embed_scale, self.logit_scale = embed_scale, logit_scale
         self.register_parameter(
             "tok_embed", nn.init.RandomNormal(0.0, 0.02)(
                 (vocab_size, embed_dim)))
@@ -115,21 +168,24 @@ class HybridDecoderLM(Module):
                 kind, embed_dim, num_heads, mlp_dim, num_kv_heads,
                 linear_heads or num_heads, linear_key_dim,
                 linear_value_dim, conv_kernel, allow_neg_eigval, eps,
-                rope_theta))
+                rope_theta, block_style, residual_scale, sparse))
         self.norm_f = RMSNorm(embed_dim, eps)
         self.head = nn.Linear(embed_dim, vocab_size, with_bias=False)
 
     def _embed(self, ids):
-        return jnp.take(self.tok_embed, ids, axis=0).astype(jnp.float32)
+        x = jnp.take(self.tok_embed, ids, axis=0).astype(jnp.float32)
+        return x if self.embed_scale == 1.0 else x * self.embed_scale
 
-    def _blocks(self, kind=None):
+    def _blocks(self, *kinds):
         return [getattr(self, f"block{i}")
                 for i, k in enumerate(self.layer_types)
-                if kind is None or k == kind]
+                if not kinds or k in kinds]
 
     def _logits(self, x):
         lead = x.shape[:-1]
         x = self.norm_f(x).reshape(-1, self.embed_dim)
+        if self.logit_scale != 1.0:
+            x = x * self.logit_scale
         return project(self.head, x).reshape(lead + (self.vocab_size,))
 
     def forward(self, input):
@@ -139,11 +195,14 @@ class HybridDecoderLM(Module):
         return self._logits(x)
 
     # ------------------------------------------------- what the engine asks
-    def kv_token_elems(self) -> int:
-        """K and V elements one cached token holds over every layer that
-        holds pages."""
-        return (2 * len(self._blocks(FULL)) * self.num_kv_heads
-                * self.head_dim)
+    def kv_token_elems(self) -> float:
+        """Cache elements one token holds over every layer that holds
+        pages: K and V, and a sparse layer's share of its page's
+        compressed key."""
+        kv = 2 * self.num_kv_heads * self.head_dim
+        return (len(self._blocks(*PAGED)) * kv
+                + sum(kv / 2 / b.mixer.kernel_stride
+                      for b in self._blocks(SPARSE)))
 
     def kv_page_pool_sharding(self, mesh, model_axis: str = "model"):
         raise NotImplementedError(
@@ -155,41 +214,80 @@ class HybridDecoderLM(Module):
         return sum(int(leaf.size) for leaf in jax.tree.leaves(
             self.params_dict())) - self.vocab_size * self.embed_dim
 
+    def _state_elems(self) -> int:
+        """Elements of the recurrent matrices one lane holds."""
+        return (sum(b.mixer.num_heads * b.mixer.key_dim * b.mixer.value_dim
+                    for b in self._blocks(LINEAR))
+                + sum(b.mixer.num_heads * b.mixer.head_dim ** 2
+                      for b in self._blocks(LIGHTNING)))
+
+    def _attended(self, context: int) -> float:
+        """Cached tokens the paged layers read for one query over
+        ``context`` cached ones, summed over those layers: all of them in
+        a full layer, the selected ones in a sparse layer."""
+        c = max(0, int(context))
+        return (len(self._blocks(FULL)) * c
+                + sum(min(c, int(b.mixer.attended_tokens(max(c - 1, 0))))
+                      for b in self._blocks(SPARSE)))
+
+    def decode_read_counts(self, positions, table_pages: int):
+        """What a selecting layer reads for one decode token at each of
+        ``positions`` through block tables of ``table_pages`` pages (host
+        arithmetic for the engine's span and counters), summed over the
+        rows: the tokens the rule attends, the tokens' worth of pages the
+        step gathers for them, the tokens the rows hold, and the rows at
+        or over the length from which the layers select; None when no
+        layer selects."""
+        sparse = self._blocks(SPARSE)
+        if not sparse:
+            return None
+        t = np.asarray(positions, np.int64)
+        mixer = sparse[0].mixer
+        return {"attended_tokens": int(mixer.attended_tokens(t).sum()),
+                "gathered_tokens": int(
+                    mixer.gathered_tokens(t, table_pages).sum()),
+                "cached_tokens": int((t + 1).sum()),
+                "selecting_rows": int((t >= mixer.dense_len).sum())}
+
     def analytic_flops(self, tokens: int, context: int) -> float:
         """Forward FLOPs for ``tokens`` positions over ``context``
         cached ones: two a matmul weight, the score and value products
-        of the full layers, and per linear layer the state's decay,
-        read, update and query (about ``6 dk dv`` a head and token)."""
-        lin = self._blocks(LINEAR)
-        state = sum(6 * b.mixer.num_heads * b.mixer.key_dim
-                    * b.mixer.value_dim for b in lin)
+        over the tokens the paged layers attend (all of them in a full
+        layer, the selected ones and the compressed keys in a sparse
+        one), and per recurrent layer the state's decay, read, update and
+        query (about 6 an element and token)."""
+        scored = sum(max(0, int(context)) / b.mixer.kernel_stride
+                     for b in self._blocks(SPARSE))
         per_tok = (2.0 * self._matmul_params()
-                   + 4.0 * len(self._blocks(FULL)) * self.embed_dim
-                   * max(0, int(context)) + state)
+                   + 4.0 * self.embed_dim * self._attended(context)
+                   + 2.0 * self.embed_dim * scored
+                   + 6 * self._state_elems())
         return float(per_tok * max(0, int(tokens)))
 
     def analytic_bytes(self, tokens: int, context: int,
                        dtype_bytes: int = 2) -> float:
-        """HBM traffic of the same pass: every parameter once, K and V
-        of the full layers written a token and read ``context`` deep,
-        each row's recurrent state read and written once a pass (taken
-        as one row a token, the decode step's case)."""
+        """HBM traffic of the same pass: every parameter once, a token's
+        cache elements written, K and V read over the tokens attended and
+        a sparse layer's compressed keys over the whole context, each
+        row's recurrent state read and written once a pass (taken as one
+        row a token, the decode step's case)."""
         param_bytes = sum(int(leaf.size) * leaf.dtype.itemsize
                           for leaf in jax.tree.leaves(self.params_dict()))
         t, c = max(0, int(tokens)), max(0, int(context))
-        state = sum(8 * b.mixer.num_heads * b.mixer.key_dim
-                    * b.mixer.value_dim for b in self._blocks(LINEAR))
-        return float(param_bytes
-                     + self.kv_token_elems() * dtype_bytes * t * (1 + c)
-                     + state * t)
+        kv = 2 * self.num_kv_heads * self.head_dim
+        compressed = sum(c * kv / 2 / b.mixer.kernel_stride
+                         for b in self._blocks(SPARSE))
+        return float(param_bytes + dtype_bytes * t * (
+            self.kv_token_elems() + kv * self._attended(c) + compressed)
+            + 8 * self._state_elems() * t)
 
     # ------------------------------------------------------------ the pool
     def init_page_pool(self, max_pages: int, page_size: int,
                        dtype=jnp.float32, sharding=None, kv_dtype=None,
                        lanes: int = 1):
-        """``{"pages": [...], "lanes": [...]}``: K and V page leaves for
-        each full layer (``MultiHeadAttention.init_page_pool``) and
-        ``(S, tail)`` for each linear layer, ``lanes`` of them (the
+        """``{"pages": [...], "lanes": [...]}``: the page leaves of each
+        full or sparse layer (its mixer's ``init_page_pool``) and the
+        state of each linear or lightning layer, ``lanes`` of them (the
         engine passes its slots plus one scratch lane that idle prefill
         rows write)."""
         if sharding is not None:
@@ -200,9 +298,9 @@ class HybridDecoderLM(Module):
                 f"kv_dtype={kv_dtype!r} is not implemented for it")
         return {
             "pages": [b.mixer.init_page_pool(max_pages, page_size, dtype)
-                      for b in self._blocks(FULL)],
+                      for b in self._blocks(*PAGED)],
             "lanes": [b.mixer.init_state(lanes, dtype)
-                      for b in self._blocks(LINEAR)],
+                      for b in self._blocks(LINEAR, LIGHTNING)],
         }
 
     def prefill_chunk_at_paged(self, ids, pool, tables, pos0, last_idx,
@@ -227,22 +325,29 @@ class HybridDecoderLM(Module):
         fresh = pos0 == 0
         x = self._embed(ids)
         pages, states = list(pool["pages"]), list(pool["lanes"])
-        i_full = i_lin = 0
+        i_page = i_lane = 0
         for blk in self._blocks():
-            if blk.kind == FULL:
-                mixed, pages[i_full] = blk.mixer.forward_chunk_paged(
-                    blk._served(x), pages[i_full], tables, pos0)
-                i_full += 1
+            inp = blk._enter(x)
+            if blk.kind in PAGED:
+                mixed, pages[i_page] = blk.mixer.forward_chunk_paged(
+                    inp, pages[i_page], tables, pos0)
+                i_page += 1
             else:
-                s_all, tail_all = states[i_lin]
-                s = jnp.where(fresh[:, None, None, None], 0.0, s_all[lanes])
-                tail = jnp.where(fresh[:, None, None], 0,
-                                 tail_all[lanes]).astype(tail_all.dtype)
-                mixed, (s, tail) = blk.mixer.forward_chunk(
-                    x, (s, tail), n_valid)
-                states[i_lin] = (s_all.at[lanes].set(s),
-                                 tail_all.at[lanes].set(tail))
-                i_lin += 1
+                # the rows' lanes, from zero where a row starts afresh
+                state = jax.tree.map(
+                    lambda a: jnp.where(
+                        fresh.reshape((b,) + (1,) * (a.ndim - 1)), 0,
+                        a[lanes]).astype(a.dtype), states[i_lane])
+                if blk.kind == LINEAR:
+                    mixed, state = blk.mixer.forward_chunk(
+                        inp, state, n_valid)
+                else:
+                    mixed, state = blk.mixer.forward_chunk(
+                        inp, state, pos0, n_valid)
+                states[i_lane] = jax.tree.map(
+                    lambda a, new: a.at[lanes].set(new),
+                    states[i_lane], state)
+                i_lane += 1
             x = blk._rest(x, mixed)
         return x, {"pages": pages, "lanes": states}
 
@@ -253,25 +358,35 @@ class HybridDecoderLM(Module):
         b = ids_t.shape[0]
         x = self._embed(ids_t)
         pages, states = list(pool["pages"]), list(pool["lanes"])
-        i_full = i_lin = 0
+        live = jnp.ones((b,), bool) if active is None else active
+        i_page = i_lane = 0
         for blk in self._blocks():
+            inp = blk._enter(x)
             if blk.kind == FULL:
-                mixed, pages[i_full] = blk.mixer.forward_step_paged(
-                    blk._served(x)[:, None], pages[i_full], tables, pos,
+                mixed, pages[i_page] = blk.mixer.forward_step_paged(
+                    inp[:, None], pages[i_page], tables, pos,
                     decode_attention=decode_attention)
                 mixed = mixed[:, 0]
-                i_full += 1
+                i_page += 1
+            elif blk.kind == SPARSE:
+                mixed, pages[i_page] = blk.mixer.forward_step_paged(
+                    inp, pages[i_page], tables, pos)
+                i_page += 1
             else:
                 # every lane of the pool goes through the mixer (the
                 # scratch lane and any beyond the batch as inactive
                 # rows), so the state is updated in place under its
                 # mask and never sliced out and written back
-                spare = states[i_lin][0].shape[0] - b
-                live = jnp.ones((b,), bool) if active is None else active
-                mixed, states[i_lin] = blk.mixer.forward_step(
-                    jnp.pad(x, ((0, spare), (0, 0))), states[i_lin],
-                    jnp.pad(live, (0, spare)))
+                spare = jax.tree.leaves(states[i_lane])[0].shape[0] - b
+                wide = jnp.pad(inp, ((0, spare), (0, 0)))
+                if blk.kind == LINEAR:
+                    mixed, states[i_lane] = blk.mixer.forward_step(
+                        wide, states[i_lane], jnp.pad(live, (0, spare)))
+                else:
+                    mixed, states[i_lane] = blk.mixer.forward_step(
+                        wide, states[i_lane], jnp.pad(pos, (0, spare)),
+                        jnp.pad(live, (0, spare)))
                 mixed = mixed[:b]
-                i_lin += 1
+                i_lane += 1
             x = blk._rest(x, mixed)
         return self._logits(x), {"pages": pages, "lanes": states}

@@ -64,6 +64,8 @@ from bigdl_tpu.nn.attention import (
     dot_product_attention,
 )
 from bigdl_tpu.nn.gated_delta import GatedDeltaNet, GatedMLP
+from bigdl_tpu.nn.lightning_attention import LightningAttention
+from bigdl_tpu.nn.sparse_attention import BlockSparseAttention
 from bigdl_tpu.nn.criterion import (
     Criterion, ClassNLLCriterion, CrossEntropyCriterion, CategoricalCrossEntropy,
     MSECriterion, AbsCriterion, BCECriterion, SmoothL1Criterion,

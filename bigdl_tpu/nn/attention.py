@@ -362,6 +362,19 @@ def rotary_embedding_rowwise(x, positions, base: float = 10000.0):
         lambda xi, pi: rotary_embedding(xi, pi, base))(x, positions)
 
 
+def rotary_embedding_tokens(x, positions, base: float = 10000.0):
+    """RoPE on the token-major layout: ``x`` (..., H, D) with one
+    absolute position a token, ``positions`` (...,); the pairs and angles
+    of :func:`rotary_embedding`."""
+    d = x.shape[-1]
+    inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    sin, cos = jnp.sin(ang), jnp.cos(ang)
+    x1, x2 = x[..., ::2], x[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 class RMSNorm(Module):
     """Root-mean-square normalization over the last dim with a learned
     gain and no bias or mean subtraction (Zhang & Sennrich 2019): the

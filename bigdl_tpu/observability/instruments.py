@@ -292,6 +292,28 @@ def serving_engine_instruments(service: str = "engine",
             "Prefix hits cut short of the matched pages because no "
             "lane-state snapshot stood at the match (the difference is "
             "prefilled again)", labelnames=lbl).labels(service),
+        selected_attended_tokens_total=r.counter(
+            "bigdl_serving_selected_attended_tokens_total",
+            "Cached tokens the layers that select what they read "
+            "attended, a layer, over the decode rows dispatched (a "
+            "model with such layers; host arithmetic from the rows' "
+            "positions)", labelnames=lbl).labels(service),
+        selected_gathered_tokens_total=r.counter(
+            "bigdl_serving_selected_gathered_tokens_total",
+            "Tokens' worth of K and V pages the decode step gathered for "
+            "those rows, a layer (the selection's pages; for a row under "
+            "the length from which the layers select, every block it "
+            "may take)", labelnames=lbl).labels(service),
+        selected_cached_tokens_total=r.counter(
+            "bigdl_serving_selected_cached_tokens_total",
+            "Cached tokens those decode rows held (what a layer that "
+            "reads everything would have attended)",
+            labelnames=lbl).labels(service),
+        selecting_decode_rows_total=r.counter(
+            "bigdl_serving_selecting_decode_rows_total",
+            "Decode rows dispatched at or over the length from which "
+            "those layers select (under it they read everything)",
+            labelnames=lbl).labels(service),
         prefix_host_hits_total=r.counter(
             "bigdl_serving_prefix_host_hits_total",
             "Prefix-cache hits served from the host tier (row demoted "

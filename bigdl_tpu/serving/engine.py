@@ -170,7 +170,7 @@ class _SlotState:
     """Host-side view of one occupied KV slot."""
 
     __slots__ = ("handle", "pos", "last_token", "last_token_at",
-                 "delivered", "snaps")
+                 "delivered", "snaps", "prompt_entry")
 
     def __init__(self, handle: RequestHandle, pos: int, last_token: int,
                  now: float):
@@ -186,6 +186,9 @@ class _SlotState:
         self.last_token_at = now
         self.delivered = 1
         self.snaps: List[tuple] = []
+        #: the prefix entry donated when the prompt's prefill ended;
+        #: the one donated when the slot is given up takes its place
+        self.prompt_entry = None
 
 
 class ContinuousBatchingEngine:
@@ -224,6 +227,13 @@ class ContinuousBatchingEngine:
     scheduler pop the queued request with the LONGEST cached prefix
     from the first ``admission_window`` candidates (FCFS on ties, with
     a hard starvation bound — see ``AdmissionQueue.pop_ready``).
+    A request offers its pages to the index when it ends; with
+    ``donate_at_prefill_end`` it offers its prompt's pages already
+    when its prefill ends (the entry of its end takes that one's
+    place), so a request for the same prefix that arrives while it
+    still decodes is a hit. None asks the model
+    (``model.donate_at_prefill_end``, off where it says nothing): a
+    deployment's builder sets it where prompts are long and hot.
 
     BATCHED PREFILL: ``prefill_rows`` widens the prefill dispatch so
     that many queued admissions chunk-prefill TOGETHER through one
@@ -384,7 +394,8 @@ class ContinuousBatchingEngine:
                  page_size: Optional[int] = None,
                  max_pages: Optional[int] = None,
                  incident_dir: Optional[str] = None,
-                 anomaly_detectors=None):
+                 anomaly_detectors=None,
+                 donate_at_prefill_end: Optional[bool] = None):
         from bigdl_tpu.models.transformer import _validate_sampling
         from bigdl_tpu.observability import serving_engine_instruments
         from bigdl_tpu.observability import memory as obs_memory
@@ -416,6 +427,15 @@ class ContinuousBatchingEngine:
                     f"got {val!r}")
         self.kv_dtype = "int8" if kv_dtype is not None else None
         self.weights_dtype = "int8" if weights_dtype is not None else None
+        #: a request offers its prompt's pages to the prefix index when
+        #: its PREFILL ends (a request for the same prefix that arrives
+        #: while this one decodes is then a hit), not only when it ends.
+        #: None: what the model's builder said of its deployment, off
+        #: where it said nothing (docs/programming-guide/serving.md has
+        #: why off is still the default)
+        self._donate_at_prefill_end = bool(
+            getattr(model, "donate_at_prefill_end", False)
+            if donate_at_prefill_end is None else donate_at_prefill_end)
         if self.weights_dtype == "int8":
             # serve through the int8 clone (nn/quantized Quantizer):
             # Linear weights become int8 codes + per-channel scales in
@@ -431,6 +451,12 @@ class ContinuousBatchingEngine:
         #: two kinds of state: some layers keep one recurrent state a
         #: lane beside the pages of the others
         self._lane_state = bool(getattr(model, "has_lane_state", False))
+        #: layers that select what they read: the model's host arithmetic
+        #: for the decode span's counts (None: every layer reads it all)
+        self._read_counts = getattr(model, "decode_read_counts", None)
+        if self._read_counts is not None \
+                and self._read_counts([0], 1) is None:
+            self._read_counts = None
         if self._lane_state:
             refused = {
                 "draft": (draft is not None, "speculation needs the "
@@ -2502,7 +2528,8 @@ class ContinuousBatchingEngine:
         # exactly the donation key a finishing slot would use
         tokens = np.concatenate(
             [h.prompt, np.asarray(h._tokens[:-1], np.int32)])
-        self._maybe_donate(sid, tokens, h.request_id, st.snaps)
+        self._maybe_donate(sid, tokens, h.request_id, st.snaps,
+                           st.prompt_entry)
         if self._prefix is not None:
             # pin the covering entry so the LRU cannot evict the
             # donated KV while the victim waits in the queue — the
@@ -2981,6 +3008,14 @@ class ContinuousBatchingEngine:
             return
         st = _SlotState(h, a.t0, tok, now)
         st.snaps = held_snaps
+        if self._donate_at_prefill_end:
+            # the prompt's pages are complete (decode writes past them):
+            # a request for the same prefix that arrives while this one
+            # decodes is a hit and does not prefill it again
+            st.prompt_entry = self._maybe_donate(
+                a.slot, np.concatenate(
+                    [h.prompt, np.asarray(h._tokens[:-1], np.int32)]),
+                h.request_id, held_snaps)
         # a resumed request's slot picks up where the preempted one
         # left off: pos == effective-prompt length keeps the
         # variable-advance invariant (KV covers [0, pos), the just-
@@ -3007,26 +3042,32 @@ class ContinuousBatchingEngine:
 
     # --------------------------------------------------- prefix donation
     def _maybe_donate(self, sid: int, tokens: np.ndarray,
-                      request_id: str, snaps=()) -> None:
-        """Offer a finishing slot's KV to the prefix index. ``tokens``
-        are exactly the ids whose KV the slot holds (positions
-        ``0..len-1``); the index decides (covered / LRU-evict /
-        decline). Donation is a refcount move, never a copy: the
-        covering pages are SHARED into the new entry; the slot's own
-        references are freed separately by the caller. ``snaps`` (a
-        model with lane state): the request's state snapshots, shared
-        into the entry the same way."""
+                      request_id: str, snaps=(), supersede=None):
+        """Offer a slot's KV to the prefix index: when its prompt's
+        prefill ends, and again (``supersede`` the entry of the first
+        time) when the slot is given up. ``tokens`` are exactly the ids
+        whose KV the slot holds (positions ``0..len-1``); the index
+        decides (covered / LRU-evict / decline). Donation is a refcount
+        move, never a copy: the covering pages are SHARED into the new
+        entry; the slot's own references are freed separately by the
+        caller. ``snaps`` (a model with lane state): the request's
+        state snapshots, shared into the entry the same way. Returns
+        the entry, None where none was made."""
         if self._prefix is None:
-            return
+            return None
         tbl = self._tables[sid]
+        entry = None
         if tbl is not None and tokens.shape[0] > 0:
             held = tbl.covering(int(tokens.shape[0]))
-            if self._prefix.donate_pages(tokens, held, snaps):
+            entry = self._prefix.donate_pages(tokens, held, snaps,
+                                              supersede)
+            if entry is not None:
                 self._rec.record(
                     "request/prefix_donated", request_id,
                     service=self.service_name,
                     tokens=int(tokens.shape[0]), pages=len(held))
         self._sync_prefix_gauges()
+        return entry
 
     def _sync_prefix_gauges(self) -> None:
         """Publish the prefix cache's flow deltas and occupancy, both
@@ -3355,8 +3396,8 @@ class ContinuousBatchingEngine:
         was_warm = "step" in self._warm   # cold = compile in the wall
         if self._chaos is not None:
             self._chaos.on_dispatch()
-        with trace.span("serving/decode_dispatch",
-                        rows=len(active)) as disp:
+        with trace.span("serving/decode_dispatch", rows=len(active),
+                        **self._selected_read(pos[active])) as disp:
             active_arg = ()
             if self._lane_state:
                 live = np.zeros((self.max_slots,), bool)
@@ -3390,6 +3431,21 @@ class ContinuousBatchingEngine:
                 rows_advanced=len(active), capacity_rows=self.max_slots)
         for sid in active:
             self._deliver_burst(sid, nxt_np[sid:sid + 1], now)
+
+    def _selected_read(self, positions) -> dict:
+        """Attributes for the decode span of a model some of whose layers
+        select what they read (it says so by ``decode_read_counts``):
+        what they attended and what the step gathered of what the rows
+        hold, reckoned from the rows' positions; nothing for any other
+        model."""
+        if self._read_counts is None:
+            return {}
+        read = self._read_counts(positions, self._table_len)
+        self._ins.selected_attended_tokens_total.inc(read["attended_tokens"])
+        self._ins.selected_gathered_tokens_total.inc(read["gathered_tokens"])
+        self._ins.selected_cached_tokens_total.inc(read["cached_tokens"])
+        self._ins.selecting_decode_rows_total.inc(read["selecting_rows"])
+        return read
 
     def _decode_all_spec(self, active: List[int]) -> None:
         """Speculative decode over every occupied slot: one draft
@@ -3557,7 +3613,8 @@ class ContinuousBatchingEngine:
         tokens = np.concatenate(
             [st.handle.prompt,
              np.asarray(st.handle._tokens[:-1], np.int32)])
-        self._maybe_donate(sid, tokens, st.handle.request_id, st.snaps)
+        self._maybe_donate(sid, tokens, st.handle.request_id, st.snaps,
+                           st.prompt_entry)
         self._release_snaps(st.snaps)
         self._free_slot_table(sid)
         self._slots[sid] = None

@@ -751,18 +751,23 @@ class PagedPrefixIndex:
     # --------------------------------------------------------- donation
     def donate_pages(self, tokens: np.ndarray,
                      pages: Sequence[int],
-                     snaps: Sequence[Tuple[int, int]] = ()) -> bool:
-        """Retain a finished request's prefix by sharing the ``pages``
-        that hold its KV (position order; the caller keeps its own
-        references — the slot's table is freed separately). Declined
-        (False) when too short, already covered by an existing entry
-        (LRU-touched instead), or the entry budget is exhausted by
-        pinned entries. May evict the LRU ``refs == 0`` entry — the
+                     snaps: Sequence[Tuple[int, int]] = (),
+                     supersede: Optional[PrefixEntry] = None
+                     ) -> Optional[PrefixEntry]:
+        """Retain a request's prefix by sharing the ``pages`` that hold
+        its KV (position order; the caller keeps its own references —
+        the slot's table is freed separately). Returns the new entry;
+        declined (None) when too short, already covered by an existing
+        entry (LRU-touched instead), or the entry budget is exhausted
+        by pinned entries. May evict the LRU ``refs == 0`` entry — the
         budget resolves by recency, never by silently dropping pinned
         entries. ``snaps`` (a model with lane state) are the donor's
         ``(position, snapshot id)`` pairs at or under the donated
         length; the entry SHARES each, the donor keeps its own
-        references."""
+        references. ``supersede``: the entry the same request donated
+        when its prompt's prefill ended; the longer one takes its place
+        (one entry a request, as when only a finished request donated)
+        unless it is pinned, demoted or gone."""
         # own the key: np.asarray would ALIAS an int32 caller buffer,
         # and a client reusing one preallocated prompt array across
         # requests would then rewrite the trie key under an entry
@@ -775,7 +780,7 @@ class PagedPrefixIndex:
             if (self.max_entries == 0
                     or tokens.shape[0] < self.min_tokens
                     or n_pages == 0):
-                return False
+                return None
             if n_pages > len(pages):
                 raise ValueError(
                     f"donate_pages: {tokens.shape[0]} tokens need "
@@ -784,11 +789,14 @@ class PagedPrefixIndex:
             if covered is not None:
                 self._stamp += 1
                 covered.last_used = self._stamp
-                return False
+                return None
+            if supersede is not None and supersede.refs == 0 \
+                    and supersede in self._entries:
+                self._drop_device_entry(supersede, evicted=False)
             if len(self._entries) >= self.max_entries:
                 victim = self._lru_unpinned()
                 if victim is None:
-                    return False
+                    return None
                 self._drop_device_entry(victim)
             held = tuple(pages[:n_pages])
             # index -> pool lock order (see module docstring): the pool
@@ -804,17 +812,20 @@ class PagedPrefixIndex:
             self._insert(entry)
             self._entries.append(entry)
             self.donations += 1
-            return True
+            return entry
 
-    def _drop_device_entry(self, entry: PrefixEntry) -> None:
-        """Evict a device-tier entry outright (lock held): drop its
-        page references and remove it from the trie."""
+    def _drop_device_entry(self, entry: PrefixEntry,
+                           evicted: bool = True) -> None:
+        """Take a device-tier entry out (lock held): drop its page
+        references and remove it from the trie. ``evicted`` False: a
+        longer entry of the same request takes its place, which is no
+        eviction."""
         self._entries.remove(entry)
         self._trie_remove(entry)
         self.pool.free(entry.pages)
         entry.pages = ()
         self._free_snaps(entry)
-        self.evictions += 1
+        self.evictions += int(evicted)
         self.generation += 1
 
     def _free_snaps(self, entry: PrefixEntry) -> None:

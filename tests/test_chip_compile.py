@@ -269,6 +269,77 @@ def test_hybrid_decoder_program_compiles_and_holds_its_pool_in_place(
         padded, lanes)
 
 
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_sala_program_compiles_and_holds_its_pool_in_place(
+        topo, one_chip, program):
+    """One period of the MiniCPM-SALA cut (a block-sparse layer and three
+    lightning layers) at its published widths and the served 32768
+    positions, built as shapes from the benchmark's configuration: the
+    decode step over 16 lanes (each gathering 128 blocks' pages of the 512 a
+    lane may hold) and the 2 x 256 prefill chunk (a lane's pages by key
+    blocks) compile for the chip, the pool (K, V, compressed keys AND lane
+    state) is donated and aliased in full, pages and lanes take their
+    logical bytes (the factor the adapter budgets with), and the step's
+    temporaries for one period stay under a quarter of ``reserve_bytes``."""
+    import dataclasses
+
+    from benchmark import harness
+    from benchmark.models import minicpm_sala as adapter
+    from bigdl_tpu.nn.module import bind
+
+    cfg = harness.load_json(harness.HERE, "configs", "minicpm-sala.json")
+    cfg["sizes"] = dict(cfg["sizes"], num_hidden_layers=4,
+                        layers_held=[9, 13])          # sparse + 3 lightning
+    e = cfg["engine"]
+    slots, rows, chunk, page = (e["max_slots"], e["prefill_rows"],
+                                e["prefill_chunk"], e["page_size"])
+    ctx = cfg["sizes"]["max_position_embeddings"]
+    model = adapter.model_shapes(cfg)
+    assert [b.kind for b in model._blocks()] == [
+        "sparse_attention"] + ["lightning_attention"] * 3
+    sd = lambda a, dt=None: jax.ShapeDtypeStruct(
+        a.shape, dt or a.dtype, sharding=one_chip)
+    params = jax.tree.map(lambda a: sd(a, jnp.bfloat16), model.params_dict())
+    pool = jax.tree.map(sd, jax.eval_shape(lambda: model.init_page_pool(
+        1 + slots * ctx // page, page, dtype=jnp.bfloat16, lanes=slots + 1)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def step(p, tok, pos, pool, tables, active):
+        with bind(model, p, {}, False, None):
+            logits, pool = model.decode_step_paged(
+                tok, pos, pool, tables, active=active)
+        return jnp.argmax(logits, -1), pool
+
+    def prefill(p, ids, pool, tables, pos0, last, lanes):
+        with bind(model, p, {}, False, None):
+            return model.prefill_chunk_at_paged(ids, pool, tables, pos0,
+                                                last, lanes=lanes)
+
+    if program == "step":
+        compiled = jax.jit(step, donate_argnums=(3,)).lower(
+            params, i32(slots), i32(slots), pool, i32(slots, ctx // page),
+            jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
+    else:
+        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(rows, chunk), pool, i32(rows, ctx // page), i32(rows),
+            i32(rows), i32(rows)).compile()
+    m = _fits(compiled)
+    size = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                            for a in jax.tree.leaves(tree))
+    pages, lanes = size(pool["pages"]), size(pool["lanes"])
+    assert pages == (1 + slots * ctx // page) * adapter.cache_geometry(
+        cfg)["page_device_bytes"]          # one sparse layer's
+    assert lanes == (slots + 1) * adapter.lane_state_bytes(cfg)
+    assert m.alias_size_in_bytes >= pages + lanes
+    padded = m.alias_size_in_bytes - pages
+    assert abs(padded / lanes - adapter.LANE_DEVICE_FACTOR) < 0.01, (
+        padded, lanes)
+    print(program, dataclasses.asdict(m) if dataclasses.is_dataclass(m) else m)
+    # the programs' scratch: what reserve_bytes (1 GiB) is set from; the
+    # whole 16 layers read 0.16 GB (step) and 0.28 GB (chunk) the same way
+    assert m.temp_size_in_bytes < e["reserve_bytes"] // 4, m
+
+
 # ------------------------------------------------------- across four chips
 def test_tensor_parallel_decode_step_compiles_on_four_chips(topo,
                                                             mesh_engine):

@@ -191,3 +191,155 @@ def test_abstract_init_builds_shapes_and_moves_no_stream():
         broken(ids)
     # nothing of the trace leaks: ordinary construction still works after
     assert isinstance(nn.Linear(2, 2).weight, jax.Array)
+
+
+# ------------------------------------------- the four kinds and two styles
+def test_an_olmo_model_gives_the_logits_it_gave_before_the_class_took_more_kinds():
+    """Pinned from the commit before ``HybridDecoderLM`` learned the
+    lightning and sparse kinds, the pre-norm block and the muP scales (the
+    same script ran there and here, bit for bit equal on this host): the
+    full forward, a ragged prefill chunk and a decode step."""
+    model, _ = built(tiny_config(theta=10000.0), 11)
+    ids = np.random.RandomState(5).randint(0, 120, (2, 40))
+    out = np.asarray(model(jnp.asarray(ids)))
+    np.testing.assert_allclose(
+        out[1, 39, :4], [1.0124329328536987, 1.200801968574524,
+                         -1.2062780857086182, -0.007069682236760855],
+        rtol=2e-5, atol=2e-6)
+    assert float(np.abs(out).sum()) == pytest.approx(8832.3662109375, rel=1e-5)
+    pool = model.init_page_pool(1 + 2 * 16, 4, lanes=3)
+    tables = jnp.asarray(1 + np.arange(32).reshape(2, 16), jnp.int32)
+    lg, pool = model.prefill_chunk_at_paged(
+        jnp.asarray(ids[:, :8]), pool, tables, jnp.zeros((2,), jnp.int32),
+        jnp.asarray([7, 5]), lanes=jnp.asarray([0, 1]))
+    np.testing.assert_allclose(
+        np.asarray(lg).ravel()[:3], [-0.29622891545295715, 0.8449987769126892,
+                                     1.556283950805664], rtol=2e-5, atol=2e-6)
+    lg2, pool = model.decode_step_paged(
+        jnp.asarray(ids[:, 8]), jnp.asarray([8, 6]), pool, tables,
+        active=jnp.asarray([True, True]))
+    assert np.isfinite(np.asarray(lg2)).all()
+    assert model.block0.style == "post_norm" and model.embed_scale == 1.0
+
+
+def test_sala_full_forward_equals_the_reference():
+    from benchmark.reference import minicpm_sala as ref
+    from sala_tiny import built as sala_built, tiny_config as sala_config
+
+    config = sala_config()
+    model, w = sala_built(config, 3)
+    assert [b.kind for b in model._blocks()] == [
+        "lightning_attention", "sparse_attention", "lightning_attention",
+        "lightning_attention"]
+    assert model.block0.style == "pre_norm"
+    assert model.block0.residual_scale == pytest.approx(1.4 / 8 ** 0.5)
+    assert model.embed_scale == 12 and model.logit_scale == 8 / 32
+    ids = np.random.RandomState(0).randint(0, 120, (2, 150))
+    want = ref.forward(w, ids, config)
+    got = np.asarray(model(jnp.asarray(ids)))
+    assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+    # the sparse layers' rule is seen: the reference that attends densely
+    # everywhere and the one that takes the forced blocks only read apart
+    for rule in ("dense", "forced"):
+        other = ref.forward(w, ids, config, sparse_rule=rule)
+        assert np.abs(other[:, :64] - want[:, :64]).max() \
+            < 1e-5 * np.abs(want).max()            # under dense_len: the same
+        assert np.abs(other - want).max() > 1e-3 * np.abs(want).max(), rule
+
+
+def test_sala_prefill_then_decode_through_the_cache_equals_the_reference():
+    """Ragged rows: prompts of 101 and 77 in chunks of 16 (the last chunk
+    right-padded) into lanes 2 and 0 of a 4-lane pool, then 24 decode steps
+    with lane 1 inactive; past ``dense_len`` (64) and ``topk`` (96 tokens);
+    logits at every position against the reference's full forward."""
+    from benchmark.reference import minicpm_sala as ref
+    from sala_tiny import built as sala_built, tiny_config as sala_config
+
+    config = sala_config()
+    model, w = sala_built(config, 4)
+    rng = np.random.RandomState(1)
+    lens, new = [101, 77], 24
+    ids = rng.randint(0, 120, (2, 101 + new))
+    want = ref.forward(w, ids, config)
+    tol = 1e-4 * np.abs(want).max()
+    pages = 32
+    pool = model.init_page_pool(1 + 2 * pages, 4, lanes=4)
+    assert set(pool) == {"pages", "lanes"}
+    assert len(pool["pages"]) == 1 and len(pool["lanes"]) == 3
+    assert set(pool["pages"][0]) == {"k", "v", "ck"}
+    tables = jnp.asarray(1 + np.arange(2 * pages).reshape(2, pages), jnp.int32)
+    lanes = jnp.asarray([2, 0], jnp.int32)
+    chunk_fn = jax.jit(model.prefill_chunk_at_paged)
+    step_fn = jax.jit(model.decode_step_paged)
+    for c in range(0, 112, 16):
+        chunk = np.zeros((2, 16), np.int32)
+        last = np.zeros((2,), np.int32)
+        for r, n in enumerate(lens):
+            m = max(0, min(16, n - c))
+            chunk[r, :m] = ids[r, c:c + m]
+            last[r] = max(m - 1, 0)
+        done = [n <= c for n in lens]
+        logits, pool = chunk_fn(
+            jnp.asarray(chunk), pool, tables, jnp.full((2,), c, jnp.int32),
+            jnp.asarray(last), lanes=jnp.where(jnp.asarray(done), 3, lanes))
+        for r, n in enumerate(lens):
+            if c < n <= c + 16:
+                assert np.abs(np.asarray(logits[r]) - want[r, n - 1]).max() < tol
+    active = jnp.asarray([True, False, True, False])
+    order = [1, None, 0, None]          # lane -> row
+    pos = np.asarray([lens[1], 0, lens[0], 0])
+    step_tables = jnp.zeros((4, pages), jnp.int32).at[0].set(tables[1]) \
+        .at[2].set(tables[0])
+    idle = [np.asarray(jax.tree.leaves(s)[0][1]) for s in pool["lanes"]]
+    for i in range(new):
+        tok = np.zeros((4,), np.int32)
+        for lane, r in enumerate(order):
+            if r is not None:
+                tok[lane] = ids[r, lens[r] + i]
+        logits, pool = step_fn(jnp.asarray(tok), jnp.asarray(pos + i), pool,
+                               step_tables, active=active)
+        for lane, r in enumerate(order):
+            if r is not None:
+                assert np.abs(np.asarray(logits[lane])
+                              - want[r, lens[r] + i]).max() < tol
+    for before, s in zip(idle, pool["lanes"]):
+        assert np.array_equal(before, np.asarray(jax.tree.leaves(s)[0][1]))
+
+
+def test_sala_verify_chunk_and_what_the_engine_asks():
+    from benchmark.models import minicpm_sala as adapter
+    from benchmark.reference import minicpm_sala as ref
+    from sala_tiny import built as sala_built, tiny_config as sala_config
+
+    config = sala_config(positions=128)
+    model, w = sala_built(config, 5)
+    rows = np.random.RandomState(2).randint(0, 120, (3, 128))
+    want = ref.forward(w, rows, config)
+    got = adapter.paged_logits(model, None, config, rows)
+    assert np.abs(got - want).max() < 1e-4 * np.abs(want).max()
+    # one sparse layer: K and V of 2 KV heads of 8, and a quarter of a
+    # compressed key a token (one of 16 elements a page of 4)
+    assert model.kv_token_elems() == 2 * 2 * 8 + 2 * 8 / 4
+    # topk 6 blocks hold dense_len's 4: one gather of 6 blocks a row
+    assert model.decode_read_counts([10, 63, 64, 200], 32) == {
+        "attended_tokens": 11 + 64 + 65 + (96 - 7),
+        "gathered_tokens": 4 * 6 * 16,
+        "cached_tokens": 11 + 64 + 65 + 201, "selecting_rows": 2}
+    assert built(tiny_config(), 6)[0].decode_read_counts([5], 32) is None
+    # the selected tokens are what the analytic counts charge: a query
+    # over 4000 cached tokens reads K and V of 96, over 100 of 84 (six
+    # blocks less the 12 tokens ahead of it), and every compressed key
+    far, near = model.analytic_bytes(1, 4000), model.analytic_bytes(1, 100)
+    assert far - near == pytest.approx(
+        2 * (2 * 2 * 8 * (96 - 84) + 2 * 8 * (4000 - 100) / 4))
+    assert model.analytic_flops(1, 4000) > model.analytic_flops(1, 100)
+
+
+def test_what_the_class_refuses_of_its_configuration():
+    from bigdl_tpu.models.hybrid import HybridDecoderLM
+
+    with pytest.raises(ValueError, match="layer type"):
+        HybridDecoderLM(50, 16, 2, ("window_attention",), 24, 32)
+    with pytest.raises(ValueError, match="block style"):
+        HybridDecoderLM(50, 16, 2, ("full_attention",), 24, 32,
+                        block_style="sandwich")

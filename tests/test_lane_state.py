@@ -359,3 +359,150 @@ def test_a_request_keeps_its_newest_snapshot_and_the_boundary_it_matched(lm, reg
         st = eng.stats()["paging"]["state"]
         assert st["hits_shortened_total"] == 1       # the second's
         assert st["restored_total"] == 1
+
+
+# ---------------------------- a compressed-key cache beside K, V and the lanes
+# three lightning layers and two sparse ones (layers 1..5 of the tiny list):
+# a lane's 3072 bytes over a token's 288 (K, V and a quarter of a compressed
+# key in each sparse layer) is 10.7 tokens: a snapshot every 12, in chunks of
+# 4: boundaries that are NOT multiples of a span's 8 tokens
+import sala_tiny  # noqa: E402
+
+SALA = sala_tiny.tiny_config(positions=192, layers=5)
+SALA_KW = dict(max_slots=3, prefill_chunk=4, prefill_rows=2, page_size=4)
+
+
+@pytest.fixture(scope="module")
+def sala():
+    return sala_tiny.built(SALA, 9)
+
+
+def sala_held_to_reference(w, prompt, served):
+    from benchmark import compare
+    from benchmark.reference import minicpm_sala as ref
+
+    row = np.concatenate([prompt, served]).astype(np.int32)
+    logits = ref.forward(w, row[None], SALA)[0]
+    gaps = compare.served_token_gaps(logits, row, len(prompt))
+    assert gaps.max() == 0.0, gaps
+
+
+def test_sala_cold_then_a_hit_whose_boundary_splits_a_compressed_key_span(sala, reg):
+    """A cold request of 95 + 40 tokens (past ``dense_len`` 64, where six
+    blocks of 16 are taken of up to nine), then one that shares its first 90
+    tokens: the pages match up to 88 and the hit resumes at 84, the deepest
+    snapshot under the match (a multiple of 12, not of a span's 8: the span
+    of tokens 80..87 ends in the request's own first page and is the
+    request's to compute, from K of a page it shares). Both are the
+    reference's argmax at every token."""
+    model, w = sala
+    rng = np.random.RandomState(0)
+    doc = rng.randint(0, 120, 90).astype(np.int32)
+    ask = lambda n: np.concatenate([doc, rng.randint(0, 120, n)]).astype(np.int32)
+    with ContinuousBatchingEngine(model, **SALA_KW) as eng:
+        state = eng.stats()["paging"]["state"]
+        lane_bytes = 3 * 4 * 8 * 8 * 4
+        assert state["snapshot_bytes"] == lane_bytes == 3072
+        # the pages' bytes count the compressed keys: 2 layers x (K and V of
+        # 2 KV heads of 8 a token, and 16 elements a page of 4), float32
+        assert eng._pages.page_bytes == 2 * (4 * 2 * 2 * 8 + 2 * 8) * 4
+        assert state["snapshot_stride_tokens"] == 12
+        cold = ask(5)        # 95 tokens: its newest snapshot stands at 84
+        h = eng.submit(cold, 40)
+        served = np.asarray(h.result(timeout=600))[len(cold):]
+        assert h.prefix_tokens == 0
+        sala_held_to_reference(w, cold, served)
+        warm = ask(17)
+        trace.reset()
+        h = eng.submit(warm, 24)
+        served = np.asarray(h.result(timeout=600))[len(warm):]
+        assert h.prefix_tokens == 84 and 84 % 8 == 4
+        sala_held_to_reference(w, warm, served)
+        st = eng.stats()
+        assert st["paging"]["state"]["restored_total"] == 1
+        assert st["jit_compiles"] == 6          # nothing new compiles on a hit
+        # what the selection read, from the rows' positions at dispatch
+        spans = trace.export(names=["serving/decode_dispatch"])
+        assert len(spans) == 23
+        first = spans[0]["attrs"]
+        at = len(warm)                          # 107: 7 blocks seen, 6 taken
+        assert first == {"rows": 1, "cached_tokens": at + 1,
+                         "attended_tokens": 96 - (15 - at % 16),
+                         "gathered_tokens": 96, "selecting_rows": 1}
+        total = lambda name: reg.get(name).labels(
+            service=eng.service_name).get()
+        assert total("bigdl_serving_selecting_decode_rows_total") >= 23
+        assert total("bigdl_serving_selected_attended_tokens_total") < \
+            total("bigdl_serving_selected_cached_tokens_total")
+    assert eng._snaps.in_use == 0 and eng._pages.pages_in_use == 0
+
+
+def test_sala_a_request_hits_a_document_its_first_asker_is_still_decoding(sala, reg):
+    """With ``donate_at_prefill_end`` the first request for a document
+    donates its prompt's pages and its snapshots when its PREFILL ends: one
+    that asks for the same 90 tokens
+    while the first still decodes resumes at 84 and does not prefill the
+    document again. Both are the reference's argmax at every token, and the
+    entry the first donates at its end takes its first one's place."""
+    model, w = sala
+    rng = np.random.RandomState(3)
+    doc = rng.randint(0, 120, 90).astype(np.int32)
+    ask = lambda n: np.concatenate([doc, rng.randint(0, 120, n)]).astype(np.int32)
+    first, second = ask(5), ask(9)
+    with ContinuousBatchingEngine(model, **SALA_KW,
+                                  donate_at_prefill_end=True) as eng:
+        h1 = eng.submit(first, 60)
+        next(h1.tokens())
+        h2 = eng.submit(second, 12)
+        served2 = np.asarray(h2.result(timeout=600))[len(second):]
+        assert not h1.done() and h2.prefix_tokens == 84
+        sala_held_to_reference(w, second, served2)
+        sala_held_to_reference(
+            w, first, np.asarray(h1.result(timeout=600))[len(first):])
+        prefix = eng.stats()["prefix_cache"]
+        assert prefix["entries"] == 2 and prefix["evictions"] == 0
+        assert eng.stats()["paging"]["state"]["restored_total"] == 1
+    assert eng._snaps.in_use == 0 and eng._pages.pages_in_use == 0
+
+
+def test_sala_a_preempted_request_resumes_with_its_pages_and_compressed_keys(sala, reg):
+    model, w = sala
+    rng = np.random.RandomState(1)
+    victim = rng.randint(0, 120, 90).astype(np.int32)
+    urgent = rng.randint(0, 120, 6).astype(np.int32)
+    with ContinuousBatchingEngine(model, **dict(SALA_KW, max_slots=1),
+                                  preempt_slack_s=0.002) as eng:
+        h_low = eng.submit(victim, 40, priority="low")
+        next(h_low.tokens())
+        h_high = eng.submit(urgent, 4, priority="high")
+        sala_held_to_reference(w, urgent,
+                               np.asarray(h_high.result(timeout=600))[6:])
+        sala_held_to_reference(w, victim,
+                               np.asarray(h_low.result(timeout=600))[90:])
+        assert h_low.preempted >= 1
+        assert eng.stats()["paging"]["state"]["restored_total"] >= 1
+        assert eng.stats()["jit_compiles"] == 6
+
+
+@pytest.mark.parametrize("what", ["draft", "host_tier", "mesh", "kv_dtype"])
+def test_sala_what_lane_state_cannot_do_yet_is_refused_here_too(sala, what):
+    model, _ = sala
+    kw = {"draft": dict(draft=model),
+          "host_tier": dict(prefix_host_rows=2),
+          "mesh": dict(mesh=object()),
+          "kv_dtype": dict(kv_dtype="int8")}[what]
+    with pytest.raises(ValueError, match="lane state"):
+        ContinuousBatchingEngine(model, **SALA_KW, **kw)
+
+
+def test_the_sala_adapter_reckons_the_page_and_the_store_the_engine_derives(sala):
+    from benchmark.models import minicpm_sala as adapter
+
+    model, _ = sala
+    geometry = adapter.cache_geometry(SALA)
+    with ContinuousBatchingEngine(model, **SALA_KW) as eng:
+        assert geometry["page_device_bytes"] == eng._pages.page_bytes
+        assert adapter.lane_state_bytes(SALA) == eng._snaps.snapshot_bytes
+        assert adapter.SNAPSHOTS_PER_LANE == engine_mod.SNAPSHOTS_PER_LANE
+    assert geometry["fixed_device_bytes_per_lane"] == int(
+        3072 * (1 + 1 / 3 + 2))

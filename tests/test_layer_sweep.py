@@ -273,6 +273,11 @@ FIXTURES = {
     "gatedmlp": (lambda: nn.GatedMLP(6, 10), lambda: _f(2, 5, 6)),
     "gateddeltanet": (lambda: nn.GatedDeltaNet(8, 2, 4, 6),
                       lambda: _f(2, 9, 8)),
+    "lightningattention": (lambda: nn.LightningAttention(8, 2, 4),
+                           lambda: _f(2, 9, 8)),
+    "blocksparseattention": (lambda: nn.BlockSparseAttention(
+        8, 4, 2, 2, kernel_size=4, kernel_stride=2, block_size=4, topk=4,
+        init_blocks=1, window_size=4, dense_len=8), lambda: _f(2, 21, 8)),
     "transformer_block": (lambda: nn.TransformerBlock(8, 2),
                           lambda: _f(2, 5, 8)),
 }

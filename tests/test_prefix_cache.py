@@ -81,7 +81,7 @@ def _serve(pool, pc, tokens):
         pages = pool.alloc(n)
     if pages is None:
         return None
-    accepted = pc.donate_pages(tokens, pages)
+    accepted = pc.donate_pages(tokens, pages) is not None
     pool.free(pages)
     return accepted
 
@@ -283,6 +283,47 @@ def test_prefix_hit_parity_and_stats(lm):
         assert s["bytes"] <= s["capacity_bytes"]
         dbg = eng.debug_requests()
         assert dbg["prefix_cache"]["hits"] == 1
+
+
+@pytest.mark.parametrize("asked_by", ["argument", "model", "nobody"])
+def test_a_prompt_is_donated_when_its_prefill_ends(lm, asked_by):
+    """With ``donate_at_prefill_end`` (the engine's argument, or the
+    model's say where the argument is left None) a request that shares a
+    template head with one still DECODING is a hit: the first donates its
+    prompt's pages when its prefill ends, and the entry it donates at its
+    end takes that one's place: one entry a request, no eviction. Asked
+    by nobody, a request donates when it ends and the second one prefills
+    its whole prompt."""
+    import time
+
+    r = np.random.RandomState(12)
+    tpl = r.randint(0, 32, (8,))
+    pa = np.concatenate([tpl, r.randint(0, 32, (3,))])
+    pb = np.concatenate([tpl, r.randint(0, 32, (2,))])
+    early = asked_by != "nobody"
+    kw = {"donate_at_prefill_end": True} if asked_by == "argument" else {}
+    if asked_by == "model":
+        lm.donate_at_prefill_end = True
+    try:
+        with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=4,
+                                      **kw) as eng:
+            ha = eng.submit(pa, 30)
+            while ha.first_token_at is None:
+                time.sleep(0.001)
+            during = eng.stats()["prefix_cache"]
+            hb = eng.submit(pb, 4)
+            np.testing.assert_array_equal(hb.result(timeout=60),
+                                          _direct(lm, pb, 4))
+            np.testing.assert_array_equal(ha.result(timeout=60),
+                                          _direct(lm, pa, 30))
+            s = eng.stats()["prefix_cache"]
+    finally:
+        if asked_by == "model":
+            del lm.donate_at_prefill_end
+    assert during["entries"] == during["donations"] == int(early)
+    assert hb.prefix_tokens == (8 if early else 0)
+    assert s["hits"] == int(early) and s["donations"] == (4 if early else 2)
+    assert s["entries"] == 2 and s["evictions"] == 0
 
 
 def test_greedy_parity_shared_prefix_load_vs_cold_engine(lm):

@@ -94,7 +94,7 @@ def _serve(pool, pc, tokens, spill=_spill):
         pc.reclaim(n, spill)
         pages = pool.alloc(n)
     assert pages is not None
-    accepted = pc.donate_pages(tokens, pages)
+    accepted = pc.donate_pages(tokens, pages) is not None
     pool.free(pages)
     return accepted
 
