@@ -249,6 +249,18 @@ class HybridDecoderLM(Module):
                 "cached_tokens": int((t + 1).sum()),
                 "selecting_rows": int((t >= mixer.dense_len).sum())}
 
+    def prefill_read_counts(self, pos0, chunk: int, page_size: int,
+                            table_pages: int):
+        """What one full-attention layer of a prefill dispatch gathers
+        of what its rows' tables hold (``MultiHeadAttention
+        .chunk_read_counts``; host arithmetic for the engine's span and
+        counters); None when no layer is a full one."""
+        full = self._blocks(FULL)
+        if not full:
+            return None
+        return full[0].mixer.chunk_read_counts(pos0, chunk, page_size,
+                                               table_pages)
+
     def analytic_flops(self, tokens: int, context: int) -> float:
         """Forward FLOPs for ``tokens`` positions over ``context``
         cached ones: two a matmul weight, the score and value products

@@ -461,10 +461,19 @@ class TransformerLM(Module):
                     kv_dtype=kv_dtype)
                 for i in range(self.num_layers)]
 
+    def prefill_read_counts(self, pos0, chunk: int, page_size: int,
+                            table_pages: int) -> dict:
+        """What one attention layer of a prefill dispatch gathers of
+        what its rows' tables hold, the rows' chunks of ``chunk`` tokens
+        starting at ``pos0`` (host arithmetic for the engine's span and
+        counters: ``MultiHeadAttention.chunk_read_counts``)."""
+        return self.block0.attn.chunk_read_counts(pos0, chunk, page_size,
+                                                  table_pages)
+
     def prefill_chunk_at_paged(self, ids, pools, tables, pos0, last_idx):
         """Paged twin of :meth:`prefill_chunk_at`: each row's chunk
         scatters its KV into the pool pages its block-table row names
-        and attends the gathered view (``pos0`` is always the (B,)
+        and attends them by key blocks (``pos0`` is always the (B,)
         ragged form — the paged engine has no lockstep path). Same
         caller contract per row: every written position must fall
         inside the row's reserved pages."""

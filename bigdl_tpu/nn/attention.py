@@ -16,6 +16,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from bigdl_tpu.nn.module import Module
 from bigdl_tpu.nn.linear import Linear
@@ -147,7 +148,7 @@ def _kv_buffers(shape, scale_shape, dtype, sharding, kv_dtype):
             mk(scale_shape, jnp.float32), mk(scale_shape, jnp.float32))
 
 
-def _gather_pages(leaf, tables, heads=None):
+def _gather_pages(leaf, tables, heads=None, mode=None):
     """Assemble one logical KV row per batch entry from a page pool:
     ``leaf`` is a pool buffer (max_pages, page_size, H * D) — one
     token's heads side by side in the minor dimension (the scale
@@ -169,9 +170,12 @@ def _gather_pages(leaf, tables, heads=None):
     XLA lowers the take to one gather, so compiled shape depends only
     on the POOL geometry, never on any request's length. Table slots
     past a request's reservation point at the scratch page — garbage
-    the caller's causal mask must (and does) discard."""
+    the caller's causal mask must (and does) discard. ``mode`` is
+    ``jnp.take``'s: the default fills what an out-of-range id names, a
+    select over everything gathered; a caller whose ids are known valid
+    may say ``"clip"`` and pay for the gather alone."""
     b, tlen = tables.shape
-    g = jnp.take(leaf, tables, axis=0)          # (B, table_len, ps, H*D)
+    g = jnp.take(leaf, tables, axis=0, mode=mode)  # (B, table_len, ps, H*D)
     rows = g.reshape(b, tlen * g.shape[2], g.shape[3])
     if heads is None:
         return rows
@@ -199,14 +203,9 @@ def _dequantize_kv_rows(codes, scale, dtype):
     return (codes.astype(jnp.float32) * scale_rows).astype(dtype)
 
 
-def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
-    """Paged twin of :func:`_write_kv`: scatter one K/V block
-    (B, H, T, D) into the page-pool buffers through per-row block
-    tables, then gather the dense per-row views attention attends
-    over: per head, (B, T_total, H, D), or with ``rows=True`` as the
-    pool stores them, (B, T_total, H * D) (the two views of
-    :func:`_gather_pages`; the decode step without a mesh asks for
-    rows, the chunk and the mesh step for heads). ``positions`` is
+def _scatter_kv_paged(pool, k_t, v_t, tables, positions):
+    """Scatter one K/V block (B, H, T, D) into the page-pool buffers
+    through per-row block tables and return the pool. ``positions`` is
     (B,) (one decode token per row) or (B, T) (a ragged chunk); token
     ``t`` of row ``b`` scatters to page
     ``tables[b, positions[b,t] // page_size]`` at offset
@@ -221,10 +220,8 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
     which the TPU runtime stores pages-MINOR to dodge lane padding
     (PERF.md, PR 27; tests/test_chip_compile.py holds it).
 
-    The quantized 4-tuple form mirrors the dense path exactly — codes
-    and scale sidecars share the scatter index math, and what is
-    attended is the dequantized STORED view, so a paged cold pass
-    attends the values a dense engine's pass attends.
+    The quantized 4-tuple form mirrors the dense path exactly: codes
+    and scale sidecars share the scatter index math.
 
     Rows whose table slots are the scratch page (idle dispatch lanes)
     scatter junk there — multiple lanes may collide on it, which is
@@ -233,7 +230,6 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
     if jnp.ndim(positions) == 1:
         positions = positions[:, None]          # decode step: T == 1
     ps = pool[0].shape[1]
-    heads = k_t.shape[1]
     pg = jnp.take_along_axis(tables, positions // ps, axis=1)  # (B, T)
     off = positions % ps
 
@@ -244,29 +240,123 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
         rows = blk.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         return buf.at[pg, off].set(rows.astype(buf.dtype))
 
-    view = None if rows else heads
     if len(pool) == 2:
         k_buf, v_buf = pool
-        k_buf = write(k_buf, k_t)
-        v_buf = write(v_buf, v_t)
-        return ((k_buf, v_buf),
-                _gather_pages(k_buf, tables, view),
-                _gather_pages(v_buf, tables, view))
+        return write(k_buf, k_t), write(v_buf, v_t)
     k_q, v_q, k_s, v_s = pool
     kq, ks = quantize_kv(k_t)
     vq, vs = quantize_kv(v_t)
-    k_q = write(k_q, kq)
-    v_q = write(v_q, vq)
-    k_s = write(k_s, ks)
-    v_s = write(v_s, vs)
+    return write(k_q, kq), write(v_q, vq), write(k_s, ks), write(v_s, vs)
+
+
+def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
+    """Paged twin of :func:`_write_kv` for the decode step:
+    :func:`_scatter_kv_paged`, then gather the dense per-row views
+    attention attends over: per head, (B, T_total, H, D), or with
+    ``rows=True`` as the pool stores them, (B, T_total, H * D) (the two
+    views of :func:`_gather_pages`; the decode step without a mesh asks
+    for rows, the mesh step for heads; the chunk gathers by key blocks,
+    :func:`_attend_key_blocks`).
+
+    With the quantized 4-tuple what is attended is the dequantized
+    STORED view, so a paged cold pass attends the values a dense
+    engine's pass attends."""
+    heads = k_t.shape[1]
+    pool = _scatter_kv_paged(pool, k_t, v_t, tables, positions)
+    view = None if rows else heads
+    if len(pool) == 2:
+        k_buf, v_buf = pool
+        return (pool,
+                _gather_pages(k_buf, tables, view),
+                _gather_pages(v_buf, tables, view))
+    k_q, v_q, k_s, v_s = pool
     # the sidecar's own per-head view is (B, T, H, 1); as rows it stays
     # (B, T, H) and is spread over each head's columns
     dequant = _dequantize_kv_rows if rows else dequantize_kv
-    return ((k_q, v_q, k_s, v_s),
+    return (pool,
             dequant(_gather_pages(k_q, tables, view),
                     _gather_pages(k_s, tables, view), k_t.dtype),
             dequant(_gather_pages(v_q, tables, view),
                     _gather_pages(v_s, tables, view), v_t.dtype))
+
+
+#: keys a round of the chunk's paged attention scores at once. On the
+#: chip the cost follows the keys read, rounded up to whole rounds: at
+#: head size 128 rounds of 256 to 1024 keys read alike and 128 pays for
+#: its trips, at head size 64 rounds of 64 to 256 read alike and wider
+#: ones pay for scratch slots (PERF.md, PR 38)
+KEY_BLOCK_TOKENS = 256
+
+
+def _key_block_pages(page_size: int, table_len: int) -> int:
+    """Pages of keys :func:`_attend_key_blocks` gathers a round: the
+    whole pages of ``KEY_BLOCK_TOKENS`` keys, no more than the table
+    has. From shapes alone, so one compiled width a pool geometry; a
+    table no longer than a round is read in one, which is the dense
+    form."""
+    return max(1, min(table_len, KEY_BLOCK_TOKENS // page_size))
+
+
+def _attend_key_blocks(q, pool, tables, positions):
+    """A chunk's causal softmax over the pages its rows hold: ``q``
+    (B, H, T, D) whose token ``t`` of row ``b`` stands at
+    ``positions[b, t]`` and attends the keys at or before it, ``pool``
+    the leaves the chunk was just scattered into (the float pair, or
+    the int8 4-tuple, dequantized block by block as stored). Returns
+    (B, H, T, D) float32.
+
+    The table is walked :func:`_key_block_pages` pages a round with a
+    running maximum, sum and float32 accumulator, up to the furthest
+    position any row of the dispatch has reached and nothing behind it
+    (the trip count is traced, the width is not): a row's scratch
+    slots past that are never gathered, where the dense form gathered
+    every row's whole table and made (B, H, T, table_len * page_size)
+    float32 scores whatever the rows held. The page ids are the
+    table's own, so the take clips and needs no fill. GQA runs grouped
+    against the un-expanded block; heads stay a batch dimension of
+    both products, so a heads-sharded pool needs no collective."""
+    b, h, t, d = q.shape
+    ps = pool[0].shape[1]
+    h_kv = pool[0].shape[2] // d
+    kp = _key_block_pages(ps, tables.shape[1])
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % kp)))
+    width = kp * ps
+    qg = q.reshape(b, h_kv, h // h_kv, t, d)
+    scale = 1.0 / math.sqrt(d)
+
+    def block(leaves, tb):
+        # (B, width, H_kv, D) in q's dtype: the pages as stored, or the
+        # codes times their scales (dequantize_kv's float32 product)
+        got = [_gather_pages(leaf, tb, h_kv, mode="clip") for leaf in leaves]
+        return got[0] if len(got) == 1 else dequantize_kv(*got, q.dtype)
+
+    def some_keys(i, carry):
+        top, den, acc = carry
+        tb = jax.lax.dynamic_slice_in_dim(tables, i * kp, kp, axis=1)
+        # (k, v) or (k_q, v_q, k_s, v_s): K's leaves are the even ones
+        k_i, v_i = block(pool[0::2], tb), block(pool[1::2], tb)
+        s = jnp.einsum("bgrtd,bngd->bgrtn", qg, k_i,
+                       preferred_element_type=jnp.float32) * scale
+        live = (i * width + jnp.arange(width))[None, None] \
+            <= positions[:, :, None]                         # (B, T, N)
+        s = jnp.where(live[:, None, None], s, -jnp.inf)
+        # key 0 is live for every query, so from the first round on
+        # every maximum is finite
+        new = jnp.maximum(top, jnp.max(s, axis=-1))
+        p = jnp.exp(s - new[..., None])
+        shrink = jnp.exp(top - new)
+        den = den * shrink + jnp.sum(p, axis=-1)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "bgrtn,bngd->bgrtd", p.astype(v_i.dtype), v_i,
+            preferred_element_type=jnp.float32)
+        return new, den, acc
+
+    rounds = (jnp.max(positions) + width) // width
+    _, den, acc = jax.lax.fori_loop(0, rounds, some_keys, (
+        jnp.full(qg.shape[:-1], -jnp.inf, jnp.float32),
+        jnp.zeros(qg.shape[:-1], jnp.float32),
+        jnp.zeros(qg.shape, jnp.float32)))
+    return (acc / den[..., None]).reshape(b, h, t, d)
 
 
 def _attend_pages_heads(q, k_read, v_read, pos):
@@ -722,8 +812,9 @@ class MultiHeadAttention(Module):
     def forward_chunk_paged(self, x, pool, tables, pos0):
         """RAGGED chunked prefill against a page pool (the paged twin
         of :meth:`forward_chunk` with a (B,) ``pos0``): each row's
-        chunk scatters into its own pages and attends the gathered
-        view under its own position mask.
+        chunk scatters into its own pages and attends the pages the
+        dispatch's rows hold, by key blocks under its own position
+        mask (:func:`_attend_key_blocks`).
 
         CALLER CONTRACT (the paged form of forward_chunk's): every
         written position ``pos0 + i`` must fall inside the row's
@@ -738,23 +829,26 @@ class MultiHeadAttention(Module):
         if self.rotary:
             q = rotary_embedding_rowwise(q, positions, self.rotary_base)
             k = rotary_embedding_rowwise(k, positions, self.rotary_base)
-        pool, k_read, v_read = _write_kv_paged(pool, k, v,
-                                               tables, positions)
-        h_kv = self.num_kv_heads
-        rep = self.num_heads // h_kv
-        qg = q.reshape(b, h_kv, rep, t, self.head_dim)
-        scale = 1.0 / math.sqrt(self.head_dim)
-        # k_read / v_read are token-major (B, T_total, H_kv, D)
-        s = jnp.einsum("bgrtd,bTgd->bgrtT", qg, k_read,
-                       preferred_element_type=jnp.float32) * scale
-        ln = k_read.shape[1]
-        live = jnp.arange(ln)[None, None, :] <= positions[:, :, None]
-        s = jnp.where(live[:, None, None], s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1).astype(v_read.dtype)
-        o = jnp.einsum("bgrtT,bTgd->bgrtd", p, v_read)
-        o = o.transpose(0, 3, 1, 2, 4).reshape(b, t, self.embed_dim)
-        o = self.out_proj(o.reshape(b * t, self.embed_dim).astype(x.dtype))
+        pool = _scatter_kv_paged(pool, k, v, tables, positions)
+        o = _attend_key_blocks(q, pool, tables, positions)
+        o = o.transpose(0, 2, 1, 3).reshape(b * t, self.embed_dim)
+        o = self.out_proj(o.astype(x.dtype))
         return o.reshape(b, t, -1), pool
+
+    def chunk_read_counts(self, pos0, t: int, page_size: int,
+                          table_len: int) -> dict:
+        """What :meth:`forward_chunk_paged` gathers for a dispatch whose
+        rows' chunks of ``t`` tokens start at ``pos0`` (host arithmetic
+        of :func:`_attend_key_blocks`' trip count and width, for the
+        engine's span and counters), summed over those rows: the
+        tokens' worth of table slots gathered, and what the rows'
+        whole tables hold."""
+        width = _key_block_pages(page_size, table_len) * page_size
+        whole = table_len * page_size
+        reach = int(np.max(pos0)) + int(t)
+        return {"kv_read_tokens":
+                len(pos0) * min(-(-reach // width) * width, whole),
+                "kv_table_tokens": len(pos0) * whole}
 
     def _rope(self, x, positions):
         return rotary_embedding(x, positions, self.rotary_base) \

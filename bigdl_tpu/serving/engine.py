@@ -457,6 +457,14 @@ class ContinuousBatchingEngine:
         if self._read_counts is not None \
                 and self._read_counts([0], 1) is None:
             self._read_counts = None
+        #: the model's host arithmetic for what a prefill dispatch's
+        #: full-attention layers gather of their rows' tables (None: it
+        #: has no such layer, or does not say)
+        self._chunk_counts = getattr(model, "prefill_read_counts", None)
+        if self._chunk_counts is not None \
+                and self._chunk_counts([0], 1, 1, 1) is None:
+            self._chunk_counts = None
+        self._prefill_kv_read = self._prefill_kv_table = 0
         if self._lane_state:
             refused = {
                 "draft": (draft is not None, "speculation needs the "
@@ -2887,8 +2895,9 @@ class ContinuousBatchingEngine:
         with trace.span(
                 "serving/prefill_dispatch", rows=len(self._adms),
                 tokens=sum(t + d for _, t, d in done_by),
-                request_ids=[a.handle.request_id
-                             for a in self._adms]) as disp:
+                request_ids=[a.handle.request_id for a in self._adms],
+                **self._chunk_read([pos0[a.row] for a in self._adms],
+                                   c)) as disp:
             # each row writes through its admission's reserved block
             # table (idle rows carry the all-scratch table — their
             # padding writes hit page 0)
@@ -3367,6 +3376,9 @@ class ContinuousBatchingEngine:
                "decode_attention": self._decode_attention,
                "fragmentation": self._fragmentation(),
                "pool": self._pages.stats()}
+        if self._chunk_counts is not None:
+            out["prefill_kv_read_tokens"] = self._prefill_kv_read
+            out["prefill_kv_table_tokens"] = self._prefill_kv_table
         if self._d_pages is not None:
             out["draft_pool"] = self._d_pages.stats()
         if self._prefix is not None:
@@ -3431,6 +3443,21 @@ class ContinuousBatchingEngine:
                 rows_advanced=len(active), capacity_rows=self.max_slots)
         for sid in active:
             self._deliver_burst(sid, nxt_np[sid:sid + 1], now)
+
+    def _chunk_read(self, pos0, chunk: int) -> dict:
+        """Attributes for the prefill span of a model whose chunk attends
+        by key blocks (it says so by ``prefill_read_counts``): the
+        tokens' worth of table slots a full-attention layer gathers for
+        the rows that hold an admission, and what their whole tables
+        hold; also summed into ``stats()["paging"]``. Nothing for any
+        other model."""
+        if self._chunk_counts is None:
+            return {}
+        read = self._chunk_counts(pos0, chunk, self.page_size,
+                                  self._table_len)
+        self._prefill_kv_read += read["kv_read_tokens"]
+        self._prefill_kv_table += read["kv_table_tokens"]
+        return read
 
     def _selected_read(self, positions) -> dict:
         """Attributes for the decode span of a model some of whose layers

@@ -213,32 +213,32 @@ def test_paged_engine_program_compiles(topo, one_chip, engine, program):
 
 
 # ------------------------------------------- the hybrid decoder's programs
-@pytest.mark.parametrize("program", ["step", "chunk"])
-def test_hybrid_decoder_program_compiles_and_holds_its_pool_in_place(
-        topo, one_chip, program):
+HYBRID = dict(slots=16, rows=2, chunk=256, page=16, ctx=4096, heads=30,
+              embed=3840)
+
+
+def _hybrid_period(sharding):
     """One period of the hybrid decoder (3 gated delta-rule layers and a
-    full-attention layer) at its published widths, built as shapes: the
-    decode step over 16 lanes and the 2 x 256 prefill chunk compile for the
-    chip, the pool (pages AND lane state) is donated and aliased in full,
-    pages take their logical bytes, and the lane state takes 4/3 of its
-    (the minor 192 of S pads to 256 lanes of the tile): the factor the
-    benchmark's adapter budgets with."""
-    from benchmark.models import olmo_hybrid as adapter
+    full-attention layer) at its published widths, built as shapes:
+    ``{"step": (fn, args, donated), "chunk": ...}`` at the cell's geometry,
+    16 lanes and a 2 x 256 chunk over tables of 256 pages of 16."""
     from bigdl_tpu.models.hybrid import HybridDecoderLM
     from bigdl_tpu.nn.module import abstract_init, bind
 
     kinds = ("linear_attention",) * 3 + ("full_attention",)
-    slots, rows, chunk, page, ctx = 16, 2, 256, 16, 4096
+    slots, rows, chunk, page, ctx = (HYBRID[k] for k in (
+        "slots", "rows", "chunk", "page", "ctx"))
     model = abstract_init(lambda: HybridDecoderLM(
-        100352, 3840, 30, kinds, 11008, ctx, num_kv_heads=30,
-        linear_heads=30, linear_key_dim=96, linear_value_dim=192))
+        100352, HYBRID["embed"], HYBRID["heads"], kinds, 11008, ctx,
+        num_kv_heads=30, linear_heads=30, linear_key_dim=96,
+        linear_value_dim=192))
     model.evaluate()
     sd = lambda a, dt=None: jax.ShapeDtypeStruct(
-        a.shape, dt or a.dtype, sharding=one_chip)
+        a.shape, dt or a.dtype, sharding=sharding)
     params = jax.tree.map(lambda a: sd(a, jnp.bfloat16), model.params_dict())
     pool = jax.tree.map(sd, jax.eval_shape(lambda: model.init_page_pool(
         1 + slots * ctx // page, page, dtype=jnp.bfloat16, lanes=slots + 1)))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=sharding)
 
     def step(p, tok, pos, pool, tables, active):
         with bind(model, p, {}, False, None):
@@ -251,15 +251,31 @@ def test_hybrid_decoder_program_compiles_and_holds_its_pool_in_place(
             return model.prefill_chunk_at_paged(ids, pool, tables, pos0,
                                                 last, lanes=lanes)
 
-    if program == "step":
-        compiled = jax.jit(step, donate_argnums=(3,)).lower(
-            params, i32(slots), i32(slots), pool, i32(slots, ctx // page),
-            jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
-    else:
-        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
-            params, i32(rows, chunk), pool, i32(rows, ctx // page), i32(rows),
-            i32(rows), i32(rows)).compile()
+    return {
+        "step": (step, (params, i32(slots), i32(slots), pool,
+                        i32(slots, ctx // page), jax.ShapeDtypeStruct(
+                            (slots,), bool, sharding=sharding)), 3),
+        "chunk": (prefill, (params, i32(rows, chunk), pool,
+                            i32(rows, ctx // page), i32(rows), i32(rows),
+                            i32(rows)), 2),
+    }
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_hybrid_decoder_program_compiles_and_holds_its_pool_in_place(
+        topo, one_chip, program):
+    """One period of the hybrid decoder at its published widths: the
+    decode step over 16 lanes and the 2 x 256 prefill chunk compile for the
+    chip, the pool (pages AND lane state) is donated and aliased in full,
+    pages take their logical bytes, and the lane state takes 4/3 of its
+    (the minor 192 of S pads to 256 lanes of the tile): the factor the
+    benchmark's adapter budgets with."""
+    from benchmark.models import olmo_hybrid as adapter
+
+    fn, args, donated = _hybrid_period(one_chip)[program]
+    compiled = jax.jit(fn, donate_argnums=(donated,)).lower(*args).compile()
     m = _fits(compiled)
+    pool = args[donated]
     size = lambda tree: sum(int(np.prod(a.shape)) * a.dtype.itemsize
                             for a in jax.tree.leaves(tree))
     pages, lanes = size(pool["pages"]), size(pool["lanes"])
@@ -267,6 +283,41 @@ def test_hybrid_decoder_program_compiles_and_holds_its_pool_in_place(
     padded = m.alias_size_in_bytes - pages
     assert abs(padded / lanes - adapter.LANE_DEVICE_FACTOR) < 0.01, (
         padded, lanes)
+
+
+@pytest.mark.parametrize("config", ["gpt2-large", "olmo-hybrid-7b"])
+def test_chunk_attends_by_key_blocks_and_makes_no_whole_table_view(
+        engine, config):
+    """The prefill chunk's full-attention layers walk the rows' tables by
+    key blocks (PERF.md, PR 38): lowered at the cell's chunk geometry the
+    program has a ``while`` for the rounds and makes neither the scores over
+    a whole table, (rows, heads, T, table_len * page_size), nor a row's
+    whole gathered table, (rows, table_len, page_size, H * D): the dense
+    form's 252 MB of float32 scores and 63 MB views a layer at the hybrid's
+    widths. The property by shape, nothing compiled."""
+    if config == "gpt2-large":
+        lowered = engine._chunk_jit.lower(*_engine_args(
+            engine, _abstract(engine._params, None), None, None)["chunk"])
+        rows, t = SERVE["prefill_rows"], SERVE["prefill_chunk"]
+        page, table, width = (SERVE["page_size"], engine._table_len,
+                              LM["embed_dim"])
+    else:
+        fn, args, _ = _hybrid_period(None)["chunk"]
+        lowered = jax.jit(fn).lower(*args)
+        rows, t, page, width = (HYBRID["rows"], HYBRID["chunk"],
+                                HYBRID["page"], HYBRID["embed"])
+        table = HYBRID["ctx"] // page
+    text = lowered.as_text()
+    assert "stablehlo.while" in text
+    scores = re.findall(rf"tensor<{rows}x[0-9x]*x{t}x{table * page}x\w+>",
+                        text)
+    views = re.findall(rf"tensor<{rows}x{table}x{page}x{width}x\w+>", text)
+    assert not scores and not views, (scores[:2], views[:2])
+    # the rounds are narrower than the table, and the blocks are there
+    from bigdl_tpu.nn.attention import _key_block_pages
+    kp = _key_block_pages(page, table)
+    assert kp < table
+    assert f"tensor<{rows}x{kp}x{page}x{width}x" in text
 
 
 @pytest.mark.parametrize("program", ["step", "chunk"])
