@@ -1,0 +1,8 @@
+"""Kernels: median device milliseconds a run of the decode-step program under
+the recurrent layers' state update (``gdn/*``, ``lightning/*``), self times
+summed by scope (``benchmark/program_scopes.py``)."""
+from benchmark import program_scopes
+
+
+def value(run, trace):
+    return program_scopes.group_ms(run, trace, "decode_step", "recurrent")

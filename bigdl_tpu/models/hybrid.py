@@ -45,9 +45,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from bigdl_tpu import nn
-from bigdl_tpu.nn.attention import RMSNorm
+from bigdl_tpu.nn.attention import RMSNorm, normed
 from bigdl_tpu.nn.gated_delta import GatedMLP, project
-from bigdl_tpu.nn.module import Module
+from bigdl_tpu.nn.module import Module, scoped
 
 LINEAR, FULL = "linear_attention", "full_attention"
 LIGHTNING, SPARSE = "lightning_attention", "sparse_attention"
@@ -108,18 +108,22 @@ class HybridBlock(Module):
     def _enter(self, x):
         """What the mixer takes of the float32 stream ``x``."""
         if self.style == PRE_NORM:
-            x = self.mixer_norm(x)
+            x = normed(self.mixer_norm, x)
         # the full-attention mixer takes the weights' dtype (its pages are)
         return (x.astype(self.mlp.down.weight.dtype) if self.kind == FULL
                 else x)
 
     def _rest(self, x, mixed):
-        mixed = mixed.astype(jnp.float32)
-        if self.style == PRE_NORM:
-            h = x + self.residual_scale * mixed
-            return h + self.residual_scale * self.mlp(self.mlp_norm(h))
-        h = x + self.mixer_norm(mixed)
-        return h + self.mlp_norm(self.mlp(h))
+        pre = self.style == PRE_NORM
+        with jax.named_scope("attn/out"):       # the mixer's residual
+            mixed = mixed.astype(jnp.float32)
+            h = x + (self.residual_scale * mixed if pre
+                     else normed(self.mixer_norm, mixed))
+        with jax.named_scope("mlp"):            # and the MLP's
+            if pre:
+                return h + self.residual_scale * self.mlp(
+                    normed(self.mlp_norm, h))
+            return h + normed(self.mlp_norm, self.mlp(h))
 
     def forward(self, input):
         x = input.astype(jnp.float32)
@@ -172,6 +176,7 @@ class HybridDecoderLM(Module):
         self.norm_f = RMSNorm(embed_dim, eps)
         self.head = nn.Linear(embed_dim, vocab_size, with_bias=False)
 
+    @scoped("embed")
     def _embed(self, ids):
         x = jnp.take(self.tok_embed, ids, axis=0).astype(jnp.float32)
         return x if self.embed_scale == 1.0 else x * self.embed_scale
@@ -181,6 +186,7 @@ class HybridDecoderLM(Module):
                 for i, k in enumerate(self.layer_types)
                 if not kinds or k in kinds]
 
+    @scoped("head")
     def _logits(self, x):
         lead = x.shape[:-1]
         x = self.norm_f(x).reshape(-1, self.embed_dim)
@@ -322,8 +328,9 @@ class HybridDecoderLM(Module):
         the tokens up to ``last_idx[row]`` (a row at ``pos0`` 0 starts
         from zero). Logits at ``last_idx``."""
         x, pool = self._chunk(ids, pool, tables, pos0, lanes, last_idx + 1)
-        x = jnp.take_along_axis(
-            x, last_idx[:, None, None].astype(jnp.int32), axis=1)
+        with jax.named_scope("head"):
+            x = jnp.take_along_axis(
+                x, last_idx[:, None, None].astype(jnp.int32), axis=1)
         return self._logits(x)[:, 0], pool
 
     def verify_chunk_paged(self, ids, pool, tables, pos0, lanes=None):
@@ -346,19 +353,23 @@ class HybridDecoderLM(Module):
                 i_page += 1
             else:
                 # the rows' lanes, from zero where a row starts afresh
-                state = jax.tree.map(
-                    lambda a: jnp.where(
-                        fresh.reshape((b,) + (1,) * (a.ndim - 1)), 0,
-                        a[lanes]).astype(a.dtype), states[i_lane])
-                if blk.kind == LINEAR:
-                    mixed, state = blk.mixer.forward_chunk(
-                        inp, state, n_valid)
-                else:
-                    mixed, state = blk.mixer.forward_chunk(
-                        inp, state, pos0, n_valid)
-                states[i_lane] = jax.tree.map(
-                    lambda a, new: a.at[lanes].set(new),
-                    states[i_lane], state)
+                # (the lanes' read and write go with the recurrence; the
+                # mixer's own scopes inside are the innermost and win)
+                with jax.named_scope("gdn/chunk" if blk.kind == LINEAR
+                                     else "lightning/chunk"):
+                    state = jax.tree.map(
+                        lambda a: jnp.where(
+                            fresh.reshape((b,) + (1,) * (a.ndim - 1)), 0,
+                            a[lanes]).astype(a.dtype), states[i_lane])
+                    if blk.kind == LINEAR:
+                        mixed, state = blk.mixer.forward_chunk(
+                            inp, state, n_valid)
+                    else:
+                        mixed, state = blk.mixer.forward_chunk(
+                            inp, state, pos0, n_valid)
+                    states[i_lane] = jax.tree.map(
+                        lambda a, new: a.at[lanes].set(new),
+                        states[i_lane], state)
                 i_lane += 1
             x = blk._rest(x, mixed)
         return x, {"pages": pages, "lanes": states}
@@ -390,15 +401,17 @@ class HybridDecoderLM(Module):
                 # rows), so the state is updated in place under its
                 # mask and never sliced out and written back
                 spare = jax.tree.leaves(states[i_lane])[0].shape[0] - b
-                wide = jnp.pad(inp, ((0, spare), (0, 0)))
-                if blk.kind == LINEAR:
-                    mixed, states[i_lane] = blk.mixer.forward_step(
-                        wide, states[i_lane], jnp.pad(live, (0, spare)))
-                else:
-                    mixed, states[i_lane] = blk.mixer.forward_step(
-                        wide, states[i_lane], jnp.pad(pos, (0, spare)),
-                        jnp.pad(live, (0, spare)))
-                mixed = mixed[:b]
+                with jax.named_scope("gdn/step" if blk.kind == LINEAR
+                                     else "lightning/step"):
+                    wide = jnp.pad(inp, ((0, spare), (0, 0)))
+                    if blk.kind == LINEAR:
+                        mixed, states[i_lane] = blk.mixer.forward_step(
+                            wide, states[i_lane], jnp.pad(live, (0, spare)))
+                    else:
+                        mixed, states[i_lane] = blk.mixer.forward_step(
+                            wide, states[i_lane], jnp.pad(pos, (0, spare)),
+                            jnp.pad(live, (0, spare)))
+                    mixed = mixed[:b]
                 i_lane += 1
             x = blk._rest(x, mixed)
         return self._logits(x), {"pages": pages, "lanes": states}

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu import nn
 from bigdl_tpu.nn.attention import LayerNorm, TransformerBlock
-from bigdl_tpu.nn.module import Module
+from bigdl_tpu.nn.module import Module, scoped
 
 # jitted decode fns cached per live model instance (weak: a saved/cloned
 # model never carries a jit wrapper through pickle)
@@ -195,17 +195,18 @@ class TransformerLM(Module):
     def forward(self, input):
         ids = input.astype(jnp.int32)
         b, t = ids.shape
-        x = jnp.take(self.tok_embed, ids, axis=0)
-        if not self.use_rope:  # RoPE rotates inside each attention layer
-            if self.sequence_parallel is not None:
-                # each device holds sequence block axis_index: offset pos
-                idx = jax.lax.axis_index(self.sequence_parallel)
-                pos0 = idx * t
-            else:
-                pos0 = 0
-            pos = jax.lax.dynamic_slice_in_dim(self.pos_embed, pos0, t,
-                                               axis=0)
-            x = x + pos[None]
+        with jax.named_scope("embed"):
+            x = jnp.take(self.tok_embed, ids, axis=0)
+            if not self.use_rope:  # RoPE rotates inside each attention layer
+                if self.sequence_parallel is not None:
+                    # each device holds sequence block axis_index: offset pos
+                    idx = jax.lax.axis_index(self.sequence_parallel)
+                    pos0 = idx * t
+                else:
+                    pos0 = 0
+                pos = jax.lax.dynamic_slice_in_dim(self.pos_embed, pos0, t,
+                                                   axis=0)
+                x = x + pos[None]
         aux_total = 0.0
         moe_stats = []
         for i in range(self.num_layers):
@@ -253,11 +254,12 @@ class TransformerLM(Module):
             n = len(moe_stats)
             self.last_moe_stats = jax.tree.map(
                 lambda *leaves: sum(leaves) / n, *moe_stats)
-        x = self.ln_f(x)
-        if self.tie_embeddings:
-            logits = jnp.einsum("btc,vc->btv", x, self.tok_embed)
-        else:
-            logits = self.head(x.reshape(b * t, -1)).reshape(b, t, -1)
+        with jax.named_scope("head"):
+            x = self.ln_f(x)
+            if self.tie_embeddings:
+                logits = jnp.einsum("btc,vc->btv", x, self.tok_embed)
+            else:
+                logits = self.head(x.reshape(b * t, -1)).reshape(b, t, -1)
         return logits
 
     # ------------------------------------------------- KV-cache decoding
@@ -413,24 +415,34 @@ class TransformerLM(Module):
         the head — O(B) vocab projections, not O(B*T)): the ragged
         prefill's per-row last-valid position."""
         b, t = ids.shape
-        x = jnp.take(self.tok_embed, ids, axis=0)
-        if not self.use_rope:
-            if chunked and jnp.ndim(pos0) == 1:
-                # ragged chunk: per-row positional rows, (B, T, C)
-                x = x + jnp.take(self.pos_embed,
-                                 pos0[:, None] + jnp.arange(t)[None],
-                                 axis=0)
-            else:
-                pe = (jax.lax.dynamic_slice_in_dim(
-                          self.pos_embed, pos0, t, 0)
-                      if chunked else self.pos_embed[pos0:pos0 + t])
-                x = x + pe[None]
+        with jax.named_scope("embed"):
+            x = jnp.take(self.tok_embed, ids, axis=0)
+            if not self.use_rope:
+                if chunked and jnp.ndim(pos0) == 1:
+                    # ragged chunk: per-row positional rows, (B, T, C)
+                    x = x + jnp.take(self.pos_embed,
+                                     pos0[:, None] + jnp.arange(t)[None],
+                                     axis=0)
+                else:
+                    pe = (jax.lax.dynamic_slice_in_dim(
+                              self.pos_embed, pos0, t, 0)
+                          if chunked else self.pos_embed[pos0:pos0 + t])
+                    x = x + pe[None]
         new_caches = []
         for i in range(self.num_layers):
             blk = getattr(self, f"block{i}")
             x, c = (blk.forward_chunk(x, caches[i], pos0) if chunked
                     else blk.forward_prefill(x, caches[i], pos0))
             new_caches.append(c)
+        logits = self._chunk_logits(x, all_logits, gather_last)
+        if all_logits and gather_last is None:
+            return logits, new_caches
+        return logits[:, 0], new_caches
+
+    @scoped("head")
+    def _chunk_logits(self, x, all_logits: bool, gather_last):
+        """Final norm and logits of a chunk's hidden states (B, T, C): at
+        every position, at each row's ``gather_last``, or at the last."""
         if gather_last is not None:
             x = jnp.take_along_axis(
                 x, gather_last[:, None, None].astype(jnp.int32), axis=1)
@@ -438,13 +450,18 @@ class TransformerLM(Module):
             x = x[:, -1:]
         x = self.ln_f(x)
         if self.tie_embeddings:
-            logits = jnp.einsum("btc,vc->btv", x, self.tok_embed)
-        else:
-            logits = self.head(x.reshape(-1, x.shape[-1])).reshape(
-                b, x.shape[1], -1)
-        if all_logits and gather_last is None:
-            return logits, new_caches
-        return logits[:, 0], new_caches
+            return jnp.einsum("btc,vc->btv", x, self.tok_embed)
+        return self.head(x.reshape(-1, x.shape[-1])).reshape(
+            x.shape[0], x.shape[1], -1)
+
+    @scoped("head")
+    def _step_logits(self, x):
+        """Final norm and logits of one token a row: (B, 1, C) ->
+        (B, 1, V)."""
+        x = self.ln_f(x)
+        if self.tie_embeddings:
+            return jnp.einsum("btc,vc->btv", x, self.tok_embed)
+        return self.head(x.reshape(x.shape[0], -1))[:, None, :]
 
     def init_page_pool(self, max_pages: int, page_size: int,
                        dtype=jnp.float32, sharding=None, kv_dtype=None):
@@ -490,27 +507,18 @@ class TransformerLM(Module):
     def _prefill_impl_paged(self, ids, pools, tables, pos0,
                             all_logits: bool = False, gather_last=None):
         b, t = ids.shape
-        x = jnp.take(self.tok_embed, ids, axis=0)
-        if not self.use_rope:
-            x = x + jnp.take(self.pos_embed,
-                             pos0[:, None] + jnp.arange(t)[None],
-                             axis=0)
+        with jax.named_scope("embed"):
+            x = jnp.take(self.tok_embed, ids, axis=0)
+            if not self.use_rope:
+                x = x + jnp.take(self.pos_embed,
+                                 pos0[:, None] + jnp.arange(t)[None],
+                                 axis=0)
         new_pools = []
         for i in range(self.num_layers):
             blk = getattr(self, f"block{i}")
             x, c = blk.forward_chunk_paged(x, pools[i], tables, pos0)
             new_pools.append(c)
-        if gather_last is not None:
-            x = jnp.take_along_axis(
-                x, gather_last[:, None, None].astype(jnp.int32), axis=1)
-        elif not all_logits:
-            x = x[:, -1:]
-        x = self.ln_f(x)
-        if self.tie_embeddings:
-            logits = jnp.einsum("btc,vc->btv", x, self.tok_embed)
-        else:
-            logits = self.head(x.reshape(-1, x.shape[-1])).reshape(
-                b, x.shape[1], -1)
+        logits = self._chunk_logits(x, all_logits, gather_last)
         if all_logits and gather_last is None:
             return logits, new_pools
         return logits[:, 0], new_pools
@@ -524,21 +532,17 @@ class TransformerLM(Module):
         length, never on any request's span. ``decode_attention`` is
         ``"rows"`` on one device and ``"heads"`` under a mesh that
         shards heads (``MultiHeadAttention.forward_step_paged``)."""
-        x = jnp.take(self.tok_embed, ids_t, axis=0)[:, None, :]  # (B,1,C)
-        if not self.use_rope:
-            x = x + jnp.take(self.pos_embed, pos, axis=0)[:, None]
+        with jax.named_scope("embed"):
+            x = jnp.take(self.tok_embed, ids_t, axis=0)[:, None, :]  # (B,1,C)
+            if not self.use_rope:
+                x = x + jnp.take(self.pos_embed, pos, axis=0)[:, None]
         new_pools = []
         for i in range(self.num_layers):
             x, c = getattr(self, f"block{i}").forward_step_paged(
                 x, pools[i], tables, pos,
                 decode_attention=decode_attention)
             new_pools.append(c)
-        x = self.ln_f(x)
-        if self.tie_embeddings:
-            logits = jnp.einsum("btc,vc->btv", x, self.tok_embed)
-        else:
-            logits = self.head(x.reshape(x.shape[0], -1))[:, None, :]
-        return logits[:, 0], new_pools
+        return self._step_logits(x)[:, 0], new_pools
 
     def decode_step(self, ids_t, pos, caches):
         """One token in, next-token logits out. ids_t (B,) int, ``pos`` a
@@ -546,23 +550,19 @@ class TransformerLM(Module):
         (each row at its own depth); caches from ``init_cache`` (static
         shapes — the whole step jits once and is reused for every
         position)."""
-        x = jnp.take(self.tok_embed, ids_t, axis=0)[:, None, :]  # (B,1,C)
-        if not self.use_rope:
-            if jnp.ndim(pos) == 1:
-                x = x + jnp.take(self.pos_embed, pos, axis=0)[:, None]
-            else:
-                x = x + jax.lax.dynamic_slice_in_dim(self.pos_embed, pos,
-                                                     1, 0)[None]
+        with jax.named_scope("embed"):
+            x = jnp.take(self.tok_embed, ids_t, axis=0)[:, None, :]  # (B,1,C)
+            if not self.use_rope:
+                if jnp.ndim(pos) == 1:
+                    x = x + jnp.take(self.pos_embed, pos, axis=0)[:, None]
+                else:
+                    x = x + jax.lax.dynamic_slice_in_dim(self.pos_embed, pos,
+                                                         1, 0)[None]
         new_caches = []
         for i in range(self.num_layers):
             x, c = getattr(self, f"block{i}").forward_step(x, caches[i], pos)
             new_caches.append(c)
-        x = self.ln_f(x)
-        if self.tie_embeddings:
-            logits = jnp.einsum("btc,vc->btv", x, self.tok_embed)
-        else:
-            logits = self.head(x.reshape(x.shape[0], -1))[:, None, :]
-        return logits[:, 0], new_caches
+        return self._step_logits(x)[:, 0], new_caches
 
     def decode_scan(self, logits, pos0, caches, rng, temperature, n: int,
                     sampled: bool = False, eos_id=None, top_k=None,

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bigdl_tpu.nn.module import Module
+from bigdl_tpu.nn.module import Module, scoped
 from bigdl_tpu.nn.linear import Linear
 from bigdl_tpu.nn.dropout import Dropout
 
@@ -175,11 +175,13 @@ def _gather_pages(leaf, tables, heads=None, mode=None):
     select over everything gathered; a caller whose ids are known valid
     may say ``"clip"`` and pay for the gather alone."""
     b, tlen = tables.shape
-    g = jnp.take(leaf, tables, axis=0, mode=mode)  # (B, table_len, ps, H*D)
-    rows = g.reshape(b, tlen * g.shape[2], g.shape[3])
-    if heads is None:
-        return rows
-    return rows.reshape(b, rows.shape[1], heads, -1)
+    with jax.named_scope("attn/kv_gather"):
+        # (B, table_len, ps, H*D)
+        g = jnp.take(leaf, tables, axis=0, mode=mode)
+        rows = g.reshape(b, tlen * g.shape[2], g.shape[3])
+        if heads is None:
+            return rows
+        return rows.reshape(b, rows.shape[1], heads, -1)
 
 
 def _dequantize_kv_rows(codes, scale, dtype):
@@ -230,8 +232,6 @@ def _scatter_kv_paged(pool, k_t, v_t, tables, positions):
     if jnp.ndim(positions) == 1:
         positions = positions[:, None]          # decode step: T == 1
     ps = pool[0].shape[1]
-    pg = jnp.take_along_axis(tables, positions // ps, axis=1)  # (B, T)
-    off = positions % ps
 
     def write(buf, blk):
         # blk (B, H, T, D') -> one row of H * D' per token; the
@@ -240,13 +240,17 @@ def _scatter_kv_paged(pool, k_t, v_t, tables, positions):
         rows = blk.transpose(0, 2, 1, 3).reshape(b, t, h * d)
         return buf.at[pg, off].set(rows.astype(buf.dtype))
 
-    if len(pool) == 2:
-        k_buf, v_buf = pool
-        return write(k_buf, k_t), write(v_buf, v_t)
-    k_q, v_q, k_s, v_s = pool
-    kq, ks = quantize_kv(k_t)
-    vq, vs = quantize_kv(v_t)
-    return write(k_q, kq), write(v_q, vq), write(k_s, ks), write(v_s, vs)
+    with jax.named_scope("attn/kv_write"):
+        pg = jnp.take_along_axis(tables, positions // ps, axis=1)  # (B, T)
+        off = positions % ps
+        if len(pool) == 2:
+            k_buf, v_buf = pool
+            return write(k_buf, k_t), write(v_buf, v_t)
+        k_q, v_q, k_s, v_s = pool
+        kq, ks = quantize_kv(k_t)
+        vq, vs = quantize_kv(v_t)
+        return (write(k_q, kq), write(v_q, vq), write(k_s, ks),
+                write(v_s, vs))
 
 
 def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
@@ -273,11 +277,12 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
     # the sidecar's own per-head view is (B, T, H, 1); as rows it stays
     # (B, T, H) and is spread over each head's columns
     dequant = _dequantize_kv_rows if rows else dequantize_kv
-    return (pool,
-            dequant(_gather_pages(k_q, tables, view),
-                    _gather_pages(k_s, tables, view), k_t.dtype),
-            dequant(_gather_pages(v_q, tables, view),
-                    _gather_pages(v_s, tables, view), v_t.dtype))
+    with jax.named_scope("attn/kv_gather"):
+        return (pool,
+                dequant(_gather_pages(k_q, tables, view),
+                        _gather_pages(k_s, tables, view), k_t.dtype),
+                dequant(_gather_pages(v_q, tables, view),
+                        _gather_pages(v_s, tables, view), v_t.dtype))
 
 
 #: keys a round of the chunk's paged attention scores at once. On the
@@ -297,6 +302,7 @@ def _key_block_pages(page_size: int, table_len: int) -> int:
     return max(1, min(table_len, KEY_BLOCK_TOKENS // page_size))
 
 
+@scoped("attn/attend")
 def _attend_key_blocks(q, pool, tables, positions):
     """A chunk's causal softmax over the pages its rows hold: ``q``
     (B, H, T, D) whose token ``t`` of row ``b`` stands at
@@ -327,8 +333,10 @@ def _attend_key_blocks(q, pool, tables, positions):
     def block(leaves, tb):
         # (B, width, H_kv, D) in q's dtype: the pages as stored, or the
         # codes times their scales (dequantize_kv's float32 product)
-        got = [_gather_pages(leaf, tb, h_kv, mode="clip") for leaf in leaves]
-        return got[0] if len(got) == 1 else dequantize_kv(*got, q.dtype)
+        with jax.named_scope("attn/kv_gather"):
+            got = [_gather_pages(leaf, tb, h_kv, mode="clip")
+                   for leaf in leaves]
+            return got[0] if len(got) == 1 else dequantize_kv(*got, q.dtype)
 
     def some_keys(i, carry):
         top, den, acc = carry
@@ -359,6 +367,7 @@ def _attend_key_blocks(q, pool, tables, positions):
     return (acc / den[..., None]).reshape(b, h, t, d)
 
 
+@scoped("attn/attend")
 def _attend_pages_heads(q, k_read, v_read, pos):
     """One query token a row over gathered pages, PER HEAD: ``q``
     (B, H, D), ``k_read`` / ``v_read`` the token-major per-head view
@@ -383,6 +392,7 @@ def _attend_pages_heads(q, k_read, v_read, pos):
     return jnp.einsum("bgrt,btgd->bgrd", p, v_read).reshape(b, h, d)
 
 
+@scoped("attn/attend")
 def _attend_pages_rows(q, k_rows, v_rows, pos):
     """One query token a row over gathered pages left as ROWS: ``q``
     (B, H, D), ``k_rows`` / ``v_rows`` (B, T, H_kv * D) as the pool
@@ -481,6 +491,13 @@ class RMSNorm(Module):
         y = x * jax.lax.rsqrt(
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
         return (y * self.weight.astype(jnp.float32)).astype(input.dtype)
+
+
+def normed(norm, x):
+    """``norm(x)`` for a LayerNorm / RMSNorm a block calls on its own:
+    its operations carry the ``norm`` scope."""
+    with jax.named_scope("norm"):
+        return norm(x)
 
 
 class MultiHeadAttention(Module):
@@ -796,17 +813,19 @@ class MultiHeadAttention(Module):
                 f"decode_attention must be one of "
                 f"{sorted(_DECODE_ATTENTION)}, got {decode_attention!r}")
         b = x_t.shape[0]
-        qkv = self.qkv(x_t.reshape(b, self.embed_dim)).reshape(b, 1, -1)
-        q, k_t, v_t = self._split_kv_step(qkv)      # q (B,H,1,D)
-        if self.rotary:
-            q = rotary_embedding_rowwise(q, pos, self.rotary_base)
-            k_t = rotary_embedding_rowwise(k_t, pos, self.rotary_base)
+        with jax.named_scope("attn/qkv"):
+            qkv = self.qkv(x_t.reshape(b, self.embed_dim)).reshape(b, 1, -1)
+            q, k_t, v_t = self._split_kv_step(qkv)      # q (B,H,1,D)
+            if self.rotary:
+                q = rotary_embedding_rowwise(q, pos, self.rotary_base)
+                k_t = rotary_embedding_rowwise(k_t, pos, self.rotary_base)
         pool, k_read, v_read = _write_kv_paged(
             pool, k_t, v_t, tables, pos, rows=decode_attention == "rows")
         o = _DECODE_ATTENTION[decode_attention](q[:, :, 0], k_read,
                                                 v_read, pos)
-        o = o.reshape(b, self.embed_dim).astype(x_t.dtype)
-        o = self.out_proj(o).reshape(b, 1, -1)
+        with jax.named_scope("attn/out"):
+            o = o.reshape(b, self.embed_dim).astype(x_t.dtype)
+            o = self.out_proj(o).reshape(b, 1, -1)
         return o, pool
 
     def forward_chunk_paged(self, x, pool, tables, pos0):
@@ -823,16 +842,19 @@ class MultiHeadAttention(Module):
         and page-aligned reuse (``prefill_chunk % page_size == 0``)
         guarantees no chunk ever straddles into a SHARED page."""
         b, t, _ = x.shape
-        qkv = self.qkv(x.reshape(b * t, self.embed_dim)).reshape(b, t, -1)
-        q, k, v = self._split_kv_step(qkv)
-        positions = pos0[:, None] + jnp.arange(t)[None]  # (B, T)
-        if self.rotary:
-            q = rotary_embedding_rowwise(q, positions, self.rotary_base)
-            k = rotary_embedding_rowwise(k, positions, self.rotary_base)
+        with jax.named_scope("attn/qkv"):
+            qkv = self.qkv(x.reshape(b * t, self.embed_dim)).reshape(
+                b, t, -1)
+            q, k, v = self._split_kv_step(qkv)
+            positions = pos0[:, None] + jnp.arange(t)[None]  # (B, T)
+            if self.rotary:
+                q = rotary_embedding_rowwise(q, positions, self.rotary_base)
+                k = rotary_embedding_rowwise(k, positions, self.rotary_base)
         pool = _scatter_kv_paged(pool, k, v, tables, positions)
         o = _attend_key_blocks(q, pool, tables, positions)
-        o = o.transpose(0, 2, 1, 3).reshape(b * t, self.embed_dim)
-        o = self.out_proj(o.astype(x.dtype))
+        with jax.named_scope("attn/out"):
+            o = o.transpose(0, 2, 1, 3).reshape(b * t, self.embed_dim)
+            o = self.out_proj(o.astype(x.dtype))
         return o.reshape(b, t, -1), pool
 
     def chunk_read_counts(self, pos0, t: int, page_size: int,
@@ -856,35 +878,41 @@ class MultiHeadAttention(Module):
 
     def forward(self, input):
         b, t, _ = input.shape
-        qkv = self.qkv(input.reshape(b * t, self.embed_dim)).reshape(b, t, -1)
-        q, k, v = self._split_kv_step(qkv)
-        if self.rotary:
-            pos0 = 0
+        with jax.named_scope("attn/qkv"):
+            qkv = self.qkv(input.reshape(b * t, self.embed_dim)).reshape(
+                b, t, -1)
+            q, k, v = self._split_kv_step(qkv)
+            if self.rotary:
+                pos0 = 0
+                if self.sequence_parallel is not None:
+                    # absolute positions of this shard's sequence block
+                    pos0 = jax.lax.axis_index(self.sequence_parallel) * t
+                positions = pos0 + jnp.arange(t)
+                q, k = self._rope(q, positions), self._rope(k, positions)
+        with jax.named_scope("attn/attend"):
             if self.sequence_parallel is not None:
-                # absolute positions of this shard's sequence block
-                pos0 = jax.lax.axis_index(self.sequence_parallel) * t
-            positions = pos0 + jnp.arange(t)
-            q, k = self._rope(q, positions), self._rope(k, positions)
-        if self.sequence_parallel is not None:
-            from bigdl_tpu.parallel.ring_attention import ring_attention
+                from bigdl_tpu.parallel.ring_attention import ring_attention
 
-            # ring_attention handles GQA itself: the flash path rotates
-            # the UN-expanded kv heads (group-factor less ICI traffic),
-            # the dense path materializes them
-            o = ring_attention(q, k, v, axis_name=self.sequence_parallel,
-                               causal=self.causal,
-                               use_flash=self.use_flash)
-        elif self.use_flash:
-            from bigdl_tpu.ops.flash_attention import flash_attention
+                # ring_attention handles GQA itself: the flash path
+                # rotates the UN-expanded kv heads (group-factor less ICI
+                # traffic), the dense path materializes them
+                o = ring_attention(q, k, v,
+                                   axis_name=self.sequence_parallel,
+                                   causal=self.causal,
+                                   use_flash=self.use_flash)
+            elif self.use_flash:
+                from bigdl_tpu.ops.flash_attention import flash_attention
 
-            o = flash_attention(q, k, v, causal=self.causal)
-        else:
-            k, v = self._expand_kv(k, v)
-            o = dot_product_attention(q, k, v, causal=self.causal)
-        o = o.transpose(0, 2, 1, 3).reshape(b, t, self.embed_dim)
-        o = self.out_proj(o.reshape(b * t, self.embed_dim)).reshape(b, t, -1)
-        if self.dropout_p > 0:
-            o = self.drop(o)
+                o = flash_attention(q, k, v, causal=self.causal)
+            else:
+                k, v = self._expand_kv(k, v)
+                o = dot_product_attention(q, k, v, causal=self.causal)
+        with jax.named_scope("attn/out"):
+            o = o.transpose(0, 2, 1, 3).reshape(b, t, self.embed_dim)
+            o = self.out_proj(o.reshape(b * t, self.embed_dim)).reshape(
+                b, t, -1)
+            if self.dropout_p > 0:
+                o = self.drop(o)
         return o
 
 
@@ -954,57 +982,69 @@ class TransformerBlock(Module):
         """One decode step through the block with the attention KV cache
         ((k, v) from ``self.attn.init_cache``); returns (out, new_cache).
         Inference-time path: dropout off, MoE stats discarded."""
-        h, cache = self.attn.forward_step(self.ln1(x_t), cache, pos)
-        return self._mlp_residual(x_t + h), cache
+        h, cache = self.attn.forward_step(
+            normed(self.ln1, x_t), cache, pos)
+        return self._mlp_residual(x_t, h), cache
 
     def forward_prefill(self, x, cache, pos0: int = 0):
         """Batched prompt pass writing the attention cache (see
         MultiHeadAttention.forward_prefill)."""
-        h, cache = self.attn.forward_prefill(self.ln1(x), cache, pos0)
-        return self._mlp_residual(x + h), cache
+        h, cache = self.attn.forward_prefill(
+            normed(self.ln1, x), cache, pos0)
+        return self._mlp_residual(x, h), cache
 
     def forward_chunk(self, x, cache, pos0):
         """Traced-offset chunk pass (see
         MultiHeadAttention.forward_chunk)."""
-        h, cache = self.attn.forward_chunk(self.ln1(x), cache, pos0)
-        return self._mlp_residual(x + h), cache
+        h, cache = self.attn.forward_chunk(
+            normed(self.ln1, x), cache, pos0)
+        return self._mlp_residual(x, h), cache
 
     def forward_step_paged(self, x_t, pool, tables, pos,
                            decode_attention="rows"):
         """Paged decode step (see
         MultiHeadAttention.forward_step_paged)."""
         h, pool = self.attn.forward_step_paged(
-            self.ln1(x_t), pool, tables, pos,
+            normed(self.ln1, x_t), pool, tables, pos,
             decode_attention=decode_attention)
-        return self._mlp_residual(x_t + h), pool
+        return self._mlp_residual(x_t, h), pool
 
     def forward_chunk_paged(self, x, pool, tables, pos0):
         """Paged ragged chunk pass (see
         MultiHeadAttention.forward_chunk_paged)."""
-        h, pool = self.attn.forward_chunk_paged(self.ln1(x), pool,
-                                                tables, pos0)
-        return self._mlp_residual(x + h), pool
+        h, pool = self.attn.forward_chunk_paged(
+            normed(self.ln1, x), pool, tables, pos0)
+        return self._mlp_residual(x, h), pool
 
-    def _mlp_residual(self, x):
+    def _mlp_residual(self, x, attended):
+        """``x + attended``, then the MLP branch on top of it."""
+        with jax.named_scope("attn/out"):
+            x = x + attended
         b, t, c = x.shape
-        if self.n_experts > 0:
-            m, _, _ = self.mlp.forward_with_stats(self.ln2(x))
-        else:
-            m = self.fc2(jax.nn.gelu(
-                self.fc1(self.ln2(x).reshape(b * t, c)))).reshape(b, t, c)
-        return x + m
+        h = normed(self.ln2, x)
+        with jax.named_scope("mlp"):
+            if self.n_experts > 0:
+                m, _, _ = self.mlp.forward_with_stats(h)
+            else:
+                m = self.fc2(jax.nn.gelu(
+                    self.fc1(h.reshape(b * t, c)))).reshape(b, t, c)
+            return x + m
 
     def _forward_impl(self, input):
-        x = input + self.attn(self.ln1(input))
+        h = self.attn(normed(self.ln1, input))
+        with jax.named_scope("attn/out"):
+            x = input + h
         b, t, c = x.shape
         aux, stats = 0.0, None
-        if self.n_experts > 0:
-            # MoEMLP flattens/restores internally
-            h, aux, stats = self.mlp.forward_with_stats(self.ln2(x))
-        else:
-            h = self.fc1(self.ln2(x).reshape(b * t, c))
-            h = jax.nn.gelu(h)
-            h = self.fc2(h).reshape(b, t, c)
-        if self.dropout_p > 0:
-            h = self.drop(h)
-        return x + h, aux, stats
+        h = normed(self.ln2, x)
+        with jax.named_scope("mlp"):
+            if self.n_experts > 0:
+                # MoEMLP flattens/restores internally
+                h, aux, stats = self.mlp.forward_with_stats(h)
+            else:
+                h = self.fc1(h.reshape(b * t, c))
+                h = jax.nn.gelu(h)
+                h = self.fc2(h).reshape(b, t, c)
+            if self.dropout_p > 0:
+                h = self.drop(h)
+            return x + h, aux, stats

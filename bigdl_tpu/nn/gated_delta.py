@@ -34,7 +34,7 @@ import numpy as np
 from bigdl_tpu.nn import init as bt_init
 from bigdl_tpu.nn.attention import RMSNorm
 from bigdl_tpu.nn.linear import Linear
-from bigdl_tpu.nn.module import Module
+from bigdl_tpu.nn.module import Module, scoped
 
 #: tokens whose mutual dependence is resolved by matrix products
 SUB = 64
@@ -70,6 +70,7 @@ class GatedMLP(Module):
         self.up = Linear(embed_dim, hidden_dim, with_bias=False)
         self.down = Linear(hidden_dim, embed_dim, with_bias=False)
 
+    @scoped("mlp")
     def forward(self, input):
         return project(self.down, jax.nn.silu(project(self.gate, input))
                        * project(self.up, input))
@@ -221,6 +222,7 @@ class GatedDeltaNet(Module):
         beta = jax.nn.sigmoid(b)
         return g, 2.0 * beta if self.allow_neg_eigval else beta
 
+    @scoped("attn/out")
     def _output(self, o, x):
         """Per-head o (..., H, dv) float32 and the layer's input ->
         the layer's output."""
@@ -236,20 +238,21 @@ class GatedDeltaNet(Module):
         ``(S, tail)``. ``active`` (B,) bool: a row that is False keeps
         its state bit for bit (its output is junk the caller ignores)."""
         s, tail = state
-        w = self.conv_weight.astype(jnp.float32)
-        window = jnp.concatenate(
-            [tail, project(self.qkv, x_t)[:, None].astype(tail.dtype)],
-            axis=1)
-        y = jax.nn.silu(jnp.einsum(
-            "bjc,jc->bc", window.astype(jnp.float32), w))
-        q, k, v = self._heads(y)
-        g, beta = self._gates(x_t)
+        with jax.named_scope("attn/qkv"):
+            w = self.conv_weight.astype(jnp.float32)
+            window = jnp.concatenate(
+                [tail, project(self.qkv, x_t)[:, None].astype(tail.dtype)],
+                axis=1)
+            y = jax.nn.silu(jnp.einsum(
+                "bjc,jc->bc", window.astype(jnp.float32), w))
+            q, k, v = self._heads(y)
+            g, beta = self._gates(x_t)
         with jax.named_scope("gdn/step"):
             o, s_new = gated_delta_step(q, k, v, g, beta, s)
-        tail_new = window[:, 1:]
-        if active is not None:
-            s_new = jnp.where(active[:, None, None, None], s_new, s)
-            tail_new = jnp.where(active[:, None, None], tail_new, tail)
+            tail_new = window[:, 1:]
+            if active is not None:
+                s_new = jnp.where(active[:, None, None, None], s_new, s)
+                tail_new = jnp.where(active[:, None, None], tail_new, tail)
         return self._output(o, x_t), (s_new, tail_new)
 
     def forward_chunk(self, x, state, n_valid=None):
@@ -262,27 +265,29 @@ class GatedDeltaNet(Module):
         keep = self.conv_kernel - 1
         n_valid = (jnp.full((b,), t, jnp.int32) if n_valid is None
                    else n_valid.astype(jnp.int32))
-        full = jnp.concatenate(
-            [tail, project(self.qkv, x).astype(tail.dtype)], axis=1)
-        w = self.conv_weight.astype(jnp.float32)
-        y = sum(full[:, j:j + t].astype(jnp.float32) * w[j]
-                for j in range(self.conv_kernel))
-        q, k, v = self._heads(jax.nn.silu(y))
-        g, beta = self._gates(x)
-        real = (jnp.arange(t)[None, :] < n_valid[:, None])[..., None]
-        g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
-        pad = -t % SUB
-        if pad:
-            widen = lambda a: jnp.pad(
-                a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
-            q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
+        with jax.named_scope("attn/qkv"):
+            full = jnp.concatenate(
+                [tail, project(self.qkv, x).astype(tail.dtype)], axis=1)
+            w = self.conv_weight.astype(jnp.float32)
+            y = sum(full[:, j:j + t].astype(jnp.float32) * w[j]
+                    for j in range(self.conv_kernel))
+            q, k, v = self._heads(jax.nn.silu(y))
+            g, beta = self._gates(x)
+            real = (jnp.arange(t)[None, :] < n_valid[:, None])[..., None]
+            g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+            pad = -t % SUB
+            if pad:
+                widen = lambda a: jnp.pad(
+                    a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+                q, k, v, g, beta = (widen(a) for a in (q, k, v, g, beta))
         with jax.named_scope("gdn/chunk"):
             o, s_new = gated_delta_chunk(q, k, v, g, beta, s)
-        # the last inputs BEFORE the padding: rows n_valid .. n_valid+keep
-        # of [tail, inputs]
-        tail_new = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
-            f, n, keep, axis=0))(full, n_valid)
-        return self._output(o[:, :t], x), (s_new, tail_new)
+            # the last inputs BEFORE the padding: rows n_valid ..
+            # n_valid+keep of [tail, inputs]
+            tail_new = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
+                f, n, keep, axis=0))(full, n_valid)
+            o = o[:, :t]
+        return self._output(o, x), (s_new, tail_new)
 
     def forward(self, input):
         out, _ = self.forward_chunk(

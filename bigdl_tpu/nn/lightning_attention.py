@@ -36,7 +36,7 @@ import numpy as np
 from bigdl_tpu.nn.attention import RMSNorm, rotary_embedding_tokens
 from bigdl_tpu.nn.gated_delta import project
 from bigdl_tpu.nn.linear import Linear
-from bigdl_tpu.nn.module import Module
+from bigdl_tpu.nn.module import Module, scoped
 
 #: tokens resolved by matrix products between two sequential state passes
 SUB = 256
@@ -130,6 +130,7 @@ class LightningAttention(Module):
                            self.head_dim), jnp.float32),)
 
     # ------------------------------------------------------------- pieces
+    @scoped("attn/qkv")
     def _heads(self, x, positions):
         """(..., embed) at ``positions`` (...,) -> q, k, v (..., H, d)
         float32, q and k normed and rotated."""
@@ -143,6 +144,7 @@ class LightningAttention(Module):
             k = rotary_embedding_tokens(k, positions, self.rotary_base)
         return q, k, qkv[..., 2, :, :]
 
+    @scoped("attn/out")
     def _output(self, o, x):
         """Per-head o (..., H, d) float32, unscaled, and the layer's
         input -> the layer's output."""
@@ -160,8 +162,8 @@ class LightningAttention(Module):
         q, k, v = self._heads(x_t, pos)
         with jax.named_scope("lightning/step"):
             o, s_new = lightning_step(q, k, v, jnp.asarray(self.log_decay), s)
-        if active is not None:
-            s_new = jnp.where(active[:, None, None, None], s_new, s)
+            if active is not None:
+                s_new = jnp.where(active[:, None, None, None], s_new, s)
         return self._output(o, x_t), (s_new,)
 
     def forward_chunk(self, x, state, pos0, n_valid=None):
@@ -173,14 +175,16 @@ class LightningAttention(Module):
         n_valid = (jnp.full((b,), t, jnp.int32) if n_valid is None
                    else n_valid.astype(jnp.int32))
         q, k, v = self._heads(x, pos0[:, None] + jnp.arange(t)[None, :])
-        pad = -t % SUB
-        if pad:
-            widen = lambda a: jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            q, k, v = widen(q), widen(k), widen(v)
         with jax.named_scope("lightning/chunk"):
+            pad = -t % SUB
+            if pad:
+                widen = lambda a: jnp.pad(
+                    a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                q, k, v = widen(q), widen(k), widen(v)
             o, s_new = lightning_chunk(q, k, v, jnp.asarray(self.log_decay),
                                        s, n_valid)
-        return self._output(o[:, :t], x), (s_new,)
+            o = o[:, :t]
+        return self._output(o, x), (s_new,)
 
     def forward(self, input):
         b = input.shape[0]

@@ -31,6 +31,7 @@ State model: each Module owns
 
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from contextlib import contextmanager
@@ -59,6 +60,22 @@ _VJP_CACHE: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
 
 #: >0 while constructors run under :func:`abstract_init`
 _ABSTRACT_INIT_DEPTH = 0
+
+
+def scoped(name: str):
+    """Decorator: the function's operations are traced under
+    ``jax.named_scope(name)`` (a name of
+    ``observability.tracing.DEVICE_SCOPES``), through a context manager
+    of its own each call. jax's own object used as a decorator is ONE
+    manager for every call and thread, and keeps the context it will
+    restore on itself."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kw):
+            with jax.named_scope(name):
+                return fn(*args, **kw)
+        return inner
+    return wrap
 
 
 def abstract_init(build: Callable[[], "Module"]) -> "Module":
@@ -231,7 +248,11 @@ class Module:
             self._forward_key = bt_random.RNG.peek_key()
         t0 = time.perf_counter()
         try:
-            out = self.forward(input)
+            # the class names this layer's operations, forward and
+            # backward, in the compiled program and in a capture of it
+            # (observability.tracing.DEVICE_SCOPES)
+            with jax.named_scope(type(self).__name__):
+                out = self.forward(input)
             # record eagerly only — under a pure bind `out` is a tracer that
             # must not outlive the trace (it would poison clone/checkpoint)
             if _PURE_BIND_DEPTH == 0:

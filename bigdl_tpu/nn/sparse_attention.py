@@ -49,7 +49,7 @@ import numpy as np
 from bigdl_tpu.nn.attention import RMSNorm, _gather_pages
 from bigdl_tpu.nn.gated_delta import project
 from bigdl_tpu.nn.linear import Linear
-from bigdl_tpu.nn.module import Module
+from bigdl_tpu.nn.module import Module, scoped
 
 #: pages of keys the prefill chunk scores at once (a chunk's scores over a
 #: whole long lane do not fit: they are formed by key blocks)
@@ -198,6 +198,7 @@ class BlockSparseAttention(Module):
         return (keys > -jnp.inf) & (rank < limit[:, None, :, None])
 
     # ---------------------------------------------------------------- pieces
+    @scoped("attn/qkv")
     def _qkv(self, x):
         """(..., embed) -> q (..., H, D), k, v (..., G, D) float32, q and k
         normed per head."""
@@ -214,6 +215,7 @@ class BlockSparseAttention(Module):
         g = self.num_kv_heads
         return q.reshape(b, t, g, h // g, d).transpose(0, 2, 3, 1, 4)
 
+    @scoped("attn/out")
     def _output(self, o, x):
         """(..., H * D) attention output and the layer's input -> the
         layer's output."""
@@ -261,12 +263,13 @@ class BlockSparseAttention(Module):
         m, bp = self.span_pages, self.block_pages
         dtype = pool["ck"].dtype
         q, k, v = self._qkv(x_t)
-        p_now, off = pos // ps, pos % ps
-        pg = jnp.take_along_axis(tables, p_now[:, None], axis=1)[:, 0]
-        ks = [leaf.at[pg, off].set(k[:, i].astype(dtype))
-              for i, leaf in enumerate(pool["k"])]
-        vs = [leaf.at[pg, off].set(v[:, i].astype(dtype))
-              for i, leaf in enumerate(pool["v"])]
+        with jax.named_scope("attn/kv_write"):
+            p_now, off = pos // ps, pos % ps
+            pg = jnp.take_along_axis(tables, p_now[:, None], axis=1)[:, 0]
+            ks = [leaf.at[pg, off].set(k[:, i].astype(dtype))
+                  for i, leaf in enumerate(pool["k"])]
+            vs = [leaf.at[pg, off].set(v[:, i].astype(dtype))
+                  for i, leaf in enumerate(pool["v"])]
         with jax.named_scope("sparse/select"):
             # the span that ends with this token's page, from the pool
             span = jnp.take_along_axis(tables, jnp.maximum(
@@ -365,13 +368,14 @@ class BlockSparseAttention(Module):
             raise ValueError(f"a chunk of {t} tokens is not whole pages "
                              f"of {ps}")
         q, k, v = self._qkv(x)
-        positions = pos0[:, None] + jnp.arange(t)[None]
-        pg = jnp.take_along_axis(tables, positions // ps, axis=1)
-        off = positions % ps
-        ks = [leaf.at[pg, off].set(k[:, :, i].astype(dtype))
-              for i, leaf in enumerate(pool["k"])]
-        vs = [leaf.at[pg, off].set(v[:, :, i].astype(dtype))
-              for i, leaf in enumerate(pool["v"])]
+        with jax.named_scope("attn/kv_write"):
+            positions = pos0[:, None] + jnp.arange(t)[None]
+            pg = jnp.take_along_axis(tables, positions // ps, axis=1)
+            off = positions % ps
+            ks = [leaf.at[pg, off].set(k[:, :, i].astype(dtype))
+                  for i, leaf in enumerate(pool["k"])]
+            vs = [leaf.at[pg, off].set(v[:, :, i].astype(dtype))
+                  for i, leaf in enumerate(pool["v"])]
         q5 = self._grouped(q).astype(dtype)
         with jax.named_scope("sparse/select"):
             # the spans that end in this chunk's pages, from the pool

@@ -105,7 +105,9 @@ from bigdl_tpu.observability.metrics import (
     DEFAULT_BUCKETS, Metric, MetricRegistry, REGISTRY,
     default_registry, set_default_registry,
 )
-from bigdl_tpu.observability.tracing import Span, Tracer, self_ns, trace
+from bigdl_tpu.observability.tracing import (
+    DEVICE_SCOPES, Span, Tracer, self_ns, trace,
+)
 from bigdl_tpu.observability.events import (
     Event, FlightRecorder, RECORDER, default_recorder, next_request_id,
     percentile_summary, record, set_default_recorder,
@@ -173,7 +175,7 @@ from bigdl_tpu.observability.instruments import incident_instruments
 __all__ = [
     "DEFAULT_BUCKETS", "Metric", "MetricRegistry", "REGISTRY",
     "default_registry", "set_default_registry",
-    "Span", "Tracer", "self_ns", "trace",
+    "DEVICE_SCOPES", "Span", "Tracer", "self_ns", "trace",
     "Event", "FlightRecorder", "RECORDER", "default_recorder",
     "set_default_recorder", "record", "next_request_id",
     "percentile_summary",

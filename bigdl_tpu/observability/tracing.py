@@ -13,8 +13,11 @@ thread is a root of that thread's trace.
 
 Every span also enters a ``jax.profiler.TraceAnnotation`` when the JAX
 profiler is importable, so the same names show on the host timeline of a
-capture that records host events. (Device-side HLO naming is separate:
-traced code uses ``jax.named_scope``, see parallel/all_reduce.py.)
+capture that records host events. Device-side naming is separate:
+traced code opens ``jax.named_scope``s from ONE vocabulary,
+``DEVICE_SCOPES`` below (docs/programming-guide/observability.md has the
+table), which name every operation of the compiled programs in a
+profiler capture.
 
 Completed ROOT spans accumulate in a bounded ring (oldest dropped);
 ``trace.roots()`` / ``trace.render()`` read trees back and
@@ -47,6 +50,32 @@ from typing import Dict, Iterable, List, Optional
 #: at a 170 ms decode step, 33 at the 30 ms the roadmap aims for): 4096
 #: hold 120 s of either at its fastest.
 MAX_ROOTS = 4096
+
+#: The ``jax.named_scope`` names the programs open around their parts: the
+#: whole vocabulary of device-side scopes (an operation's ``op_name`` in
+#: the HLO and in a profiler capture is the path of the scopes it was
+#: traced under). A reader charges an operation to the INNERMOST of these
+#: on its path; where the path holds none, to the innermost module class,
+#: which ``Module.__call__`` opens (``type(self).__name__``).
+DEVICE_SCOPES = (
+    "embed",            # token and position embedding
+    "attn/qkv",         # a mixer's input projections, head split, rotary,
+                        # qk-norm (a recurrent mixer's convolution and gates)
+    "attn/kv_write",    # the scatter into the page pool, quantisation with it
+    "attn/kv_gather",   # the take out of the page pool, dequantisation with it
+    "attn/attend",      # scores, mask, softmax, P.V over gathered pages
+    "attn/out",         # a mixer's output gate, norm and projection
+    "mlp",              # the feed-forward branch, dense, gated or MoE
+    "norm",             # LayerNorm / RMSNorm called on their own
+    "head",             # final norm and logits
+    "sample",           # arg-max, or filter + categorical
+    "gdn/step", "gdn/chunk",                # the gated delta rule
+    "lightning/step", "lightning/chunk",    # fixed-decay linear attention
+    "sparse/select", "sparse/attend",       # block-sparse attention
+    "optim/loss",       # the criterion
+    "optim/update",     # decay, clipping, the method's update, masters' cast
+    "bigdl/grad_reduce_scatter", "bigdl/weight_all_gather",
+)
 
 _IDS = itertools.count(1)   # next() is atomic under the GIL
 

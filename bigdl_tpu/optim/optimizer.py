@@ -36,7 +36,7 @@ from bigdl_tpu.dataset.dataset import AbstractDataSet, LocalDataSet
 from bigdl_tpu.dataset.minibatch import MiniBatch
 from bigdl_tpu.dataset.sample import Sample
 from bigdl_tpu.dataset.transformer import SampleToMiniBatch
-from bigdl_tpu.nn.module import Module, pure_apply
+from bigdl_tpu.nn.module import Module, pure_apply, scoped
 from bigdl_tpu.optim.metrics import Metrics
 from bigdl_tpu.optim.optim_method import OptimMethod, SGD, TrainState
 from bigdl_tpu.optim.trigger import Trigger
@@ -124,6 +124,10 @@ class TrainStep:
                           for k in range(n_groups)]
         self._idxs_per_group = idxs_per_group
 
+        # the scopes name the step's parts in the compiled program
+        # (observability.tracing.DEVICE_SCOPES); the model's layers name
+        # themselves (Module.__call__)
+        @scoped("optim/update")
         def _compute_params(params):
             if compute_dtype is None:
                 return params
@@ -134,8 +138,10 @@ class TrainStep:
         def data_loss_fn(params, buffers, x, y, rng):
             cparams = _compute_params(params)
             out, new_buffers = apply_fn(cparams, buffers, x, rng=rng, training=True)
-            return criterion.forward(out, y), new_buffers
+            with jax.named_scope("optim/loss"):
+                return criterion.forward(out, y), new_buffers
 
+        @scoped("optim/loss")
         def reg_loss_fn(params):
             return model.regularization_loss(_compute_params(params))
 
@@ -187,9 +193,8 @@ class TrainStep:
             grads = jax.tree.map(jnp.add, g_sum, reg_grads)
             return l_sum + reg_val, new_buffers, grads
 
-        def _core(params, buffers, slots, x, y, lrs, rng):
-            loss, new_buffers, grads = grad_of_batch(params, buffers, x, y,
-                                                     rng)
+        @scoped("optim/update")
+        def update(params, grads, slots, lrs):
             # global pre-clip grad norm for telemetry; callers jitting the
             # plain ``step`` never pay for it — an unused output is dead
             # code to XLA
@@ -223,7 +228,13 @@ class TrainStep:
             new_params = jax.tree.unflatten(treedef, new_leaves)
             if any_frozen:
                 new_params = _mask_frozen(new_params, params, trainable)
-            return loss, gnorm, new_params, new_buffers, tuple(new_slots)
+            return gnorm, new_params, tuple(new_slots)
+
+        def _core(params, buffers, slots, x, y, lrs, rng):
+            loss, new_buffers, grads = grad_of_batch(params, buffers, x, y,
+                                                     rng)
+            gnorm, new_params, new_slots = update(params, grads, slots, lrs)
+            return loss, gnorm, new_params, new_buffers, new_slots
 
         def step(params, buffers, slots, x, y, lrs, rng):
             loss, _, new_params, new_buffers, new_slots = _core(

@@ -1039,7 +1039,7 @@ class ContinuousBatchingEngine:
         from bigdl_tpu.models.transformer import (
             _filter_logits, _spec_accept,
         )
-        from bigdl_tpu.nn.module import bind
+        from bigdl_tpu.nn.module import bind, scoped
 
         model = self.model
         sampled = self.temperature > 0.0
@@ -1056,6 +1056,15 @@ class ContinuousBatchingEngine:
 
         lane_state = self._lane_state
 
+        @scoped("sample")
+        def sample0(logits, rng, temperature):
+            if sampled:
+                return jax.random.categorical(
+                    rng, _filter_logits(logits, temperature, top_k,
+                                        top_p),
+                    axis=-1).astype(jnp.int32)
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
         def step(p, bufs, tok, pos, pool, tables, rng, temperature,
                  *active):
             # one fused decode over ALL slots; idle lanes carry the
@@ -1068,14 +1077,7 @@ class ContinuousBatchingEngine:
                 logits, pool = model.decode_step_paged(
                     tok, pos, pool, tables, decode_attention=attend,
                     **kw)
-            if sampled:
-                nxt = jax.random.categorical(
-                    rng, _filter_logits(logits, temperature, top_k,
-                                        top_p),
-                    axis=-1).astype(jnp.int32)
-            else:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return nxt, pool
+            return sample0(logits, rng, temperature), pool
 
         def chunk(p, bufs, ids, pool, tables, pos0, last_idx, *lanes):
             # the ragged admission prefill, writing through each row's
@@ -1127,14 +1129,6 @@ class ContinuousBatchingEngine:
                 lambda s, l: row_copy(
                     s, one_row(l, lane).reshape(1, -1), sid, 0),
                 store, lane_leaves(pool))
-
-        def sample0(logits, rng, temperature):
-            if sampled:
-                return jax.random.categorical(
-                    rng, _filter_logits(logits, temperature, top_k,
-                                        top_p),
-                    axis=-1).astype(jnp.int32)
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
         # On a mesh, output shardings are PINNED: every program's pool
         # leaves with the same NamedSharding it entered with (and
@@ -1227,36 +1221,37 @@ class ContinuousBatchingEngine:
                 with bind(model, p, bufs, False, None):
                     logits, pool = model.verify_chunk_paged(
                         chunk_ids, pool, tables, pos)
-                if sampled:
-                    accept, resid, bonus = _spec_accept(
-                        logits, jnp.swapaxes(qlogits, 0, 1),
-                        chunk_ids[:, 1:], temperature, rng)
-                    n_acc = jnp.sum(jnp.cumprod(
-                        accept.astype(jnp.int32), axis=1), axis=1)
-                    # emit column j: the proposal while accepted; at
-                    # the first rejection the residual draw, on full
-                    # acceptance the bonus draw (columns past n_acc
-                    # are never read by the host)
-                    fix = jnp.take_along_axis(
-                        jnp.concatenate([resid, bonus[:, None]],
-                                        axis=1),
-                        n_acc[:, None], axis=1)
-                    cols = jnp.arange(g + 1)[None, :]
-                    padded = jnp.concatenate(
-                        [chunk_ids[:, 1:],
-                         jnp.zeros_like(tok)[:, None]], axis=1)
-                    emit = jnp.where(cols < n_acc[:, None], padded, fix)
-                else:
-                    v_tok = jnp.argmax(logits, axis=-1).astype(
-                        jnp.int32)
-                    match = (chunk_ids[:, 1:] == v_tok[:, :g]).astype(
-                        jnp.int32)
-                    n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
-                    # matched proposals ARE the target argmax, so the
-                    # emitted burst is v_tok[:, :n_acc+1] verbatim —
-                    # exactly the tokens the non-speculative engine
-                    # would have argmaxed one step at a time
-                    emit = v_tok
+                with jax.named_scope("sample"):
+                    if sampled:
+                        accept, resid, bonus = _spec_accept(
+                            logits, jnp.swapaxes(qlogits, 0, 1),
+                            chunk_ids[:, 1:], temperature, rng)
+                        n_acc = jnp.sum(jnp.cumprod(
+                            accept.astype(jnp.int32), axis=1), axis=1)
+                        # emit column j: the proposal while accepted; at
+                        # the first rejection the residual draw, on full
+                        # acceptance the bonus draw (columns past n_acc
+                        # are never read by the host)
+                        fix = jnp.take_along_axis(
+                            jnp.concatenate([resid, bonus[:, None]],
+                                            axis=1),
+                            n_acc[:, None], axis=1)
+                        cols = jnp.arange(g + 1)[None, :]
+                        padded = jnp.concatenate(
+                            [chunk_ids[:, 1:],
+                             jnp.zeros_like(tok)[:, None]], axis=1)
+                        emit = jnp.where(cols < n_acc[:, None], padded, fix)
+                    else:
+                        v_tok = jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32)
+                        match = (chunk_ids[:, 1:] == v_tok[:, :g]).astype(
+                            jnp.int32)
+                        n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
+                        # matched proposals ARE the target argmax, so the
+                        # emitted burst is v_tok[:, :n_acc+1] verbatim —
+                        # exactly the tokens the non-speculative engine
+                        # would have argmaxed one step at a time
+                        emit = v_tok
                 return emit, n_acc, pool
 
             self._d_chunk_jit = _jit(d_chunk, (3,), (repl, kv))

@@ -9,6 +9,15 @@ directory is part of the cache key, so it must not move between runs:
 * unset: ``<checkout>/.jax_cache``, fixed — never the cwd, a temp name,
   a pid or a time.
 
+The key holds the HLO's metadata too (``op_name``: the path of
+``jax.named_scope``s an operation was traced under, and its source
+line). jax leaves it out by default, and an executable compiled before a
+scope was renamed would then be handed back with the OLD names in it:
+a profiler capture, and every reader of device time by scope
+(``benchmark/program_scopes.py``), would see names the source no longer
+has, or none. The price: an edit that only moves lines of traced code is
+a new key too, and compiles once more.
+
 Every entry point that compiles at real sizes (``bench.py``,
 ``chip_smoke.py``, the serving example, the perf CLI, ``tpu_sweep``,
 ``flash_matrix``) calls :func:`enable_persistent_cache` before its first
@@ -30,6 +39,7 @@ def enable_persistent_cache() -> str:
     import jax
 
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get(ENV_CACHE_DIR)
     if placed:
         return placed

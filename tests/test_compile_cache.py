@@ -17,7 +17,8 @@ def cache_config():
     """Restore jax's cache settings after a policy test."""
     before = {k: getattr(jax.config, k) for k in (
         "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs")}
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_compilation_cache_include_metadata_in_key")}
     yield
     for k, v in before.items():
         jax.config.update(k, v)
