@@ -110,25 +110,34 @@ def live_in(clients, a, b, samples=50):
 
 
 def window_numbers(clients, t0, seconds, cutoff):
-    """End-to-end numbers over the requests due in the window."""
+    """End-to-end numbers over the requests due in the window. Throughput
+    counts THEIR tokens delivered inside it: a stamp lies at or after its
+    request's due time, so a faster engine can only bring one into the
+    window. The count over every client, lead-in tails included, falls as
+    an engine finishes those tails before the window opens (PERF.md section
+    7 (f)); it stays in the log beside the new one."""
     measured = [c for c in clients if c.req["due_s"] >= 0]
     t1 = t0 + seconds
     ttft = [((c.stamps[0] if c.stamps else cutoff) - c.due) * 1e3
             for c in measured]
     gaps = [(b - a) * 1e3 for c in measured
             for a, b in zip(c.stamps, c.stamps[1:])]
-    delivered = sum(1 for c in clients for s in c.stamps if t0 <= s <= t1)
+    def inside(cs):
+        return sum(1 for c in cs for s in c.stamps if t0 <= s <= t1)
+
+    delivered, due_delivered = inside(clients), inside(measured)
     failed = [c for c in measured if not c.finished]
     return measured, failed, {
         "ttft_ms": ttft,
         "itl_p95_ms": harness.percentile(gaps, 95),
-        "serve_tok_per_s": delivered / seconds,
+        "serve_due_tok_per_s": due_delivered / seconds,
     }, {"ttft_p50_p90_p95_p99_ms": [harness.percentile(ttft, q)
                                     for q in (50, 90, 95, 99)],
         "itl_p50_p90_p99_ms": [harness.percentile(gaps, q)
                                for q in (50, 90, 99)],
         "gaps": len(gaps),
-        "tokens_in_window": delivered}
+        "tokens_in_window": delivered,
+        "due_tokens_in_window": due_delivered}
 
 
 def check_sample(measured, seed, k):

@@ -84,6 +84,13 @@ def main(argv=None):
     else:
         line["metrics"] = harness.metric_entries(cell["end_to_end"],
                                                  result["values"])
+    # each number compared beside its limit: the run's last lines on
+    # standard error and the result line's last key
+    line["checks"] = {r["name"]: {"value": r["value"], "limit": r["limit"]}
+                      for r in result["checks"]}
+    for r in result["checks"]:
+        harness.log(f"[check] {r['name']} {r['value']} limit {r['limit']} "
+                    f"{'ok' if r['ok'] else 'NOT OK'}")
     harness.say(line)
     return 0
 

@@ -4,6 +4,7 @@ arithmetic is right on hand-made inputs, each driver runs end to end at a
 tiny size (and writes no device metric), and ``correct`` comes out false for
 a lower precision and for a broken timed path."""
 
+import math
 import os
 import re
 import subprocess
@@ -119,28 +120,35 @@ def test_percentile():
     assert harness.median([5, 1, 3]) == 3
 
 
+class _Done:
+    def done(self):
+        return True
+
+
+def _finished(serve, req, stamps, t0):
+    """``req`` as its client saw it served, made by hand: due ``due_s`` into
+    the window that opens at ``t0`` (a lead-in request where that is
+    negative), a token at each of ``stamps``."""
+    c = serve.Client(req, t0 + req["due_s"])
+    c.handle, c.stamps, c.tokens = _Done(), list(stamps), [0] * len(stamps)
+    c.submitted = c.due + 0.002
+    return c
+
+
+def _client(serve, due_s, stamps, new=None, t0=100.0):
+    return _finished(serve, {"due_s": due_s, "prompt": np.zeros(4, np.int32),
+                             "new_tokens": new if new is not None
+                             else len(stamps)}, stamps, t0)
+
+
 def test_window_arithmetic_counts_from_the_due_time():
     serve = harness.load_module("drivers", "serve")
-
-    class Done:
-        def done(self):
-            return True
-
-    def client(due_s, stamps, new=None, lead=False):
-        c = serve.Client({"due_s": -1.0 if lead else due_s,
-                          "prompt": np.zeros(4, np.int32),
-                          "new_tokens": new if new is not None
-                          else len(stamps)}, 100.0 + due_s)
-        c.handle, c.stamps, c.tokens = Done(), stamps, [0] * len(stamps)
-        c.submitted = c.due + 0.002
-        return c
-
     t0, seconds = 100.0, 10.0
     clients = [
-        client(1.0, [101.5, 101.6, 101.8]),              # ttft 500 ms
-        client(2.0, [102.1, 109.9, 110.4]),              # last token outside
-        client(9.0, [], new=5),                          # never served
-        client(-1.0, [100.5, 100.6], lead=True),         # lead-in: not measured
+        _client(serve, 1.0, [101.5, 101.6, 101.8]),      # ttft 500 ms
+        _client(serve, 2.0, [102.1, 109.9, 110.4]),      # last token outside
+        _client(serve, 9.0, [], new=5),                  # never served
+        _client(serve, -1.0, [100.5, 100.6]),           # lead-in: not measured
     ]
     measured, failed, e2e, extra = serve.window_numbers(
         clients, t0, seconds, cutoff=115.0)
@@ -153,10 +161,107 @@ def test_window_arithmetic_counts_from_the_due_time():
     assert extra["gaps"] == 4
     assert e2e["itl_p95_ms"] == pytest.approx(harness.percentile(
         [100.0, 200.0, 7800.0, 500.0], 95), rel=1e-6)
-    # tokens inside [t0, t0 + seconds], lead-in requests' included
-    assert e2e["serve_tok_per_s"] == pytest.approx((3 + 2 + 2) / 10.0)
+    # tokens inside [t0, t0 + seconds] of the requests DUE in the window: the
+    # lead-in request's two are in the log's other count only
+    assert set(e2e) == {"ttft_ms", "itl_p95_ms", "serve_due_tok_per_s"}
+    assert e2e["serve_due_tok_per_s"] == pytest.approx((3 + 2) / 10.0)
+    assert extra["due_tokens_in_window"] == 5
+    assert extra["tokens_in_window"] == 7
     late = [(c.submitted - c.due) * 1e3 for c in measured]
     assert max(late) == pytest.approx(2.0)
+
+
+def _streams(serve, speedup, t0=100.0):
+    """Four requests against a window [t0, t0 + 10]: two lead-in requests
+    whose tails reach into it, two due inside it of which one runs past its
+    end. ``speedup`` shortens every request's own time line (first token
+    and every gap) by that factor: no stamp comes before its due time."""
+    plan = [(-6.0, 1.0, 0.5, 30),      # (due_s, to first token, gap, tokens)
+            (-0.5, 0.8, 0.4, 12),
+            (1.0, 1.0, 0.5, 10),
+            (6.0, 1.0, 0.5, 14)]
+    return [_client(serve, due, [t0 + due + (first + k * gap) / speedup
+                                 for k in range(n)])
+            for due, first, gap, n in plan]
+
+
+SPEEDUPS = [1.0, 1.25, 1.6, 2.0, 3.0, 5.0, 50.0]
+
+
+@pytest.mark.parametrize("slower, faster", zip(SPEEDUPS, SPEEDUPS[1:]))
+def test_a_faster_engine_never_lowers_the_due_count(slower, faster):
+    """The property the count is there for, and the artefact it replaced,
+    pinned: with every stamp moved earlier (never before its request's due
+    time) the tokens of the requests due in the window can only enter it,
+    while the count over all clients loses the lead-in requests' tails."""
+    serve = harness.load_module("drivers", "serve")
+
+    def counts(speedup):
+        clients = _streams(serve, speedup)
+        assert all(c.stamps[0] >= c.due for c in clients)
+        _, failed, e2e, extra = serve.window_numbers(
+            clients, 100.0, 10.0, cutoff=200.0)
+        assert not failed
+        assert e2e["serve_due_tok_per_s"] * 10.0 == pytest.approx(
+            extra["due_tokens_in_window"])
+        return extra["due_tokens_in_window"], extra["tokens_in_window"]
+
+    assert counts(1.0) == (10 + 7, 10 + 7 + 20 + 12)
+    assert counts(faster)[0] >= counts(slower)[0] >= counts(1.0)[0]
+    if faster >= 2.0:
+        # the artefact: the faster engine ended the lead-in tails before the
+        # window opened and reads FEWER tokens by the old count
+        assert counts(faster)[1] < counts(1.0)[1]
+    if faster == 50.0:
+        # all of the window's requests, whole: the offered load
+        assert counts(faster) == (10 + 14, 10 + 14)
+
+
+def _replayed(serve, step_ms, seconds=51.0, t0=1000.0):
+    """PERF.md section 7 (f)'s replay of ``gpt2l-chat-steady`` over the mix's
+    committed schedule, as hand-made clients: a turn is the decode step plus
+    6 ms of host, 1.5 % over; a request's first token comes one turn a
+    128-token chunk of its prompt (and half a turn) after its due time, the
+    next every turn."""
+    mix = harness.load_json(harness.HERE, "traffic", "chat-steady.json")
+    reqs = loadgen.open_loop_requests(mix, 1, seconds, 50257)
+    turn = 1.015 * (step_ms + 6.0) / 1e3
+
+    def first(r):
+        return r["due_s"] + (math.ceil(len(r["prompt"]) / 128) + 0.5) * turn
+
+    clients = [_finished(serve, r, [t0 + first(r) + k * turn
+                                    for k in range(r["new_tokens"])], t0)
+               for r in sorted(reqs, key=lambda r: r["due_s"])]
+    offered = sum(r["new_tokens"] for r in reqs if r["due_s"] >= 0) / seconds
+    return clients, offered
+
+
+def test_replay_of_chat_steady_reads_the_offered_load_at_every_speed():
+    serve = harness.load_module("drivers", "serve")
+    due, every = {}, {}
+    for step_ms in (60.0, 36.6, 25.0, 14.9):
+        clients, offered = _replayed(serve, step_ms)
+        measured, failed, e2e, extra = serve.window_numbers(
+            clients, 1000.0, 51.0, cutoff=2000.0)
+        assert len(measured) == 53 and not failed
+        due[step_ms] = e2e["serve_due_tok_per_s"]
+        every[step_ms] = extra["tokens_in_window"] / 51.0
+        assert due[step_ms] == extra["due_tokens_in_window"] / 51.0
+    assert offered == pytest.approx(100.745, abs=1e-3)
+    # PERF.md's columns, to the digit
+    assert [round(every[s], 2) for s in (60.0, 36.6, 25.0, 14.9)] == [
+        115.04, 113.12, 110.43, 107.25]
+    assert [round(due[s], 2) for s in (60.0, 36.6, 25.0, 14.9)] == [
+        96.2, 99.53, 100.1, 100.59]
+    # the due count never falls as the engine gets faster, and reads the
+    # offered load less the tail the window's end cuts off: within 1.5 % of
+    # it from today's step on, 4.5 % under at a step of 60 ms
+    assert due[60.0] <= due[36.6] <= due[25.0] <= due[14.9] <= offered
+    assert all(due[s] >= 0.985 * offered for s in (36.6, 25.0, 14.9))
+    assert due[60.0] >= 0.95 * offered
+    # the old count: the `clip` step reads over 1 % (5.2 %) UNDER today's
+    assert every[14.9] < 0.99 * every[36.6]
 
 
 # ------------------------------------------------------------------- trace
@@ -330,7 +435,8 @@ def test_serving_rehearsal_is_correct_and_writes_no_device_metric(served):
     cell, out = served
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] == round(6.0 * 2.0) or out["attempted"] > 8
-    assert set(out["values"]) == {"itl_p95_ms", "serve_tok_per_s", "setup_s"}
+    assert set(out["values"]) == {"itl_p95_ms", "serve_due_tok_per_s",
+                                  "setup_s"}
     assert all(v > 0 for v in out["values"].values())
     assert out["device"]["platform"] == "cpu"
     line = harness.metric_entries(cell["end_to_end"], out["values"])

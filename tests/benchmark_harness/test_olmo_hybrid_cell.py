@@ -70,7 +70,8 @@ def test_the_cells_files_are_served_and_correct_through_the_driver(served):
     cell, out = served
     assert out["correct"] is True, out["checks"]
     assert out["failed"] == 0 and out["attempted"] > 8
-    assert set(out["values"]) == {"itl_p95_ms", "serve_tok_per_s", "setup_s"}
+    assert set(out["values"]) == {"itl_p95_ms", "serve_due_tok_per_s",
+                                  "setup_s"}
     assert out["compared"]["rows"].shape == (10, 96)
     # three requests in four open with a shared document and resume from
     # its snapshot at the stride (32 of its 40 tokens)
@@ -277,7 +278,7 @@ def fake_span(name, start, end, **attrs):
     "state_restore_ms", "state_snapshot_ms", "prefix_resume_shortfall_pct"])
 def test_new_metric_is_in_the_manifest_and_reads_nothing_from_nothing(name):
     entry = {m["name"]: m for m in harness.manifest()["per_layer"]}[name]
-    assert entry["workloads"] == [CELL]
+    assert CELL in entry["workloads"]
     assert entry["layer"] == ("kernels" if "roofline" in name
                               else "cache manager")
     reader = harness.load_module("metrics", name)

@@ -227,7 +227,8 @@ def test_second_architecture_is_served_and_correct_by_new_files_alone(served):
     sizes = out["record"]["sizes"]
     assert sizes is cell["config_json"]["sizes"]
     assert not [k for k in sizes if k.startswith("n_")]
-    assert set(out["values"]) == {"itl_p95_ms", "serve_tok_per_s", "setup_s"}
+    assert set(out["values"]) == {"itl_p95_ms", "serve_due_tok_per_s",
+                                  "setup_s"}
     # the sampled rows are as wide as the geometry says, not as GPT-2 is
     assert out["compared"]["rows"].shape[1] == 96
     assert out["record"]["prefix_tokens"] > 0
