@@ -148,7 +148,7 @@ def _kv_buffers(shape, scale_shape, dtype, sharding, kv_dtype):
             mk(scale_shape, jnp.float32), mk(scale_shape, jnp.float32))
 
 
-def _gather_pages(leaf, tables, heads=None, mode=None):
+def _gather_pages(leaf, tables, heads=None):
     """Assemble one logical KV row per batch entry from a page pool:
     ``leaf`` is a pool buffer (max_pages, page_size, H * D) — one
     token's heads side by side in the minor dimension (the scale
@@ -170,14 +170,24 @@ def _gather_pages(leaf, tables, heads=None, mode=None):
     XLA lowers the take to one gather, so compiled shape depends only
     on the POOL geometry, never on any request's length. Table slots
     past a request's reservation point at the scratch page — garbage
-    the caller's causal mask must (and does) discard. ``mode`` is
-    ``jnp.take``'s: the default fills what an out-of-range id names, a
-    select over everything gathered; a caller whose ids are known valid
-    may say ``"clip"`` and pay for the gather alone."""
+    the caller's causal mask must (and does) discard.
+
+    CALLER CONTRACT: every id in ``tables`` lies in ``[0, max_pages)``,
+    and a slot that holds nothing names the scratch page (id 0), which
+    is a page like any other. The take CLIPS: it pays for the gather
+    alone, where ``jnp.take``'s default fills what an out-of-range id
+    would name and so runs a select over every gathered byte (seven
+    tenths of a decode step at GPT-2 Large's widths; PERF.md, PR 41).
+    The ids are in range by construction — ``PagePool`` hands out
+    ``1 .. max_pages - 1``, ``BlockTable.as_array`` and the engine's
+    slot tables pad with ``SCRATCH_PAGE`` — and
+    ``tests/test_paged_kv.py`` holds every dispatched table to it. An
+    id out of range would read the nearest end's page instead of NaN:
+    a fault of the allocator either way, never a served result."""
     b, tlen = tables.shape
     with jax.named_scope("attn/kv_gather"):
         # (B, table_len, ps, H*D)
-        g = jnp.take(leaf, tables, axis=0, mode=mode)
+        g = jnp.take(leaf, tables, axis=0, mode="clip")
         rows = g.reshape(b, tlen * g.shape[2], g.shape[3])
         if heads is None:
             return rows
@@ -334,8 +344,7 @@ def _attend_key_blocks(q, pool, tables, positions):
         # (B, width, H_kv, D) in q's dtype: the pages as stored, or the
         # codes times their scales (dequantize_kv's float32 product)
         with jax.named_scope("attn/kv_gather"):
-            got = [_gather_pages(leaf, tb, h_kv, mode="clip")
-                   for leaf in leaves]
+            got = [_gather_pages(leaf, tb, h_kv) for leaf in leaves]
             return got[0] if len(got) == 1 else dequantize_kv(*got, q.dtype)
 
     def some_keys(i, carry):

@@ -248,9 +248,11 @@ class BlockSparseAttention(Module):
                                 (0, -tables.shape[1] % self.block_pages)))
 
     def _compressed(self, ck, tables):
-        """The lane's compressed keys through its table: (B, G, P, D)."""
+        """The lane's compressed keys through its table: (B, G, P, D).
+        Clips as :func:`_gather_pages` does, under its contract on the
+        ids (``ck`` holds one row a page: not that function's shape)."""
         b, pages = tables.shape
-        return jnp.take(ck, tables, axis=0).reshape(
+        return jnp.take(ck, tables, axis=0, mode="clip").reshape(
             b, pages, self.num_kv_heads, self.head_dim).transpose(0, 2, 1, 3)
 
     # ------------------------------------------------------------ the forms
@@ -385,7 +387,8 @@ class BlockSparseAttention(Module):
                 first[:, None] - (m - 1) + jnp.arange(n + m - 1)[None], 0),
                 axis=1)
             sums = jnp.concatenate([
-                jnp.take(leaf, span, axis=0).astype(jnp.float32).sum(2)
+                jnp.take(leaf, span, axis=0, mode="clip")
+                .astype(jnp.float32).sum(2)
                 for leaf in ks], axis=-1)          # (B, n + m - 1, G * D)
             own = jnp.take_along_axis(
                 tables, first[:, None] + jnp.arange(n)[None], axis=1)

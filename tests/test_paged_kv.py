@@ -425,6 +425,121 @@ def test_paged_preemption_no_leak_jit_flat(lm, reg, rec):
     assert sum(c.get() for _, c in g.children()) == 0
 
 
+# ===================================== what the clipping take rests on
+def _watch_tables(eng, monkeypatch):
+    """Record every table the engine hands to a dispatch (the step's
+    ``_slot_tables``, the chunk's ``_adm_tables``, target and draft) and
+    every ``BlockTable.as_array``; returns ``(seen, faults)``: (builder,
+    draft, the table as a host array) a dispatch, and what broke
+    ``nn.attention._gather_pages``' contract: an id outside
+    ``[0, max_pages)``, or anything but ``SCRATCH_PAGE`` where a row
+    holds nothing."""
+    seen, faults = [], []
+    as_array = BlockTable.as_array
+
+    def padded(tbl, table_len):
+        out = as_array(tbl, table_len)
+        held = len(tbl.pages)
+        if not ((out[:held] >= 1) & (out[:held] < tbl.pool.max_pages)).all():
+            faults.append(("as_array: a held page's id", out.tolist()))
+        if not (out[held:] == SCRATCH_PAGE).all():
+            faults.append(("as_array: padding", out.tolist()))
+        return out
+
+    monkeypatch.setattr(BlockTable, "as_array", padded)
+
+    def watched(name, holders):
+        build = getattr(eng, name)
+
+        def tables(draft=False):
+            held = holders(draft)        # row -> pages held, before the build
+            out = build(draft=draft)
+            t = np.asarray(out)
+            pool = eng._d_pages if draft else eng._pages
+            if not ((t >= 0) & (t < pool.max_pages)).all():
+                faults.append((name, "an id out of range", t.tolist()))
+            for row in range(t.shape[0]):
+                n = held.get(row, 0)
+                if not (t[row, n:] == SCRATCH_PAGE).all() \
+                        or (t[row, :n] == SCRATCH_PAGE).any():
+                    faults.append((name, draft, row, n, t[row].tolist()))
+            seen.append((name, draft, t))
+            return out
+        monkeypatch.setattr(eng, name, tables)
+
+    def slots(draft):
+        tables = eng._d_tables if draft else eng._tables
+        return {sid: len(tbl.pages) for sid, tbl in enumerate(tables)
+                if tbl is not None}
+
+    def admissions(draft):
+        return {a.row: len(tbl.pages) for a in eng._adms
+                for tbl in [a.d_table if draft else a.table]
+                if tbl is not None}
+
+    watched("_slot_tables", slots)
+    watched("_adm_tables", admissions)
+    return seen, faults
+
+
+@pytest.mark.parametrize("draft", [False, True], ids=["plain", "draft"])
+def test_every_dispatched_table_holds_valid_ids_and_scratch(
+        lm, monkeypatch, draft):
+    """A take through a block table clips (``_gather_pages``): it rests
+    on every id a dispatch is handed lying in ``[0, max_pages)``, with
+    ``SCRATCH_PAGE`` wherever a row holds nothing. Held here over a run
+    with cold admissions, prefix hits, finished requests' frees and a
+    preemption (with a speculative draft's tables too); and on those
+    tables the clipping gather equals plain indexing to the bit."""
+    from bigdl_tpu.nn.attention import _gather_pages
+    from bigdl_tpu.nn.quantized import Quantizer
+
+    kw = dict(draft=Quantizer.quantize(lm), spec_gamma=3) if draft else {}
+    template = np.asarray([5, 9, 2, 7, 1, 8, 3, 6], np.int32)
+    with ContinuousBatchingEngine(lm, max_slots=2, prefill_chunk=CHUNK,
+                                  prefill_rows=2, page_size=PS,
+                                  preempt_slack_s=0.002,
+                                  prefix_cache_rows=4,
+                                  service_name=f"paged_ids_{draft}",
+                                  **kw) as eng:
+        seen, faults = _watch_tables(eng, monkeypatch)
+        eng.submit(np.append(template, [4, 4]), 3).result(timeout=120)
+        hit = eng.submit(np.append(template, [11, 12, 13]), 5)
+        hit.result(timeout=120)
+        assert hit.prefix_tokens == len(template)
+        # both slots decoding, then a high-class arrival: one is preempted
+        low = [eng.submit(_VICTIM + i, 30, priority="low") for i in (0, 1)]
+        for h in low:
+            next(h.tokens())
+        eng.submit(_URGENT, 4, priority="high").result(timeout=120)
+        for h in low:
+            h.result(timeout=120)
+        assert sum(h.preempted for h in low) >= 1
+        max_pages, _, hd = jax.tree.leaves(eng._kv_pool)[0].shape
+        assert max_pages == eng._pages.max_pages
+        assert eng._pages.stats()["freed_total"] > 0
+    assert not faults, faults[:3]
+    assert {(name, d) for name, d, _ in seen} == {
+        (name, d) for name in ("_slot_tables", "_adm_tables")
+        for d in (False, True)[:1 + draft]}
+    seen = [t for _, _, t in seen]
+    assert {t.shape for t in seen} == {(2, eng._table_len)}
+    assert any((t != SCRATCH_PAGE).any() for t in seen)
+    assert any((t == SCRATCH_PAGE).all(axis=1).any() for t in seen)
+
+    # on every distinct table of the run: the clipped gather IS the index
+    tables = np.unique(np.concatenate(seen), axis=0)
+    leaf = jnp.asarray(np.random.RandomState(5).standard_normal(
+        (max_pages, PS, hd)), jnp.bfloat16)
+    want = np.asarray(leaf)[tables].reshape(len(tables), -1, hd)
+    got = np.asarray(_gather_pages(leaf, jnp.asarray(tables)))
+    assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
+    h_kv = lm.num_kv_heads
+    heads = np.asarray(_gather_pages(leaf, jnp.asarray(tables), h_kv))
+    assert heads.tobytes() == want.tobytes() \
+        and heads.shape == want.shape[:2] + (h_kv, hd // h_kv)
+
+
 # ================================================ engine: usage ledger
 def test_usage_ledger_bills_held_pages(lm):
     """kv_byte_seconds accrues per actually-held page, pro-rata per
