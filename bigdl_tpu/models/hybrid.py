@@ -267,6 +267,18 @@ class HybridDecoderLM(Module):
         return full[0].mixer.chunk_read_counts(pos0, chunk, page_size,
                                                table_pages)
 
+    def step_read_counts(self, pos, page_size: int, table_pages: int,
+                         decode_attention: str = "rows"):
+        """What one full-attention layer of a decode dispatch reads of
+        what its rows' tables hold (``MultiHeadAttention
+        .step_read_counts``; host arithmetic for the engine's span and
+        counters); None when no layer is a full one."""
+        full = self._blocks(FULL)
+        if not full:
+            return None
+        return full[0].mixer.step_read_counts(pos, page_size, table_pages,
+                                              decode_attention)
+
     def analytic_flops(self, tokens: int, context: int) -> float:
         """Forward FLOPs for ``tokens`` positions over ``context``
         cached ones: two a matmul weight, the score and value products

@@ -487,6 +487,16 @@ class TransformerLM(Module):
         return self.block0.attn.chunk_read_counts(pos0, chunk, page_size,
                                                   table_pages)
 
+    def step_read_counts(self, pos, page_size: int, table_pages: int,
+                         decode_attention: str = "rows") -> dict:
+        """What one attention layer of a decode dispatch reads of what
+        its rows' tables hold, the rows standing at ``pos`` (host
+        arithmetic for the engine's span and counters:
+        ``MultiHeadAttention.step_read_counts``)."""
+        return self.block0.attn.step_read_counts(pos, page_size,
+                                                 table_pages,
+                                                 decode_attention)
+
     def prefill_chunk_at_paged(self, ids, pools, tables, pos0, last_idx):
         """Paged twin of :meth:`prefill_chunk_at`: each row's chunk
         scatters its KV into the pool pages its block-table row names
@@ -530,8 +540,10 @@ class TransformerLM(Module):
         the page pool through ``tables`` inside the same dispatch —
         compiled shape depends on the pool geometry and the table
         length, never on any request's span. ``decode_attention`` is
-        ``"rows"`` on one device and ``"heads"`` under a mesh that
-        shards heads (``MultiHeadAttention.forward_step_paged``)."""
+        ``"kernel"`` on one TPU chip (nothing gathered: a kernel reads
+        the pages in place), ``"rows"`` on any other single device and
+        ``"heads"`` under a mesh that shards heads
+        (``MultiHeadAttention.forward_step_paged``)."""
         with jax.named_scope("embed"):
             x = jnp.take(self.tok_embed, ids_t, axis=0)[:, None, :]  # (B,1,C)
             if not self.use_rope:

@@ -170,7 +170,13 @@ def _gather_pages(leaf, tables, heads=None):
     XLA lowers the take to one gather, so compiled shape depends only
     on the POOL geometry, never on any request's length. Table slots
     past a request's reservation point at the scratch page — garbage
-    the caller's causal mask must (and does) discard.
+    the caller's causal mask must (and does) discard, and bytes the
+    gather moves all the same: a decode step that takes whole tables
+    reads 35 and 9 times what its rows hold at the two loads served
+    (PERF.md, PR 44). Who calls this: the chunk's key-block loop, a
+    few pages a round; the decode step off a TPU and on a mesh, whole
+    tables. The decode step on one TPU chip gathers nothing
+    (``ops/paged_attention.py``, under this same contract).
 
     CALLER CONTRACT: every id in ``tables`` lies in ``[0, max_pages)``,
     and a slot that holds nothing names the scratch page (id 0), which
@@ -268,9 +274,11 @@ def _write_kv_paged(pool, k_t, v_t, tables, positions, rows=False):
     :func:`_scatter_kv_paged`, then gather the dense per-row views
     attention attends over: per head, (B, T_total, H, D), or with
     ``rows=True`` as the pool stores them, (B, T_total, H * D) (the two
-    views of :func:`_gather_pages`; the decode step without a mesh asks
-    for rows, the mesh step for heads; the chunk gathers by key blocks,
-    :func:`_attend_key_blocks`).
+    views of :func:`_gather_pages`; the decode step on one device
+    that is no TPU asks for rows, the mesh step for heads; on one TPU
+    chip the step takes :func:`_scatter_kv_paged` alone and a kernel
+    reads the pages in place, ``ops/paged_attention.py``; the chunk
+    gathers by key blocks, :func:`_attend_key_blocks`).
 
     With the quantized 4-tuple what is attended is the dequantized
     STORED view, so a paged cold pass attends the values a dense
@@ -442,6 +450,9 @@ def _attend_pages_rows(q, k_rows, v_rows, pos):
 #: what ``engine.stats()["paging"]["decode_attention"]`` reports
 _DECODE_ATTENTION = {"rows": _attend_pages_rows,
                      "heads": _attend_pages_heads}
+#: ... and the third word gathers nothing: ``ops/paged_attention.py``
+#: reads the pages where they lie
+_DECODE_FORMS = sorted((*_DECODE_ATTENTION, "kernel"))
 
 
 def rotary_embedding(x, positions, base: float = 10000.0):
@@ -808,19 +819,27 @@ class MultiHeadAttention(Module):
         lanes whose output the caller ignores.
 
         ``decode_attention`` names how the one query token of a row
-        meets the pages gathered for it: ``"rows"`` hands the gathered
-        K and V out as rows of H_kv * D and contracts a block-diagonal
-        q with them (:func:`_attend_pages_rows`: no re-lay of what was
-        gathered, the form for one device); ``"heads"`` hands out the
+        meets its pages. ``"kernel"`` gathers nothing: behind the write
+        a Pallas kernel (``ops/paged_attention.py``) walks each row's
+        table to the row's own ``pos`` and reads the pages from the
+        pool's leaves where they lie, the form for one TPU chip. The
+        other two gather every slot of every row's table into a new
+        array first (:func:`_write_kv_paged`): ``"rows"`` hands the
+        gathered K and V out as rows of H_kv * D and contracts a
+        block-diagonal q with them (:func:`_attend_pages_rows`: no
+        re-lay of what was gathered; one device that is no TPU, and
+        the kernel's parity reference); ``"heads"`` hands out the
         per-head view (:func:`_attend_pages_heads`: no collective
         under a heads-sharded pool, the form for a mesh). Whoever
         builds the program knows which it is; the engine decides from
-        whether it has a mesh. :meth:`forward_chunk_paged`, many query
-        tokens a row, always reads the per-head view."""
-        if decode_attention not in _DECODE_ATTENTION:
+        its mesh, its backend and the pool's leaves
+        (``ContinuousBatchingEngine._decode_form``).
+        :meth:`forward_chunk_paged`, many query tokens a row, attends
+        by key blocks."""
+        if decode_attention not in _DECODE_FORMS:
             raise ValueError(
-                f"decode_attention must be one of "
-                f"{sorted(_DECODE_ATTENTION)}, got {decode_attention!r}")
+                f"decode_attention must be one of {_DECODE_FORMS}, got "
+                f"{decode_attention!r}")
         b = x_t.shape[0]
         with jax.named_scope("attn/qkv"):
             qkv = self.qkv(x_t.reshape(b, self.embed_dim)).reshape(b, 1, -1)
@@ -828,10 +847,18 @@ class MultiHeadAttention(Module):
             if self.rotary:
                 q = rotary_embedding_rowwise(q, pos, self.rotary_base)
                 k_t = rotary_embedding_rowwise(k_t, pos, self.rotary_base)
-        pool, k_read, v_read = _write_kv_paged(
-            pool, k_t, v_t, tables, pos, rows=decode_attention == "rows")
-        o = _DECODE_ATTENTION[decode_attention](q[:, :, 0], k_read,
-                                                v_read, pos)
+        if decode_attention == "kernel":
+            from bigdl_tpu.ops.paged_attention import paged_attention
+
+            pool = _scatter_kv_paged(pool, k_t, v_t, tables, pos)
+            with jax.named_scope("attn/attend"):
+                o = paged_attention(q[:, :, 0], *pool, tables, pos)
+        else:
+            pool, k_read, v_read = _write_kv_paged(
+                pool, k_t, v_t, tables, pos,
+                rows=decode_attention == "rows")
+            o = _DECODE_ATTENTION[decode_attention](q[:, :, 0], k_read,
+                                                    v_read, pos)
         with jax.named_scope("attn/out"):
             o = o.reshape(b, self.embed_dim).astype(x_t.dtype)
             o = self.out_proj(o).reshape(b, 1, -1)
@@ -880,6 +907,22 @@ class MultiHeadAttention(Module):
         return {"kv_read_tokens":
                 len(pos0) * min(-(-reach // width) * width, whole),
                 "kv_table_tokens": len(pos0) * whole}
+
+    def step_read_counts(self, pos, page_size: int, table_len: int,
+                         decode_attention: str = "rows") -> dict:
+        """What :meth:`forward_step_paged` reads of the pool for a
+        dispatch whose rows stand at ``pos``, idle lanes (``pos`` 0)
+        among them (host arithmetic, for the engine's span and
+        counters), summed over the rows: the tokens' worth of pages its
+        attention reads (the kernel: the pages up to each row's
+        ``pos``; the gathered forms: every slot of every table), and
+        what the rows' whole tables hold."""
+        whole = len(pos) * table_len * page_size
+        read = whole
+        if decode_attention == "kernel":
+            held = np.asarray(pos, np.int64) // page_size + 1
+            read = int(held.sum()) * page_size
+        return {"kv_read_tokens": read, "kv_table_tokens": whole}
 
     def _rope(self, x, positions):
         return rotary_embedding(x, positions, self.rotary_base) \

@@ -465,6 +465,13 @@ class ContinuousBatchingEngine:
                 and self._chunk_counts([0], 1, 1, 1) is None:
             self._chunk_counts = None
         self._prefill_kv_read = self._prefill_kv_table = 0
+        #: the same for a decode dispatch: what a full-attention layer's
+        #: step reads of its rows' tables, by the form the step takes
+        self._step_counts = getattr(model, "step_read_counts", None)
+        if self._step_counts is not None \
+                and self._step_counts([0], 1, 1) is None:
+            self._step_counts = None
+        self._decode_kv_read = self._decode_kv_table = 0
         if self._lane_state:
             refused = {
                 "draft": (draft is not None, "speculation needs the "
@@ -1028,6 +1035,32 @@ class ContinuousBatchingEngine:
         self._draining = False
 
     # ------------------------------------------------- compiled programs
+    def _decode_form(self, pool) -> str:
+        """How one decode token meets the pages of ``pool`` (the
+        engine's, or its draft's), from what the engine can see. On a
+        mesh heads stay a batch dimension of both products
+        (``"heads"``: the rows form would sum over the sharded heads
+        dimension, two collectives a layer). On one TPU chip a kernel
+        reads the pages where they lie (``"kernel"``,
+        ops/paged_attention.py), where every full-attention layer's
+        pool (a tuple a layer; a selecting layer's is a dict and has
+        its own step) is the float pair of whole tiles: the int8
+        4-tuple and a toy model's narrow pages are not. Everywhere
+        else every table is gathered and K and V stay rows of
+        H_kv * D under a block-diagonal q (``"rows"``: nn/attention.py
+        _attend_pages_rows)."""
+        if self.mesh is not None:
+            return "heads"
+        if jax.default_backend() != "tpu":
+            return "rows"
+        # (importing Pallas takes a second: only where the kernel can run)
+        from bigdl_tpu.ops.paged_attention import supported
+
+        full = [g for g in page_leaves(pool) if isinstance(g, tuple)]
+        if full and all(len(g) == 2 and supported(g[0]) for g in full):
+            return "kernel"
+        return "rows"
+
     def _build_fns(self):
         """The compiled programs: every KV surface is the page pool,
         gathered/scattered through per-request block tables INSIDE the
@@ -1044,15 +1077,9 @@ class ContinuousBatchingEngine:
         model = self.model
         sampled = self.temperature > 0.0
         top_k, top_p = self.top_k, self.top_p
-        # how one decode token meets its gathered pages, decided here
-        # once for every program below that runs decode_step_paged: on
-        # one device K and V stay rows of H_kv * D under a
-        # block-diagonal q (nothing gathered is re-laid); on a mesh
-        # that contraction would sum over the sharded heads dimension
-        # (two collectives a layer), so heads stay a batch dimension
-        # (nn/attention.py _attend_pages_rows / _attend_pages_heads)
-        attend = self._decode_attention = (
-            "rows" if self.mesh is None else "heads")
+        # how one decode token meets its pages, decided here once for
+        # every program below that runs decode_step_paged
+        attend = self._decode_attention = self._decode_form(self._kv_pool)
 
         lane_state = self._lane_state
 
@@ -1175,6 +1202,7 @@ class ContinuousBatchingEngine:
         if self.draft is not None:
             draft = self.draft
             g = self._spec.gamma
+            d_attend = self._decode_form(self._d_kv_pool)
 
             # the draft proposer IS the standalone speculative path's
             # cached lax.scan: (max_slots,) tokens at (max_slots,)
@@ -1183,7 +1211,7 @@ class ContinuousBatchingEngine:
             self._propose_jit = draft._propose_fn_paged(
                 self.max_slots, g, self._table_len, sampled=sampled,
                 cache_sharding=self._d_kv_shard,
-                repl_sharding=self._repl, decode_attention=attend)
+                repl_sharding=self._repl, decode_attention=d_attend)
 
             def d_chunk(p, bufs, ids, pool, tables, pos0, last_idx):
                 # the draft's mirror of the ragged admission prefill:
@@ -1204,7 +1232,7 @@ class ContinuousBatchingEngine:
                 # one fixed-shape dispatch serves all rows
                 with bind(draft, p, bufs, False, None):
                     _, pool = draft.decode_step_paged(
-                        tok, pos, pool, tables, decode_attention=attend)
+                        tok, pos, pool, tables, decode_attention=d_attend)
                 return pool
 
             def spec_verify(p, bufs, tok, props, qlogits, pos, pool,
@@ -3374,6 +3402,9 @@ class ContinuousBatchingEngine:
         if self._chunk_counts is not None:
             out["prefill_kv_read_tokens"] = self._prefill_kv_read
             out["prefill_kv_table_tokens"] = self._prefill_kv_table
+        if self._step_counts is not None:
+            out["decode_kv_read_tokens"] = self._decode_kv_read
+            out["decode_kv_table_tokens"] = self._decode_kv_table
         if self._d_pages is not None:
             out["draft_pool"] = self._d_pages.stats()
         if self._prefix is not None:
@@ -3404,6 +3435,7 @@ class ContinuousBatchingEngine:
         if self._chaos is not None:
             self._chaos.on_dispatch()
         with trace.span("serving/decode_dispatch", rows=len(active),
+                        **self._step_read(pos),
                         **self._selected_read(pos[active])) as disp:
             active_arg = ()
             if self._lane_state:
@@ -3452,6 +3484,21 @@ class ContinuousBatchingEngine:
                                   self._table_len)
         self._prefill_kv_read += read["kv_read_tokens"]
         self._prefill_kv_table += read["kv_table_tokens"]
+        return read
+
+    def _step_read(self, pos) -> dict:
+        """Attributes for the decode span of a model with full-attention
+        layers (it says so by ``step_read_counts``): the tokens' worth
+        of pages one such layer's step reads for the dispatch's lanes,
+        idle ones included, and what their whole tables hold; also
+        summed into ``stats()["paging"]``. Nothing for any other
+        model."""
+        if self._step_counts is None:
+            return {}
+        read = self._step_counts(pos, self.page_size, self._table_len,
+                                 self._decode_attention)
+        self._decode_kv_read += read["kv_read_tokens"]
+        self._decode_kv_table += read["kv_table_tokens"]
         return read
 
     def _selected_read(self, positions) -> dict:

@@ -58,6 +58,44 @@ def flash_attention_program(b: int = 2, h: int = 8, h_kv: int = 4,
     return fn, (q, kv, kv)
 
 
+def paged_decode_step_program(lanes: int = 8, vocab: int = 50304,
+                              embed_dim: int = 1280, heads: int = 20,
+                              layers: int = 2, max_len: int = 1024,
+                              page_size: int = 16,
+                              decode_attention: str = "kernel",
+                              dtype=jnp.bfloat16):
+    """The paged engine's decode step as it is built for ONE TPU chip
+    (``decode_attention="kernel"``: the write scatters into the donated
+    pool in place, then the pallas paged-attention kernel reads each
+    lane's pages from the pool's leaves where they lie,
+    ``ops/paged_attention.py``), at GPT-2 Large's widths cut to two
+    layers: every lane with its full table over a pool of
+    ``1 + lanes * table_len`` pages. ``"rows"`` and ``"heads"`` give the
+    gathered forms the engine builds off a TPU and on a mesh."""
+    from bigdl_tpu.models.transformer import TransformerLM
+    from bigdl_tpu.nn.module import abstract_init, bind
+
+    model = abstract_init(lambda: TransformerLM(
+        vocab, embed_dim=embed_dim, num_heads=heads, num_layers=layers,
+        max_len=max_len))
+    model.evaluate()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, dtype), model.params_dict())
+    table_len = max_len // page_size
+    pool = jax.eval_shape(lambda: model.init_page_pool(
+        1 + lanes * table_len, page_size, dtype=dtype))
+
+    def step(p, tok, pos, pool, tables):
+        with bind(model, p, {}, False, None):
+            logits, pool = model.decode_step_paged(
+                tok, pos, pool, tables, decode_attention=decode_attention)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    return (jax.jit(step, donate_argnums=(3,)),
+            (params, i32(lanes), i32(lanes), pool, i32(lanes, table_len)))
+
+
 def ring_flash_program(n_devices: int = 8, t_per_shard: int = 256,
                        dtype=jnp.bfloat16):
     """Ring attention composed with the flash kernel (trainable custom

@@ -77,6 +77,20 @@ def engine():
 
 
 @pytest.fixture(scope="module")
+def kernel_engine():
+    """The same engine as it is built on one TPU chip: told, in this test,
+    that its backend is a TPU (the CPU is what ``jax.default_backend()``
+    sees here), it takes the paged-attention kernel for its decode step."""
+    from unittest import mock
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        eng = _serving_engine("chip_compile_kernel")
+    assert eng.stats()["paging"]["decode_attention"] == "kernel"
+    yield eng
+    eng.stop()
+
+
+@pytest.fixture(scope="module")
 def mesh_engine():
     """The same under the engine's tensor-parallel mode, on four of the
     CPU's devices: the source of the step the MESH engine builds (it keeps
@@ -217,7 +231,7 @@ HYBRID = dict(slots=16, rows=2, chunk=256, page=16, ctx=4096, heads=30,
               embed=3840)
 
 
-def _hybrid_period(sharding):
+def _hybrid_period(sharding, decode_attention="rows"):
     """One period of the hybrid decoder (3 gated delta-rule layers and a
     full-attention layer) at its published widths, built as shapes:
     ``{"step": (fn, args, donated), "chunk": ...}`` at the cell's geometry,
@@ -243,7 +257,8 @@ def _hybrid_period(sharding):
     def step(p, tok, pos, pool, tables, active):
         with bind(model, p, {}, False, None):
             logits, pool = model.decode_step_paged(
-                tok, pos, pool, tables, active=active)
+                tok, pos, pool, tables, active=active,
+                decode_attention=decode_attention)
         return jnp.argmax(logits, -1), pool
 
     def prefill(p, ids, pool, tables, pos0, last, lanes):
@@ -318,6 +333,58 @@ def test_chunk_attends_by_key_blocks_and_makes_no_whole_table_view(
     kp = _key_block_pages(page, table)
     assert kp < table
     assert f"tensor<{rows}x{kp}x{page}x{width}x" in text
+
+
+@pytest.mark.parametrize("config", ["gpt2-large", "olmo-hybrid-7b"])
+def test_kernel_decode_step_compiles_and_reads_its_pool_in_place(
+        topo, one_chip, kernel_engine, config):
+    """The decode step as an engine builds it on one TPU chip, at both
+    cells' widths (the engine's own program over 8 lanes of GPT-2 Large's
+    1280 columns; one period of the hybrid decoder over 16 lanes of 3840):
+    it compiles for the chip with the Mosaic paged-attention kernel in it,
+    the pool is donated, aliased in full and takes its logical bytes, the
+    write is still a scatter in place, no leaf is copied or transposed on
+    its way into the custom call, and nothing of a lane's whole table is
+    gathered: no (lanes, table_len, page_size, H * D) view, merged or
+    not (the decode twin of
+    ``test_chunk_attends_by_key_blocks_and_makes_no_whole_table_view``)."""
+    from bigdl_tpu.ops.flash_attention import force_interpret
+
+    if config == "gpt2-large":
+        args = _engine_args(kernel_engine,
+                            _abstract(kernel_engine._params, one_chip),
+                            one_chip, one_chip)["step"]
+        jitted, pool = kernel_engine._step_jit, args[4]
+        lanes, table = SERVE["max_slots"], kernel_engine._table_len
+        page, width = SERVE["page_size"], LM["embed_dim"]
+    else:
+        fn, args, donated = _hybrid_period(one_chip, "kernel")["step"]
+        jitted, pool = jax.jit(fn, donate_argnums=(donated,)), args[donated]
+        lanes, page, width = HYBRID["slots"], HYBRID["page"], HYBRID["embed"]
+        table = HYBRID["ctx"] // page
+    # jax.devices() is the CPU here: steer the kernel to Mosaic in the test
+    with force_interpret(False):
+        compiled = jitted.lower(*args).compile()
+    m = _fits(compiled)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    leaves = jax.tree.leaves(pool["pages"] if isinstance(pool, dict)
+                             else pool)
+    pool_bytes = sum(int(np.prod(leaf.shape)) * leaf.dtype.itemsize
+                     for leaf in leaves)
+    if isinstance(pool, dict):
+        assert m.alias_size_in_bytes >= pool_bytes      # + the lanes' state
+    else:
+        assert m.alias_size_in_bytes == pool_bytes
+    dims = ",".join(str(d) for d in leaves[0].shape)
+    assert f"bf16[{dims}]{{2,1,0:" in text
+    assert "scatter(" in text
+    assert not re.findall(rf"= bf16\[{dims}\]\S* (?:copy|transpose)\(", text)
+    views = re.findall(
+        rf"= \w+\[{lanes},(?:{table},{page}|{table * page}),{width}\]", text)
+    assert not views, views[:4]
+    gathers = re.findall(rf"= bf16\[{lanes * table},{page},{width}\]", text)
+    assert not gathers, gathers[:4]
 
 
 @pytest.mark.parametrize("program", ["step", "chunk"])

@@ -37,6 +37,30 @@ def test_flash_attention_grad_lowers_for_tpu():
     assert "tpu_custom_call" in exported.mlir_module()
 
 
+@pytest.mark.parametrize("form", ["kernel", "rows", "heads"])
+def test_paged_decode_step_lowers_for_tpu(form):
+    """The paged engine's decode step at GPT-2 Large's widths. As it is
+    built for one TPU chip it carries the Mosaic paged-attention kernel
+    (``ops/paged_attention.py``) and makes no gathered view of the
+    lanes' tables, (lanes, table_len, page_size, H * D) or that with
+    (table_len, page_size) merged; the two gathered forms, off a TPU
+    and on a mesh, lower as before, with no kernel and with that
+    view."""
+    lanes, table_len = 8, 64
+    fn, args = ep.paged_decode_step_program(lanes=lanes,
+                                            decode_attention=form)
+    mod = _export(fn, args).mlir_module()
+    views = [f"tensor<{lanes}x{table_len}x16x1280xbf16>",
+             f"tensor<{lanes}x{table_len * 16}x1280xbf16>"]
+    if form == "kernel":
+        assert "tpu_custom_call" in mod
+        assert not any(v in mod for v in views)
+        assert "stablehlo.scatter" in mod               # the write stays
+    else:
+        assert "tpu_custom_call" not in mod
+        assert views[0] in mod
+
+
 def test_ring_flash_composed_lowers_for_tpu():
     """Ring attention (ppermute over 'seq') composed with the Mosaic
     flash kernel, with gradients through the custom vjp, on the 8-way
