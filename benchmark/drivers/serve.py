@@ -287,6 +287,7 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
                            np.asarray(c.tokens, np.int32)) for c in finished)
         record = {
             "programs": config["programs"], "sizes": config["sizes"],
+            "scopes": config.get("scopes", {}),
             "ttft_ms": e2e.pop("ttft_ms"),
             "late_ms": [(c.submitted - c.due) * 1e3 for c in measured
                         if c.submitted is not None],

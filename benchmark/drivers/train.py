@@ -229,6 +229,7 @@ def run(cell, seed, seconds, trace, t_start, trace_dir=None):
         stamps.losses[:CHECK_STEPS], config["optimizer"]["recipe"])
     prog["window_losses"] = stamps.losses[warmup:]
     record = {"programs": config["programs"], "sizes": config["sizes"],
+              "scopes": config.get("scopes", {}),
               "batch_per_chip": int(mix["batch_per_chip"]),
               "iteration_ms": walls}
     # the reference needs the device's memory: the program's state goes

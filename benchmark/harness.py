@@ -26,7 +26,10 @@ def manifest():
 
 def load_cell(name, man=None):
     """The manifest entry of cell ``name`` with its configuration and traffic
-    files, each found by the name the manifest gives."""
+    files, each found by the name the manifest gives. A configuration whose
+    ``scopes`` re-group what the scope reader knows is refused here."""
+    from benchmark import program_scopes       # it imports this module
+
     man = man or manifest()
     cells = {w["name"]: w for w in man["workloads"]}
     if name not in cells:
@@ -34,6 +37,7 @@ def load_cell(name, man=None):
     cell = dict(cells[name])
     files = {c["name"]: c["file"] for c in man["configs"]}
     cell["config_json"] = load_json(ROOT, files[cell["config"]])
+    program_scopes.vocabulary(cell["config_json"].get("scopes"))
     cell["traffic_json"] = load_json(HERE, "traffic", cell["traffic"] + ".json")
     cell["end_to_end"] = [m for m in man["end_to_end"]
                           if name in m.get("workloads", [name])]

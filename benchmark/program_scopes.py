@@ -2,12 +2,17 @@
 ``jax.named_scope`` names the programs gave their parts.
 
     python -m benchmark.program_scopes <dir or .xplane.pb> \
-        [--program jit_step] [--by shape]
+        [--program jit_step] [--by shape] [--config configs/<config>.json]
 
 The programs open scopes from one vocabulary
 (``bigdl_tpu.observability.tracing.DEVICE_SCOPES``; ``GROUPS`` below is that
 vocabulary as this reader knows it, so that it reads a program from before
-the tuple too), and ``Module.__call__`` opens the layer's class. XLA keeps
+the tuple too), and ``Module.__call__`` opens the layer's class. A
+configuration whose program opens a scope ``GROUPS`` lacks says so in its own
+file, ``"scopes": {"<scope>": "<group>"}``: for that configuration's runs the
+scope is vocabulary as a row of ``GROUPS`` is, read under the group it names,
+which may be a new one (``vocabulary``). A declaration adds and never
+re-groups: one that names a scope ``GROUPS`` has is refused. XLA keeps
 the path of scopes an operation was traced under as the ``op_name`` of its
 HLO metadata, a fusion that of its root instruction. A TPU capture holds it:
 its ``/host:metadata`` plane carries every program's optimized HLO module as
@@ -68,29 +73,56 @@ STEP_ROLES = ("decode_step", "prefill_chunk", "train_step")
 _JIT = re.compile(r"(?:^|/)p?jit\([^()/]*\)")
 _WRAPPER = re.compile(r"[\w.\-]+\(")
 _CLASS = re.compile(r"^[A-Z][A-Za-z0-9_]*$")
+_DECLARED_SCOPE = re.compile(r"^[\w.\-]+(/[\w.\-]+)?$")
+_DECLARED_GROUP = re.compile(r"^\w+$")
 
 
 # ------------------------------------------------------------- the names
-def scope_of(op_name):
-    """The scope an operation with this ``op_name`` is charged to: a
-    vocabulary scope, else a module class, else None."""
+def vocabulary(declared=None):
+    """``GROUPS`` with the scopes a configuration's file declares
+    (``"scopes": {scope: group}``) beside them; ``GROUPS`` itself where it
+    declares none. A declared scope is a one- or two-component name (a
+    module class's name may be one), its group any name, a new one too.
+    Refused: a scope ``GROUPS`` has or a class ``CLASS_GROUPS`` reads (a
+    declaration adds, it never moves time out of a metric an accepted cell
+    reports), and a name the rule could not find on a path."""
+    if not declared:
+        return GROUPS
+    for scope, group in declared.items():
+        if not (_DECLARED_SCOPE.match(scope) and isinstance(group, str)
+                and _DECLARED_GROUP.match(group)) or group == UNSCOPED:
+            raise harness.BenchmarkError(
+                f"declared scope {scope!r}: {group!r}: a scope is one or two "
+                "components of letters, digits, _ . -, its group a name "
+                f"other than {UNSCOPED!r}")
+        if scope in GROUPS or group_of(scope) != "other":
+            raise harness.BenchmarkError(
+                f"declared scope {scope!r} is read under "
+                f"{group_of(scope)!r} already: a configuration adds scopes, "
+                "it re-groups none")
+    return {**GROUPS, **declared}
+
+
+def scope_of(op_name, groups=GROUPS):
+    """The scope an operation with this ``op_name`` is charged to: a scope
+    of ``groups`` (a ``vocabulary``), else a module class, else None."""
     if not op_name:
         return None
     parts = [c for c in _WRAPPER.sub("", _JIT.sub("", op_name))
              .replace(")", "").split("/") if c]
     for i in range(len(parts) - 1, -1, -1):
-        if i and parts[i - 1] + "/" + parts[i] in GROUPS:
+        if i and parts[i - 1] + "/" + parts[i] in groups:
             return parts[i - 1] + "/" + parts[i]
-        if parts[i] in GROUPS:
+        if parts[i] in groups:
             return parts[i]
     return next((c for c in reversed(parts) if _CLASS.match(c)), None)
 
 
-def group_of(scope):
+def group_of(scope, groups=GROUPS):
     if scope in (None, UNSCOPED):
         return UNSCOPED
-    if scope in GROUPS:
-        return GROUPS[scope]
+    if scope in groups:
+        return groups[scope]
     return next((g for part, g in CLASS_GROUPS if part in scope), "other")
 
 
@@ -223,17 +255,17 @@ def shape_kind(event_name):
     return " ".join([re.sub(r"[.\d]+$", "", parts[0])] + parts[2:])
 
 
-def program_table(runs, op_names, by_shape=False):
+def program_table(runs, op_names, by_shape=False, groups=GROUPS):
     """One program's runs against its ``{instruction: op_name}``: the
-    median per-run milliseconds by scope and by group, the closure, and the
-    costliest unscoped operations. ``op_names`` None: the capture holds no
-    HLO for the program."""
+    median per-run milliseconds by scope and by group of ``groups`` (a
+    ``vocabulary``), the closure, and the costliest unscoped operations.
+    ``op_names`` None: the capture holds no HLO for the program."""
     scope_cache = {}
 
     def scope(event):
         if event not in scope_cache:
             scope_cache[event] = scope_of(
-                (op_names or {}).get(instruction_name(event)))
+                (op_names or {}).get(instruction_name(event)), groups)
         return scope_cache[event]
 
     per_run, totals, unscoped, shapes = [], [], {}, {}
@@ -260,7 +292,8 @@ def program_table(runs, op_names, by_shape=False):
     def grouped(by):
         out = {}
         for sc, ns in by.items():
-            out[group_of(sc)] = out.get(group_of(sc), 0.0) + ns
+            g = group_of(sc, groups)
+            out[g] = out.get(g, 0.0) + ns
         return out
 
     by_scope = medians(per_run)
@@ -297,7 +330,7 @@ def program_table(runs, op_names, by_shape=False):
     return out
 
 
-def tables(capture, op_names, programs=None, by_shape=False):
+def tables(capture, op_names, programs=None, by_shape=False, groups=GROUPS):
     """``{program: program_table}`` for the programs of ``capture`` (what
     ``program_spans.read_capture`` gives, with its ``window``) that ran
     whole inside the window; ``programs`` keeps those named."""
@@ -308,16 +341,18 @@ def tables(capture, op_names, programs=None, by_shape=False):
         if programs is None or prog in programs:
             # one program, one fingerprint: its runs share the event name
             out[prog] = program_table(
-                runs, op_names.get(runs[0]["event"]), by_shape)
+                runs, op_names.get(runs[0]["event"]), by_shape, groups)
     return out
 
 
 # ------------------------------------------------- what the metrics read
 def scopes(run, trace):
     """The traced window's step programs by role (``run["programs"]``'
-    names), ``{role: program_table}``, read once a run and logged as the
-    ``[scopes]`` line. None without a trace (nothing is read then: a stale
-    capture on disk is not this run's) or without a capture."""
+    names), ``{role: program_table}``, by the vocabulary of the run's
+    configuration (``run["scopes"]``: what its file declares), read once a
+    run and logged as the ``[scopes]`` line. None without a trace (nothing
+    is read then: a stale capture on disk is not this run's) or without a
+    capture."""
     if trace is None:
         return None
     return program_spans.kept(run, "scopes", lambda: _scopes(run))
@@ -332,7 +367,8 @@ def _scopes(run):
     roles = {role: names for role, names in run.get("programs", {}).items()
              if role in STEP_ROLES}
     wanted = {n for names in roles.values() for n in names}
-    found = tables(cap, hlo_op_names(path), wanted)
+    found = tables(cap, hlo_op_names(path), wanted,
+                   groups=vocabulary(run.get("scopes")))
     out = {}
     for role, names in roles.items():
         hit = next((n for n in names if n in found), None)
@@ -349,7 +385,8 @@ def _scopes(run):
 
 def group_ms(run, trace, role, group):
     """Median milliseconds a run of ``role``'s program under ``group``'s
-    scopes; None where the program shows no operation under them."""
+    scopes (a group of ``GROUPS`` or one the run's configuration declares);
+    None where the program shows no operation under them."""
     t = (scopes(run, trace) or {}).get(role)
     return t["by_group"].get(group) if t else None
 
@@ -395,7 +432,11 @@ def main(argv=None):
     ap.add_argument("path", help="a trace directory or an .xplane.pb")
     ap.add_argument("--program", help="one program (jit_step); default all")
     ap.add_argument("--by", choices=("scope", "shape"), default="scope")
+    ap.add_argument("--config", help="a configuration's file: the scopes it "
+                    "declares are read as its runs read them")
     args = ap.parse_args(argv)
+    groups = vocabulary(harness.load_json(args.config).get("scopes")
+                        if args.config else None)
     path = (reduce_trace.find_xplane(args.path)
             if os.path.isdir(args.path) else args.path)
     cap = program_spans.read_capture(path)
@@ -406,7 +447,8 @@ def main(argv=None):
     if cap["window"] is None:
         raise SystemExit(f"{path}: no window (no marker, too few runs)")
     found = tables(cap, hlo_op_names(path),
-                   args.program and {args.program}, args.by == "shape")
+                   args.program and {args.program}, args.by == "shape",
+                   groups)
     if not found:
         raise SystemExit(f"{path}: no whole run of "
                          f"{args.program or 'any program'} in the window")

@@ -6,7 +6,6 @@ a lower precision and for a broken timed path."""
 
 import math
 import os
-import re
 import subprocess
 import sys
 import time
@@ -19,6 +18,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import manifest_invariants  # noqa: E402  (beside this file)
 from benchmark import compare, counts, harness, loadgen, reduce_trace  # noqa: E402
 
 
@@ -31,44 +31,10 @@ def no_chip_needed(monkeypatch):
     monkeypatch.setattr(harness, "require_chips", lambda n: jax.devices()[:n])
 
 
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-
-
 # ---------------------------------------------------------------- manifest
 def test_manifest_names_units_and_files_resolve():
-    man = harness.manifest()
-    assert man["command"] == ["python3", "benchmark/run.py"]
-    assert 1 <= man["run_seconds"] <= 51
-    metrics = man["end_to_end"] + man["per_layer"]
-    names = [m["name"] for m in metrics]
-    assert len(set(names)) == len(names)
-    for m in metrics:
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
-        assert m["better"] in ("lower", "higher")
-    e2e = {m["name"]: m for m in man["end_to_end"]}
-    assert "setup_s" in e2e and all(0 < m["bound"] <= 0.1
-                                    for m in e2e.values())
-    cells = [w["name"] for w in man["workloads"]]
-    assert sum(w["chips"] == 4 for w in man["workloads"]) <= max(
-        1, len(cells) // 4)
-    for w in man["workloads"]:
-        assert NAME.match(w["name"]) and len(w["why"]) <= 200
-        cell = harness.load_cell(w["name"], man)        # both files open
-        assert cell["config_json"]["driver"] in ("serve", "train")
-        assert os.path.exists(os.path.join(
-            harness.HERE, "drivers", cell["config_json"]["driver"] + ".py"))
-        assert len(cell["end_to_end"]) >= 2 and cell["per_layer"]
-    for c in man["configs"]:
-        assert c["file"].startswith("benchmark/configs/")
-        assert sorted(harness.load_json(ROOT, c["file"])["reduced"]) == \
-            sorted(c["reduced"])
-    for m in man["per_layer"]:
-        assert m["moves"] in e2e and set(m["workloads"]) <= set(cells)
-        reader = harness.load_module("metrics", m["name"])
-        assert callable(reader.value)
-        # a reader with nothing to read returns nothing
-        assert reader.value({"programs": {}}, None) is None
+    manifest_invariants.names_units_and_files_resolve(harness.manifest(),
+                                                      ROOT)
 
 
 # ----------------------------------------------------------------- traffic
@@ -448,6 +414,7 @@ def test_serving_rehearsal_is_correct_and_writes_no_device_metric(served):
                 dict(out["record"], peaks={}), None) is None
     rec = out["record"]
     assert rec["prefix_tokens"] > 0 and rec["jit_compiles"] > 0
+    assert rec["scopes"] == {}       # the configuration's file declares none
     assert harness.load_module("metrics", "loop_host_pct").value(
         rec, None) > 0
 
@@ -669,8 +636,12 @@ def test_training_rehearsal_is_correct_and_bfloat16_fails(no_chip_needed,
 
     train = harness.load_module("drivers", "train")
     cell = tiny_training_cell(prebuilt)
+    if prebuilt:       # what the file declares rides on the run's record
+        cell["config_json"]["scopes"] = {"aug/mix": "augment"}
     out = train.run(cell, 2 ** 31 + 3, 1.0, False, time.perf_counter())
     assert out["correct"] is True, out["checks"]
+    assert out["record"]["scopes"] == ({"aug/mix": "augment"} if prebuilt
+                                       else {})
     # the reference followed the batches the loop was fed: whole batches of
     # the seeded arrays in their own order, or rows of a shuffled draw
     x, y = loadgen.synthetic_dataset(cell["traffic_json"], 2 ** 31 + 3)

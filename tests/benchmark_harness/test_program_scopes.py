@@ -2,7 +2,8 @@
 checked on the CPU: the rule that charges an ``op_name`` to a scope, the few
 protobuf fields read from a capture's bytes (on a hand-encoded capture), self
 times and whole runs on hand-built events, the thirteen per-layer metrics on
-hand-worked numbers, and the manifest's entries."""
+hand-worked numbers, the scopes a configuration's file declares, and the
+manifest's entries (as invariants: ``manifest_invariants.py``)."""
 
 import json
 import os
@@ -15,41 +16,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+import manifest_invariants  # noqa: E402  (beside this file)
 from benchmark import harness, program_scopes, program_spans  # noqa: E402
 
 MS = 1_000_000
-SERVING = ["gpt2l-chat-steady", "olmoh-docqa-steady", "sala-longdoc-steady"]
-LANES = SERVING[1:]
-TRAIN = ["resnet50-local-b256"]
 #: metric -> (layer, moves, cells, role, group)
-NEW = {
-    "decode_kv_pages_ms": ("kernels", "itl_p95_ms", SERVING, "decode_step",
-                           "kv_pages"),
-    "decode_attend_ms": ("kernels", "itl_p95_ms", SERVING, "decode_step",
-                         "attend"),
-    "decode_dense_ms": ("kernels", "itl_p95_ms", SERVING, "decode_step",
-                        "dense"),
-    "decode_recurrent_ms": ("kernels", "itl_p95_ms", LANES, "decode_step",
-                            "recurrent"),
-    "decode_select_ms": ("cache manager", "itl_p95_ms", SERVING[2:],
-                         "decode_step", "select"),
-    "chunk_kv_pages_ms": ("kernels", "itl_p95_ms", SERVING, "prefill_chunk",
-                          "kv_pages"),
-    "chunk_attend_ms": ("kernels", "itl_p95_ms", SERVING, "prefill_chunk",
-                        "attend"),
-    "chunk_dense_ms": ("kernels", "itl_p95_ms", SERVING, "prefill_chunk",
-                       "dense"),
-    "chunk_recurrent_ms": ("kernels", "itl_p95_ms", LANES, "prefill_chunk",
-                           "recurrent"),
-    "train_conv_ms": ("kernels", "train_samples_per_s", TRAIN, "train_step",
-                      "conv"),
-    "train_bn_ms": ("kernels", "train_samples_per_s", TRAIN, "train_step",
-                    "bn"),
-    "program_unscoped_pct.serve": ("device", "itl_p95_ms", SERVING, None,
-                                   None),
-    "program_unscoped_pct.train": ("device", "train_samples_per_s", TRAIN,
-                                   None, None),
-}
+NEW = manifest_invariants.BY_SCOPE
 
 
 # --------------------------------------------------------------- the rule
@@ -102,10 +74,61 @@ def test_the_groups_the_metrics_read():
     assert g(None) == "unscoped"
 
 
-def test_the_reader_knows_the_programs_vocabulary():
-    from bigdl_tpu.observability.tracing import DEVICE_SCOPES
+# a configuration's file: {"scopes": DECLARED}
+DECLARED = {"moe/experts": "experts", "moe/route": "route",
+            "mla/absorb": "attend", "RMSNorm": "rmsnorm"}
 
-    assert set(program_scopes.GROUPS) == set(DEVICE_SCOPES)
+
+@pytest.mark.parametrize("op_name, scope, plain", [
+    # a declared scope is vocabulary as a row of GROUPS is: innermost wins
+    ("jit(step)/mlp/GatedMLP/moe/experts/dot_general", "moe/experts", "mlp"),
+    ("jit(step)/moe/experts/mlp/mul", "mlp", "mlp"),
+    ("jit(step)/mlp/moe/route/Linear/dot_general", "moe/route", "mlp"),
+    ("jit(step)/while/body/mla/absorb/jit(_einsum)/dot_general",
+     "mla/absorb", None),
+    # a module class's name may be declared: then it is no mere fallback
+    ("jit(step)/attn/out/RMSNorm/rsqrt", "RMSNorm", "attn/out"),
+    ("jit(step)/RMSNorm/norm/rsqrt", "norm", "norm"),
+    ("jit(step)/jvp(RMSNorm)/rsqrt", "RMSNorm", "RMSNorm"),
+    # what no file declares reads as it did
+    ("jit(step)/attn/qkv/Linear/dot_general", "attn/qkv", "attn/qkv"),
+    ("jit(step)/moe/add", None, None),
+])
+def test_a_declared_scope_is_vocabulary_for_its_configuration(
+        op_name, scope, plain):
+    groups = program_scopes.vocabulary(DECLARED)
+    assert program_scopes.scope_of(op_name, groups) == scope
+    assert program_scopes.scope_of(op_name) == plain
+    assert program_scopes.scope_of(
+        op_name, program_scopes.vocabulary({})) == plain
+
+
+def test_a_declaration_adds_groups_and_regroups_nothing():
+    assert program_scopes.vocabulary(None) is program_scopes.GROUPS
+    assert program_scopes.vocabulary({}) is program_scopes.GROUPS
+    groups = program_scopes.vocabulary(DECLARED)
+    before = dict(program_scopes.GROUPS)
+    assert {k: groups[k] for k in before} == before == program_scopes.GROUPS
+    g = lambda scope: program_scopes.group_of(scope, groups)
+    assert g("moe/experts") == "experts" and g("moe/route") == "route"
+    assert g("mla/absorb") == g("attn/attend") == "attend"
+    assert g("RMSNorm") == "rmsnorm" and g("LayerNorm") == "other"
+    assert g("SpatialConvolution") == "conv" and g(None) == "unscoped"
+    assert program_scopes.group_of("RMSNorm") == "other"
+    for bad in ({"mlp": "experts"}, {"attn/attend": "attend"},
+                {"optim/loss": "other"}, {"SpatialConvolution": "conv2"},
+                {"BatchNormalization": "other"}, {"a/b/c": "x"},
+                {"": "x"}, {"jit(step)": "x"}, {"moe/experts": "unscoped"},
+                {"moe/experts": ""}, {"moe/experts": 3}):
+        with pytest.raises(harness.BenchmarkError):
+            program_scopes.vocabulary(bad)
+
+
+def test_the_reader_knows_the_programs_vocabulary():
+    """The program may name a new part when, and only when, the
+    configuration that runs it says how to read it."""
+    manifest_invariants.the_reader_knows_the_programs_vocabulary(
+        harness.manifest(), harness.ROOT)
 
 
 # ------------------------------------------------ the capture's HLO protos
@@ -312,6 +335,60 @@ def test_the_metrics_on_hand_worked_numbers(hand_built, capsys):
     assert "read_s" in line and line["capture_bytes"] == 0
 
 
+def test_a_declared_group_is_read_and_counts_as_scoped(hand_built, capsys):
+    """The same capture under a configuration that declares two scopes: the
+    routed experts' 3 ms leave ``dense`` for a group of their own, the
+    residual add nobody named (1 ms) is the router's and no longer unscoped,
+    and every group ``GROUPS`` reads stays where it was."""
+    hlo = {"jit_step(11)": dict(
+        STEP_NAMES, **{"fusion.6": "jit(step)/mlp/moe/experts/dot_general",
+                       "fusion.8": "jit(step)/moe/route/add"}),
+        "jit_chunk(22)": CHUNK_NAMES}
+    hand_built(capture(), hlo)
+    plain, run = dict(RUN), dict(RUN, scopes={"moe/experts": "experts",
+                                              "moe/route": "route"})
+    g = program_scopes.group_ms
+    assert g(run, TRACE, "decode_step", "experts") == 3
+    assert g(run, TRACE, "decode_step", "route") == 1
+    assert g(run, TRACE, "decode_step", "dense") is None
+    assert g(run, TRACE, "prefill_chunk", "experts") is None
+    assert g(plain, TRACE, "decode_step", "experts") is None
+    assert g(plain, TRACE, "decode_step", "dense") == 3
+    for group in ("kv_pages", "attend"):
+        assert g(run, TRACE, "decode_step", group) == g(
+            plain, TRACE, "decode_step", group) == 7
+    new, old = (program_scopes.scopes(r, TRACE)["decode_step"]
+                for r in (run, plain))
+    assert sum(new["by_group"].values()) == sum(old["by_group"].values())
+    assert new["mean_ms"] == {"ops": 19, "scoped": 18, "unscoped": 1}
+    assert old["mean_ms"] == {"ops": 19, "scoped": 17, "unscoped": 2}
+    assert read("program_unscoped_pct.serve", run, TRACE) == pytest.approx(
+        100 * 3 / 85)
+    assert read("program_unscoped_pct.serve", plain, TRACE) == pytest.approx(
+        100 * 6 / 85)
+    assert "moe/experts" in capsys.readouterr().err
+
+
+def test_by_hand_the_same_declaration_from_the_configurations_file(
+        monkeypatch, tmp_path, capsys):
+    hlo = {"jit_step(11)": dict(
+        STEP_NAMES, **{"fusion.6": "jit(step)/mlp/moe/experts/dot_general"}),
+        "jit_chunk(22)": CHUNK_NAMES}
+    monkeypatch.setattr(program_scopes, "hlo_op_names", lambda p: hlo)
+    cap = capture()
+    monkeypatch.setattr(program_spans, "read_capture",
+                        lambda path: dict(cap, marker=cap["window"]))
+    config = tmp_path / "config.json"
+    config.write_text(json.dumps({"scopes": {"moe/experts": "experts"}}))
+    path = str(tmp_path / "x.xplane.pb")
+    assert program_scopes.main([path, "--program", "jit_step"]) == 0
+    assert "experts" not in capsys.readouterr().out
+    assert program_scopes.main([path, "--program", "jit_step", "--config",
+                                str(config)]) == 0
+    out = capsys.readouterr().out
+    assert "experts 3.000" in out and "moe/experts" in out
+
+
 def test_the_training_cells_metrics(hand_built):
     names = {"fusion.1": "jit(_core)/transpose(jvp(Sequential/"
                          "SpatialConvolution))/conv_general_dilated",
@@ -383,33 +460,9 @@ def test_without_a_trace_nothing_is_read(monkeypatch):
 
 # ------------------------------------------------------------- the manifest
 def test_every_new_metric_has_its_file_its_entry_and_its_cells():
+    """Invariants, not a snapshot (``manifest_invariants.py``): they hold
+    when a later PR appends, and ``test_manifest_grows.py`` shows it."""
     man = harness.manifest()
-    entries = {m["name"]: m for m in man["per_layer"]}
-    for name, (layer, moves, cells, role, group) in NEW.items():
-        m = entries[name]
-        assert os.path.exists(os.path.join(harness.HERE, "metrics",
-                                           name + ".py"))
-        assert (m["layer"], m["moves"], m["workloads"]) == (
-            layer, moves, cells)
-        assert m["source"] == "device_trace" and m["better"] == "lower"
-        assert m["unit"] == ("%" if role is None else "ms")
-        assert set(m) == {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        if role:
-            src = open(os.path.join(harness.HERE, "metrics",
-                                    name + ".py")).read()
-            assert f'"{role}", "{group}"' in src
-    # appended, in this order, after what the benchmark had; nothing else of
-    # the manifest moved
-    names = [m["name"] for m in man["per_layer"]]
-    assert names[-len(NEW):] == list(NEW) and len(names) == 31 + len(NEW)
-    assert names[:3] == ["gen_late_p95_ms", "queue_wait_p95_ms",
-                         "ttft_p95_ms"]
-    assert names[30] == "sparse_decode_rows_pct"
-    assert [w["name"] for w in man["workloads"]] == [
-        "gpt2l-chat-steady", "resnet50-local-b256", "olmoh-docqa-steady",
-        "sala-longdoc-steady"]
-    assert man["run_seconds"] == 51 and len(man["configs"]) == 4
-    assert [(m["name"], m["bound"]) for m in man["end_to_end"]] == [
-        ("itl_p95_ms", 0.05), ("serve_due_tok_per_s", 0.01),
-        ("train_samples_per_s", 0.01), ("setup_s", 0.1)]
+    manifest_invariants.the_scope_readers_metrics(man, harness.ROOT)
+    manifest_invariants.what_the_benchmark_had_keeps_its_place(
+        man, harness.ROOT)
