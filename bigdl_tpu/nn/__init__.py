@@ -66,6 +66,8 @@ from bigdl_tpu.nn.attention import (
 from bigdl_tpu.nn.gated_delta import GatedDeltaNet, GatedMLP
 from bigdl_tpu.nn.lightning_attention import LightningAttention
 from bigdl_tpu.nn.sparse_attention import BlockSparseAttention
+from bigdl_tpu.nn.latent_attention import LatentAttention
+from bigdl_tpu.nn.routed_experts import RoutedExperts
 from bigdl_tpu.nn.criterion import (
     Criterion, ClassNLLCriterion, CrossEntropyCriterion, CategoricalCrossEntropy,
     MSECriterion, AbsCriterion, BCECriterion, SmoothL1Criterion,

@@ -893,14 +893,16 @@ class MultiHeadAttention(Module):
             o = self.out_proj(o.astype(x.dtype))
         return o.reshape(b, t, -1), pool
 
-    def chunk_read_counts(self, pos0, t: int, page_size: int,
+    @staticmethod
+    def chunk_read_counts(pos0, t: int, page_size: int,
                           table_len: int) -> dict:
         """What :meth:`forward_chunk_paged` gathers for a dispatch whose
         rows' chunks of ``t`` tokens start at ``pos0`` (host arithmetic
         of :func:`_attend_key_blocks`' trip count and width, for the
-        engine's span and counters), summed over those rows: the
-        tokens' worth of table slots gathered, and what the rows'
-        whole tables hold."""
+        engine's span and counters; any mixer that walks its rows' pages
+        by :func:`_key_block_pages` reads the same), summed over those
+        rows: the tokens' worth of table slots gathered, and what the
+        rows' whole tables hold."""
         width = _key_block_pages(page_size, table_len) * page_size
         whole = table_len * page_size
         reach = int(np.max(pos0)) + int(t)

@@ -314,6 +314,25 @@ def serving_engine_instruments(service: str = "engine",
             "Decode rows dispatched at or over the length from which "
             "those layers select (under it they read everything)",
             labelnames=lbl).labels(service),
+        routed_assignments_held_total=r.counter(
+            "bigdl_serving_routed_assignments_held_total",
+            "Assignments of live decode rows to experts held here, over "
+            "the routed layers of the decode steps dispatched (a model "
+            "with routed experts; counted by the step's program)",
+            labelnames=lbl).labels(service),
+        routed_experts_touched_total=r.counter(
+            "bigdl_serving_routed_experts_touched_total",
+            "Held experts some live decode row chose, over those layers "
+            "and steps", labelnames=lbl).labels(service),
+        routed_expert_load_max_total=r.counter(
+            "bigdl_serving_routed_expert_load_max_total",
+            "Rows of the fullest held expert, summed over those layers "
+            "and steps", labelnames=lbl).labels(service),
+        routed_expert_slots_total=r.counter(
+            "bigdl_serving_routed_expert_slots_total",
+            "Experts held, over those layers and steps (what touched "
+            "and mean load are shares of)",
+            labelnames=lbl).labels(service),
         prefix_host_hits_total=r.counter(
             "bigdl_serving_prefix_host_hits_total",
             "Prefix-cache hits served from the host tier (row demoted "

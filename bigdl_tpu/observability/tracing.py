@@ -72,6 +72,13 @@ DEVICE_SCOPES = (
     "gdn/step", "gdn/chunk",                # the gated delta rule
     "lightning/step", "lightning/chunk",    # fixed-decay linear attention
     "sparse/select", "sparse/attend",       # block-sparse attention
+    "mla/expand",       # latent attention: a chunk's keys and values made
+                        # of the gathered latents
+    "mla/absorb",       # ... the decode step's two absorbed products
+    "moe/route",        # routed experts: scores, top-k, gates, the order of
+                        # the assignments that fall on held experts
+    "moe/experts",      # ... the held experts' batched products
+    "moe/shared",       # ... the shared expert beside them
     "optim/loss",       # the criterion
     "optim/update",     # decay, clipping, the method's update, masters' cast
     "bigdl/grad_reduce_scatter", "bigdl/weight_all_gather",

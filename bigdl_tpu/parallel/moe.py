@@ -14,6 +14,16 @@ Expert parallelism: inside ``shard_map`` over an 'expert' axis, each
 device holds E/n experts and T/n tokens; ``moe_spmd`` dispatches with
 ``lax.all_to_all`` (source-shard buffers travel to the expert's owner and
 back), the canonical MoE comm pattern over ICI.
+
+``MoEMLP`` DROPS what an expert gets over its capacity and is reached from
+``TransformerBlock`` alone (training-time routing with a softmax gate). It
+is not the layer the paged engine serves: published sparse models choose
+per token, drop nothing, score by sigmoid with a selection bias and add a
+shared expert, and a chip of an expert-parallel deployment holds a share of
+the experts. That layer is ``bigdl_tpu.nn.RoutedExperts``
+(``nn/routed_experts.py``; ``models/hybrid.py`` puts it in a block); it runs
+on one chip without an exchange, and ``moe_spmd``'s ``all_to_all`` is not
+wired to it (ROADMAP Reach: experts across chips).
 """
 
 from __future__ import annotations

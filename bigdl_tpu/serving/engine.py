@@ -472,6 +472,10 @@ class ContinuousBatchingEngine:
                 and self._step_counts([0], 1, 1) is None:
             self._step_counts = None
         self._decode_kv_read = self._decode_kv_table = 0
+        #: layers that route their tokens over experts: the decode step
+        #: hands their routing counts out behind the tokens (0: the
+        #: programs of a model without them are what they were)
+        self._routed = int(getattr(model, "routed_layers", 0))
         if self._lane_state:
             refused = {
                 "draft": (draft is not None, "speculation needs the "
@@ -1082,6 +1086,10 @@ class ContinuousBatchingEngine:
         attend = self._decode_attention = self._decode_form(self._kv_pool)
 
         lane_state = self._lane_state
+        # rows that are no request move no lane's state and take no
+        # expert's slot: such models are told which rows are live
+        routed = bool(self._routed)
+        masked = lane_state or routed
 
         @scoped("sample")
         def sample0(logits, rng, temperature):
@@ -1098,13 +1106,18 @@ class ContinuousBatchingEngine:
             # all-scratch table (SCRATCH_PAGE padding) so their junk
             # write lands on page 0, never on a live page. A lane's
             # recurrent state has no scratch to park on: ``active``
-            # (lane-state models only) masks it inside the program
-            kw = {"active": active[0]} if lane_state else {}
+            # (lane-state and routed models) masks it inside the program
+            kw = {"active": active[0]} if masked else {}
+            if routed:
+                kw["routing"] = True
             with bind(model, p, bufs, False, None):
-                logits, pool = model.decode_step_paged(
+                logits, pool, *counts = model.decode_step_paged(
                     tok, pos, pool, tables, decode_attention=attend,
                     **kw)
-            return sample0(logits, rng, temperature), pool
+            nxt = sample0(logits, rng, temperature)
+            # a routed model's counts ride behind the tokens: one vector,
+            # one transfer
+            return (jnp.concatenate([nxt, *counts]) if counts else nxt), pool
 
         def chunk(p, bufs, ids, pool, tables, pos0, last_idx, *lanes):
             # the ragged admission prefill, writing through each row's
@@ -1453,6 +1466,7 @@ class ContinuousBatchingEngine:
         lanes_arg, active_arg = (), ()
         if self._lane_state:
             lanes_arg = (self._h2d(jnp.zeros((rows,), jnp.int32)),)
+        if self._lane_state or self._routed:
             active_arg = (self._h2d(jnp.zeros((S,), bool)),)
         progs = {"prefill": [(self._chunk_jit,
                               (self._params, self._buffers, ids,
@@ -3438,7 +3452,7 @@ class ContinuousBatchingEngine:
                         **self._step_read(pos),
                         **self._selected_read(pos[active])) as disp:
             active_arg = ()
-            if self._lane_state:
+            if self._lane_state or self._routed:
                 live = np.zeros((self.max_slots,), bool)
                 live[active] = True
                 active_arg = (self._h2d(live),)
@@ -3449,6 +3463,9 @@ class ContinuousBatchingEngine:
             self._warm.add("step")
             with trace.span("serving/fetch_tokens"):
                 nxt_np = np.asarray(nxt)   # blocks on the fused step
+            if self._routed:
+                disp.attrs.update(self._routing_read(
+                    nxt_np[self.max_slots:]))
         now = time.monotonic()
         # the span's warm-only wall to ledger, cost model, and loop
         # busy — one measurement, three reconciling views
@@ -3514,6 +3531,21 @@ class ContinuousBatchingEngine:
         self._ins.selected_gathered_tokens_total.inc(read["gathered_tokens"])
         self._ins.selected_cached_tokens_total.inc(read["cached_tokens"])
         self._ins.selecting_decode_rows_total.inc(read["selecting_rows"])
+        return read
+
+    def _routing_read(self, counts) -> dict:
+        """Attributes for the decode span of a model with routed experts:
+        what the step's program counted over its routed layers, fetched
+        behind the tokens (``HybridDecoderLM.decode_step_paged``): the
+        live rows' assignments that fell on held experts, the held
+        experts some row chose, the fullest expert's rows a layer
+        (summed), and layers x experts held; also summed into the
+        instruments."""
+        read = dict(zip(("assignments_held", "experts_touched",
+                         "expert_load_max", "expert_slots"),
+                        (int(c) for c in counts)))
+        for name, count in read.items():
+            getattr(self._ins, f"routed_{name}_total").inc(count)
         return read
 
     def _decode_all_spec(self, active: List[int]) -> None:

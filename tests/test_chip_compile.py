@@ -458,6 +458,77 @@ def test_sala_program_compiles_and_holds_its_pool_in_place(
     assert m.temp_size_in_bytes < e["reserve_bytes"] // 4, m
 
 
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_latent_and_routed_programs_compile_and_hold_their_pool_in_place(
+        topo, one_chip, program):
+    """The first two layers of the latent-attention decoder with routed
+    experts (a dense layer and a routed one holding 32 of 256 experts) at
+    its published widths and its cell's geometry, built as shapes from the
+    benchmark's configuration: the absorbed decode step over 32 lanes of
+    16384 positions and the expanded 2 x 256 prefill chunk compile for the
+    chip, and the latent leaves are donated and aliased in full at the
+    bytes the adapter budgets a page with (rows of 640: whole lanes). A
+    leaf whose rows are the 576 elements alone compiles to a copy of every
+    layer's whole leaf in the chunk and does not fit (PERF.md, PR 48)."""
+    from benchmark import harness
+    from benchmark.models import joyai_llm_flash as adapter
+    from bigdl_tpu.nn.module import bind
+
+    cfg = harness.load_json(harness.HERE, "configs", "joyai-llm-flash.json")
+    cfg["sizes"] = dict(cfg["sizes"], num_hidden_layers=2, layers_held=[0, 2])
+    e = cfg["engine"]
+    slots, rows, chunk, page = (e["max_slots"], e["prefill_rows"],
+                                e["prefill_chunk"], e["page_size"])
+    ctx = cfg["sizes"]["max_position_embeddings"]
+    model = adapter.model_shapes(cfg)
+    assert [(b.kind, b.routed) for b in model._blocks()] == [
+        ("latent_attention", False), ("latent_attention", True)]
+
+    def shaped(path, a):
+        name = jax.tree_util.keystr(path)
+        wide = "router" in name or "select_bias" in name
+        return jax.ShapeDtypeStruct(
+            a.shape, jnp.float32 if wide else jnp.bfloat16, sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(shaped, model.params_dict())
+    n_pages = 1 + slots * ctx // page
+    pool = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: model.init_page_pool(n_pages, page,
+                                                    dtype=jnp.bfloat16)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def step(p, tok, pos, pool, tables, active):
+        with bind(model, p, {}, False, None):
+            logits, pool, counts = model.decode_step_paged(
+                tok, pos, pool, tables, active=active, routing=True)
+        return jnp.concatenate([jnp.argmax(logits, -1).astype(jnp.int32),
+                                counts]), pool
+
+    def prefill(p, ids, pool, tables, pos0, last):
+        with bind(model, p, {}, False, None):
+            return model.prefill_chunk_at_paged(ids, pool, tables, pos0, last)
+
+    if program == "step":
+        compiled = jax.jit(step, donate_argnums=(3,)).lower(
+            params, i32(slots), i32(slots), pool, i32(slots, ctx // page),
+            jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
+    else:
+        compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
+            params, i32(rows, chunk), pool, i32(rows, ctx // page), i32(rows),
+            i32(rows)).compile()
+    m = _fits(compiled)
+    pages = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                for a in jax.tree.leaves(pool))
+    assert pool["lanes"] == [] and pages == n_pages * adapter.cache_geometry(
+        cfg)["page_device_bytes"]
+    assert pages <= m.alias_size_in_bytes < 1.01 * pages
+    # the step's scratch is the gathered rows of one layer (0.67 GB) and
+    # little else; the chunk gathers a key block at a time
+    assert m.temp_size_in_bytes < e["reserve_bytes"] // (
+        2 if program == "step" else 16), m
+
+
 # ------------------------------------------------------- across four chips
 def test_tensor_parallel_decode_step_compiles_on_four_chips(topo,
                                                             mesh_engine):

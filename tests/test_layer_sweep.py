@@ -278,6 +278,10 @@ FIXTURES = {
     "blocksparseattention": (lambda: nn.BlockSparseAttention(
         8, 4, 2, 2, kernel_size=4, kernel_stride=2, block_size=4, topk=4,
         init_blocks=1, window_size=4, dense_len=8), lambda: _f(2, 21, 8)),
+    "latentattention": (lambda: nn.LatentAttention(8, 2, 6, 4, 4, 2, 4),
+                        lambda: _f(2, 9, 8)),
+    "routedexperts": (lambda: nn.RoutedExperts(8, 6, 8, 2, held=(2, 4)),
+                      lambda: _f(2, 9, 8)),
     "transformer_block": (lambda: nn.TransformerBlock(8, 2),
                           lambda: _f(2, 5, 8)),
 }
