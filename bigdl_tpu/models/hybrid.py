@@ -329,15 +329,11 @@ class HybridDecoderLM(Module):
         reads of what its rows' tables hold (the mixer's
         ``step_read_counts``; host arithmetic for the engine's span and
         counters); None when no layer is of those kinds."""
-        full = self._blocks(FULL)
-        if full:
-            return full[0].mixer.step_read_counts(
-                pos, page_size, table_pages, decode_attention)
-        latent = self._blocks(LATENT)
-        if latent:
-            return latent[0].mixer.step_read_counts(pos, page_size,
-                                                    table_pages)
-        return None
+        whole = self._blocks(FULL, LATENT)
+        if not whole:
+            return None
+        return whole[0].mixer.step_read_counts(pos, page_size, table_pages,
+                                               decode_attention)
 
     def analytic_flops(self, tokens: int, context: int) -> float:
         """Forward FLOPs for ``tokens`` positions over ``context``
@@ -486,7 +482,8 @@ class HybridDecoderLM(Module):
             inp = blk._enter(x)
             if blk.kind == LATENT:
                 mixed, pages[i_page] = blk.mixer.forward_step_paged(
-                    inp, pages[i_page], tables, pos)
+                    inp, pages[i_page], tables, pos,
+                    decode_attention=decode_attention)
                 i_page += 1
             elif blk.kind == FULL:
                 mixed, pages[i_page] = blk.mixer.forward_step_paged(

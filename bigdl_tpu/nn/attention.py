@@ -455,6 +455,14 @@ _DECODE_ATTENTION = {"rows": _attend_pages_rows,
 _DECODE_FORMS = sorted((*_DECODE_ATTENTION, "kernel"))
 
 
+def _known_decode_form(word) -> None:
+    """Refuse a ``decode_attention`` that names no form."""
+    if word not in _DECODE_FORMS:
+        raise ValueError(
+            f"decode_attention must be one of {_DECODE_FORMS}, got "
+            f"{word!r}")
+
+
 def rotary_embedding(x, positions, base: float = 10000.0):
     """RoPE: rotate interleaved feature pairs of x (..., T, D) by
     per-position angles (RoFormer). ``positions`` is (T,) absolute
@@ -836,10 +844,7 @@ class MultiHeadAttention(Module):
         (``ContinuousBatchingEngine._decode_form``).
         :meth:`forward_chunk_paged`, many query tokens a row, attends
         by key blocks."""
-        if decode_attention not in _DECODE_FORMS:
-            raise ValueError(
-                f"decode_attention must be one of {_DECODE_FORMS}, got "
-                f"{decode_attention!r}")
+        _known_decode_form(decode_attention)
         b = x_t.shape[0]
         with jax.named_scope("attn/qkv"):
             qkv = self.qkv(x_t.reshape(b, self.embed_dim)).reshape(b, 1, -1)
@@ -910,7 +915,8 @@ class MultiHeadAttention(Module):
                 len(pos0) * min(-(-reach // width) * width, whole),
                 "kv_table_tokens": len(pos0) * whole}
 
-    def step_read_counts(self, pos, page_size: int, table_len: int,
+    @staticmethod
+    def step_read_counts(pos, page_size: int, table_len: int,
                          decode_attention: str = "rows") -> dict:
         """What :meth:`forward_step_paged` reads of the pool for a
         dispatch whose rows stand at ``pos``, idle lanes (``pos`` 0)

@@ -32,6 +32,15 @@ the latent itself, ``score = (q~_h . c_kv + q_rope_h . k_rope) / sqrt(..)``,
 ``o~_h = sum p c_kv`` and ``o_h = W_kvb^{V,h} o~_h``: one query token a row
 never forms a key or a value. Matrix operands are the weights' dtype with
 float32 accumulation; norms, rotation, softmax and the output are float32.
+
+What the absorbed step READS is the engine's to say
+(``forward_step_paged(decode_attention=)``). On one TPU chip the
+paged-attention kernel (``ops/paged_attention.py``, its one-leaf form)
+walks each row's table to the row's own position and reads every cached row
+once, from the leaf where it lies, for the score and for the value product
+alike. Everywhere else every slot of every table is gathered into a copy
+first and the copy is read twice: the form off a TPU, and the reference the
+tests hold the kernel to.
 """
 
 from __future__ import annotations
@@ -43,7 +52,7 @@ import jax.numpy as jnp
 
 from bigdl_tpu.nn.attention import (
     MultiHeadAttention, RMSNorm, _gather_pages, _key_block_pages,
-    rotary_embedding_tokens,
+    _known_decode_form, rotary_embedding_tokens,
 )
 from bigdl_tpu.nn.gated_delta import project
 from bigdl_tpu.nn.linear import Linear
@@ -51,6 +60,18 @@ from bigdl_tpu.nn.module import Module
 
 #: a TPU tile's minor width
 LANES = 128
+
+
+def _attend_rows(q, rows, pos, scale: float):
+    """Whole-row queries ``q`` (B, H, C) over every lane's gathered rows
+    (B, N, C), keys past ``pos`` (B,) masked: ``softmax(scale * q . rows)
+    @ rows``, float32 (B, H, C). A cached row is key and value at once."""
+    s = jnp.einsum("bhc,bnc->bhn", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    live = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
+    p = jax.nn.softmax(jnp.where(live[:, None], s, -jnp.inf), -1)
+    return jnp.einsum("bhn,bnc->bhc", p.astype(rows.dtype), rows,
+                      preferred_element_type=jnp.float32)
 
 
 class LatentAttention(Module):
@@ -140,17 +161,27 @@ class LatentAttention(Module):
             return leaf.at[pg, positions % ps].set(rows.astype(leaf.dtype))
 
     # ------------------------------------------------------------- the forms
-    def forward_step_paged(self, x_t, leaf, tables, pos):
+    def forward_step_paged(self, x_t, leaf, tables, pos,
+                           decode_attention="rows"):
         """One token a row, weight-absorbed: ``x_t`` (B, embed) at ``pos``
-        (B,). The row is written, every slot of every table is gathered as
-        rows (the clipped take of :func:`_gather_pages`; slots past ``pos``
-        are masked), and the query meets the latent itself."""
-        b = x_t.shape[0]
+        (B,). The row is written and the query meets the latent itself,
+        every head against whole rows of the leaf. ``decode_attention``
+        (``MultiHeadAttention.forward_step_paged``'s word, decided by the
+        engine for the whole model) names how the rows are read.
+        ``"kernel"`` gathers nothing: ``ops/paged_attention.py`` walks each
+        row's table to the row's own ``pos`` and reads each page from the
+        leaf where it lies, once for both products (one TPU chip). Any other
+        word gathers every slot of every table as rows first (the clipped
+        take of :func:`_gather_pages`; slots past ``pos`` are masked): the
+        form off a TPU, and the kernel's parity reference."""
+        _known_decode_form(decode_attention)
+        in_place = decode_attention == "kernel"
         r, dtype = self.kv_lora_rank, leaf.dtype
         q_nope, q_rope = self._queries(x_t, pos)
         leaf = self._write(leaf, self._latent(x_t, pos)[:, None], tables,
                            pos[:, None])
-        rows = _gather_pages(leaf, tables)                   # (B, N, row_width)
+        if not in_place:
+            rows = _gather_pages(leaf, tables)               # (B, N, row_width)
         w = self._kv_b_heads()
         with jax.named_scope("attn/qkv"), jax.named_scope("mla/absorb"):
             q_lat = jnp.einsum("bhd,hdc->bhc", q_nope.astype(dtype),
@@ -159,15 +190,17 @@ class LatentAttention(Module):
         with jax.named_scope("attn/attend"):
             q = self._whole_lanes(
                 jnp.concatenate([q_lat, q_rope], -1)).astype(dtype)
-            s = jnp.einsum("bhc,bnc->bhn", q, rows,
-                           preferred_element_type=jnp.float32) * self.scale
-            live = jnp.arange(rows.shape[1])[None, :] <= pos[:, None]
-            p = jax.nn.softmax(jnp.where(live[:, None], s, -jnp.inf), -1)
-            # over the whole row: the columns behind the latent are dropped
-            # after the product, where slicing the gathered rows would copy
-            # them
-            o_lat = jnp.einsum("bhn,bnc->bhc", p.astype(dtype), rows,
-                               preferred_element_type=jnp.float32)[..., :r]
+            # over the whole row either way: the columns behind the latent
+            # are dropped after the product, where slicing the cached rows
+            # would copy them
+            if in_place:
+                from bigdl_tpu.ops.paged_attention import (
+                    paged_latent_attention)
+
+                o_lat = paged_latent_attention(q, leaf, tables, pos,
+                                               self.scale)[..., :r]
+            else:
+                o_lat = _attend_rows(q, rows, pos, self.scale)[..., :r]
         with jax.named_scope("attn/out"), jax.named_scope("mla/absorb"):
             o = jnp.einsum("bhc,hdc->bhd", o_lat.astype(dtype),
                            w[:, self.nope:],
@@ -259,9 +292,7 @@ class LatentAttention(Module):
     #: blocks as the full-attention chunk's
     chunk_read_counts = staticmethod(MultiHeadAttention.chunk_read_counts)
 
-    def step_read_counts(self, pos, page_size: int, table_len: int) -> dict:
-        """What :meth:`forward_step_paged` reads of the pool for a dispatch
-        whose rows stand at ``pos``: every slot of every table (the
-        gathered form)."""
-        whole = len(pos) * table_len * page_size
-        return {"kv_read_tokens": whole, "kv_table_tokens": whole}
+    #: what :meth:`forward_step_paged` reads under each word: the kernel
+    #: the pages up to each row's ``pos``, the gathered form every slot of
+    #: every table, as the full-attention step does
+    step_read_counts = staticmethod(MultiHeadAttention.step_read_counts)

@@ -2,12 +2,13 @@
 
 One query token a lane attends the pages that lane holds, read from the
 page pool's leaves WHERE THEY LIE: a leaf stays (max_pages, page_size,
-H_kv * D) in HBM as ``MultiHeadAttention.init_page_pool`` lays it out, and
-the kernel walks each lane's block table to the lane's own length. The
-XLA form (``nn/attention.py _write_kv_paged`` + ``_attend_pages_rows``)
-gathers every slot of every lane's table into a new array first, scratch
-slots and all, and reads that back: 35 times what the lanes hold at one
-cell's load and 9 times at the other's (PERF.md, PR 44).
+columns) in HBM as ``init_page_pool`` lays it out, and the kernel walks
+each lane's block table to the lane's own length. The XLA forms
+(``nn/attention.py _write_kv_paged`` + ``_attend_pages_rows``;
+``nn/latent_attention.py _attend_rows`` over ``_gather_pages``) gather
+every slot of every lane's table into a new array first, scratch slots and
+all, and read that back: 35 and 9 times what the lanes hold at two cells'
+loads (PERF.md, PR 44), 13 times at a third's, twice over (PR 49).
 
   grid = (lanes,), one lane a step, in order ("arbitrary": the page
   buffers and the slot that is being filled carry over from lane to lane)
@@ -19,12 +20,26 @@ cell's load and 9 times at the other's (PERF.md, PR 44).
   their way; scores in float32, keys past ``pos`` masked, running maximum,
   sum and float32 accumulator, ``p`` cast to V's dtype before P.V
 
-q meets K and V as the XLA rows form does: spread onto a block diagonal
-(H, H_kv * D), so both products run over whole rows of a page as stored
-and no 64-wide head is ever sliced out of a 128-lane tile; each head keeps
-its own D columns of the (H, H_kv * D) result. The same keys, the same
-dtypes at the same places as ``_attend_pages_rows``; only the order of the
-float32 sums differs.
+One walk, two forms of pool entry, told apart by the entry's shape when the
+program is traced:
+
+* **a K and V pair** (``paged_attention``; full attention, H_kv * D
+  columns): q meets K and V as the XLA rows form does, spread onto a block
+  diagonal (H, H_kv * D), so both products run over whole rows of a page as
+  stored and no 64-wide head is ever sliced out of a 128-lane tile; each
+  head keeps its own D columns of the (H, H_kv * D) result. Rounds of
+  ``BLOCK_TOKENS`` keys, a buffer pair a leaf.
+* **ONE leaf whose rows are key and value at once** (``
+  paged_latent_attention``; latent attention's absorbed step): the query is
+  already whole rows of the leaf and there is no kv-head axis, so no block
+  diagonal surrounds the call; a page is copied ONCE and both products read
+  that copy. A page is a small copy here (20 KB at 640 bfloat16 columns
+  against 41 and 123 KB), so what a round costs beside its copies weighs
+  more, and a round is as wide as a VMEM budget allows
+  (``row_block_pages``); its live pages come page by page in a loop.
+
+The same keys, the same dtypes at the same places as the rows forms; only
+the order of the float32 sums differs.
 
 On CPU tests the kernel runs in the TPU interpreter
 (``ops/flash_attention.py default_interpret``).
@@ -57,6 +72,27 @@ def block_pages(page_size: int, table_len: int) -> int:
     return max(1, min(table_len, BLOCK_TOKENS // page_size))
 
 
+#: what the two page buffers of a ONE-leaf round may take of VMEM (a pair's
+#: round stays ``BLOCK_TOKENS``)
+ROUND_BUFFER_BYTES = 4 << 20
+#: ... and the most keys such a round holds (2048 read slower at 4 and at 13
+#: live lanes of 32: PERF.md, PR 49)
+ROUND_TOKENS_MAX = 1024
+
+
+def row_block_pages(leaf, table_len: int) -> int:
+    """Pages a round holds where the pool's entry is ONE leaf (max_pages,
+    page_size, C): as many keys as two buffers of ``ROUND_BUFFER_BYTES``
+    take, a power of two times ``BLOCK_TOKENS`` and at most
+    ``ROUND_TOKENS_MAX``; whole pages, no more than a table has."""
+    _, page_size, cols = leaf.shape
+    fit = ROUND_BUFFER_BYTES // (2 * cols * jnp.dtype(leaf.dtype).itemsize)
+    tokens = BLOCK_TOKENS
+    while 2 * tokens <= min(fit, ROUND_TOKENS_MAX):
+        tokens *= 2
+    return max(1, min(table_len, tokens // page_size))
+
+
 def supported(leaf) -> bool:
     """Whether the kernel can read a pool leaf (max_pages, page_size,
     H_kv * D) as it lies: a page has to be whole tiles of the chip's
@@ -70,9 +106,19 @@ def supported(leaf) -> bool:
             and width % 128 == 0 and page_size % rows == 0)
 
 
-def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
-            k_buf, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref,
-            *, table_len: int, page_size: int, pages: int, scale: float):
+def _kernel(pos_ref, tab_ref, q_ref, *refs, table_len: int, page_size: int,
+            pages: int, scale: float, one_leaf: bool):
+    """``refs``: the leaves in HBM, the output block, a pair of page
+    buffers a leaf, then the semaphores (a leaf's copies, a buffer), the
+    buffer the next lane starts in, and the running maximum, sum and
+    accumulator. ``one_leaf``: the values are the keys' rows (a latent
+    layer's pool), so a page is copied once and both products read it."""
+    if one_leaf:
+        v_hbm, o_ref, v_buf, sems, slot_ref, m_ref, l_ref, acc_ref = refs
+        k_hbm, k_buf = v_hbm, v_buf
+    else:
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, slot_ref, m_ref, l_ref,
+         acc_ref) = refs
     b = pl.program_id(0)
     lanes = pl.num_programs(0)
     width = pages * page_size
@@ -81,20 +127,30 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
         # ``act`` on the copies of round ``rnd`` of ``lane`` into buffer
         # ``slot``: the pages that hold a key at or before the lane's pos
         left = pos_ref[lane] + 1 - rnd * width
-        for i in range(pages):
-            @pl.when(i * page_size < left)
-            def _():
-                page = tab_ref[lane * table_len + rnd * pages + i]
-                rows = pl.ds(i * page_size, page_size)
-                act(pltpu.make_async_copy(
-                    v_hbm.at[page], v_buf.at[slot, rows], sems.at[1, slot]))
 
-                # a lane's only key needs no score (``lone_key``)
-                @pl.when(pos_ref[lane] > 0)
-                def _():
-                    act(pltpu.make_async_copy(
-                        k_hbm.at[page], k_buf.at[slot, rows],
-                        sems.at[0, slot]))
+        def page_copies(i, rows):
+            page = tab_ref[lane * table_len + rnd * pages + i]
+            act(pltpu.make_async_copy(
+                v_hbm.at[page], v_buf.at[slot, rows], sems.at[1, slot]))
+            if one_leaf:
+                return
+
+            # a lane's only key needs no score (``lone_key``)
+            @pl.when(pos_ref[lane] > 0)
+            def _():
+                act(pltpu.make_async_copy(
+                    k_hbm.at[page], k_buf.at[slot, rows], sems.at[0, slot]))
+
+        if one_leaf:
+            # a loop over the live pages, not unrolled: a round is up to 64
+            # pages here, and every copy traced is set-up (PERF.md, PR 49)
+            live = jnp.clip((left + page_size - 1) // page_size, 0, pages)
+            jax.lax.fori_loop(0, live, lambda i, _: page_copies(i, pl.ds(
+                pl.multiple_of(i * page_size, page_size), page_size)), None)
+        else:
+            for i in range(pages):
+                pl.when(i * page_size < left)(functools.partial(
+                    page_copies, i, pl.ds(i * page_size, page_size)))
 
     start = lambda copy: copy.start()
     wait = lambda copy: copy.wait()
@@ -103,7 +159,8 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
     def _():
         # rows of a buffer no copy of this call has filled are masked
         # keys: their p is 0, and 0 times what lies there must be 0
-        k_buf[...] = jnp.zeros_like(k_buf)
+        if not one_leaf:
+            k_buf[...] = jnp.zeros_like(k_buf)
         v_buf[...] = jnp.zeros_like(v_buf)
         slot_ref[0] = 0
         each_page(0, 0, 0, start)
@@ -163,46 +220,64 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
     o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("head_dim", "interpret"))
-def _call(q_bd, k_pages, v_pages, tables, pos, head_dim, interpret):
-    """The kernel over a block-diagonal ``q_bd`` (B, H', H_kv * D), H' whole
-    sublane tiles of rows; (B, H', H_kv * D) in V's dtype. Jitted on its
-    own so that a model's layers share ONE trace and one Mosaic compile of
-    it: traced a layer, 36 layers' cold set-up read 180 s against 133
-    (PERF.md, PR 44)."""
-    lanes, heads, cols = q_bd.shape
-    _, page_size, _ = k_pages.shape
+def _walk(q, leaves, tables, pos, *, pages: int, scale: float, interpret,
+          one_leaf: bool):
+    """The kernel over ``q`` (B, H', C), H' whole sublane tiles of rows and
+    C a row of the leaves, ``pages`` pages a round: the K and V pair, or
+    (``one_leaf``) the one leaf whose rows are both; (B, H', C) in the
+    values' dtype."""
+    lanes, heads, cols = q.shape
+    page_size = leaves[0].shape[1]
     table_len = tables.shape[1]
-    pages = block_pages(page_size, table_len)
     width = pages * page_size
     lane_block = pl.BlockSpec((1, heads, cols), lambda b, *_: (b, 0, 0))
     kernel = functools.partial(
         _kernel, table_len=table_len, page_size=page_size, pages=pages,
-        scale=1.0 / math.sqrt(head_dim))
+        scale=scale, one_leaf=one_leaf)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(lanes,),
-            in_specs=[lane_block,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[lane_block, *(pl.BlockSpec(memory_space=pl.ANY)
+                                    for _ in leaves)],
             out_specs=lane_block,
             scratch_shapes=[
-                pltpu.VMEM((2, width, cols), k_pages.dtype),
-                pltpu.VMEM((2, width, cols), v_pages.dtype),
+                *(pltpu.VMEM((2, width, cols), leaf.dtype)
+                  for leaf in leaves),
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.SMEM((1,), jnp.int32),
                 pltpu.VMEM((heads, 1), jnp.float32),
                 pltpu.VMEM((heads, 1), jnp.float32),
                 pltpu.VMEM((heads, cols), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q_bd.shape, v_pages.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, leaves[-1].dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="paged_attention",
+        name="paged_latent_attention" if one_leaf else "paged_attention",
     )(pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
-      q_bd, k_pages, v_pages)
+      q, *leaves)
+
+
+@functools.partial(jax.jit, static_argnames=("head_dim", "interpret"))
+def _call(q_bd, k_pages, v_pages, tables, pos, head_dim, interpret):
+    """The pair's form over a block-diagonal ``q_bd`` (B, H', H_kv * D).
+    Jitted on its own so that a model's layers share ONE trace and one
+    Mosaic compile of it: traced a layer, 36 layers' cold set-up read
+    180 s against 133 (PERF.md, PR 44)."""
+    return _walk(q_bd, (k_pages, v_pages), tables, pos,
+                 pages=block_pages(k_pages.shape[1], tables.shape[1]),
+                 scale=1.0 / math.sqrt(head_dim), interpret=interpret,
+                 one_leaf=False)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _call_rows(q, pages, tables, pos, scale, interpret):
+    """The one-leaf form over whole-row queries ``q`` (B, H', C); jitted on
+    its own as :func:`_call` is."""
+    return _walk(q, (pages,), tables, pos,
+                 pages=row_block_pages(pages, tables.shape[1]), scale=scale,
+                 interpret=interpret, one_leaf=True)
 
 
 def paged_attention(q, k_pages, v_pages, tables, pos,
@@ -245,3 +320,23 @@ def paged_attention(q, k_pages, v_pages, tables, pos,
     o_own = jnp.where(on_diag, o_full, jnp.zeros((), o_full.dtype))
     return jnp.einsum("bhc,dc->bhd", o_own[:, :h],
                       jnp.asarray(spread, o_full.dtype), precision=exact)
+
+
+def paged_latent_attention(q, pages, tables, pos, scale: float,
+                           interpret: Optional[bool] = None):
+    """One query token a lane over the pages its block table names, where
+    a cached row is key and value at once (``nn/latent_attention.py``'s
+    absorbed decode step): ``q`` (B, H, C) whole rows of the pool's ONE
+    leaf ``pages`` (max_pages, page_size, C), ``tables`` and ``pos`` as
+    :func:`paged_attention`'s. ``softmax_i(scale * q . row_i) @ rows`` over
+    the keys ``i <= pos[b]``, (B, H, C) in the leaf's dtype.
+
+    No block diagonal surrounds it: the heads have no axis in the leaf. A
+    round's pages are copied once and both products read that copy."""
+    h = q.shape[1]
+    if interpret is None:
+        interpret = default_interpret()
+    # whole sublane tiles of query rows, as above (the served 32 heads are)
+    rows = 32 // jnp.dtype(q.dtype).itemsize
+    q = jnp.pad(q, ((0, 0), (0, -h % rows), (0, 0)))
+    return _call_rows(q, pages, tables, pos, float(scale), interpret)[:, :h]

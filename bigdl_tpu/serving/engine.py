@@ -1046,13 +1046,16 @@ class ContinuousBatchingEngine:
         (``"heads"``: the rows form would sum over the sharded heads
         dimension, two collectives a layer). On one TPU chip a kernel
         reads the pages where they lie (``"kernel"``,
-        ops/paged_attention.py), where every full-attention layer's
-        pool (a tuple a layer; a selecting layer's is a dict and has
-        its own step) is the float pair of whole tiles: the int8
-        4-tuple and a toy model's narrow pages are not. Everywhere
-        else every table is gathered and K and V stay rows of
-        H_kv * D under a block-diagonal q (``"rows"``: nn/attention.py
-        _attend_pages_rows)."""
+        ops/paged_attention.py), where EVERY layer that takes the word
+        can be read so: a full-attention layer's pool (a tuple a layer)
+        the float pair of whole tiles, a latent layer's (one bare leaf)
+        whole tiles of floats; the int8 4-tuple and a toy model's
+        narrow pages are not, and one such layer keeps the whole model
+        off the kernel. A selecting layer's pool is a dict and has its
+        own step. Everywhere else every table is gathered: K and V
+        stay rows of H_kv * D under a block-diagonal q, a latent
+        layer's rows meet its whole-row q (``"rows"``: nn/attention.py
+        _attend_pages_rows, nn/latent_attention.py)."""
         if self.mesh is not None:
             return "heads"
         if jax.default_backend() != "tpu":
@@ -1060,9 +1063,25 @@ class ContinuousBatchingEngine:
         # (importing Pallas takes a second: only where the kernel can run)
         from bigdl_tpu.ops.paged_attention import supported
 
-        full = [g for g in page_leaves(pool) if isinstance(g, tuple)]
-        if full and all(len(g) == 2 and supported(g[0]) for g in full):
+        def readable(entry):
+            if isinstance(entry, tuple):
+                return len(entry) == 2 and supported(entry[0])
+            return supported(entry)
+
+        told = [g for g in page_leaves(pool) if not isinstance(g, dict)]
+        kept = [(i, g) for i, g in enumerate(told) if not readable(g)]
+        if told and not kept:
             return "kernel"
+        if kept:
+            # said out loud, once a pool: the gathered form is the slow
+            # one here, and only stats()["paging"] would show it
+            at, entry = kept[0]
+            logging.getLogger(__name__).warning(
+                "engine %s: decode attention keeps the gathered \"rows\" "
+                "form for the whole model: the paged-attention kernel "
+                "cannot read pool entry %d of %d as it lies (%s)",
+                self.service_name, at, len(told), jax.tree.map(
+                    lambda a: f"{a.dtype}{list(a.shape)}", entry))
         return "rows"
 
     def _build_fns(self):

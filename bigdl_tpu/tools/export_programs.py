@@ -96,6 +96,43 @@ def paged_decode_step_program(lanes: int = 8, vocab: int = 50304,
             (params, i32(lanes), i32(lanes), pool, i32(lanes, table_len)))
 
 
+def hybrid_decode_step_program(lanes: int = 16, embed_dim: int = 3840,
+                               heads: int = 30, ctx: int = 4096,
+                               page_size: int = 16,
+                               decode_attention: str = "kernel",
+                               dtype=jnp.bfloat16):
+    """One period of the hybrid decoder (three gated delta-rule layers and
+    a full-attention layer at Olmo-Hybrid's widths) as :func:`
+    paged_decode_step_program` builds GPT-2 Large's step: the decode step
+    over ``lanes`` lanes of ``ctx`` positions, the pool (pages and lane
+    state) donated."""
+    from bigdl_tpu.models.hybrid import HybridDecoderLM
+    from bigdl_tpu.nn.module import abstract_init, bind
+
+    model = abstract_init(lambda: HybridDecoderLM(
+        100352, embed_dim, heads, ("linear_attention",) * 3
+        + ("full_attention",), 11008, ctx, num_kv_heads=heads,
+        linear_heads=heads, linear_key_dim=96, linear_value_dim=192))
+    model.evaluate()
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, dtype), model.params_dict())
+    table_len = ctx // page_size
+    pool = jax.eval_shape(lambda: model.init_page_pool(
+        1 + lanes * table_len, page_size, dtype=dtype, lanes=lanes + 1))
+
+    def step(p, tok, pos, pool, tables, active):
+        with bind(model, p, {}, False, None):
+            logits, pool = model.decode_step_paged(
+                tok, pos, pool, tables, active=active,
+                decode_attention=decode_attention)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    return (jax.jit(step, donate_argnums=(3,)),
+            (params, i32(lanes), i32(lanes), pool, i32(lanes, table_len),
+             jax.ShapeDtypeStruct((lanes,), bool)))
+
+
 def ring_flash_program(n_devices: int = 8, t_per_shard: int = 256,
                        dtype=jnp.bfloat16):
     """Ring attention composed with the flash kernel (trainable custom
@@ -473,3 +510,14 @@ def export_for_tpu(fn, args):
 
     with force_interpret(False):
         return export.export(fn, platforms=["tpu"])(*args)
+
+
+def lower_for_tpu(fn, args) -> str:
+    """``fn.lower(*args).as_text()`` for the TPU from any host, Mosaic
+    kernels compiled in (no debug locations in the StableHLO; a kernel's
+    payload carries its own: ``scripts/mosaic_program_hash.py``)."""
+    from bigdl_tpu.ops.flash_attention import force_interpret
+
+    with force_interpret(False):
+        return fn.trace(*args).lower(lowering_platforms=("tpu",)).as_text()
+

@@ -458,7 +458,7 @@ def test_sala_program_compiles_and_holds_its_pool_in_place(
     assert m.temp_size_in_bytes < e["reserve_bytes"] // 4, m
 
 
-@pytest.mark.parametrize("program", ["step", "chunk"])
+@pytest.mark.parametrize("program", ["step", "chunk", "kernel_step"])
 def test_latent_and_routed_programs_compile_and_hold_their_pool_in_place(
         topo, one_chip, program):
     """The first two layers of the latent-attention decoder with routed
@@ -469,7 +469,12 @@ def test_latent_and_routed_programs_compile_and_hold_their_pool_in_place(
     chip, and the latent leaves are donated and aliased in full at the
     bytes the adapter budgets a page with (rows of 640: whole lanes). A
     leaf whose rows are the 576 elements alone compiles to a copy of every
-    layer's whole leaf in the chunk and does not fit (PERF.md, PR 48)."""
+    layer's whole leaf in the chunk and does not fit (PERF.md, PR 48).
+    ``kernel_step``: the step as an engine builds it on one TPU chip, the
+    Mosaic kernel reading the leaves where they lie: no leaf is copied or
+    transposed on its way into the custom call, no lane's table is gathered
+    (the rows form's 0.67 GB a layer), and its scratch is small."""
+    from bigdl_tpu.ops.flash_attention import force_interpret
     from benchmark import harness
     from benchmark.models import joyai_llm_flash as adapter
     from bigdl_tpu.nn.module import bind
@@ -498,10 +503,13 @@ def test_latent_and_routed_programs_compile_and_hold_their_pool_in_place(
                                                     dtype=jnp.bfloat16)))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
 
+    attend = "kernel" if program == "kernel_step" else "rows"
+
     def step(p, tok, pos, pool, tables, active):
         with bind(model, p, {}, False, None):
             logits, pool, counts = model.decode_step_paged(
-                tok, pos, pool, tables, active=active, routing=True)
+                tok, pos, pool, tables, active=active, routing=True,
+                decode_attention=attend)
         return jnp.concatenate([jnp.argmax(logits, -1).astype(jnp.int32),
                                 counts]), pool
 
@@ -509,24 +517,40 @@ def test_latent_and_routed_programs_compile_and_hold_their_pool_in_place(
         with bind(model, p, {}, False, None):
             return model.prefill_chunk_at_paged(ids, pool, tables, pos0, last)
 
-    if program == "step":
-        compiled = jax.jit(step, donate_argnums=(3,)).lower(
-            params, i32(slots), i32(slots), pool, i32(slots, ctx // page),
-            jax.ShapeDtypeStruct((slots,), bool, sharding=one_chip)).compile()
-    else:
+    if program == "chunk":
         compiled = jax.jit(prefill, donate_argnums=(2,)).lower(
             params, i32(rows, chunk), pool, i32(rows, ctx // page), i32(rows),
             i32(rows)).compile()
+    else:
+        # jax.devices() is the CPU here: steer the kernel to Mosaic
+        with force_interpret(False):
+            compiled = jax.jit(step, donate_argnums=(3,)).lower(
+                params, i32(slots), i32(slots), pool,
+                i32(slots, ctx // page), jax.ShapeDtypeStruct(
+                    (slots,), bool, sharding=one_chip)).compile()
     m = _fits(compiled)
     pages = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                 for a in jax.tree.leaves(pool))
     assert pool["lanes"] == [] and pages == n_pages * adapter.cache_geometry(
         cfg)["page_device_bytes"]
     assert pages <= m.alias_size_in_bytes < 1.01 * pages
-    # the step's scratch is the gathered rows of one layer (0.67 GB) and
-    # little else; the chunk gathers a key block at a time
+    # the gathered step's scratch is the rows of one layer (0.67 GB) and
+    # little else; the chunk gathers a key block at a time, the kernel
+    # nothing
     assert m.temp_size_in_bytes < e["reserve_bytes"] // (
         2 if program == "step" else 16), m
+    text = compiled.as_text()
+    width = model._blocks()[0].mixer.row_width
+    gathers = re.findall(
+        rf"= bf16\[{slots * ctx // page},{page},{width}\]", text)
+    assert ("tpu_custom_call" in text) == (program == "kernel_step")
+    assert bool(gathers) == (program == "step"), gathers[:4]
+    if program == "kernel_step":
+        assert "scatter(" in text
+        # ... as it lies
+        assert not re.findall(
+            rf"= bf16\[{n_pages},{page},{width}\]\S* (?:copy|transpose)\(",
+            text)
 
 
 # ------------------------------------------------------- across four chips

@@ -185,6 +185,107 @@ def test_the_engine_serves_it_and_records_the_routing(built):
         assert total(name) == sum(a[name] for a in spans), name
 
 
+def _serve_tiny(model, prompts, new_tokens, on_tpu, page):
+    """(rows served, stats()["paging"], the decode spans' attributes) of an
+    engine over the tiny model with ``page`` tokens a page; ``on_tpu``: told
+    at construction that its backend is a TPU, which is where it decides
+    how the decode step reads its pages."""
+    from unittest import mock
+
+    from bigdl_tpu import observability as obs
+    from bigdl_tpu.serving import ContinuousBatchingEngine
+
+    began = time.time_ns()
+    with mock.patch.object(jax, "default_backend",
+                           (lambda: "tpu") if on_tpu
+                           else jax.default_backend):
+        eng = ContinuousBatchingEngine(model, max_slots=3, page_size=page,
+                                       prefill_chunk=16, prefill_rows=2)
+    with eng:
+        handles = [eng.submit(p, new_tokens) for p in prompts]
+        rows = [np.asarray(h.result(timeout=300)) for h in handles]
+        paging = eng.stats()["paging"]
+    spans = [r["attrs"] for r in obs.trace.export(
+        names=["serving/decode_dispatch"]) if r["start_ns"] >= began]
+    return rows, paging, spans
+
+
+def test_engine_told_it_is_on_a_tpu_reads_the_latent_pages_by_the_kernel(
+        built):
+    """A latent layer's pool is one bare leaf; where the kernel can read it
+    as it lies (whole tiles of floats: 8 float32 tokens a page, rows of 128)
+    an engine on a TPU takes ``"kernel"`` for the whole model, serves the
+    tokens the rows form serves (here through the interpreter), and its
+    decode spans count each lane's pages up to its position under what the
+    tables hold. On the CPU, and for pages of 4 tokens, it keeps
+    ``"rows"`` and reads every table."""
+    cfg, model, _, _, _ = built
+    rs = np.random.RandomState(4)
+    prompts = [rs.randint(0, 120, n).astype(np.int32) for n in (21, 9)]
+    want, paging, spans = _serve_tiny(model, prompts, 10, False, 8)
+    whole = 3 * paging["table_len"] * 8
+    assert paging["decode_attention"] == "rows"
+    assert spans and all(a["kv_read_tokens"] == whole == a["kv_table_tokens"]
+                         for a in spans)
+    rows, paging, spans = _serve_tiny(model, prompts, 10, True, 8)
+    assert paging["decode_attention"] == "kernel"
+    for got, ref in zip(rows, want):
+        np.testing.assert_array_equal(got, ref)
+    reads = [a["kv_read_tokens"] for a in spans]
+    assert spans and all(a["kv_table_tokens"] == whole for a in spans)
+    # an idle lane one page, a live lane the pages up to where it stands
+    assert all(r % 8 == 0 and 3 * 8 <= r < whole for r in reads), reads
+    both = [a for a in spans if a["rows"] == 2]
+    assert both and all(a["kv_read_tokens"] >= 8 + 16 + 24 for a in both)
+    assert paging["decode_kv_read_tokens"] == sum(reads) \
+        < paging["decode_kv_table_tokens"] == len(spans) * whole
+    _, paging, _ = _serve_tiny(model, prompts[:1], 2, True, 4)
+    assert paging["decode_attention"] == "rows"
+
+
+def test_the_decode_form_is_decided_for_the_model_as_a_whole(built, caplog):
+    """One word reaches every layer that takes it, so one pool entry the
+    kernel cannot read as it lies keeps the whole model on the gathered
+    form, and the engine's log names that entry: a pair beside a latent
+    leaf, both of whole tiles, is the kernel's; a narrow pair, a leaf of 576
+    columns, or int8 codes beside a good leaf is not. A selecting layer's
+    pool (a dict) has its own step and no say."""
+    from unittest import mock
+
+    from bigdl_tpu.serving import ContinuousBatchingEngine
+
+    _, model, _, _, _ = built
+    eng = ContinuousBatchingEngine(model, max_slots=3, page_size=4,
+                                   prefill_chunk=16, prefill_rows=2)
+    sd = lambda cols, dtype="bfloat16", page=16: jax.ShapeDtypeStruct(
+        (9, page, cols), jnp.dtype(dtype))
+    pair, leaf, select = (sd(1280), sd(1280)), sd(640), {"k": sd(64)}
+    cases = [([leaf, leaf], "kernel"), ([pair, leaf], "kernel"),
+             ([select, leaf, pair], "kernel"), ([pair], "kernel"),
+             ([(sd(64), sd(64)), leaf], "rows"), ([pair, sd(576)], "rows"),
+             ([leaf, sd(640, page=8)], "rows"),
+             ([(sd(1280, "int8"),) * 4, leaf], "rows"),
+             ([sd(640, "int8")], "rows"), ([select], "rows"), ([], "rows")]
+    try:
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            for pages, want in cases:
+                caplog.clear()
+                assert eng._decode_form(pages) == want, pages
+                said = [r.getMessage() for r in caplog.records]
+                # ... and only a model with such an entry is spoken of
+                assert len(said) == (want == "rows" and any(
+                    not isinstance(g, dict) for g in pages)), said
+                assert eng._decode_form(
+                    {"pages": pages, "lanes": []}) == want, pages
+            caplog.clear()
+            eng._decode_form([select, pair, sd(576)])
+            assert "pool entry 1 of 2 as it lies (bfloat16[9, 16, 576])" \
+                in caplog.records[0].getMessage()
+        assert eng._decode_form([leaf]) == "rows"           # the CPU
+    finally:
+        eng.stop()
+
+
 def test_the_programs_name_the_new_parts(built):
     from benchmark import harness, program_scopes
     from bigdl_tpu.serving import ContinuousBatchingEngine

@@ -1,6 +1,8 @@
 """``nn.LatentAttention``: the expanded chunk and the weight-absorbed decode
 step over ONE page leaf against the layer's whole-sequence form and against
-the plain reference's attention; the leaf's shape and what it holds."""
+the plain reference's attention; the step through the paged-attention kernel
+(the TPU interpreter) against its gathered rows form; the leaf's shape and
+what it holds."""
 
 import os
 import sys
@@ -128,11 +130,59 @@ def test_the_absorbed_step_agrees_with_the_expanded_chunk_on_one_cache(
     np.testing.assert_allclose(np.asarray(y)[1], want[1, 21], atol=5e-5)
 
 
+@pytest.mark.parametrize("dtype,atol", [("float32", 2e-5),
+                                        ("bfloat16", 4e-2)])
+def test_the_step_through_the_kernel_is_the_rows_form(layer, rows, dtype,
+                                                      atol):
+    """One pool of whole tiles (8 float32 or 16 bfloat16 tokens a page, rows
+    of 128), prefilled, then the absorbed step both ways at ragged
+    positions: ``"kernel"`` reads each row's pages from the leaf where they
+    lie (the rows at a page's edge, mid-page, at the table's last key, and
+    an idle row on the scratch page), the rows form gathers every table;
+    both write the same row and return the same leaf. Pages no row holds
+    are NaN, and the kernel's tables name one in every slot past a row's
+    position."""
+    page = 32 // jnp.dtype(dtype).itemsize
+    n = T // page
+    pos = np.asarray([T - 1, page - 1, page + 3, 0], np.int32)
+    tables = np.zeros((4, n), np.int32)
+    tables[:3] = 1 + np.arange(3 * n).reshape(3, n)
+    leaf = layer.init_page_pool(2 + 3 * n, page, jnp.dtype(dtype))
+    x = jnp.concatenate([rows, rows[:1] * 0.5], 0)            # three rows
+    _, leaf = layer.forward_chunk_paged(x, leaf, jnp.asarray(tables[:3]),
+                                        jnp.zeros((3,), jnp.int32))
+    leaf = leaf.at[-1].set(jnp.nan)
+    past = tables.copy()
+    for i, p in enumerate(pos):
+        past[i, p // page + 1:] = leaf.shape[0] - 1
+    x_t = jnp.asarray(np.random.default_rng(3).standard_normal((4, D)),
+                      jnp.float32)
+    want, leaf_rows = layer.forward_step_paged(
+        x_t, leaf, jnp.asarray(tables), jnp.asarray(pos))
+    got, leaf_kernel = layer.forward_step_paged(
+        x_t, leaf, jnp.asarray(past), jnp.asarray(pos),
+        decode_attention="kernel")
+    assert got.dtype == want.dtype and not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
+    np.testing.assert_array_equal(np.asarray(leaf_kernel, np.float32),
+                                  np.asarray(leaf_rows, np.float32))
+    with pytest.raises(ValueError, match="decode_attention"):
+        layer.forward_step_paged(x_t, leaf, jnp.asarray(tables),
+                                 jnp.asarray(pos), decode_attention="pages")
+
+
 def test_the_host_arithmetic_of_what_is_read(layer):
-    # the step gathers every slot of every table; the chunk walks to the
-    # furthest position of the dispatch in rounds of 256 keys
-    assert layer.step_read_counts(np.array([5, 900, 0]), 16, 64) == {
-        "kv_read_tokens": 3 * 1024, "kv_table_tokens": 3 * 1024}
+    # the gathered step reads every slot of every table, the kernel the
+    # pages up to each row's position (an idle row one page); the chunk
+    # walks to the furthest position of the dispatch in rounds of 256 keys
+    whole = {"kv_read_tokens": 3 * 1024, "kv_table_tokens": 3 * 1024}
+    pos = np.array([5, 900, 0])
+    assert layer.step_read_counts(pos, 16, 64) == whole
+    assert layer.step_read_counts(pos, 16, 64, "rows") == whole
+    assert layer.step_read_counts(pos, 16, 64, "kernel") == {
+        "kv_read_tokens": (1 + 57 + 1) * 16, "kv_table_tokens": 3 * 1024}
+    full = layer.step_read_counts(np.array([1023]), 16, 64, "kernel")
+    assert full["kv_read_tokens"] == full["kv_table_tokens"] == 1024
     assert layer.chunk_read_counts(np.array([0, 512]), 256, 16, 1024) == {
         "kv_read_tokens": 2 * 768, "kv_table_tokens": 2 * 16384}
 

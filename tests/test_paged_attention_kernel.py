@@ -1,17 +1,21 @@
 """The decode step's paged-attention kernel (``ops/paged_attention.py``)
-against the gathered form it replaces on a TPU (``_write_kv_paged(rows=True)``
-+ ``_attend_pages_rows``), in the TPU interpreter on the CPU, where memory no
-copy has filled reads as NaN.
+against the gathered forms it replaces on a TPU (the pair's
+``_write_kv_paged(rows=True)`` + ``_attend_pages_rows``; the one leaf's
+``_gather_pages`` + ``nn/latent_attention.py _attend_rows``), in the TPU
+interpreter on the CPU, where memory no copy has filled reads as NaN.
 
 One random pool a case at the served geometries cut down in pages (GPT-2
-Large's 20 heads of 64 = 1280 columns, Olmo's 30 heads of 128 = 3840, and a
-grouped 8 over 2), in bfloat16 and float32; seven lanes, each at a position
-that is an edge of something: an idle lane on the scratch page, one and two
-keys, a page's last and next first key, a round's last and next first key, the
-table's last key. The live pages are scattered over the pool and the two
-longest lanes share their first pages. Then the engine's side: which form it
-takes, the tokens it serves through the kernel, and the host arithmetic of what
-a step reads.
+Large's 20 heads of 64 = 1280 columns, Olmo's 30 heads of 128 = 3840, a
+grouped 8 over 2, and JoyAI-LLM-Flash's ONE leaf of 640-wide latent rows
+under 32 whole-row queries), in bfloat16 and float32; eight lanes, each at a
+position that is an edge of something: an idle lane on the scratch page
+first and last, one and two keys, a page's last and next first key, a round's
+last and next first key (a round is 128 keys for a pair and follows the
+leaf's width for the one leaf), the table's last key. The live pages are
+scattered over the pool, the two longest lanes share their first pages, and
+one page no lane holds is NaN. Then the engine's side: which form it takes,
+the tokens it serves through the kernel, and the host arithmetic of what a
+step reads.
 """
 
 import functools
@@ -24,61 +28,107 @@ import numpy as np
 import pytest
 
 from bigdl_tpu.nn import attention as A
+from bigdl_tpu.nn import latent_attention as L
 from bigdl_tpu.observability import trace
-from bigdl_tpu.ops.paged_attention import (BLOCK_TOKENS, block_pages,
-                                           paged_attention, supported)
+from bigdl_tpu.ops.paged_attention import (
+    BLOCK_TOKENS, ROUND_BUFFER_BYTES, ROUND_TOKENS_MAX, block_pages,
+    paged_attention, paged_latent_attention, row_block_pages, supported)
 
 PS = 16                    # tokens a page, as served
-TABLE = 10                 # pages a table: one whole round of 8 and a part
+#: name -> (query heads, kv heads, a head's columns); no kv heads: ONE leaf
+#: whose rows are keys and values at once, under whole-row queries
 GEOMETRY = {"gpt2-large": (20, 20, 64), "olmo-hybrid": (30, 30, 128),
-            "gqa-8-over-2": (8, 2, 128)}
-#: name -> the lane's last live position
-LANES = {"idle": 0, "two-keys": 1, "page-end": PS - 1, "page-start": PS,
-         "round-end": BLOCK_TOKENS - 1, "round-start": BLOCK_TOKENS,
-         "table-end": TABLE * PS - 1}
+            "gqa-8-over-2": (8, 2, 128), "joyai-latent": (32, None, 640)}
+LANES = ["idle", "two-keys", "page-end", "page-start", "round-end",
+         "round-start", "table-end", "idle-last"]
 TOLERANCE = {"float32": 2e-5, "bfloat16": 2e-2}
+LATENT_SCALE = 192 ** -0.5
+
+
+def _round_pages(geometry, dtype):
+    """Pages a round of the case's kernel holds (of a table long enough)."""
+    _, kv_heads, d = GEOMETRY[geometry]
+    if kv_heads is None:
+        return row_block_pages(
+            jax.ShapeDtypeStruct((1, PS, d), jnp.dtype(dtype)), 1 << 20)
+    return block_pages(PS, 1 << 20)
+
+
+def _positions(geometry, dtype):
+    """(each lane's last live position, pages a table): a table is one
+    whole round and two pages of the next."""
+    pages = _round_pages(geometry, dtype)
+    table = pages + 2
+    at = {"idle": 0, "two-keys": 1, "page-end": PS - 1, "page-start": PS,
+          "round-end": pages * PS - 1, "round-start": pages * PS,
+          "table-end": table * PS - 1, "idle-last": 0}
+    return np.asarray([at[name] for name in LANES], np.int32), table
 
 
 @functools.lru_cache(maxsize=None)
 def _case(geometry, dtype):
-    """(q, k_pages, v_pages, tables, pos, k_t, v_t): the pool with random
-    history in every page, the scratch page too, and the step's own token."""
+    """(q, the pool's leaves, tables, pos, the step's own token a leaf): the
+    pool with random history in every page, the scratch page too, but the
+    last page, which no lane holds and is NaN."""
     heads, kv_heads, d = GEOMETRY[geometry]
     rng = np.random.default_rng(sorted(GEOMETRY).index(geometry))
+    pos, table = _positions(geometry, dtype)
     lanes = len(LANES)
-    max_pages = 1 + lanes * TABLE
+    max_pages = 2 + sum(int(p) // PS + 1 for p in pos if p)
     dt = jnp.dtype(dtype)
     rand = lambda *s: jnp.asarray(rng.standard_normal(s), dt)
-    pos = np.asarray(list(LANES.values()), np.int32)
-    tables = np.zeros((lanes, TABLE), np.int32)
-    free = iter(rng.permutation(np.arange(1, max_pages)))
+    tables = np.zeros((lanes, table), np.int32)
+    # the round-end lane's pages follow one another in the pool (a prompt's
+    # pages, handed out together); every other live page is scattered
+    held = pos[LANES.index("round-end")] // PS + 1
+    tables[LANES.index("round-end"), :held] = 1 + np.arange(held)
+    free = iter(rng.permutation(np.arange(1 + held, max_pages - 1)))
     for lane, p in enumerate(pos):
-        if p:                              # the idle lane stays on scratch
+        if p and not tables[lane, 0]:      # the idle lanes stay on scratch
             for i in range(p // PS + 1):
                 tables[lane, i] = next(free)
-    # the table-end lane shares the round-start lane's first seven pages
+    # lanes share pages, as requests over one prefix do: the round-start
+    # lane's second eight with the lane before it, the table-end lane's
+    # first seven with the round-start lane
+    if held >= 16:
+        tables[5, 8:16] = tables[4, 8:16]
     tables[6, :7] = tables[5, :7]
-    return (rand(lanes, heads, d), rand(max_pages, PS, kv_heads * d),
-            rand(max_pages, PS, kv_heads * d), jnp.asarray(tables),
-            jnp.asarray(pos), rand(lanes, kv_heads, 1, d),
-            rand(lanes, kv_heads, 1, d))
+    cols = d if kv_heads is None else kv_heads * d
+    leaves = tuple(rand(max_pages, PS, cols).at[-1].set(jnp.nan)
+                   for _ in range(1 if kv_heads is None else 2))
+    # both idle lanes write the scratch page's first row: the same token
+    same = lambda a: a.at[-1].set(a[0])
+    if kv_heads is None:
+        new = (same(rand(lanes, 1, cols)),)
+    else:
+        new = tuple(same(rand(lanes, kv_heads, 1, d)) for _ in range(2))
+    return rand(lanes, heads, d), leaves, tables, pos, new
 
 
 @functools.lru_cache(maxsize=None)
 def _both(geometry, dtype, repoint=False):
     """(kernel's, gathered form's) outputs (lanes, H, D) as float32, behind
-    the same write. ``repoint``: every slot past a lane's last live page
-    names another lane's page (the next lane's first) in place of scratch."""
-    q, k, v, tables, pos, k_t, v_t = _case(geometry, dtype)
+    the same write. ``repoint``: the kernel's tables name the NaN page in
+    every slot past a lane's last live page, in place of scratch."""
+    q, leaves, tables, pos, new = _case(geometry, dtype)
+    kernel_tables = tables.copy()
     if repoint:
-        t = np.asarray(tables).copy()
-        for lane, p in enumerate(np.asarray(pos)):
-            t[lane, p // PS + 1:] = t[(lane + 1) % len(t), 0] or t[1, 0]
-        tables = jnp.asarray(t)
-    pool, k_rows, v_rows = A._write_kv_paged((k, v), k_t, v_t, tables, pos,
-                                             rows=True)
-    want = A._attend_pages_rows(q, k_rows, v_rows, pos)
-    got = paged_attention(q, *pool, tables, pos)
+        for lane, p in enumerate(pos):
+            kernel_tables[lane, p // PS + 1:] = len(leaves[0]) - 1
+    tables, kernel_tables, pos = (jnp.asarray(a) for a in (
+        tables, kernel_tables, pos))
+    if len(leaves) == 1:
+        leaf = L.LatentAttention._write(leaves[0], new[0], tables,
+                                        pos[:, None])
+        want = L._attend_rows(q, A._gather_pages(leaf, tables), pos,
+                              LATENT_SCALE).astype(leaf.dtype)
+        got = paged_latent_attention(q, leaf, kernel_tables, pos,
+                                     LATENT_SCALE)
+    else:
+        pool, k_rows, v_rows = A._write_kv_paged(leaves, *new, tables, pos,
+                                                 rows=True)
+        want = A._attend_pages_rows(q, k_rows, v_rows, pos)
+        got = paged_attention(q, *pool, kernel_tables, pos)
     assert got.shape == want.shape and got.dtype == want.dtype
     return np.asarray(got, np.float32), np.asarray(want, np.float32)
 
@@ -86,14 +136,14 @@ def _both(geometry, dtype, repoint=False):
 CASES = [(g, d) for g in GEOMETRY for d in ("bfloat16", "float32")]
 
 
-@pytest.mark.parametrize("lane", list(LANES))
+@pytest.mark.parametrize("lane", LANES)
 @pytest.mark.parametrize("geometry,dtype", CASES)
 def test_kernel_attends_what_the_gathered_form_attends(geometry, dtype, lane):
     """Each lane's output is the rows form's within what re-ordering the
     float32 sums costs, and no NaN: nothing the kernel multiplies is memory
     it has not filled."""
     got, want = _both(geometry, dtype)
-    i = list(LANES).index(lane)
+    i = LANES.index(lane)
     assert not np.isnan(got[i]).any()
     np.testing.assert_allclose(got[i], want[i], atol=TOLERANCE[dtype],
                                rtol=TOLERANCE[dtype])
@@ -101,9 +151,10 @@ def test_kernel_attends_what_the_gathered_form_attends(geometry, dtype, lane):
 
 @pytest.mark.parametrize("geometry,dtype", CASES)
 def test_nothing_past_a_lanes_position_reaches_the_result(geometry, dtype):
-    """With every slot past a lane's last live page re-pointed at another
-    lane's page the kernel's outputs are the same to the bit: pages past
-    ``pos`` are not fetched, keys past ``pos`` in the last page are masked."""
+    """With every slot past a lane's last live page re-pointed at a page of
+    NaN the kernel's outputs are the same to the bit: pages past ``pos`` are
+    not fetched (a fetched NaN under a probability of 0 would still be NaN),
+    keys past ``pos`` in the last page are masked."""
     got, _ = _both(geometry, dtype)
     moved, want = _both(geometry, dtype, repoint=True)
     np.testing.assert_array_equal(moved, got)
@@ -116,8 +167,31 @@ def test_a_round_is_whole_pages_and_no_longer_than_a_table():
     assert block_pages(16, 3) == 3 and block_pages(256, 4) == 1
 
 
+@pytest.mark.parametrize("cols,dtype,tokens", [
+    (640, "bfloat16", 1024), (640, "float32", 512), (1280, "bfloat16", 512),
+    (3840, "bfloat16", 256), (3840, "float32", 128), (16384, "float32", 128)])
+def test_a_one_leaf_round_follows_the_leafs_width(cols, dtype, tokens):
+    """The width of a one-leaf round is a function of the leaf alone: the
+    keys whose two buffers fit ``ROUND_BUFFER_BYTES``, a power of two times
+    the pair's 128 and at most ``ROUND_TOKENS_MAX``; whole pages, never
+    longer than a table. (A pair's round is 128 keys whatever its width.)"""
+    leaf = jax.ShapeDtypeStruct((9, PS, cols), jnp.dtype(dtype))
+    pages = row_block_pages(leaf, 1024)
+    assert pages * PS == tokens
+    assert BLOCK_TOKENS <= tokens <= ROUND_TOKENS_MAX
+    row = cols * jnp.dtype(dtype).itemsize
+    assert 2 * tokens * row <= ROUND_BUFFER_BYTES or tokens == BLOCK_TOKENS
+    # twice as wide would not fit, or would pass the cap
+    assert 4 * tokens * row > ROUND_BUFFER_BYTES \
+        or 2 * tokens > ROUND_TOKENS_MAX
+    assert row_block_pages(leaf, 5) == 5
+    assert row_block_pages(jax.ShapeDtypeStruct((9, 256, cols), leaf.dtype),
+                           1024) == max(1, tokens // 256)
+
+
 @pytest.mark.parametrize("shape,dtype,ok", [
     ((9, 16, 1280), "bfloat16", True), ((9, 16, 3840), "bfloat16", True),
+    ((9, 16, 640), "bfloat16", True), ((9, 16, 576), "bfloat16", False),
     ((9, 8, 256), "float32", True), ((9, 8, 256), "bfloat16", False),
     ((9, 4, 16), "float32", False), ((9, 16, 1280), "int8", False)])
 def test_kernel_reads_whole_tiles_of_floats_only(shape, dtype, ok):

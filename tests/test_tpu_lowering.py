@@ -8,6 +8,10 @@ hardware, so lowering breakage costs no chip time (compiling them for a
 described chip is tests/test_chip_compile.py). Flagship-shape exports + artifact hashes: scripts/tpu_export.py
 -> TPU_LOWERING.json."""
 
+import importlib.util
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -59,6 +63,45 @@ def test_paged_decode_step_lowers_for_tpu(form):
     else:
         assert "tpu_custom_call" not in mod
         assert views[0] in mod
+
+
+@pytest.mark.parametrize("shape", ["gpt2-large", "olmo-hybrid"])
+def test_the_pairs_decode_step_keeps_its_own_kernel(shape):
+    """The decode step of a model whose pools are K and V pairs, as an
+    engine builds it on one TPU chip (``gpt2l-chat-steady`` and
+    ``olmoh-docqa-steady`` run it): its one Mosaic kernel is
+    ``paged_attention`` with rounds of 128 keys in two buffers a leaf, and
+    nothing of the one-leaf form a latent layer's pool takes
+    (``paged_latent_attention``, wider rounds) is in it. Whether a build
+    lowers it to the program another build did is
+    ``scripts/mosaic_program_hash.py``'s to say (the raw text carries
+    source lines)."""
+    if shape == "gpt2-large":
+        fn, args = ep.paged_decode_step_program(lanes=8)
+        cols = 1280
+    else:
+        fn, args = ep.hybrid_decode_step_program(lanes=4, ctx=512)
+        cols = 3840
+    text = ep.lower_for_tpu(fn, args)
+    assert "latent" not in text
+    # one trace and one kernel for every layer of the model
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == ["paged_attention"]
+    assert text.count("stablehlo.custom_call @tpu_custom_call") == 1
+    # the kernel's own module, through jax's private MLIR bindings: a jax
+    # bump that moves them is no fault of the pair's program
+    spec = importlib.util.spec_from_file_location(
+        "mosaic_program_hash", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "scripts", "mosaic_program_hash.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    try:
+        (_, module), = tool.mosaic_kernels(text)[1]
+    except Exception as e:      # noqa: BLE001 (whatever the bindings raise)
+        pytest.skip(f"the Mosaic payload could not be parsed: {e!r}")
+    assert "loc(" not in module
+    buffers = set(re.findall(r"memref<2x(\d+)x%dxbf16" % cols, module))
+    assert buffers == {"128"}, buffers
 
 
 def test_ring_flash_composed_lowers_for_tpu():
